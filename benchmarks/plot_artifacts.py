@@ -213,73 +213,6 @@ def plot_baselines_converge(path, out_dir):
     return out
 
 
-def plot_fused_sweep(path, out_dir):
-    """Per-step kernel tuning grid (fused_sweep.json): steps/s vs w_window,
-    one line per block_d that compiled; the north star (5,000) and the
-    per-step MXU roofline (~5,500 on v5e, benchmarks/ROOFLINE.md) as
-    reference lines.  Entities are block sizes — fixed hues, direct-labeled
-    at the line ends so identity never rides on color alone."""
-    with open(path) as f:
-        d = json.load(f)
-    # the main grid plus any follow-up sweep rows recorded into the same
-    # artifact (r4 added followup_grid: larger windows + the 5120 hang);
-    # duplicate (block_d, w_window) keeps the first (main-grid) measurement
-    rows = list(d.get("grid", []))
-    rows += d.get("followup_grid", {}).get("grid", [])
-    ok: dict = {}
-    failed: set = set()
-    for g in rows:
-        if "steps_per_s" in g:
-            ok.setdefault((g["block_d"], g["w_window"]), g["steps_per_s"])
-        else:
-            failed.add(g["block_d"])
-    if not ok:
-        print(f"# no successful grid points in {path}", file=sys.stderr)
-        return None
-    by_bd: dict = {}
-    for (bd, w), v in ok.items():
-        by_bd.setdefault(bd, []).append((w, v))
-    failed_bd = sorted(failed - set(by_bd))
-    # fixed entity → hue (module design note: color follows identity, never
-    # rank — a rerun where one block size fails must not repaint the rest)
-    bd_hues = {2048: "#2a78d6", 4096: "#eb6834", 8192: "#1baf7a",
-               5120: "#eda100", 6144: "#e87ba4"}
-    fig, ax = plt.subplots(figsize=(6.8, 4.2), dpi=150)
-    for bd, pts in sorted(by_bd.items()):
-        pts.sort()
-        xs = [p[0] for p in pts]
-        ys = [p[1] for p in pts]
-        hue = bd_hues.get(bd, INK_2)
-        ax.plot(xs, ys, color=hue, linewidth=2, marker="o",
-                markersize=5, zorder=3, label=f"block_d {bd}")
-        ax.annotate(f"block_d {bd}", xy=(xs[-1], ys[-1]), xytext=(6, 0),
-                    textcoords="offset points", va="center",
-                    color=hue, fontsize=8)
-    for yval, name in ((5000.0, "north star 5,000"),
-                       (5500.0, "per-step roofline ~5,500")):
-        ax.axhline(yval, color=INK_2, linewidth=1, linestyle=(0, (4, 3)),
-                   zorder=2)
-        ax.annotate(name, xy=(1, yval), xytext=(2, 4),
-                    textcoords="offset points", color=INK_2, fontsize=8)
-    if failed_bd:
-        ax.annotate("no line (compile failure): block_d " +
-                    ", ".join(str(b) for b in failed_bd),
-                    xy=(0.98, 0.04), xycoords="axes fraction", ha="right",
-                    color=INK_2, fontsize=8)
-    ax.set_xscale("log", base=2)
-    ax.set_xticks(sorted({p[0] for pts in by_bd.values() for p in pts}))
-    ax.get_xaxis().set_major_formatter(matplotlib.ticker.ScalarFormatter())
-    dev = d.get("device_kind", "")
-    _style(ax, f"Fused per-step kernel sweep — gossip-steps/s ({dev})",
-           "w_window (W_t per grid visit)", "gossip-steps/s")
-    ax.legend(frameon=False, fontsize=8, labelcolor=INK_2, loc="lower left")
-    out = os.path.join(out_dir, "fused_sweep.png")
-    fig.tight_layout()
-    fig.savefig(out)
-    plt.close(fig)
-    return out
-
-
 def plot_run_dir(run_dir, out_dir):
     """Plot a Recorder output dir — the reference's per-rank series naming
     (util.py:410-416): ``*-tacc.log`` test accuracy, ``*-losses.log`` train
@@ -315,8 +248,6 @@ def main():
     p.add_argument("--tta", default=os.path.join(here, "time_to_acc.json"))
     p.add_argument("--converge",
                    default=os.path.join(here, "baselines_converge.jsonl"))
-    p.add_argument("--fused-sweep",
-                   default=os.path.join(here, "fused_sweep.json"))
     p.add_argument("--run-dir", default=None,
                    help="a Recorder output dir to plot instead of the artifacts")
     p.add_argument("--out-dir", default=os.path.join(here, "plots"))
@@ -333,10 +264,6 @@ def main():
             outs.append(plot_time_to_acc(args.tta, args.out_dir))
         if os.path.exists(args.converge):
             out = plot_baselines_converge(args.converge, args.out_dir)
-            if out:
-                outs.append(out)
-        if os.path.exists(args.fused_sweep):
-            out = plot_fused_sweep(args.fused_sweep, args.out_dir)
             if out:
                 outs.append(out)
     for o in outs:

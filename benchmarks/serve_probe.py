@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""serve_r8: live-window evidence for the production run controller.
+"""serve_r8: on-device evidence for the production run controller.
 
 One supervised saved run (DESIGN.md §22) on whatever backend the window
 exposes: promotion every epoch behind the signed manifest, a budget
@@ -120,7 +120,7 @@ def main(argv=None) -> int:
           and swap_applied and not retraces)
 
     lines = [
-        f"# serve_r{args.round}: supervised run controller, live window",
+        f"# serve_r{args.round}: supervised run controller",
         "",
         f"- verdict: {'OK' if ok else 'FAILED'} (daemon exit {rc}, "
         f"lifetimes {controller.lifetimes}, "

@@ -36,7 +36,7 @@ Verify committed schedule/plan artifacts numerically (planlint)::
     python lint_tpu.py lint-plan                # scans benchmarks/
     python lint_tpu.py lint-plan my_plan.json
 
-JSON artifact for a live session (benchmarks/tpu_session.sh records one)::
+JSON artifact (the stamp ``tests/test_docs_artifacts.py`` pins)::
 
     python lint_tpu.py --format json > benchmarks/lint_stamp.json
 

@@ -228,12 +228,10 @@ def make_choco(
     if mesh is None:
         raise ValueError("shard_map backend needs a mesh")
 
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..parallel import WORKER_AXIS, build_folded_plan
-    from ..parallel.gossip import import_shard_map
-
-    shard_map = import_shard_map()
 
     axis = WORKER_AXIS
     C = mesh.shape[axis]

@@ -93,18 +93,20 @@ def make_decen(
                           (VMEM-resident state, streamed W_t stack) for whole
                           flag streams — the bench configuration.
       * ``"perm"``      — the permutation-form Pallas kernel for *every*
-                          phase: each step is M static-involution row
-                          gathers + weighted adds on a VMEM-resident state
-                          block, streaming only the ``[T, M]`` flag array
-                          from HBM (~2000× less than the fused W stack at
-                          N=256; the only representable form at 10k+
-                          workers).  Alive masks compose in-kernel
+                          phase: each step is per-row partner copies +
+                          weighted adds on a VMEM-resident state block,
+                          reading only the ``[T, M]`` flag array (SMEM;
+                          ~2000× less than the fused W stack at N=256).
+                          Its resident blocks fit the scoped VMEM up to
+                          ~5,400 workers.  Alive masks compose in-kernel
                           (per-edge ``alive_i·alive_{π_j(i)}`` gates), so
                           masked chains keep the fused launch
                           (``multi_step_masked``); bf16 wire rides the
                           ``resolve_wire_dtype`` seam with f32
-                          accumulation; interpret mode makes the whole
-                          backend exact on the CPU tier-1 mesh.
+                          accumulation.  Both Pallas backends compile for
+                          the device on every platform but ``cpu``, where
+                          they run under the Pallas interpreter (the
+                          tier-1 mesh) — an accelerator never interprets.
       * ``"gather"``    — per-matching static gathers (any N under jit).
       * ``"skip"``      — per-matching ``lax.cond``: inactive matchings are
                           not executed, so the MATCHA budget buys back real
@@ -112,8 +114,8 @@ def make_decen(
                           With a mesh this is the folded shard_map plan with
                           the *collectives* inside the conds (the DCN story);
                           single-array otherwise, where the saving is
-                          bounded by the cond identity-copy — measured
-                          honestly in benchmarks/skip_microbench.json.
+                          bounded by the cond identity-copy
+                          (benchmarks/skip_microbench.py measures it).
                           Masked backends spend the same time at every
                           budget.
       * ``"shard_map"`` — explicit ppermute plan over ``mesh`` (worker-sharded,
@@ -135,11 +137,13 @@ def make_decen(
     materialized, so keep the default 1 for training loops that interleave
     gossip with SGD; raise it for consensus-only chains and the bench.
 
-    ``block_d`` (fused backend only): the Pallas kernel's resident D-block
-    size; None keeps :func:`fused_gossip_run`'s default.  Per-step W-stream
+    ``block_d`` (fused/perm backends): the Pallas kernel's resident D-block
+    size; None keeps the kernel's default.  For fused, per-step W-stream
     traffic is ``ceil(D/block_d)·N²``, so bigger blocks cut HBM traffic
-    linearly until the [N, block_d] in+out blocks stop fitting VMEM
-    (~16 MB/core: 8192 is the practical max at N=256 bf16).
+    linearly until the [N, block_d] in+out blocks stop fitting the 16 MiB
+    scoped VMEM — a request that cannot fit raises
+    :class:`~matcha_tpu.parallel.GossipKernelResourceError` here, at build
+    time (f32 state at N=256: 2048 fits, 4096 does not).
 
     ``w_window`` (fused backend only): consecutive ``W_t`` per D-block grid
     visit.  Unlike ``chunk`` this keeps the exact per-step arithmetic (every
@@ -160,6 +164,7 @@ def make_decen(
     perms = np.asarray(schedule.perms)
     alpha = float(schedule.alpha)
     wire = resolve_wire_dtype(wire_dtype)
+    state_itemsize = jnp.dtype(compute_dtype).itemsize
     if wire is not None and jnp.dtype(compute_dtype).itemsize >= 4:
         # the dense/fused matmul *is* the exchange: its operand pass in the
         # wire dtype (f32 accumulate) is exactly the bf16-wire semantics
@@ -169,18 +174,19 @@ def make_decen(
         backend = resolve_gossip_backend(schedule, mesh,
                                          wire_dtype=wire_dtype)["chosen"]
 
-    if backend not in ("fused", "perm") \
-            and (block_d is not None or w_window != 1):
+    if (backend not in ("fused", "perm") and block_d is not None) \
+            or (backend != "fused" and w_window != 1):
         import warnings
 
         warnings.warn(
-            f"block_d/w_window tune the fused/perm backends' Pallas "
-            f"kernels; backend '{backend}' ignores them. Note the fused "
-            f"kernel runs multi-step *chains* (Communicator.run / the "
-            f"comm-split timer) — the per-step training mix is a single "
-            f"dense matmul either way.",
+            f"block_d tunes the fused/perm backends' Pallas kernels and "
+            f"w_window the fused one; backend '{backend}' ignores them. "
+            f"Note the fused kernel runs multi-step *chains* "
+            f"(Communicator.run / the comm-split timer) — the per-step "
+            f"training mix is a single dense matmul either way.",
             stacklevel=2,
         )
+
 
     multi_step = None
     multi_step_masked = None
@@ -213,14 +219,21 @@ def make_decen(
             compose_mixing_stack,
             fused_gossip_run,
         )
+        from ..parallel.pallas_gossip import check_fused_fits, pallas_interpret
 
         mix = dense_gossip_fn(schedule.laplacians(), compute_dtype=compute_dtype)
         laplacians = schedule.laplacians()
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
 
         kernel_kwargs = {} if block_d is None else {"block_d": block_d}
         if w_window > 1:
             kernel_kwargs["w_window"] = w_window
+        # fail here, by name, rather than in Mosaic's allocator at the
+        # first chain: the state rides in the caller's compute_dtype when
+        # that is narrower than f32 (the bench), else f32 (training)
+        check_fused_fits(perms.shape[1], state_itemsize=state_itemsize,
+                         stack_itemsize=jnp.dtype(compute_dtype).itemsize,
+                         **kernel_kwargs)
 
         def multi_step(flat, carry, flags):
             stack = build_mixing_stack(
@@ -233,14 +246,11 @@ def make_decen(
 
     elif backend == "perm":
         from ..parallel import involution_tables, perm_gossip_run
+        from ..parallel.pallas_gossip import pallas_interpret
 
         perms_i32, partnered = involution_tables(perms)
-        interpret = jax.default_backend() != "tpu"
-        kernel_kwargs = {"wire_dtype": wire_dtype, "interpret": interpret}
-        if block_d is not None:
-            kernel_kwargs["block_d"] = block_d
-        if w_window > 1:
-            kernel_kwargs["w_window"] = w_window
+        kernel_kwargs = {"wire_dtype": wire_dtype, "block_d": block_d,
+                         "interpret": pallas_interpret()}
 
         # ONE kernel for every phase: the per-step training mix is the same
         # program at T=1 (`mix` receives the already-α-scaled weight row —

@@ -43,8 +43,8 @@ from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
-__all__ = ["ChipSpec", "CHIP_PEAKS", "CPU_PROVISIONAL", "chip_peaks",
-           "resolve_chip", "abstract_args", "program_fingerprint",
+__all__ = ["ChipSpec", "CHIP_PEAKS", "CPU_PROVISIONAL", "UnknownChipError",
+           "chip_peaks", "resolve_chip", "abstract_args", "program_fingerprint",
            "analyze_program", "CostLedger", "Roofline", "gossip_step_costs",
            "gossip_chain_costs", "elision_epoch_costs", "flat_param_dim",
            "roofline_report",
@@ -80,50 +80,54 @@ CHIP_PEAKS: Dict[str, ChipSpec] = {
     "v2": ChipSpec(45.0, 700.0, 16.0),
 }
 
-#: The CPU-provisional row: this container's benches all fell back to a
-#: 1-core CPU (BENCH_r01–r05), so the roofline must still produce *finite*
-#: ceilings there — these are order-of-magnitude placeholders for one
-#: server core (AVX f32 matmul, DDR stream), flagged provisional in every
-#: report so they can never be read as a hardware claim.
+#: The CPU row: the roofline's *relative* arithmetic (which bound binds,
+#: fused-vs-perm byte ratios) must still produce finite ceilings on the
+#: CPU test host — order-of-magnitude placeholders for one server core
+#: (AVX f32 matmul, DDR stream), flagged provisional in every report so
+#: they can never be read as a hardware claim.  Returned only when the
+#: platform *is* ``cpu`` or ``chip="cpu"`` was asked for.
 CPU_PROVISIONAL = ChipSpec(0.1, 20.0, 64.0, provisional=True)
 
 
+class UnknownChipError(ValueError):
+    """A device kind that is not in :data:`CHIP_PEAKS` — an error, never a
+    default: a utilization against the wrong peaks is a wrong number."""
+
+
+def _lookup_chip(kind: str):
+    key = kind.lower().replace(" ", "")
+    for name, spec in CHIP_PEAKS.items():
+        if name in key:
+            return name, spec
+    raise UnknownChipError(
+        f"unknown chip: device kind {kind!r} is not in obs.costs.CHIP_PEAKS "
+        f"({sorted(CHIP_PEAKS)}); add its published peaks with their "
+        f"source before measuring on it")
+
+
 def chip_peaks(device_kind: str):
-    """``(peak_tflops, peak_gbps)`` for a device kind, ``(None, None)`` when
-    unknown — the historical ``bench.py`` contract (a CPU provisional bench
-    record deliberately carries no MFU)."""
-    kind = device_kind.lower().replace(" ", "")
-    for key, spec in CHIP_PEAKS.items():
-        if key in kind:
-            return spec.peak_tflops, spec.peak_gbps
-    return None, None
+    """``(peak_tflops, peak_gbps)`` for a device kind;
+    :class:`UnknownChipError` when the table does not have it."""
+    _, spec = _lookup_chip(device_kind)
+    return spec.peak_tflops, spec.peak_gbps
 
 
 def resolve_chip(chip: Optional[str] = None):
     """``(name, ChipSpec)`` for a chip override or the current backend.
 
-    ``chip`` may name a table key (``"v5e"``) or be None — then the first
-    jax device's kind is matched, falling back to the CPU-provisional row
-    (the roofline must answer on this repo's 1-core fallback host)."""
+    ``chip`` may name a table key (``"v5e"``) or ``"cpu"``; None matches
+    the first jax device: its table row on an accelerator
+    (:class:`UnknownChipError` if it has none), the CPU row on the CPU."""
     if chip is not None:
-        key = chip.lower().replace(" ", "")
-        for name, spec in CHIP_PEAKS.items():
-            if name in key:
-                return name, spec
-        if "cpu" in key:
+        if "cpu" in chip.lower():
             return "cpu-provisional", CPU_PROVISIONAL
-        raise ValueError(f"unknown chip {chip!r}; have "
-                         f"{sorted(CHIP_PEAKS)} or 'cpu'")
+        return _lookup_chip(chip)
     import jax
 
-    kind = jax.devices()[0].device_kind
-    tflops, _ = chip_peaks(kind)
-    if tflops is not None:
-        key = kind.lower().replace(" ", "")
-        for name, spec in CHIP_PEAKS.items():
-            if name in key:
-                return name, spec
-    return "cpu-provisional", CPU_PROVISIONAL
+    device = jax.devices()[0]
+    if device.platform == "cpu":
+        return "cpu-provisional", CPU_PROVISIONAL
+    return _lookup_chip(device.device_kind)
 
 
 # ---------------------------------------------------------------------------
@@ -175,16 +179,6 @@ def program_fingerprint(label: str, spec_args) -> str:
     return h.hexdigest()[:12]
 
 
-def _merge_cost_analysis(raw) -> Dict[str, float]:
-    """``Compiled.cost_analysis()`` returns a dict (or a 1-elem list of
-    dicts, per jax version); normalize to one flat dict."""
-    if raw is None:
-        return {}
-    if isinstance(raw, (list, tuple)):
-        raw = raw[0] if raw else {}
-    return dict(raw)
-
-
 def analyze_program(fn: Callable, *args, label: str = "program") -> Dict:
     """Lower + compile ``fn`` against abstract twins of ``args`` and read
     the compiled executable's own cost/memory analysis.
@@ -215,7 +209,7 @@ def analyze_program(fn: Callable, *args, label: str = "program") -> Dict:
                         f"wrapped callable")
     compiled = lowered.compile()
     compile_seconds = time.time() - t0
-    ca = _merge_cost_analysis(compiled.cost_analysis())
+    ca = compiled.cost_analysis() or {}
     ma = compiled.memory_analysis()
     arg_b = float(getattr(ma, "argument_size_in_bytes", 0) or 0)
     out_b = float(getattr(ma, "output_size_in_bytes", 0) or 0)
@@ -355,16 +349,15 @@ def gossip_step_costs(n: int, dim: int, decomposed: Sequence[Sequence[tuple]],
 
 def gossip_chain_costs(n: int, dim: int, decomposed,
                        backend: str = "fused", wire_dtype: str = "bf16",
-                       t_steps: int = 200, block_d: int = 2048,
-                       dbuf: bool = True) -> Dict:
+                       t_steps: int = 200, block_d: int = 2048) -> Dict:
     """Extracted per-step costs of a T-step *chain* program — the fused
     W-stack kernel or the permutation-form flag-stream kernel, amortized
     over its ``t_steps`` (the regime both kernels exist for: the state is
     read and written once per chain, and only the streamed operand — W
     stack vs flag array — scales with T).
 
-    Compiled abstractly (``.lower().compile()``, interpret mode off-TPU —
-    the same program text tier-1 tests execute): ``hbm_bytes`` is the
+    Compiled abstractly (``.lower().compile()``; interpret mode on the CPU
+    only — the same program text tier-1 tests execute): ``hbm_bytes`` is the
     program-boundary argument+output traffic, so the fused chain's bytes
     carry the ``[T, N, N]`` stack and the perm chain's carry the ``[T, M]``
     weights + the two ``[M, N]`` tables — the flag-stream-vs-W-stack
@@ -394,7 +387,9 @@ def gossip_chain_costs(n: int, dim: int, decomposed,
     wire = resolve_wire_dtype(None if wire_dtype == "f32" else wire_dtype)
     wire_bytes = 4 if wire is None else jnp.dtype(wire).itemsize
     state_dtype = jnp.float32 if wire is None else wire
-    interpret = jax.default_backend() != "tpu"
+    from ..parallel.pallas_gossip import pallas_interpret
+
+    interpret = pallas_interpret()
     m = len(decomposed)
     x = jax.ShapeDtypeStruct((n, dim), state_dtype)
     if backend == "fused":
@@ -422,12 +417,9 @@ def gossip_chain_costs(n: int, dim: int, decomposed,
         # the lambda's table params shadow the validated pi/pr on purpose:
         # they are exactly what analyze_program passes, and the GL101 seam
         # check resolves the names to the involution_tables binding above
-        # dbuf toggles the kernel's DMA schedule only (manual double-
-        # buffered window copies vs streamed BlockSpec) — ci/lint.sh pins
-        # that every byte figure here is invariant to it
         fn = jax.jit(lambda xx, ww, pi, pr: perm_gossip_run(
             xx, ww, pi, pr, block_d=block_d, wire_dtype=wd,
-            interpret=interpret, dbuf=dbuf))
+            interpret=interpret))
         costs = analyze_program(
             fn, x, w, pi, pr, label=f"gossip_chain_perm_{wire_dtype}")
         # boundary stream: M·4 of flag row per step + the two [M, N]

@@ -323,9 +323,9 @@ def compare_sources(sources: Sequence[str]) -> Tuple[List[Dict], List[str]]:
                         "mfu": None,
                     })
                     continue
-                # unwrap the known capture formats: bench_live_r*.json
-                # ({"record": ...}) and the driver's BENCH_r*.json
-                # ({"parsed": ...} with the raw line in "tail")
+                # unwrap the known capture formats: a {"record": ...}
+                # wrapper and the driver's BENCH_r*.json ({"parsed": ...}
+                # with the raw line in "tail")
                 rec = rec.get("record", rec)
                 rec = rec.get("parsed") or rec
                 if "value" not in rec and isinstance(rec.get("tail"), str):

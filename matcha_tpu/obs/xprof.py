@@ -25,7 +25,7 @@ Loud limitation (tested): a CPU trace carries only host lanes — there are
 no device rows to attribute, so the parser raises :class:`TraceParseError`
 instead of reporting a fake 0% overlap.  Overlap truth is a hardware
 measurement; the committed miniature fixtures pin the parser's arithmetic,
-the live capture is queued in ``benchmarks/tpu_session.sh``.
+and no capture from the chip has been reduced yet.
 """
 
 from __future__ import annotations
@@ -156,7 +156,7 @@ def overlap_report(events: Sequence[dict], source: str = "trace") -> Dict:
             f"{source}: trace contains no device rows (processes: "
             f"{hosts or 'none'}) — a CPU capture carries only host lanes, "
             f"so the comm/comp overlap cannot be measured from it; capture "
-            f"on a TPU/GPU backend (benchmarks/tpu_session.sh profile_r6)")
+            f"on the chip (train_tpu.py --trace-dir)")
     spans: Dict[str, List[Tuple[float, float]]] = {
         "comm": [], "comp": [], "other": []}
     counts: Dict[str, int] = {"comm": 0, "comp": 0, "other": 0}

@@ -18,7 +18,14 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 WORKER_AXIS = "workers"
 
-__all__ = ["WORKER_AXIS", "worker_mesh", "shard_workers", "replicated", "fold_dims"]
+__all__ = ["WORKER_AXIS", "WorkerFoldError", "worker_mesh", "shard_workers",
+           "replicated", "fold_dims"]
+
+
+class WorkerFoldError(ValueError):
+    """The workers cannot be laid out on the devices asked for: more
+    devices requested than exist, or a worker count the mesh does not
+    divide.  Never resolved by quietly using fewer chips."""
 
 
 def worker_mesh(
@@ -30,7 +37,8 @@ def worker_mesh(
     devs = list(devices if devices is not None else jax.devices())
     if num_devices is not None:
         if num_devices > len(devs):
-            raise ValueError(f"asked for {num_devices} devices, have {len(devs)}")
+            raise WorkerFoldError(
+                f"asked for {num_devices} devices, have {len(devs)}")
         devs = devs[:num_devices]
     return Mesh(np.array(devs), (axis,))
 
@@ -39,8 +47,10 @@ def fold_dims(num_workers: int, mesh: Mesh, axis: str = WORKER_AXIS) -> tuple[in
     """``(C, L)``: chips and workers-per-chip for folding N workers onto the mesh."""
     C = mesh.shape[axis]
     if num_workers % C:
-        raise ValueError(
-            f"num_workers={num_workers} must be divisible by mesh axis size {C}"
+        raise WorkerFoldError(
+            f"num_workers={num_workers} must be divisible by mesh axis size "
+            f"{C}: pass devices=<a divisor of {num_workers}> (1 keeps the "
+            f"whole fleet on one chip) or change the worker count"
         )
     return C, num_workers // C
 

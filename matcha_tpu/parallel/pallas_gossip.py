@@ -31,24 +31,29 @@ sum of **static involutions**: per row,
     (W_t x)_i = x_i + Σ_j α·flag[t,j]·(x_{π_j(i)} − x_i)
 
 with the ``π_j`` trace-time constants (fixed points map to themselves, so
-their delta is exactly zero).  ``perm_gossip_run`` applies each step as M
-in-VMEM row gathers + weighted adds on the VPU and streams only the
-``[T, M]`` weight array from HBM — ~``N²·wire_bytes / (M·4)`` ≈ 2,000×
-less per-step traffic than the W stack at the north-star shape
-(``benchmarks/perm_probe.py`` measured the hardware question; this is the
-production form it graduated into).  It is also the only representable
-form in the 10k+-virtual-worker regime, where an ``[N, N]`` matrix —
-never mind a ``[T, N, N]`` stack — does not fit anything.
+their delta is exactly zero).  ``perm_gossip_run`` applies each step as
+per-row partner copies + weighted adds on the VPU and reads only the
+``[T, M]`` weight array — ~``N²·wire_bytes / (M·4)`` ≈ 2,000× less
+per-step traffic than the W stack at the north-star shape.  The weights,
+the involution tables and the edge gates are scalars to the kernel and
+sit in SMEM; the partner of row ``i`` is a dynamic single-row load indexed
+by a scalar read, which is the gather form the TPU compiler accepts.
 
 Contracts (all pinned by ``tests/test_perm_backend.py``): f32-exact parity
 with the :func:`~matcha_tpu.parallel.gossip.gossip_mix` gather oracle,
 alive-mask composition through per-edge ``alive_i·alive_{π_j(i)}`` gates
 (realized mixing stays doubly stochastic over survivors), bf16 wire with
-f32 accumulation via the ``resolve_wire_dtype`` seam, and an
-``interpret=True`` path so the whole backend runs on the CPU tier-1 mesh.
+f32 accumulation via the ``resolve_wire_dtype`` seam, an
+``interpret=True`` path so the whole backend runs on the CPU tier-1 mesh,
+and a TPU cross-lowering of both kernels at the train shapes.
 Involution tables enter through exactly one seam —
 :func:`involution_tables` — which validates ``π∘π = id`` at build time
 (the runtime half of the GL101 static proof).
+
+Both kernels size their resident blocks against the chip's on-core
+memories before Mosaic sees them: a block that cannot fit raises
+:class:`GossipKernelResourceError` naming the shape, instead of a
+compiler allocation dump.
 """
 
 from __future__ import annotations
@@ -65,13 +70,65 @@ from jax.experimental.pallas import tpu as pltpu
 from .gossip import mxu_precision, resolve_wire_dtype
 
 __all__ = [
+    "GossipKernelResourceError",
     "build_mixing_stack",
     "canonical_chunk",
     "compose_mixing_stack",
     "fused_gossip_run",
+    "check_fused_fits",
     "involution_tables",
+    "pallas_interpret",
     "perm_gossip_run",
 ]
+
+
+#: Mosaic's default scoped-VMEM limit per kernel on the v5e (the compiler's
+#: own message: "limit 16.00M"); every resident block of a kernel here,
+#: pipeline double-buffers included, must fit under it.
+SCOPED_VMEM_BYTES = 16 * 2 ** 20
+
+#: SMEM the perm kernel lets its scalar operands take: the v5e has 1 MiB
+#: (compiler message: "Used 1.04M of 1.00M smem"), and a quarter stays free
+#: for Mosaic's own scalars.
+_PERM_SMEM_BYTES = 768 * 2 ** 10
+
+
+class GossipKernelResourceError(ValueError):
+    """A Pallas gossip kernel's resident blocks exceed the chip's VMEM or
+    SMEM at the requested shape — raised at trace time, before Mosaic."""
+
+
+def _check_vmem(kernel: str, need_bytes: int, what: str) -> None:
+    if need_bytes > SCOPED_VMEM_BYTES:
+        raise GossipKernelResourceError(
+            f"{kernel} kernel: {what} keeps {need_bytes / 2 ** 20:.1f} MiB "
+            f"resident in VMEM, over the {SCOPED_VMEM_BYTES / 2 ** 20:.0f} "
+            f"MiB scoped limit — ask for a smaller block")
+
+
+def pallas_interpret() -> bool:
+    """THE rule for ``interpret=``: the Pallas interpreter runs on the
+    ``cpu`` platform and nowhere else — on any accelerator the kernels
+    compile for the device or raise, never quietly interpret."""
+    return jax.default_backend() == "cpu"
+
+
+#: default resident D-block width of both kernels
+_BLOCK_D = 2048
+
+
+def check_fused_fits(n: int, *, block_d: int = _BLOCK_D, w_window: int = 1,
+                     state_itemsize: int, stack_itemsize: int) -> None:
+    """Raise :class:`GossipKernelResourceError` unless
+    :func:`fused_gossip_run`'s resident VMEM fits: the ``[N, block_d]`` in
+    and out blocks and the ``[w_window, N, N]`` W window, each
+    double-buffered by the Pallas pipeline.  Slightly conservative at the
+    edge (Mosaic accepts bf16 N=256 block_d=8192, 16.25 MiB by this
+    count) — the point is a named refusal before the allocator's dump."""
+    _check_vmem("fused",
+                4 * n * block_d * state_itemsize
+                + 2 * w_window * n * n * stack_itemsize,
+                f"N={n} rows x block_d={block_d}, w_window={w_window}")
 
 
 def build_mixing_stack(
@@ -183,7 +240,7 @@ def fused_gossip_run(
     x: jax.Array,
     mixing_stack: jax.Array,
     *,
-    block_d: int = 2048,
+    block_d: int = _BLOCK_D,
     w_window: int = 1,
     interpret: bool = False,
 ) -> jax.Array:
@@ -223,6 +280,9 @@ def fused_gossip_run(
         eye = jnp.broadcast_to(
             jnp.eye(n, dtype=mixing_stack.dtype), (pad, n, n))
         mixing_stack = jnp.concatenate([eye, mixing_stack])
+    check_fused_fits(n, block_d=block_d, w_window=w_window,
+                     state_itemsize=x.dtype.itemsize,
+                     stack_itemsize=mixing_stack.dtype.itemsize)
     grid = (pl.cdiv(d, block_d), (t_steps + pad) // w_window)
     return pl.pallas_call(
         _make_kernel(w_window, mxu_precision(mixing_stack.dtype)),
@@ -278,114 +338,61 @@ def involution_tables(perms) -> tuple[np.ndarray, np.ndarray]:
     return p.astype(np.int32), (p != rows[None, :]).astype(np.float32)
 
 
-def _make_perm_kernel(w_window: int, num_matchings: int, wire):
-    """Kernel body: one VMEM-resident state block × a window of steps.
+def _make_perm_kernel(t_steps: int, num_matchings: int, n: int, wire):
+    """Kernel body: one VMEM-resident state block × the whole flag stream.
 
-    Per step ``k`` of the window, with ``w = w_ref[k]`` the α-scaled flag
-    row: quantize the resident block to the wire dtype once, then for every
-    matching gather the partner rows (``pi_ref[j]`` is a static involution,
-    so the gather IS the exchange) and accumulate
-    ``w_j · gate_j · (x[π_j] − x)`` in f32.  The accumulation order and the
-    per-edge gate algebra replicate ``gossip_mix`` exactly, so the f32 path
-    is bitwise the gather oracle (tests pin it); fixed points contribute a
-    delta of exactly zero, which is why no degree bookkeeping appears.
+    Scalars live where the TPU keeps scalars: the α-scaled flag rows, the
+    involution tables and the per-slot edge gates are flat SMEM arrays, and
+    each matching's exchange is a per-row copy ``xw[π_j(i)]`` whose source
+    row is a scalar read — the form Mosaic lowers (a vector ``jnp.take``
+    row gather is refused by its gather rule).  Per step: quantize the
+    resident block to the wire dtype once into ``xw_ref``, then for every
+    row accumulate ``w_j · gate_j[i] · (xw[π_j(i)] − xw[i])`` over the
+    matchings in f32, in ``gossip_mix``'s order, so the f32 path is bitwise
+    the gather oracle (tests pin it); fixed points contribute a delta of
+    exactly zero, which is why no degree bookkeeping appears.
     """
 
-    def _kernel(x_ref, w_ref, pi_ref, gate_ref, o_ref):
-        t = pl.program_id(1)
+    def _kernel(w_ref, pi_ref, gate_ref, x_ref, o_ref, xw_ref, acc_ref):
+        o_ref[...] = x_ref[...]
 
-        @pl.when(t == 0)
-        def _():
-            o_ref[...] = x_ref[...]
+        def step(t, carry):
+            cur = o_ref[...]
+            curf = cur.astype(jnp.float32)
+            # wire image: quantized ONCE per step, read by both endpoints
+            # of every edge — edge-pairwise cancellation (exact worker-mean
+            # preservation) survives the narrow wire, same proof as
+            # gossip_mix.  f32 wire keeps the state untouched.
+            xw_ref[...] = (curf if wire is None
+                           else cur.astype(wire).astype(jnp.float32))
 
-        w_win = w_ref[...]  # [w_window, M] — one tiny read per visit
-        _perm_window_body(o_ref, w_win, pi_ref, gate_ref, w_window,
-                          num_matchings, wire)
+            def row(i, carry):
+                xi = xw_ref[pl.ds(i, 1), :]
+                acc = jnp.zeros_like(xi)
+                # python unroll over matchings, like gossip_mix: the add
+                # chain is then the same expression the oracle compiles
+                for j in range(num_matchings):
+                    k = j * n + i
+                    partner = xw_ref[pl.ds(pi_ref[k], 1), :]
+                    # graftlint: disable=GL001 — weights, not values: the
+                    # 0/1 edge gate scales this edge's *weight*; non-finite
+                    # rows are sealed upstream (gossip_quarantined)
+                    acc = acc + (w_ref[t * num_matchings + j]
+                                 * gate_ref[k]) * (partner - xi)
+                acc_ref[pl.ds(i, 1), :] = acc
+                return carry
 
-    return _kernel
+            jax.lax.fori_loop(0, n, row, 0)
+            o_ref[...] = (curf + acc_ref[...]).astype(o_ref.dtype)
+            return carry
 
-
-def _perm_window_body(o_ref, w_win, pi_ref, gate_ref, w_window,
-                      num_matchings, wire):
-    """The shared per-window step loop of both perm kernels — ``w_win``
-    (``[w_window, M]``) is the only thing the buffering strategy changes,
-    so factoring the arithmetic out is what makes the double-buffered
-    kernel *bitwise* the streamed one by construction."""
-
-    def step(k, carry):
-        cur = o_ref[...]
-        curf = cur.astype(jnp.float32)
-        # wire image: quantized ONCE per step, read by both gather
-        # endpoints — edge-pairwise cancellation (exact worker-mean
-        # preservation) survives the narrow wire, same proof as
-        # gossip_mix.  f32 wire keeps the state untouched.
-        xw = curf if wire is None else cur.astype(wire).astype(jnp.float32)
-        acc = jnp.zeros_like(curf)
-        for j in range(num_matchings):
-            # the row gather is the matching exchange: partner rows of
-            # this static involution, VMEM-local sublane movement
-            delta = jnp.take(xw, pi_ref[j], axis=0) - xw
-            acc = acc + (w_win[k, j] * gate_ref[j])[:, None] * delta
-        o_ref[...] = (curf + acc).astype(o_ref.dtype)
-        return carry
-
-    # fori_loop, not a python unroll: the step body is identical per k
-    # (only the dynamic weight-row index moves), and unrolling it made
-    # interpret-mode compile time blow up superlinearly past ~5 steps
-    # — a w_window=8 window cost 38 s of XLA CPU compile unrolled,
-    # <2 s looped, with the loop trip count a trace-time constant
-    jax.lax.fori_loop(0, w_window, step, 0)
-
-
-def _make_perm_kernel_dbuf(w_window: int, num_matchings: int, wire):
-    """Double-buffered kernel body (DESIGN.md §24): the ``[T, M]`` flag
-    stream stays in HBM (``memory_space=ANY``) and the kernel owns its
-    window DMAs through a 2-slot VMEM scratch — window ``t+1``'s async
-    copy is *started* before window ``t``'s gathers run and waited only
-    when its data is needed, so the flag-row stream rides under the VPU
-    row gathers instead of serializing with them (the Pallas
-    multiple-buffering pattern).  Same bytes, same arithmetic — only the
-    schedule changes: the streamed-BlockSpec form makes the grid's
-    implicit window fetch a dependency of the whole visit, while here the
-    only consumer of the copy is the ``.wait()`` directly before the
-    window body.
-    """
-
-    def _kernel(x_ref, w_hbm, pi_ref, gate_ref, o_ref, w_buf, sem):
-        t = pl.program_id(1)
-        nt = pl.num_programs(1)
-
-        def window_copy(win, slot):
-            return pltpu.make_async_copy(
-                w_hbm.at[pl.ds(win * w_window, w_window)],
-                w_buf.at[slot], sem.at[slot])
-
-        @pl.when(t == 0)
-        def _():
-            # first visit of this D-block: seed the output and warm the
-            # pipeline with window 0's copy (slot 0)
-            o_ref[...] = x_ref[...]
-            window_copy(0, 0).start()
-
-        slot = jax.lax.rem(t, 2)
-
-        @pl.when(t + 1 < nt)
-        def _():
-            # overlap: next window's flag rows start flowing before this
-            # window's gathers — its slot was fully consumed at t−1, so
-            # the overwrite cannot race a reader
-            window_copy(t + 1, jax.lax.rem(t + 1, 2)).start()
-
-        window_copy(t, slot).wait()
-        _perm_window_body(o_ref, w_buf[slot], pi_ref, gate_ref, w_window,
-                          num_matchings, wire)
+        jax.lax.fori_loop(0, t_steps, step, 0)
 
     return _kernel
 
 
 @functools.partial(
-    jax.jit,
-    static_argnames=("block_d", "w_window", "wire_dtype", "interpret", "dbuf"))
+    jax.jit, static_argnames=("block_d", "wire_dtype", "interpret"))
 def perm_gossip_run(
     x: jax.Array,
     weights: jax.Array,
@@ -393,24 +400,23 @@ def perm_gossip_run(
     partnered: jax.Array,
     *,
     alive: jax.Array | None = None,
-    block_d: int = 2048,
-    w_window: int = 1,
+    block_d: int | None = None,
     wire_dtype=None,
     interpret: bool = False,
-    dbuf: bool = True,
 ) -> jax.Array:
     """Apply ``T`` gossip steps in permutation form, streaming only weights.
 
     ``x``: ``[N, D]`` worker state.  ``weights``: ``f32[T, M]`` — the
     α-scaled activation flags (``alpha * flags``); this is the ONLY per-step
-    operand that streams from HBM (``M·4`` bytes per step-window visit vs
-    the fused kernel's ``N²·wire_bytes``).  ``perms``/``partnered``: the
-    ``[M, N]`` static involution tables from :func:`involution_tables`,
-    replicated into VMEM once per D-block and reused across the whole
-    window.  The grid tiles (D-blocks × step-windows) with the step axis
-    fastest, so each ``[N, block_d]`` state block is read once, mixed for
-    all T steps in VMEM, and written once — the structure that removes the
-    fused kernel's dominant W-stack stream.
+    operand (``M·4`` bytes per step vs the fused kernel's
+    ``N²·wire_bytes``).  ``perms``/``partnered``: the ``[M, N]`` static
+    involution tables from :func:`involution_tables`.  All three are
+    scalars to the kernel and are placed whole in SMEM (1 MiB on the v5e);
+    a flag stream too long for it runs as consecutive launches of at most
+    ``_PERM_SMEM_BYTES`` worth of rows, the state round-tripping HBM once
+    per launch.  The grid tiles D-blocks only: each ``[N, block_d]`` state
+    block is read once, mixed for all T steps in VMEM, and written once —
+    the structure that removes the fused kernel's dominant W-stack stream.
 
     ``alive``: optional traced ``f32[N]`` survivor mask.  Each matching's
     per-slot gate becomes ``partnered_j · alive · alive[π_j]`` (computed
@@ -424,27 +430,16 @@ def perm_gossip_run(
     ``wire_dtype`` — resolved through
     :func:`~matcha_tpu.parallel.gossip.resolve_wire_dtype`, the one GL004
     quantization seam every exchange narrows through:
-    the gathered operand is quantized once per step before the exchange;
-    accumulation is always f32 regardless of state dtype.  ``w_window``
-    steps are applied per grid visit (front-padded with zero-weight rows —
-    exact identities — when ``T % w_window != 0``); like the fused kernel's
-    window it changes DMA granularity and grid size, never arithmetic:
-    the window runs as a ``fori_loop`` over one compiled step body (only
-    the weight-row index moves), so every window size is *bitwise* the
-    same chain — and compile time stays flat instead of blowing up with
-    an unrolled body.
-    ``interpret=True`` runs the Pallas interpreter — the CPU tier-1 path.
+    the exchanged operand is quantized once per step before the exchange;
+    accumulation is always f32 regardless of state dtype.
 
-    ``dbuf`` (default on) double-buffers the weight-window stream
-    (DESIGN.md §24): the ``[T, M]`` flag rows stay in HBM
-    (``memory_space=ANY``) and the kernel issues its own async window
-    copies into a 2-slot VMEM scratch, starting window ``t+1``'s DMA
-    before window ``t``'s gathers so the only per-step HBM traffic rides
-    under the VPU work.  Bytes moved and arithmetic are identical to the
-    streamed-BlockSpec form — the window body is literally the same
-    function — so parity with the gather oracle is preserved bitwise and
-    ``gossip_chain_costs``'s extracted streamed bytes per step are
-    unchanged (pinned by ``ci/lint.sh``); only the DMA schedule differs.
+    ``block_d``: resident D-block width.  ``None`` takes the widest
+    multiple of 128 up to 2048 whose six ``[N, block_d]`` buffers (in and
+    out blocks double-buffered, the f32 wire image and accumulator) fit
+    the scoped VMEM; an explicit width that does not fit raises
+    :class:`GossipKernelResourceError`.  It retiles columns only, never
+    arithmetic.  ``interpret=True`` runs the Pallas interpreter — the CPU
+    tier-1 path.
 
     Parity contract (pinned by ``tests/test_perm_backend.py``): bitwise
     equal in f32 — masked or not, any wire — to a *compiled* ``lax.scan``
@@ -463,17 +458,21 @@ def perm_gossip_run(
     if t_steps == 0 or m == 0:
         return x
     wire = resolve_wire_dtype(wire_dtype)
-    block_d = min(operator.index(block_d), d)
+    col_bytes = n * (4 * x.dtype.itemsize + 8)  # 2 in + 2 out + xw + acc
+    if block_d is None:
+        block_d = max(128, min(_BLOCK_D,
+                               SCOPED_VMEM_BYTES // col_bytes // 128 * 128))
     # operator.index: static_argnames int, see canonical_chunk
-    w_window = max(1, min(operator.index(w_window), t_steps))
+    block_d = min(operator.index(block_d), d)
+    _check_vmem("perm", col_bytes * block_d,
+                f"N={n} rows x block_d={block_d}")
+    max_steps = (_PERM_SMEM_BYTES - 8 * m * n) // (4 * m)
+    if max_steps < 1:
+        raise GossipKernelResourceError(
+            f"perm kernel: the two [M={m}, N={n}] involution tables need "
+            f"{8 * m * n} bytes of SMEM, over the {_PERM_SMEM_BYTES} byte "
+            f"budget — this worker count needs another gossip backend")
     weights = weights.astype(jnp.float32)
-    pad = (-t_steps) % w_window
-    if pad:
-        # front-pad with zero weights: an all-zero row is the identity
-        # step bitwise (0·delta accumulates nothing; the wire quantization
-        # it computes is discarded), so padding never perturbs the chain
-        weights = jnp.concatenate(
-            [jnp.zeros((pad, m), jnp.float32), weights])
     gate = jnp.asarray(partnered, jnp.float32)
     if alive is not None:
         av = jnp.asarray(alive, jnp.float32)
@@ -485,32 +484,20 @@ def perm_gossip_run(
         # product scales each edge's *weight*; non-finite rows are sealed
         # upstream (resilience.runtime.gossip_quarantined)
         gate = gate * av[None, :] * av[jnp.asarray(perms)]
-    grid = (pl.cdiv(d, block_d), (t_steps + pad) // w_window)
-    if dbuf:
-        # manual double-buffered weight stream: whole [T, M] stack stays
-        # in HBM, the kernel owns the window DMAs (2-slot scratch + DMA
-        # semaphore pair)
-        kernel = _make_perm_kernel_dbuf(w_window, m, wire)
-        w_spec = pl.BlockSpec(memory_space=pltpu.ANY)
-        scratch = [
-            pltpu.VMEM((2, w_window, m), jnp.float32),
-            pltpu.SemaphoreType.DMA((2,)),
-        ]
-    else:
-        kernel = _make_perm_kernel(w_window, m, wire)
-        w_spec = pl.BlockSpec((w_window, m), lambda i, t: (t, 0))
-        scratch = []
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((n, block_d), lambda i, t: (0, i)),
-            w_spec,
-            pl.BlockSpec((m, n), lambda i, t: (0, 0)),
-            pl.BlockSpec((m, n), lambda i, t: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((n, block_d), lambda i, t: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((n, d), x.dtype),
-        scratch_shapes=scratch,
-        interpret=interpret,
-    )(x, weights, jnp.asarray(perms, jnp.int32), gate)
+    pi_flat = jnp.asarray(perms, jnp.int32).reshape(-1)
+    gate_flat = gate.reshape(-1)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    block = pl.BlockSpec((n, block_d), lambda i: (0, i))
+    for start in range(0, t_steps, max_steps):
+        seg = weights[start:start + max_steps]
+        x = pl.pallas_call(
+            _make_perm_kernel(seg.shape[0], m, n, wire),
+            grid=(pl.cdiv(d, block_d),),
+            in_specs=[smem, smem, smem, block],
+            out_specs=block,
+            out_shape=jax.ShapeDtypeStruct((n, d), x.dtype),
+            scratch_shapes=[pltpu.VMEM((n, block_d), jnp.float32),
+                            pltpu.VMEM((n, block_d), jnp.float32)],
+            interpret=interpret,
+        )(seg.reshape(-1), pi_flat, gate_flat, x)
+    return x

@@ -215,9 +215,8 @@ GOSSIP_BACKEND_GATE = 0.85
 
 #: Worker count beyond which the dense W-stack is treated as
 #: unrepresentable regardless of any measurement: an [N, N] f32 matrix at
-#: 4096 workers is 64 MB *per step of the stack* — the 10k+-virtual-worker
-#: regime only the permutation form can express (ROADMAP: oversubscribed
-#: fleet emulator).
+#: 4096 workers is 64 MB *per step of the stack*.  (The perm kernel's own
+#: VMEM-resident blocks top out near 5,400 workers — ROADMAP D2.)
 PERM_FORCED_WORKERS = 4096
 
 
@@ -353,8 +352,7 @@ def load_measured_vs_ceiling(source: str) -> Tuple[float, dict]:
     * a run-journal JSONL whose ``bench`` events carry a roofline report
       (``obs_tpu.py roofline --journal``): the report's
       ``measured_vs_ceiling`` + ``measured_vs_ceiling_backend``;
-    * a ``bench_live_r*.json`` capture (``{"record": {...}}``) or raw
-      bench record: the fused/dense kernel's ``mfu`` — the fused chain is
+    * a ``{"record": {...}}``-wrapped or raw bench record: the fused/dense kernel's ``mfu`` — the fused chain is
       MXU-bound, so its compute-bound MFU *is* the measured/ceiling ratio;
     * a raw roofline-report JSON (the ``roofline_report`` dict).
 

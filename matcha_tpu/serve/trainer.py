@@ -239,8 +239,13 @@ def main(argv=None) -> int:
         spec = json.load(f)
 
     from ..train import TrainConfig, train
+    from ..utils import announce_devices, pin_platform
 
+    # the shared compile-cache seam: a supervised restart re-runs the same
+    # programs, and should read them back instead of compiling them again
+    pin_platform(None)
     config = TrainConfig(**spec["config"])
+    announce_devices(config.devices)
     harness = TrainerHarness(spec)
     train(config, boundary_hook=harness.on_boundary)
     return RESTART_EXIT if harness.restart_requested else 0

@@ -73,8 +73,8 @@ class TrainConfig:
     # top-k-10% runs far behind their uncompressed control early on.
     compress_warmup_epochs: int = 0
     # gossip backend: dense (MXU matmul/step), fused (Pallas W-stack
-    # multi-step kernel), perm (permutation-form Pallas kernel — streams
-    # only the [T, M] flag array, the 10k+-worker form), gather, skip,
+    # multi-step kernel), perm (permutation-form Pallas kernel — reads
+    # only the [T, M] flag array), gather, skip,
     # shard_map, or auto (shard_map on a real mesh; single-chip the
     # perm-vs-dense choice runs through plan.cost.choose_gossip_backend
     # and the decision is journaled as a `backend` event)
@@ -85,14 +85,14 @@ class TrainConfig:
     # measured-vs-ceiling ratio from `obs_tpu.py roofline` (the
     # measured_vs_ceiling field of a prior round's report).  None = no
     # measurement, so auto never promotes perm below the N>=4096
-    # representability wall; feeding ~0.9 here (e.g. the committed r4
-    # fused rate vs the v5e ceiling) is how an operator closes the
+    # representability wall; feeding a measured ratio here is how an
+    # operator closes the
     # roofline->selection loop for a real run.  Journaled in the
     # `backend` decision event either way.
     gossip_measured_vs_ceiling: Optional[float] = None
     # ... or extract that ratio from an artifact instead of typing it: a
     # run journal carrying `bench` roofline records (obs_tpu.py roofline
-    # --journal), a bench_live_r*.json capture, or a raw roofline-report
+    # --journal), a wrapped or raw bench record, or a raw roofline-report
     # JSON (plan.cost.load_measured_vs_ceiling resolves all three; the
     # provenance is journaled in the `backend` decision event).  An
     # unusable artifact raises — auto must never promote on a ratio that

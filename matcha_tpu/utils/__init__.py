@@ -3,9 +3,9 @@ atomic publication."""
 
 from .atomicio import atomic_publish
 from .metrics import AverageMeter, cross_entropy_loss, top_k_accuracy
-from .platform import pin_platform, user_cache_dir
+from .platform import announce_devices, compile_cache_dir, pin_platform
 from .profiling import annotate, device_span, trace
 
-__all__ = ["AverageMeter", "annotate", "atomic_publish",
-           "cross_entropy_loss", "device_span", "pin_platform",
-           "user_cache_dir", "top_k_accuracy", "trace"]
+__all__ = ["AverageMeter", "annotate", "announce_devices", "atomic_publish",
+           "compile_cache_dir", "cross_entropy_loss", "device_span",
+           "pin_platform", "top_k_accuracy", "trace"]

@@ -27,9 +27,9 @@ Commands
 ``compare SRC... [--md PATH]``
     One table across heterogeneous sources: run dirs / journals (their
     ``bench`` events, or the final telemetry row), bare
-    ``BENCH_r*.json`` / ``benchmarks/bench_live_r*.json`` records, and
-    ``MULTICHIP_r*.json`` dryrun stamps — so pre-journal rounds and
-    journal-emitting rounds land side by side.
+    ``BENCH_r*.json``-style bench records, and ``MULTICHIP_r*.json``
+    dryrun stamps — so driver captures and journal-emitting runs land
+    side by side.
 
 Performance observability (DESIGN.md §15):
 
@@ -220,23 +220,20 @@ def _resolve_measured(args):
           f"units: {', '.join(found)}); accepted source shapes: a bench "
           f"journal / run dir with `bench` events carrying "
           f"unit=gossip_steps_per_sec, a BENCH_r*.json driver capture "
-          f"(record/parsed/tail wrappers ok), or a bench_live_r*.json "
-          f"record", file=sys.stderr)
+          f"(record/parsed/tail wrappers ok), or a raw bench record",
+          file=sys.stderr)
     return None, None
 
 
 def _normalize_measured_backend(label):
     """Map a bench record's ``backend`` field onto the roofline backend
-    vocabulary: the cpu-fallback provisional is a dense f32 measurement;
-    unknown labels return None (unattributable)."""
+    vocabulary; unknown labels return None (unattributable)."""
     if label is None:
         return None
     label = str(label)
     for key in ("perm", "fused", "dense"):
         if key in label:
             return key
-    if "cpu-fallback" in label:
-        return "dense"
     return None
 
 
@@ -550,7 +547,7 @@ def main(argv=None) -> int:
                    help="which backend produced the measured rate "
                         "(default: the --source record's own `backend` "
                         "field).  `--backend both` withholds the measured "
-                        "row for non-chain (dense/cpu-fallback) sources; "
+                        "row for non-chain (dense) sources; "
                         "single-backend reports always emit the ratio but "
                         "record BOTH labels (measured_backend + "
                         "measured_vs_ceiling_backend) and note "

@@ -355,7 +355,7 @@ CHAOS_CFG = TrainConfig(
     model="mlp",
     dataset="synthetic",
     dataset_kwargs={"num_train": 64, "num_test": 16},
-    num_workers=4,
+    num_workers=4, devices=1,
     graphid=None,
     topology="ring",
     batch_size=8,

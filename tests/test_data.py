@@ -163,7 +163,7 @@ def test_photo_patches_trains_in_loop():
     cfg = TrainConfig(
         name="photo-t", model="mlp", dataset="photo_patches",
         dataset_kwargs={"train_per_class": 64, "test_per_class": 16},
-        num_workers=4, graphid=None, topology="ring", batch_size=16,
+        num_workers=4, devices=1, graphid=None, topology="ring", batch_size=16,
         epochs=2, lr=0.05, warmup=False, matcha=True, budget=0.5, seed=0,
         save=False, eval_every=1, augment=True, measure_comm_split=False,
     )

@@ -1,13 +1,12 @@
-"""Docs must not cite benchmark artifacts that don't exist (VERDICT Weak #1).
+"""Docs must not cite benchmark artifacts that don't exist.
 
 Round 5 shipped README/DESIGN text describing ``benchmarks/train_step_r5.json``
 and ``benchmarks/scale_probe_r5.json`` as committed measurements when neither
 file existed — promissory tense laundered into evidence.  This guard scans
 ``README.md`` and ``docs/*.md`` for every ``benchmarks/*.json`` reference and
-fails unless the artifact is committed, with one escape hatch: a reference
-whose line explicitly says ``queued`` (case-insensitive) is a declared
-future-session ask, not an evidence claim — the honest way to point at the
-next live-TPU window's deliverables (``benchmarks/tpu_session.sh``).
+every round-suffixed ``*_rN.*`` cite and fails unless the artifact is
+committed.  What has not been measured is written "not measured", not
+pointed at a file that may appear later.
 """
 
 import pathlib
@@ -17,12 +16,10 @@ REPO = pathlib.Path(__file__).resolve().parents[1]
 # jsonl? with a word-boundary: "baselines_smoke.jsonl" must match as the
 # .jsonl file it names, not as a phantom .json prefix of it
 REF = re.compile(r"benchmarks/[A-Za-z0-9_.\-]*\.jsonl?\b")
-# round-suffixed session deliverables (`lint_stamp_r6.json`,
-# `roofline_r6.md`, …) are often cited bare — without the benchmarks/
-# prefix REF keys on — and in every format tpu_session.sh emits, markdown
-# included.  The `_r<N>.` suffix is the promissory-tense marker: each cite
-# must resolve on disk (they land under benchmarks/) or declare itself
-# queued.
+# round-suffixed deliverables (`lint_stamp_r6.json`, `roofline_r6.md`, …)
+# were often cited bare — without the benchmarks/ prefix REF keys on — and
+# in markdown as well as JSON.  The `_r<N>.` suffix is the promissory-tense
+# marker: each cite must resolve on disk.
 ROUND_REF = re.compile(r"\b[A-Za-z0-9_\-]+_r\d+\.(?:jsonl?|md)\b")
 
 
@@ -53,73 +50,29 @@ def test_doc_benchmark_artifact_references_exist():
     missing = []
     for doc in _docs():
         for lineno, line in enumerate(doc.read_text().splitlines(), 1):
-            if "queued" in line.lower():
-                continue  # declared future ask, not an evidence claim
             for ref in REF.findall(line):
                 if not (REPO / ref).exists():
                     missing.append(f"{doc.name}:{lineno} -> {ref}")
     assert not missing, (
-        "docs cite uncommitted benchmark artifacts (either commit the "
-        "artifact, or mark the line 'queued' if it names a future session "
-        f"deliverable): {missing}"
+        "docs cite uncommitted benchmark artifacts (commit the artifact, "
+        f"or say 'not measured'): {missing}"
     )
 
 
-def test_round_artifact_cites_resolve_or_say_queued():
-    """ISSUE 9 satellite (VERDICT item 3): every ``*_rN.*`` artifact cite
-    in prose either exists under ``benchmarks/`` (or at its stated path)
-    or says ``queued`` on the same line — the promissory-tense laundering
-    guard, extended past REF's ``benchmarks/*.json`` surface to the bare
-    and markdown-format cites the round-5 audit found slipping through."""
+def test_round_artifact_cites_resolve():
+    """Every ``*_rN.*`` artifact cite in prose exists under ``benchmarks/``
+    (or at its stated path) — the promissory-tense laundering guard,
+    extended past REF's ``benchmarks/*.json`` surface to bare and
+    markdown-format cites."""
     bad = []
     for doc in _docs():
         for lineno, line in _prose_lines(doc):
-            if "queued" in line.lower():
-                continue
             for ref in ROUND_REF.findall(line):
                 if not ((REPO / "benchmarks" / ref).exists()
                         or (REPO / ref).exists()):
                     bad.append(f"{doc.name}:{lineno} -> {ref}")
     assert not bad, (
-        "docs cite round-suffixed artifacts that are neither committed "
-        f"nor marked 'queued' on their line: {bad}"
-    )
-
-
-def test_round_scanner_sees_both_outcomes():
-    """Non-vacuous both ways: the docs do cite a committed round artifact
-    (bench_live_r4) and do declare queued ones — the pattern hits both."""
-    prose = [(ref, "queued" in line.lower())
-             for doc in _docs() for _, line in _prose_lines(doc)
-             for ref in ROUND_REF.findall(line)]
-    assert any((REPO / "benchmarks" / r).exists() for r, _ in prose), \
-        "no committed round artifact cited — pattern rotted?"
-    assert any(q for _, q in prose), "no queued round artifact cited"
-
-
-def test_committed_compare_table_covers_every_bench_record():
-    """ISSUE 19 satellite: the committed compare table
-    (``benchmarks/obs_compare_r6.md``) names every repo-root
-    ``BENCH_r*.json`` — the bench trajectory sat at repo root for five
-    rounds while no committed table carried it.  The library's own
-    completeness check agrees: comparing the full set yields no
-    'missing from table' problems."""
-    from matcha_tpu.obs.report import compare_sources
-
-    table = REPO / "benchmarks" / "obs_compare_r6.md"
-    assert table.exists(), "committed compare table missing"
-    text = table.read_text()
-    records = sorted(p.name for p in REPO.glob("BENCH_r*.json"))
-    assert records, "no repo-root BENCH_r*.json — scan surface rotted?"
-    absent = [r for r in records if r not in text]
-    assert not absent, (
-        f"repo-root BENCH records missing from {table.name}: {absent} — "
-        f"regenerate with: python obs_tpu.py compare "
-        f"{' '.join(records)} --md benchmarks/obs_compare_r6.md")
-    assert "missing from table" not in text
-    rows, problems = compare_sources([str(REPO / r) for r in records])
-    assert len(rows) == len(records)
-    assert not [p for p in problems if p.startswith("missing from table")]
+        f"docs cite round-suffixed artifacts that are not committed: {bad}")
 
 
 def test_scanner_sees_the_committed_artifacts():
@@ -131,9 +84,9 @@ def test_scanner_sees_the_committed_artifacts():
 
 
 # --------------------------------------------------------------- lint stamps
-# benchmarks/tpu_session.sh step 0.1 records `lint_tpu.py --format json` next
-# to the bench captures; DESIGN.md cites the stamp as evidence the measured
-# tree passed graftlint.  Pin the stamp schema here so (a) every committed
+# `lint_tpu.py --format json` renders a stamp a measurement can be recorded
+# next to, as evidence the measured tree passed graftlint.  Pin the stamp
+# schema here so (a) every committed
 # stamp parses as what the docs claim it is, and (b) the renderer cannot
 # silently change shape between sessions — the same contract style as the
 # benchmark-reference scan above.
@@ -170,8 +123,8 @@ def test_committed_lint_stamps_conform_to_schema():
 
 
 def test_lint_stamp_renderer_emits_the_pinned_schema():
-    """Non-vacuous even while no live-session stamp is committed (r6 is
-    queued): render a stamp in-process and hold it to the same schema the
+    """Non-vacuous even while no stamp is committed: render a stamp
+    in-process and hold it to the same schema the
     committed ones must satisfy."""
     import json
 
@@ -185,9 +138,8 @@ def test_lint_stamp_renderer_emits_the_pinned_schema():
 
 
 def test_contracts_stamp_schema():
-    """benchmarks/tpu_session.sh step 0.1 also records the graftcontract
-    verdict (`--rules GL201,GL202,GL203 --format json`) next to the
-    graftlint stamp: pin that shape too — committed stamps and the
+    """The graftcontract verdict (`--rules GL201,GL202,GL203 --format
+    json`) is stamped the same way: pin that shape too — committed stamps and the
     renderer both — so the sync-budget evidence cannot silently change
     schema between sessions."""
     import json

@@ -944,8 +944,12 @@ def test_cli_compare_mixes_bench_records_and_journals(ring8_run, tmp_path,
               "backend": "dense"}
     append_journal_record(str(journal), "bench", record=record,
                           status="measured")
-    rc = obs_tpu.main(["compare", str(journal),
-                       str(REPO / "BENCH_r01.json"), run_dir])
+    # a driver-style capture whose command failed: rc and a tail, no record
+    failed = tmp_path / "BENCH_r01.json"
+    failed.write_text(json.dumps(
+        {"n": 1, "cmd": "python bench.py", "rc": 1,
+         "tail": "Traceback (most recent call last):\n", "parsed": None}))
+    rc = obs_tpu.main(["compare", str(journal), str(failed), run_dir])
     assert rc == 0
     out = capsys.readouterr().out
     assert "123.4" in out and "BENCH_r01.json" in out
@@ -1003,17 +1007,16 @@ def test_cli_compare_reads_multichip_records(tmp_path, capsys):
 def test_bench_journal_sink_appends_valid_event(tmp_path):
     """bench.py --journal mirrors the final record as a `bench` event the
     compare renderer reads (no subprocess: the sink function is the
-    contract; the orchestration around it is covered by
-    test_bench_contract)."""
+    contract)."""
     import argparse
 
     import bench
 
     path = tmp_path / "j.jsonl"
     args = argparse.Namespace(journal=str(path))
-    bench._journal_record(args, {"value": 5000.1, "unit": "x"}, "measured")
-    bench._journal_record(argparse.Namespace(journal=None), {"value": 1},
-                          "measured")  # no-op, must not create anything
+    bench._journal_record(args, {"value": 5000.1, "unit": "x"})
+    # no-op, must not create anything
+    bench._journal_record(argparse.Namespace(journal=None), {"value": 1})
     [event] = read_journal(str(path))
     assert validate_event(event) == []
     assert event["record"]["value"] == 5000.1

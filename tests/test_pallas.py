@@ -5,13 +5,20 @@ Mosaic); arithmetic must match a lax.scan over ``gossip_mix_dense``
 step-for-step in f32.
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from matcha_tpu import topology as tp
 from matcha_tpu.communicator import make_decen
-from matcha_tpu.parallel import build_mixing_stack, fused_gossip_run
-from matcha_tpu.schedule import matcha_schedule
+from matcha_tpu.parallel import (
+    GossipKernelResourceError,
+    build_mixing_stack,
+    fused_gossip_run,
+    perm_gossip_run,
+)
+from matcha_tpu.schedule import fixed_schedule, matcha_schedule
 
 
 def _schedule(n=8, iterations=12, budget=0.6):
@@ -122,3 +129,133 @@ def test_w_window_bitwise_matches_window1():
             out, _ = make_decen(sched, backend="fused", compute_dtype=dtype,
                                 w_window=w).run(x, flags)
             np.testing.assert_array_equal(np.asarray(base), np.asarray(out))
+
+
+# ------------------------------------------------- the TPU compiler's view
+# Both kernels only ever ran under the interpreter in tier-1, which accepts
+# programs Mosaic refuses (a vector row gather, an (1, 8) block).  These
+# lower — and, where libtpu offers a compile-only v5e topology, compile —
+# the real kernels at the train shapes, from the CPU host.
+
+RESNET20_DIM = 273_258  # not a multiple of 128: the last D-block is ragged
+CHAIN = 20
+
+
+def _kernel_program(kernel, n, wire, masked=False):
+    """``(fn, abstract args)`` of one kernel chain at ``[n, RESNET20_DIM]``,
+    f32 state, compiled (``interpret=False``)."""
+    f32 = jnp.float32
+    x = jax.ShapeDtypeStruct((n, RESNET20_DIM), f32)
+    if kernel == "fused":
+        stack = jax.ShapeDtypeStruct(
+            (CHAIN, n, n), f32 if wire == "f32" else jnp.bfloat16)
+        return (lambda x, stack: fused_gossip_run(x, stack)), (x, stack)
+    m = 8 if n == 16 else 27  # the zoo ER graph / the bench geometric graph
+    args = (x, jax.ShapeDtypeStruct((CHAIN, m), f32),
+            jax.ShapeDtypeStruct((m, n), jnp.int32),
+            jax.ShapeDtypeStruct((m, n), f32))
+    if masked:
+        return (lambda x, w, pi, pr, alive: perm_gossip_run(
+            x, w, pi, pr, alive=alive, wire_dtype=wire)), \
+            args + (jax.ShapeDtypeStruct((n,), f32),)
+    return (lambda x, w, pi, pr: perm_gossip_run(
+        x, w, pi, pr, wire_dtype=wire)), args
+
+
+KERNEL_CASES = [(k, n, w, masked)
+                for k in ("fused", "perm") for n in (16, 256)
+                for w in ("f32", "bf16")
+                for masked in ((False, True) if k == "perm" else (False,))]
+
+
+@pytest.mark.perm
+@pytest.mark.parametrize("kernel,n,wire,masked", KERNEL_CASES)
+def test_pallas_kernels_cross_lower_for_tpu(kernel, n, wire, masked):
+    """The Pallas TPU lowering accepts both kernels at 16 x 273,258 and
+    256 x 273,258, f32 and bf16 wire — no chip needed, and the next
+    construct it refuses fails here."""
+    fn, args = _kernel_program(kernel, n, wire, masked)
+    lowered = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
+    assert "tpu_custom_call" in lowered.as_text()
+
+
+def _compile_all_for_v5e() -> int:
+    """Child-process body of the test below: compile every kernel case for
+    one device of a compile-only v5e topology (libtpu, no hardware)."""
+    import os
+
+    # no metadata server here: describe the host to libtpu by hand
+    for key, value in (("TPU_SKIP_MDS_QUERY", "1"),
+                       ("TPU_ACCELERATOR_TYPE", "v5litepod-4"),
+                       ("TPU_WORKER_HOSTNAMES", "localhost")):
+        os.environ.setdefault(key, value)
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(topology_name="v5e:2x2",
+                                            platform="tpu")
+    except Exception as e:  # noqa: BLE001 — any libtpu refusal means "n/a"
+        print(f"NO-TOPOLOGY {type(e).__name__}: {e}")
+        return 0
+    sharding = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    for case in KERNEL_CASES:
+        fn, args = _kernel_program(*case)
+        args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
+                for a in args]
+        jax.jit(fn).lower(*args).compile()
+        print("COMPILED", *case)
+    return 0
+
+
+@pytest.mark.perm
+def test_pallas_kernels_compile_for_v5e():
+    """Mosaic itself (layout inference, VMEM and SMEM allocation) compiles
+    both kernels for the v5e at the train shapes, ahead of time.  In a
+    child process: loading libtpu here would hang a TPU plane on every
+    later profiler trace of this one."""
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run(
+        [sys.executable, os.path.abspath(__file__)], cwd=repo,
+        capture_output=True, text=True, timeout=600,
+        env={**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": repo})
+    if "NO-TOPOLOGY" in proc.stdout:
+        pytest.skip(f"no compile-only TPU topology here: {proc.stdout[-300:]}")
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert proc.stdout.count("COMPILED") == len(KERNEL_CASES), proc.stdout
+
+
+def test_kernel_blocks_that_cannot_fit_are_refused_by_name():
+    """A D-block whose resident buffers overrun the 16 MiB scoped VMEM is
+    a GossipKernelResourceError naming the shape — from the kernel at
+    trace time and from make_decen at build time — not a Mosaic dump."""
+    x = jax.ShapeDtypeStruct((256, RESNET20_DIM), jnp.float32)
+    stack = jax.ShapeDtypeStruct((CHAIN, 256, 256), jnp.float32)
+    with pytest.raises(GossipKernelResourceError, match="block_d=8192"):
+        jax.eval_shape(lambda x, s: fused_gossip_run(x, s, block_d=8192),
+                       x, stack)
+    n = 256
+    sched = fixed_schedule(tp.decompose(tp.ring_graph(n), n, seed=0), n,
+                           iterations=2)
+    with pytest.raises(GossipKernelResourceError, match="fused"):
+        make_decen(sched, backend="fused", block_d=8192)
+    make_decen(sched, backend="fused")  # the default block fits
+    # perm sizes its own block to fit, and refuses an explicit one that
+    # cannot
+    pi = jax.ShapeDtypeStruct((2, n), jnp.int32)
+    pr = jax.ShapeDtypeStruct((2, n), jnp.float32)
+    w = jax.ShapeDtypeStruct((CHAIN, 2), jnp.float32)
+    jax.eval_shape(lambda x, w, pi, pr: perm_gossip_run(x, w, pi, pr),
+                   x, w, pi, pr)
+    with pytest.raises(GossipKernelResourceError, match="perm"):
+        jax.eval_shape(lambda x, w, pi, pr: perm_gossip_run(
+            x, w, pi, pr, block_d=8192), x, w, pi, pr)
+
+
+if __name__ == "__main__":
+    import sys
+
+    sys.exit(_compile_all_for_v5e())

@@ -21,6 +21,7 @@ import pytest
 from matcha_tpu.obs import make_event, read_journal, validate_event
 from matcha_tpu.obs.costs import (
     CostLedger,
+    UnknownChipError,
     analyze_program,
     capacity_report,
     chip_peaks,
@@ -138,16 +139,34 @@ def test_roofline_cpu_provisional_is_finite_and_flagged():
                 "ceiling_steps_per_sec"):
         assert math.isfinite(rep[key]) and rep[key] > 0
     assert "provisional" in render_roofline_markdown(rep)
-    with pytest.raises(ValueError, match="unknown chip"):
+    with pytest.raises(UnknownChipError, match="unknown chip"):
         roofline_report(4, 512, dec, chip="v99")
 
 
-def test_chip_peaks_bench_contract():
-    """bench.py's MFU computation imports this: known kinds resolve,
-    unknown kinds (the CPU provisional path) get (None, None)."""
+def test_chip_table_knows_the_chip_or_raises(monkeypatch):
+    """The one chip table: known kinds resolve (the v5e reports itself as
+    "TPU v5 lite"), and a device that is not in it is a named error —
+    never ``(None, None)`` and never the CPU row, which only the CPU
+    platform (or an explicit ``chip="cpu"``) gets."""
+    import types
+
+    import jax
+
+    from matcha_tpu.obs.costs import CPU_PROVISIONAL, resolve_chip
+
     assert chip_peaks("TPU v5e") == (197.0, 819.0)
+    assert chip_peaks("TPU v5 lite") == (197.0, 819.0)
     assert chip_peaks("TPU v4") == (275.0, 1228.0)
-    assert chip_peaks("cpu") == (None, None)
+    with pytest.raises(UnknownChipError):
+        chip_peaks("cpu")
+    assert resolve_chip(None) == ("cpu-provisional", CPU_PROVISIONAL)
+    assert resolve_chip("cpu") == ("cpu-provisional", CPU_PROVISIONAL)
+    fake = types.SimpleNamespace(platform="tpu", device_kind="TPU v99")
+    monkeypatch.setattr(jax, "devices", lambda *a: [fake])
+    with pytest.raises(UnknownChipError, match="TPU v99"):
+        resolve_chip(None)
+    fake.device_kind = "TPU v5 lite"
+    assert resolve_chip(None)[0] == "v5lite"
 
 
 def test_capacity_report_rederives_design9_table():
@@ -337,11 +356,18 @@ def test_cli_roofline_tiny_cpu_writes_markdown(tmp_path, capsys):
 
 
 def test_cli_roofline_reads_measured_rate_from_bench_record(tmp_path, capsys):
+    import json
+
     import obs_tpu
 
+    source = tmp_path / "bench_record.json"
+    source.write_text(json.dumps(
+        {"metric": "gossip-steps/sec", "value": 1150.0,
+         "unit": "gossip_steps_per_sec", "vs_baseline": 0.23,
+         "backend": "dense"}))
     rc = obs_tpu.main(["roofline", "--workers", "4", "--topology", "ring",
                        "--dim", "512", "--chip", "v5e",
-                       "--source", str(REPO / "BENCH_r05.json")])
+                       "--source", str(source)])
     assert rc == 0
     assert "Measured" in capsys.readouterr().out
 

@@ -536,6 +536,43 @@ class _FakeProc:
         return self._rc
 
 
+def test_supervisor_initializes_no_jax_backend(tmp_path):
+    """One process per chip: the supervisor imports the train package
+    (config validation, checkpoint progress) but must never touch a JAX
+    backend, or it would hold the chip its trainer child needs.  A fresh
+    interpreter walks the whole supervisor surface around a lifetime —
+    the daemon CLI module, spec publication, restart-field merge, progress
+    read, status, the endpoint — with a child that exits at once."""
+    import subprocess
+    import sys
+
+    script = f"""
+import sys
+import serve_tpu
+from matcha_tpu.serve import (Controller, ServeConfig, ServeEndpoint,
+                              write_control)
+cfg = dict(name="nochip", model="mlp", dataset="synthetic", num_workers=4,
+           graphid=None, topology="ring", savePath={str(tmp_path)!r})
+ctl = Controller(ServeConfig(config=cfg, restart_budget=0))
+write_control(ctl.control_path, {{"version": 1, "budget": 0.25}})
+ctl._launch = lambda: __import__("subprocess").Popen(
+    [sys.executable, "-c", "pass"])
+endpoint = ServeEndpoint({{"nochip": ctl}}, port=0).start()
+ctl._write_spec()
+assert ctl.run() == 0
+ctl._merge_restart_fields(); ctl._progress(); ctl.status()
+endpoint.stop()
+from jax._src import xla_bridge
+assert not xla_bridge.backends_are_initialized(), "supervisor holds a backend"
+print("supervisor-clean")
+"""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run([sys.executable, "-c", script], cwd=repo,
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert "supervisor-clean" in proc.stdout
+
+
 def test_controller_budget_charges_and_aborts(tmp_path, monkeypatch):
     """Crash-loop policy without spawning a trainer: every crash charges
     the budget and journals; exhaustion aborts with the crash's code."""

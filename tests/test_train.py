@@ -144,7 +144,7 @@ def test_train_conv_model_smoke():
     cfg = TrainConfig(
         name="conv-smoke", model="resnet8", dataset="synthetic_image",
         dataset_kwargs={"num_train": 64, "num_test": 32, "separation": 40.0},
-        num_workers=4, graphid=None, topology="ring", batch_size=4, epochs=2,
+        num_workers=4, devices=1, graphid=None, topology="ring", batch_size=4, epochs=2,
         lr=0.05, warmup=False, matcha=False, fixed_mode="all", seed=0,
         save=False, eval_every=3, measure_comm_split=False,
     )
@@ -163,7 +163,7 @@ def test_train_remat_and_grad_chunk_exact():
     cfg = TrainConfig(
         name="remat-eq", model="resnet8", dataset="synthetic_image",
         dataset_kwargs={"num_train": 32, "num_test": 16, "separation": 40.0},
-        num_workers=4, graphid=None, topology="ring", batch_size=4, epochs=1,
+        num_workers=4, devices=1, graphid=None, topology="ring", batch_size=4, epochs=1,
         lr=0.05, warmup=False, matcha=False, fixed_mode="all", seed=0,
         save=False, eval_every=1, measure_comm_split=False,
     )
