@@ -47,7 +47,8 @@ Regenerate after a journal schema bump (the v1→v2 bump of ISSUE 8 added
 the v2→v3 bump of ISSUE 10 added ``heartbeat`` and ``anomaly``; the
 v3→v4 bump of ISSUE 11 added ``attribution``; the v5→v6 bump of
 ISSUE 17 added ``control`` and ``promotion``; the v6→v7 bump of
-ISSUE 18 added ``recovery``):
+ISSUE 18 added ``recovery``; the v7→v8 bump of ISSUE 24 added ``spans``,
+one record an epoch period, the rejoin's bootstrap among them):
 
     JAX_PLATFORMS=cpu python benchmarks/make_reference_journal.py
 """

@@ -35,7 +35,8 @@ from typing import Dict, Iterable, List, Optional, Sequence
 
 __all__ = ["SCHEMA_VERSION", "ACCEPTED_VERSIONS", "EVENT_KINDS",
            "FAULT_KINDS", "V2_KINDS", "V3_KINDS", "V4_KINDS", "V5_KINDS",
-           "V6_KINDS", "V7_KINDS", "KIND_MIN_VERSION", "REQUIRED_FIELDS",
+           "V6_KINDS", "V7_KINDS", "V8_KINDS", "KIND_MIN_VERSION",
+           "REQUIRED_FIELDS",
            "make_event", "validate_event", "Journal", "read_journal",
            "salvage_journal", "read_journal_tail", "count_journal_lines",
            "resolve_journal_path",
@@ -58,9 +59,10 @@ __all__ = ["SCHEMA_VERSION", "ACCEPTED_VERSIONS", "EVENT_KINDS",
 #: the epoch boundary it landed on), and ``promotion`` — one checkpoint-
 #: promotion pipeline decision (promote / rollback / retain with the
 #: gating held-out metric).  Every pre-bump event validates verbatim under
-#: the v6 reader — old journals stay first-class sources.
-SCHEMA_VERSION = 7
-ACCEPTED_VERSIONS = frozenset({1, 2, 3, 4, 5, 6, 7})
+#: the v6 reader — old journals stay first-class sources.  v8 (ISSUE 24)
+#: adds ``spans``: the host phases of one epoch period.
+SCHEMA_VERSION = 8
+ACCEPTED_VERSIONS = frozenset({1, 2, 3, 4, 5, 6, 7, 8})
 
 #: Every kind a journal may contain.  The five fault kinds keep their
 #: historical ``faults.json`` names so the view stays a pure filter.
@@ -101,17 +103,26 @@ V6_KINDS = frozenset({"control", "promotion"})
 #: does not journal is recovery that silently rewrites history — the
 #: chaos harness's invariants reject exactly that.
 V7_KINDS = frozenset({"recovery"})
+#: Kinds introduced by schema v8 (ISSUE 24) — ``spans`` carries the named
+#: host phases of one epoch period (``utils.profiling.SpanRecorder``): from
+#: one loop top of ``train()`` to the next, every statement under one name
+#: of ``SPAN_NAMES``, on the journal's clock.  A kind of its own and not a
+#: field of ``epoch``: that event is journaled mid-period, a recorder flush
+#: later in the same period may already have written it, and a rolled-back
+#: attempt has no ``epoch`` event at all.
+V8_KINDS = frozenset({"spans"})
 #: Minimum envelope version per kind — the generalized "a vK kind claiming
 #: an earlier v is a lying envelope" rule.
 KIND_MIN_VERSION: Dict[str, int] = {
     **{k: 2 for k in V2_KINDS}, **{k: 3 for k in V3_KINDS},
     **{k: 4 for k in V4_KINDS}, **{k: 5 for k in V5_KINDS},
-    **{k: 6 for k in V6_KINDS}, **{k: 7 for k in V7_KINDS}}
+    **{k: 6 for k in V6_KINDS}, **{k: 7 for k in V7_KINDS},
+    **{k: 8 for k in V8_KINDS}}
 EVENT_KINDS = frozenset({
     "run_start", "resume", "epoch", "telemetry", "drift", "checkpoint",
     "retrace", "bench",
 }) | FAULT_KINDS | V2_KINDS | V3_KINDS | V4_KINDS | V5_KINDS | V6_KINDS \
-    | V7_KINDS
+    | V7_KINDS | V8_KINDS
 
 #: Kind-specific payload keys an event must carry to validate.  Kinds not
 #: listed need only the envelope (v / kind / t).
@@ -182,6 +193,14 @@ REQUIRED_FIELDS: Dict[str, frozenset] = {
     # (the quarantined path, the salvaged line count, the sink name) but
     # the pinned triple is what every auditor can rely on.
     "recovery": frozenset({"scope", "action", "reason"}),
+    # v8 (ISSUE 24): one per epoch period (a rolled-back epoch has one per
+    # ``attempt``) — ``period`` is the identifier its spans share as
+    # ``parent``, ``t0``/``t1`` bound it on the journal's clock, ``samples``
+    # counts the worker-samples it trained on, and ``spans`` lists
+    # ``{name, t0, t1, parent, **counts}`` in start order (``h2d`` carries
+    # ``bytes``, ``dispatch`` ``steps``, a chunked epoch's ``segment``)
+    "spans": frozenset({"epoch", "attempt", "period", "t0", "t1",
+                        "samples", "spans"}),
 }
 
 

@@ -11,12 +11,17 @@ scrubbable in a browser:
   fleet-scope events), named via ``M`` metadata events;
 * **spans** (``ph: "X"``) for the work phases: per-host ``compute`` /
   ``comm`` pairs from heartbeats, the scanned ``epoch`` window, program
-  ``compile``s, and zero-duration completion marks for ``checkpoint`` /
-  heal / rollback / α re-derivation / membership ``refold`` (the journal
-  records when they *finished*; a zero-length span is honest about the
-  missing duration);
+  ``compile``s, the loop's own host phases from the ``spans`` records (one
+  ``period`` an epoch and, inside it, every ``SPAN_NAMES`` phase at its
+  recorded start and end, on a ``host phases`` thread of the journal
+  track), and zero-duration completion marks for heal / rollback / α
+  re-derivation / membership ``refold`` and for a ``checkpoint`` whose
+  period has no ``spans`` record (the journal records when they
+  *finished*; a zero-length span is honest about the missing duration);
 * **instant events** (``ph: "i"``) for anomalies, membership churn,
-  drift/retrace trips, and run lifecycle marks;
+  drift/retrace trips, run lifecycle marks, and a ``checkpoint`` whose
+  period recorded the ``checkpoint`` span (the span is drawn once, with
+  its real length; the instant marks the journal entry);
 * **counter events** (``ph: "C"``) for the telemetry series
   (disagreement, wire bytes).
 
@@ -50,6 +55,8 @@ _MARK_SPANS = {
     "rollback": "rollback",
     "alpha_rederived": "refold",
 }
+#: the journal track's thread for the loop's host phases (``spans`` records)
+_PHASE_TID = 1
 #: journal kinds drawn as instants
 _INSTANTS = {"run_start", "resume", "plan", "drift", "retrace", "anomaly",
              "bench", "profile", "attribution"}
@@ -64,9 +71,52 @@ def _ev(name: str, ph: str, ts: float, pid: int, tid: int, src: str,
     return e
 
 
-def _meta(name: str, pid: int, label: str) -> dict:
-    return {"name": name, "ph": "M", "pid": pid, "tid": 0,
+def _meta(name: str, pid: int, label: str, tid: int = 0) -> dict:
+    return {"name": name, "ph": "M", "pid": pid, "tid": tid,
             "args": {"name": label}}
+
+
+def _period_spans(rec: dict, src: str) -> List[dict]:
+    """One ``spans`` record -> the period and each of its phases, at their
+    recorded times.  A name that repeats within the record (a chunked
+    epoch's segments, the two ``wait_device``) gets ``#2``, ``#3``: the
+    round trip wants each (source, name) once."""
+    t0 = float(rec.get("t0") or 0.0)
+    out = [_ev("period", "X", t0, 0, _PHASE_TID, src,
+               dur=max(float(rec.get("t1") or t0) - t0, 0.0) * _US,
+               args={k: rec.get(k) for k in ("period", "epoch", "attempt",
+                                             "samples")})]
+    seen: Dict[str, int] = {}
+    for sp in rec.get("spans") or []:
+        name = str(sp.get("name"))
+        seen[name] = seen.get(name, 0) + 1
+        lo = float(sp.get("t0") or 0.0)
+        out.append(_ev(
+            name if seen[name] == 1 else f"{name} #{seen[name]}", "X", lo,
+            0, _PHASE_TID, src,
+            dur=max(float(sp.get("t1") or lo) - lo, 0.0) * _US,
+            args={k: v for k, v in sp.items()
+                  if k not in ("name", "t0", "t1")}))
+    return out
+
+
+def _spanned_marks(events: Sequence[dict]) -> set:
+    """Indices of the ``checkpoint`` / ``emergency_checkpoint`` events whose
+    period's ``spans`` record (the next one in line order) holds a
+    ``checkpoint`` span: their duration is known, no mark is invented."""
+    spanned, pending = set(), []
+    for i, e in enumerate(events):
+        kind = e.get("kind")
+        if kind in ("checkpoint", "emergency_checkpoint"):
+            pending.append(i)
+        elif kind in ("run_start", "resume"):
+            pending = []
+        elif kind == "spans":
+            if any(sp.get("name") == "checkpoint"
+                   for sp in e.get("spans") or []):
+                spanned.update(pending)
+            pending = []
+    return spanned
 
 
 def _heartbeat_spans(rec: dict, pid: int, src: str) -> List[dict]:
@@ -94,6 +144,9 @@ def build_timeline(events: Sequence[dict],
                    | set(heartbeats_by_host))
     pid_of = {h: i + 1 for i, h in enumerate(hosts)}
     trace_events: List[dict] = [_meta("process_name", 0, "journal")]
+    if any(e.get("kind") == "spans" for e in events):
+        trace_events.append(
+            _meta("thread_name", 0, "host phases", tid=_PHASE_TID))
     trace_events += [_meta("process_name", pid_of[h], f"host {h}")
                      for h in hosts]
 
@@ -105,6 +158,7 @@ def build_timeline(events: Sequence[dict],
     horizon = max((float(e.get("t", 0.0)) for e in events
                    if float(e.get("t", 0.0)) < _ABS), default=0.0)
     mirrored: Dict[Tuple[str, int, int], float] = {}  # (host,epoch,step)->t
+    spanned = _spanned_marks(events)
     for i, e in enumerate(events):
         kind = e.get("kind")
         src = f"journal:{i}"
@@ -144,7 +198,9 @@ def build_timeline(events: Sequence[dict],
             else:
                 ev["s"] = "g"
             trace_events.append(ev)
-        elif kind in _MARK_SPANS:
+        elif kind == "spans":
+            trace_events += _period_spans(e, src)
+        elif kind in _MARK_SPANS and i not in spanned:
             trace_events.append(_ev(_MARK_SPANS[kind], "X", t, 0, 0, src,
                                     dur=0.0, args=detail))
         else:  # _INSTANTS and any future additive kind: never drop events

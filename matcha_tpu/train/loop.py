@@ -21,6 +21,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
+import itertools
 import os
 import time
 from typing import Dict, List, Optional
@@ -33,7 +34,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 from ..communicator import select_communicator
 from ..obs import CostLedger, DriftMonitor, Telemetry, compose_predicted_rho
 from ..obs.telemetry import make_telemetry_spec, telemetry_flush
-from ..utils import annotate, trace
+from ..utils import SpanRecorder, trace
 from ..data import (
     WorkerBatches,
     load_npz,
@@ -615,6 +616,9 @@ def train(config: TrainConfig, resume_dir: Optional[str] = None,
 
     evaluate = make_eval_fn(model)
     recorder = Recorder(config, config.num_workers)
+    # the loop's named host phases (utils.profiling.SPAN_NAMES), on the
+    # journal's clock; one `spans` record an epoch period
+    spans = SpanRecorder(origin=time.time() - recorder.start)
     # compiled-cost ledger (DESIGN.md §15): every distinct program this
     # loop runs is introspected once (.lower().compile().cost_analysis())
     # and journaled as a v2 `compile` event — FLOPs, boundary HBM bytes,
@@ -777,9 +781,10 @@ def train(config: TrainConfig, resume_dir: Optional[str] = None,
     # sums fleet metrics that may legitimately go non-finite one step
     # before the detector's own exemption logic would excuse them (a
     # quarantined worker's spike), and it is scratch, not model state
-    finite_check = jax.jit(
-        lambda s: state_finite_rows(s.replace(telemetry=()),
-                                    config.num_workers))
+    @jax.jit
+    def finite_check(s):
+        return state_finite_rows(s.replace(telemetry=()), config.num_workers)
+
     # retrace watch: the jitted epoch program's compile-cache size, read
     # for free after each epoch — a growing cache after the allowed shapes
     # (whole-epoch scan: 1; chunked scan: chunk + tail = 2) is the silent
@@ -879,12 +884,12 @@ def train(config: TrainConfig, resume_dir: Optional[str] = None,
             if self.epoch == 0:
                 return None  # nothing completed yet — nothing to save
             path = f"{config.savePath}/{config.name}_ckpt"
-            with annotate("matcha/checkpoint"):
+            with spans.span("checkpoint"):
                 save_checkpoint(path, state, self.epoch - 1,
                                 schedule=schedule0,
                                 membership=_membership_sidecar())
-            recorder.log_event("checkpoint", epoch=self.epoch - 1,
-                               path=path)
+                recorder.log_event("checkpoint", epoch=self.epoch - 1,
+                                   path=path)
             return path
 
         def request_stop(self):
@@ -896,73 +901,95 @@ def train(config: TrainConfig, resume_dir: Optional[str] = None,
     seam = _BoundarySeam() if boundary_hook is not None else None
 
     epoch = start_epoch
-    while epoch < config.epochs:
-        # chaos barrier (no-op unless armed): the campaign's SIGKILL-at-
-        # epoch-boundary injector fires here, before any of this epoch's
-        # host-state transitions (DESIGN.md §23)
-        from ..chaos.taps import maybe_kill
+    attempt = 0  # rollback retries of `epoch` so far
+    period_samples = 0
 
-        maybe_kill("epoch_boundary")
-        if boundary_hook is not None:
-            # the control plane's one entry point: apply pending control
-            # documents, run the promotion cadence, then re-prime the
-            # device knob image (fresh every boundary, like telemetry —
-            # one input placement signature whether or not it changed).
-            # A rollback retry re-enters this loop top: the hook must be
-            # idempotent per control-doc version (serve.trainer is).
-            seam.epoch = epoch
-            boundary_hook(seam)
-            if stop_requested:
-                break
-            state = state.replace(control=_fresh_control())
-        if elastic_ctl is not None:
-            # membership reconciliation — at this host boundary and nowhere
-            # else (DESIGN.md §16).  advance() is idempotent per epoch, so
-            # a rollback retry re-entering this loop top does not re-apply
-            # the transition (the bootstrap is part of the retry snapshot).
-            trans = elastic_ctl.advance(epoch, schedule)
-            if trans is not None:
-                member_alive_np = trans.new_alive > 0
-                if trans.joined.any() or trans.restored.any():
-                    with annotate("matcha/membership_bootstrap"):
-                        state = _bootstrap_rows(state, trans.joined,
-                                                trans.restored)
-                new_pred = None
-                if trans.replanned:
-                    # the re-folded α IS the plan from here on — the drift
-                    # monitor and the journal both re-base, exactly like
-                    # the recovery path's α re-derivation (§8)
-                    plan_alpha = float(trans.alpha)
-                    if drift_monitor is not None:
-                        predicted = new_pred = _compose_predicted()
-                        drift_monitor = DriftMonitor(
-                            predicted["rho"], int(bpe),
-                            tolerance=config.drift_tolerance,
-                            patience=config.drift_patience)
-                recorder.log_event(
-                    "membership", epoch=epoch,
-                    old_alive=[float(v) for v in trans.old_alive],
-                    new_alive=[float(v) for v in trans.new_alive],
-                    trigger=list(trans.trigger),
-                    alpha=float(trans.alpha),
-                    rho=None if trans.rho is None else float(trans.rho),
-                    alpha_scale=float(trans.alpha_scale),
-                    replanned=bool(trans.replanned),
-                    predicted=new_pred or {})
-            # re-primed host-fresh EVERY epoch (transition or not), so the
-            # compiled epoch program sees one input placement signature —
-            # the same discipline as _fresh_telemetry, for the same reason
-            state = state.replace(membership=_fresh_membership())
-        if recoveries_used < config.max_recoveries:
-            # budget exhausted ⇒ stop paying the copy (it could never be
-            # used); the stale snapshot must not linger in HBM either
-            snapshot = jax.tree_util.tree_map(jnp.copy, state)
-        else:
-            snapshot = None
-        e_step, e_scan, e_timer = step_fn, scan_step, comm_timer
-        stage = _stage_fns(epoch)
-        if stage is not None:  # compression-warmup epoch: ramped-ratio programs
-            _, e_step, e_scan, e_timer = stage
+    def _journal_period():
+        """The period that just ended (none before the first) as one
+        `spans` record: loop top to loop top, a rollback's `continue` and
+        the stop's `break` included."""
+        record = spans.end(samples=period_samples)
+        if record is not None:
+            recorder.log_event("spans", **record)
+
+    while epoch < config.epochs:
+        _journal_period()
+        spans.begin(f"{epoch}.{attempt}", epoch=epoch, attempt=attempt)
+        period_samples = 0
+        with spans.span("boundary_hook"):
+            # chaos barrier (no-op unless armed): the campaign's SIGKILL-at-
+            # epoch-boundary injector fires here, before any of this epoch's
+            # host-state transitions (DESIGN.md §23)
+            from ..chaos.taps import maybe_kill
+
+            maybe_kill("epoch_boundary")
+            if boundary_hook is not None:
+                # the control plane's one entry point: apply pending control
+                # documents, run the promotion cadence; `prime` below then
+                # re-primes the device knob image (fresh every boundary,
+                # like telemetry — one input placement signature whether or
+                # not it changed).  A rollback retry re-enters this loop
+                # top: the hook must be idempotent per control-doc version
+                # (serve.trainer is).
+                seam.epoch = epoch
+                boundary_hook(seam)
+        if stop_requested:
+            break
+        with spans.span("prime"):
+            if boundary_hook is not None:
+                state = state.replace(control=_fresh_control())
+            if elastic_ctl is not None:
+                # membership reconciliation — at this host boundary and
+                # nowhere else (DESIGN.md §16).  advance() is idempotent per
+                # epoch, so a rollback retry re-entering this loop top does
+                # not re-apply the transition (the bootstrap is part of the
+                # retry snapshot).
+                trans = elastic_ctl.advance(epoch, schedule)
+                if trans is not None:
+                    member_alive_np = trans.new_alive > 0
+                    if trans.joined.any() or trans.restored.any():
+                        with spans.span("membership_bootstrap"):
+                            state = _bootstrap_rows(state, trans.joined,
+                                                    trans.restored)
+                    new_pred = None
+                    if trans.replanned:
+                        # the re-folded α IS the plan from here on — the
+                        # drift monitor and the journal both re-base,
+                        # exactly like the recovery path's α re-derivation
+                        # (§8)
+                        plan_alpha = float(trans.alpha)
+                        if drift_monitor is not None:
+                            predicted = new_pred = _compose_predicted()
+                            drift_monitor = DriftMonitor(
+                                predicted["rho"], int(bpe),
+                                tolerance=config.drift_tolerance,
+                                patience=config.drift_patience)
+                    recorder.log_event(
+                        "membership", epoch=epoch,
+                        old_alive=[float(v) for v in trans.old_alive],
+                        new_alive=[float(v) for v in trans.new_alive],
+                        trigger=list(trans.trigger),
+                        alpha=float(trans.alpha),
+                        rho=None if trans.rho is None else float(trans.rho),
+                        alpha_scale=float(trans.alpha_scale),
+                        replanned=bool(trans.replanned),
+                        predicted=new_pred or {})
+                # re-primed host-fresh EVERY epoch (transition or not), so
+                # the compiled epoch program sees one input placement
+                # signature — the same discipline as _fresh_telemetry, for
+                # the same reason
+                state = state.replace(membership=_fresh_membership())
+            e_step, e_scan, e_timer = step_fn, scan_step, comm_timer
+            stage = _stage_fns(epoch)
+            if stage is not None:  # compression-warmup epoch: ramped-ratio programs
+                _, e_step, e_scan, e_timer = stage
+        with spans.span("snapshot"):
+            if recoveries_used < config.max_recoveries:
+                # budget exhausted ⇒ stop paying the copy (it could never
+                # be used); the stale snapshot must not linger in HBM either
+                snapshot = jax.tree_util.tree_map(jnp.copy, state)
+            else:
+                snapshot = None
         # overlap-truth capture (DESIGN.md §15): exactly one clamped epoch
         # runs inside a jax.profiler trace window when trace_dir is set;
         # the epoch-boundary block_until_ready below sits INSIDE the
@@ -975,187 +1002,193 @@ def train(config: TrainConfig, resume_dir: Optional[str] = None,
             if config.scan_epoch:
                 state, epoch_metrics = _run_epoch_scanned(
                     e_scan, state, loader, epoch, rng, config.scan_chunk,
-                    ledger=cost_ledger, label=_step_label, mesh=mesh)
+                    spans, ledger=cost_ledger, label=_step_label, mesh=mesh)
             else:
-                sums: Dict[str, float] = {}
-                count = 0
-                for xb, yb in loader.epoch(epoch):
-                    xb, yb = jnp.asarray(xb), jnp.asarray(yb)
-                    if cost_ledger is not None and count == 0:
-                        # once per epoch is enough: batches share a shape,
-                        # and the ledger dedups by program signature anyway
-                        cost_ledger.observe(_step_label, e_step,
-                                            state, xb, yb, rng)
-                    state, m = e_step(state, xb, yb, rng)
-                    # graftcontract: sync — the per-batch python path reads
-                    # every step's metrics back by design (debug mode;
-                    # scan_epoch=True is the zero-per-batch-sync path)
-                    m = {k: float(np.asarray(v)) for k, v in m.items()}
-                    for k, v in m.items():
-                        sums[k] = sums.get(k, 0.0) + v
-                    count += 1
-                epoch_metrics = {k: v / count for k, v in sums.items()}
-            # graftcontract: sync — THE one deliberate per-epoch barrier
-            # (wall-clock truth + everything below rides this sync)
-            jax.block_until_ready(state.params)
+                with spans.span("epoch_python"):
+                    sums: Dict[str, float] = {}
+                    count = 0
+                    for xb, yb in loader.epoch(epoch):
+                        xb, yb = jnp.asarray(xb), jnp.asarray(yb)
+                        if cost_ledger is not None and count == 0:
+                            # once per epoch is enough: batches share a
+                            # shape, and the ledger dedups by program
+                            # signature anyway
+                            cost_ledger.observe(_step_label, e_step,
+                                                state, xb, yb, rng)
+                        state, m = e_step(state, xb, yb, rng)
+                        # graftcontract: sync — the per-batch python path reads
+                        # every step's metrics back by design (debug mode;
+                        # scan_epoch=True is the zero-per-batch-sync path)
+                        m = {k: float(np.asarray(v)) for k, v in m.items()}
+                        for k, v in m.items():
+                            sums[k] = sums.get(k, 0.0) + v
+                        count += 1
+                    epoch_metrics = {k: v / count for k, v in sums.items()}
+            with spans.span("wait_device"):
+                # graftcontract: sync — THE one deliberate per-epoch barrier
+                # (wall-clock truth + everything below rides this sync)
+                jax.block_until_ready(state.params)
         epoch_time = time.time() - t0
+        period_samples = bpe * config.num_workers * config.batch_size
 
-        if config.halt_on_divergence:
-            loss_bad = not np.isfinite(epoch_metrics["loss"])
-            # full-TrainState detector (params + BN stats + momentum + comm
-            # carry): an Inf that so far lives only in momentum is caught
-            # now, not an epoch later when it reaches the parameters.
-            # Only workers currently quarantined by a *dead* event are
-            # exempt — they are guaranteed a heal (params) + row reset
-            # (momentum/carry) at revival.  Stragglers are never healed, so
-            # their state must stay finite like anyone else's.
-            # graftcontract: sync — divergence-detector readback, riding
-            # the epoch-boundary barrier that already completed above
-            finite_rows = np.asarray(finite_check(state))
-            if faults is not None:
-                # graftcontract: sync — schedule-cursor read for the fault
-                # quarantine exemption (one scalar, already materialized)
-                cursor = max(min(int(np.asarray(state.step)) - 1,
-                                 faults.iterations - 1), 0)
-                relevant = faults.dead_alive[cursor] > 0
-            else:
-                relevant = np.ones_like(finite_rows)
-            if member_alive_np is not None:
-                # vacant pool slots are frozen, quarantined rows — their
-                # content is nobody's training state until a (re)join
-                # bootstraps it, so it cannot convict the run
-                relevant = relevant & member_alive_np
-            params_bad = bool(np.any(~finite_rows & relevant))
-            if loss_bad or params_bad:
-                what = ("training loss " + str(epoch_metrics["loss"])) if loss_bad \
-                    else "train state (params/BN stats/momentum/comm carry)"
-                if recoveries_used < config.max_recoveries and snapshot is not None:
-                    # ---- recover instead of abort (DESIGN.md §8) --------
-                    recoveries_used += 1
-                    if config.save and not emergency_written and epoch > 0:
-                        # last-good state, resumable with --resume
-                        path = f"{config.savePath}/{config.name}_emergency"
-                        with annotate("matcha/checkpoint"):
-                            # graftcontract: sync — emergency checkpoint:
-                            # the last good state must reach disk now
-                            save_checkpoint(path, snapshot, epoch - 1,
-                                            schedule=schedule0,
-                                            membership=_membership_sidecar())
-                        emergency_written = True
-                        recorder.log_fault("emergency_checkpoint",
-                                           epoch=epoch, path=path)
-                    if faults is not None:
-                        # the chaos already happened: replaying the rolled-
-                        # back window must not re-fire its NaN injections
-                        lo = epoch * bpe
-                        hi = min((epoch + 1) * bpe, faults.iterations)
-                        faults = faults.without_nan_in(lo, hi)
-                    lr_scale *= config.recovery_lr_backoff
-                    if not alpha_rederived:
-                        # re-derive α for the reliability actually realized:
-                        # the fault plan's alive/link expectation (runtime
-                        # degradation) or the schedule's own stored probs
-                        # (already effective under offline link thinning) —
-                        # effective_activation_probs finally feeding the
-                        # solver at run time instead of only in offline
-                        # studies
-                        alpha_rederived = True
-                        member_mask = (elastic_ctl.alive_mask()
-                                       if elastic_ctl is not None else None)
+        with spans.span("divergence_check"):
+            if config.halt_on_divergence:
+                loss_bad = not np.isfinite(epoch_metrics["loss"])
+                # full-TrainState detector (params + BN stats + momentum + comm
+                # carry): an Inf that so far lives only in momentum is caught
+                # now, not an epoch later when it reaches the parameters.
+                # Only workers currently quarantined by a *dead* event are
+                # exempt — they are guaranteed a heal (params) + row reset
+                # (momentum/carry) at revival.  Stragglers are never healed, so
+                # their state must stay finite like anyone else's.
+                # graftcontract: sync — divergence-detector readback, riding
+                # the epoch-boundary barrier that already completed above
+                finite_rows = np.asarray(finite_check(state))
+                if faults is not None:
+                    # graftcontract: sync — schedule-cursor read for the fault
+                    # quarantine exemption (one scalar, already materialized)
+                    cursor = max(min(int(np.asarray(state.step)) - 1,
+                                     faults.iterations - 1), 0)
+                    relevant = faults.dead_alive[cursor] > 0
+                else:
+                    relevant = np.ones_like(finite_rows)
+                if member_alive_np is not None:
+                    # vacant pool slots are frozen, quarantined rows — their
+                    # content is nobody's training state until a (re)join
+                    # bootstraps it, so it cannot convict the run
+                    relevant = relevant & member_alive_np
+                params_bad = bool(np.any(~finite_rows & relevant))
+                if loss_bad or params_bad:
+                    what = ("training loss " + str(epoch_metrics["loss"])) if loss_bad \
+                        else "train state (params/BN stats/momentum/comm carry)"
+                    if recoveries_used < config.max_recoveries and snapshot is not None:
+                        # ---- recover instead of abort (DESIGN.md §8) --------
+                        recoveries_used += 1
+                        if config.save and not emergency_written and epoch > 0:
+                            # last-good state, resumable with --resume
+                            path = f"{config.savePath}/{config.name}_emergency"
+                            with spans.span("checkpoint"):
+                                # graftcontract: sync — emergency checkpoint:
+                                # the last good state must reach disk now
+                                save_checkpoint(path, snapshot, epoch - 1,
+                                                schedule=schedule0,
+                                                membership=_membership_sidecar())
+                            emergency_written = True
+                            recorder.log_fault("emergency_checkpoint",
+                                               epoch=epoch, path=path)
                         if faults is not None:
-                            from ..resilience import resolve_degraded_alpha
+                            # the chaos already happened: replaying the rolled-
+                            # back window must not re-fire its NaN injections
+                            lo = epoch * bpe
+                            hi = min((epoch + 1) * bpe, faults.iterations)
+                            faults = faults.without_nan_in(lo, hi)
+                        lr_scale *= config.recovery_lr_backoff
+                        if not alpha_rederived:
+                            # re-derive α for the reliability actually realized:
+                            # the fault plan's alive/link expectation (runtime
+                            # degradation) or the schedule's own stored probs
+                            # (already effective under offline link thinning) —
+                            # effective_activation_probs finally feeding the
+                            # solver at run time instead of only in offline
+                            # studies
+                            alpha_rederived = True
+                            member_mask = (elastic_ctl.alive_mask()
+                                           if elastic_ctl is not None else None)
+                            if faults is not None:
+                                from ..resilience import resolve_degraded_alpha
 
-                            # membership occupancy composes into the solve
-                            # (a vacant slot is dead whatever the fault
-                            # plan expected) — same rule as the drift
-                            # monitor's _compose_predicted
-                            new_alpha, new_rho, _ = resolve_degraded_alpha(
-                                schedule, faults, worker_alive=member_mask)
-                        elif member_mask is not None:
-                            new_alpha, new_rho, _ = schedule.refold_for(
-                                member_mask)
-                        else:
-                            from ..schedule import solve_mixing_weight
+                                # membership occupancy composes into the solve
+                                # (a vacant slot is dead whatever the fault
+                                # plan expected) — same rule as the drift
+                                # monitor's _compose_predicted
+                                new_alpha, new_rho, _ = resolve_degraded_alpha(
+                                    schedule, faults, worker_alive=member_mask)
+                            elif member_mask is not None:
+                                new_alpha, new_rho, _ = schedule.refold_for(
+                                    member_mask)
+                            else:
+                                from ..schedule import solve_mixing_weight
 
-                            new_alpha, new_rho = solve_mixing_weight(
-                                schedule.laplacians(), schedule.probs)
-                        # the α actually executing is base × membership
-                        # scale — that is what the re-derivation replaces
-                        executed_alpha = float(schedule.alpha) * (
-                            elastic_ctl.alpha_scale
-                            if elastic_ctl is not None else 1.0)
-                        if abs(new_alpha - executed_alpha) > 1e-9:
-                            old_alpha = executed_alpha
-                            schedule = dataclasses.replace(
-                                schedule, alpha=float(new_alpha))
-                            if elastic_ctl is not None:
-                                # the composed solve subsumes the
-                                # membership re-fold: new_alpha IS the
-                                # executed α, so the controller re-bases
-                                # to scale 1 against the rebound schedule
-                                # (later membership folds re-derive
-                                # against the new base); the loop-top
-                                # _fresh_membership() re-primes the
-                                # device copy on the retry
-                                elastic_ctl.alpha = float(new_alpha)
-                                elastic_ctl.rho = float(new_rho)
-                                elastic_ctl.alpha_scale = 1.0
-                            # the re-derived α IS the plan from here on:
-                            # the drift monitor must predict with it, or
-                            # every post-recovery epoch would be scored
-                            # against a schedule that no longer runs —
-                            # and the journal must carry the re-based
-                            # prediction so `obs_tpu.py drift` replays
-                            # against the same plan the live monitor used
-                            plan_alpha = float(new_alpha)
-                            new_pred = None
-                            if drift_monitor is not None:
-                                predicted = new_pred = _compose_predicted()
-                                drift_monitor = DriftMonitor(
-                                    predicted["rho"], int(bpe),
-                                    tolerance=config.drift_tolerance,
-                                    patience=config.drift_patience)
-                            recorder.log_fault(
-                                "alpha_rederived", epoch=epoch,
-                                old=old_alpha,
-                                new=float(new_alpha), rho=float(new_rho),
-                                predicted=new_pred)
-                    # rebuild the compiled programs against the updated
-                    # lr_scale / α / consumed fault arrays — the same recipe
-                    # setup used, so retries can never run a stale program
-                    _build_programs()
-                    recorder.log_fault(
-                        "rollback", epoch=epoch, reason=what,
-                        lr_scale=lr_scale, attempt=recoveries_used)
-                    state = snapshot
-                    snapshot = None
-                    continue  # retry this epoch from the last good state
-                # preserve the curve leading into the blow-up (flush beats the
-                # every-10-epochs cadence, which would drop up to 9 epochs)
-                recorder.add_epoch(
-                    epoch_time=epoch_time, comp_time=epoch_time, comm_time=0.0,
-                    train_acc=epoch_metrics["accuracy"],
-                    train_loss=epoch_metrics["loss"],
-                    test_acc=np.zeros(config.num_workers),
-                    disagreement=epoch_metrics["disagreement"],
-                )
-                if config.save:
-                    # graftcontract: sync — divergence-abort flush: the
-                    # curve leading into the blow-up must survive on disk
-                    recorder.save()
-                budget_note = (f", {recoveries_used}/{config.max_recoveries} "
-                               f"recoveries exhausted"
-                               if config.max_recoveries else "")
-                raise TrainingDiverged(
-                    f"non-finite {what} in epoch {epoch} "
-                    f"(lr={config.lr}, communicator={config.communicator}"
-                    f"{budget_note})"
-                )
+                                new_alpha, new_rho = solve_mixing_weight(
+                                    schedule.laplacians(), schedule.probs)
+                            # the α actually executing is base × membership
+                            # scale — that is what the re-derivation replaces
+                            executed_alpha = float(schedule.alpha) * (
+                                elastic_ctl.alpha_scale
+                                if elastic_ctl is not None else 1.0)
+                            if abs(new_alpha - executed_alpha) > 1e-9:
+                                old_alpha = executed_alpha
+                                schedule = dataclasses.replace(
+                                    schedule, alpha=float(new_alpha))
+                                if elastic_ctl is not None:
+                                    # the composed solve subsumes the
+                                    # membership re-fold: new_alpha IS the
+                                    # executed α, so the controller re-bases
+                                    # to scale 1 against the rebound schedule
+                                    # (later membership folds re-derive
+                                    # against the new base); the loop-top
+                                    # _fresh_membership() re-primes the
+                                    # device copy on the retry
+                                    elastic_ctl.alpha = float(new_alpha)
+                                    elastic_ctl.rho = float(new_rho)
+                                    elastic_ctl.alpha_scale = 1.0
+                                # the re-derived α IS the plan from here on:
+                                # the drift monitor must predict with it, or
+                                # every post-recovery epoch would be scored
+                                # against a schedule that no longer runs —
+                                # and the journal must carry the re-based
+                                # prediction so `obs_tpu.py drift` replays
+                                # against the same plan the live monitor used
+                                plan_alpha = float(new_alpha)
+                                new_pred = None
+                                if drift_monitor is not None:
+                                    predicted = new_pred = _compose_predicted()
+                                    drift_monitor = DriftMonitor(
+                                        predicted["rho"], int(bpe),
+                                        tolerance=config.drift_tolerance,
+                                        patience=config.drift_patience)
+                                recorder.log_fault(
+                                    "alpha_rederived", epoch=epoch,
+                                    old=old_alpha,
+                                    new=float(new_alpha), rho=float(new_rho),
+                                    predicted=new_pred)
+                        # rebuild the compiled programs against the updated
+                        # lr_scale / α / consumed fault arrays — the same recipe
+                        # setup used, so retries can never run a stale program
+                        _build_programs()
+                        recorder.log_fault(
+                            "rollback", epoch=epoch, reason=what,
+                            lr_scale=lr_scale, attempt=recoveries_used)
+                        state = snapshot
+                        snapshot = None
+                        attempt += 1
+                        continue  # retry this epoch from the last good state
+                    # preserve the curve leading into the blow-up (flush beats the
+                    # every-10-epochs cadence, which would drop up to 9 epochs)
+                    recorder.add_epoch(
+                        epoch_time=epoch_time, comp_time=epoch_time, comm_time=0.0,
+                        train_acc=epoch_metrics["accuracy"],
+                        train_loss=epoch_metrics["loss"],
+                        test_acc=np.zeros(config.num_workers),
+                        disagreement=epoch_metrics["disagreement"],
+                    )
+                    if config.save:
+                        # graftcontract: sync — divergence-abort flush: the
+                        # curve leading into the blow-up must survive on disk
+                        recorder.save()
+                    budget_note = (f", {recoveries_used}/{config.max_recoveries} "
+                                   f"recoveries exhausted"
+                                   if config.max_recoveries else "")
+                    raise TrainingDiverged(
+                        f"non-finite {what} in epoch {epoch} "
+                        f"(lr={config.lr}, communicator={config.communicator}"
+                        f"{budget_note})"
+                    )
 
         comm_time = comm_encode_time = 0.0
         if e_timer is not None:
             window = run_flags[epoch * bpe : (epoch + 1) * bpe]
-            with annotate("matcha/comm_split_timer"):
+            with spans.span("comm_split_timer"):
                 split = e_timer(state, window)
             comm_time = min(split["comm_time"], epoch_time)
             # encode is a component of comm_time, never exceeding it
@@ -1168,117 +1201,123 @@ def train(config: TrainConfig, resume_dir: Optional[str] = None,
         test_loss = test_acc = np.zeros(config.num_workers)
         eval_alive = None
         if config.eval_every and (epoch + 1) % config.eval_every == 0:
-            eval_batch = config.eval_batch or max(16, 1024 // config.num_workers)
-            test_loss, test_acc = _evaluate_in_batches(
-                evaluate, state, dataset.x_test, dataset.y_test,
-                batch=eval_batch, ledger=cost_ledger
+            with spans.span("evaluate"):
+                eval_batch = config.eval_batch or max(16, 1024 // config.num_workers)
+                test_loss, test_acc = _evaluate_in_batches(
+                    evaluate, state, dataset.x_test, dataset.y_test,
+                    batch=eval_batch, ledger=cost_ledger
+                )
+                if faults is not None or member_alive_np is not None:
+                    # same quarantine exemption as the train-side metrics: a
+                    # plan-dead worker's (or vacant pool slot's) local state may
+                    # legitimately be garbage — its eval entries become explicit
+                    # NaN gaps instead of silently poisoning the tacc series and
+                    # the test_*_mean history the sweep/verify consumers read
+                    if faults is not None:
+                        # graftcontract: sync — eval-side cursor read, same
+                        # quarantine exemption as the train-side detector
+                        cur = max(min(int(np.asarray(state.step)) - 1,
+                                      faults.iterations - 1), 0)
+                        eval_alive = faults.dead_alive[cur] > 0
+                        if member_alive_np is not None:
+                            eval_alive = eval_alive & member_alive_np
+                    else:
+                        eval_alive = member_alive_np
+                    test_loss = np.where(eval_alive, test_loss, np.nan)
+                    test_acc = np.where(eval_alive, test_acc, np.nan)
+
+        with spans.span("record_epoch"):
+            recorder.add_epoch(
+                epoch_time=epoch_time,
+                comp_time=epoch_time - comm_time,
+                comm_time=comm_time,
+                train_acc=epoch_metrics["accuracy"],
+                train_loss=epoch_metrics["loss"],
+                test_acc=test_acc,
+                disagreement=epoch_metrics["disagreement"],
             )
-            if faults is not None or member_alive_np is not None:
-                # same quarantine exemption as the train-side metrics: a
-                # plan-dead worker's (or vacant pool slot's) local state may
-                # legitimately be garbage — its eval entries become explicit
-                # NaN gaps instead of silently poisoning the tacc series and
-                # the test_*_mean history the sweep/verify consumers read
-                if faults is not None:
-                    # graftcontract: sync — eval-side cursor read, same
-                    # quarantine exemption as the train-side detector
-                    cur = max(min(int(np.asarray(state.step)) - 1,
-                                  faults.iterations - 1), 0)
-                    eval_alive = faults.dead_alive[cur] > 0
-                    if member_alive_np is not None:
-                        eval_alive = eval_alive & member_alive_np
-                else:
-                    eval_alive = member_alive_np
-                test_loss = np.where(eval_alive, test_loss, np.nan)
-                test_acc = np.where(eval_alive, test_acc, np.nan)
+            history.append({
+                "epoch": epoch,
+                **epoch_metrics,
+                "test_acc_mean": _masked_mean(test_acc, eval_alive),
+                "test_loss_mean": _masked_mean(test_loss, eval_alive),
+                "epoch_time": epoch_time,
+                "comm_time": comm_time,
+                "comm_encode_time": comm_encode_time,
+                "comm_exchange_time": comm_time - comm_encode_time,
+            })
 
-        recorder.add_epoch(
-            epoch_time=epoch_time,
-            comp_time=epoch_time - comm_time,
-            comm_time=comm_time,
-            train_acc=epoch_metrics["accuracy"],
-            train_loss=epoch_metrics["loss"],
-            test_acc=test_acc,
-            disagreement=epoch_metrics["disagreement"],
-        )
-        history.append({
-            "epoch": epoch,
-            **epoch_metrics,
-            "test_acc_mean": _masked_mean(test_acc, eval_alive),
-            "test_loss_mean": _masked_mean(test_loss, eval_alive),
-            "epoch_time": epoch_time,
-            "comm_time": comm_time,
-            "comm_encode_time": comm_encode_time,
-            "comm_exchange_time": comm_time - comm_encode_time,
-        })
-
-        if faults is not None and float(epoch_metrics.get("healed", 0.0)) > 0:
-            recorder.log_fault(
-                "healed", epoch=epoch,
-                rows=float(epoch_metrics["healed"]) * bpe,
-                mean_alive=float(epoch_metrics.get("alive_workers",
-                                                   config.num_workers)))
+            if faults is not None and float(epoch_metrics.get("healed", 0.0)) > 0:
+                recorder.log_fault(
+                    "healed", epoch=epoch,
+                    rows=float(epoch_metrics["healed"]) * bpe,
+                    mean_alive=float(epoch_metrics.get("alive_workers",
+                                                       config.num_workers)))
 
         if tel_spec is not None:
-            # graftcontract: sync — the ONE host read of the in-graph
-            # telemetry accumulator, riding the epoch-boundary barrier
-            # that already happened above; the accumulator then resets
-            # for the next epoch's window
-            tel = telemetry_flush(state.telemetry)
-            # the per-worker stats ride the same flush but feed the
-            # heartbeat, not the telemetry event (its scalar schema is
-            # pinned; attribution lives in the health plane)
-            worker_stats = {
-                "worker_participation": tel.pop("worker_participation"),
-                "worker_disagreement": tel.pop("worker_disagreement")}
-            recorder.log_event("telemetry", epoch=epoch, **tel)
-            state = state.replace(telemetry=_fresh_telemetry())
-            if drift_monitor is not None:
-                drift = drift_monitor.observe(epoch,
-                                              tel["disagreement_mean"])
-                if drift is not None:
-                    recorder.log_event("drift", **drift)
+            with spans.span("telemetry_flush"):
+                # graftcontract: sync — the ONE host read of the in-graph
+                # telemetry accumulator, riding the epoch-boundary barrier
+                # that already happened above; the accumulator then resets
+                # for the next epoch's window
+                tel = telemetry_flush(state.telemetry)
+                # the per-worker stats ride the same flush but feed the
+                # heartbeat, not the telemetry event (its scalar schema is
+                # pinned; attribution lives in the health plane)
+                worker_stats = {
+                    "worker_participation": tel.pop("worker_participation"),
+                    "worker_disagreement": tel.pop("worker_disagreement")}
+                recorder.log_event("telemetry", epoch=epoch, **tel)
+                state = state.replace(telemetry=_fresh_telemetry())
+                if drift_monitor is not None:
+                    drift = drift_monitor.observe(epoch,
+                                                  tel["disagreement_mean"])
+                    if drift is not None:
+                        recorder.log_event("drift", **drift)
             if health_emitter is not None:
-                # step is host arithmetic (epoch boundary × batches/epoch),
-                # NOT a device read — the zero-new-syncs contract
-                peak = max((e.get("peak_bytes") or 0.0
-                            for e in cost_ledger.programs), default=0.0) \
-                    if cost_ledger is not None else 0.0
-                # graftcontract: sync — per-epoch heartbeat emit (host
-                # values already read at this boundary; file write only)
-                hb = health_emitter.beat(
-                    epoch=epoch, step=(epoch + 1) * bpe,
-                    steps=tel["steps"], epoch_time=epoch_time,
-                    comm_time=comm_time,
-                    workers=_member_workers(worker_stats),
-                    peak_bytes=peak or None)
-                recorder.log_event("heartbeat", **hb)
-                for a in anomaly_detector.observe(hb):
-                    recorder.log_event("anomaly", **a)
-                for ev in health_emitter.drain_recovery():
-                    # the heartbeat sink degraded or recovered: the run
-                    # journal is the loud record a watcher reads when the
-                    # per-host files themselves go quiet (DESIGN.md §23)
-                    recorder.log_event("recovery", scope="io",
-                                       action=ev["action"],
-                                       reason=ev["reason"],
-                                       sink=ev["sink"], epoch=epoch)
+                with spans.span("heartbeat"):
+                    # step is host arithmetic (epoch boundary × batches/epoch),
+                    # NOT a device read — the zero-new-syncs contract
+                    peak = max((e.get("peak_bytes") or 0.0
+                                for e in cost_ledger.programs), default=0.0) \
+                        if cost_ledger is not None else 0.0
+                    # graftcontract: sync — per-epoch heartbeat emit (host
+                    # values already read at this boundary; file write only)
+                    hb = health_emitter.beat(
+                        epoch=epoch, step=(epoch + 1) * bpe,
+                        steps=tel["steps"], epoch_time=epoch_time,
+                        comm_time=comm_time,
+                        workers=_member_workers(worker_stats),
+                        peak_bytes=peak or None)
+                    recorder.log_event("heartbeat", **hb)
+                    for a in anomaly_detector.observe(hb):
+                        recorder.log_event("anomaly", **a)
+                    for ev in health_emitter.drain_recovery():
+                        # the heartbeat sink degraded or recovered: the run
+                        # journal is the loud record a watcher reads when the
+                        # per-host files themselves go quiet (DESIGN.md §23)
+                        recorder.log_event("recovery", scope="io",
+                                           action=ev["action"],
+                                           reason=ev["reason"],
+                                           sink=ev["sink"], epoch=epoch)
         _watch_retrace(e_scan if config.scan_epoch else e_step)
 
         if config.save and recorder.epochs_recorded % 10 == 0:
-            with annotate("matcha/recorder_flush"):
+            with spans.span("recorder_flush"):
                 # graftcontract: sync — recorder flush cadence parity
                 # (train_mpi.py:159-160); append-only CSV + journal write
                 recorder.save()
         if config.checkpoint_every and (epoch + 1) % config.checkpoint_every == 0:
             path = f"{config.savePath}/{config.name}_ckpt"
-            with annotate("matcha/checkpoint"):
+            with spans.span("checkpoint"):
                 # graftcontract: sync — periodic checkpoint write at the
                 # configured cadence (materializes the full TrainState)
                 save_checkpoint(path, state, epoch, schedule=schedule0,
                                 membership=_membership_sidecar())
-            recorder.log_event("checkpoint", epoch=epoch, path=path)
+                recorder.log_event("checkpoint", epoch=epoch, path=path)
         epoch += 1
+        attempt = 0
+    _journal_period()
 
     if config.overlap == "1step":
         # drain the pipeline: apply the in-flight delta(s) so the returned
@@ -1317,7 +1356,7 @@ def train(config: TrainConfig, resume_dir: Optional[str] = None,
             cost_ledger.observe("drain", _drain, state)
         state = _drain(state)
     if config.save:
-        with annotate("matcha/recorder_flush"):
+        with spans.span("recorder_flush"):
             recorder.save()
     return TrainResult(state, recorder, schedule, history)
 
@@ -1514,12 +1553,11 @@ def _make_epoch_scan(step_fn):
     return scan_step
 
 
-def _stage_batches(arrays, mesh):
-    """Host batches → one ``[steps, N, ...]`` device stack.  On a mesh the
+def _put_batches(stack, mesh):
+    """One host ``[steps, N, ...]`` stack → the device.  On a mesh the
     worker axis lands sharded like the state it meets (``P(None,
     WORKER_AXIS)``): a bare ``jnp.asarray`` would park the whole stack on
     device 0 and leave every epoch a reshard from that one chip."""
-    stack = np.stack(arrays)
     if mesh is None:
         return jnp.asarray(stack)
     return jax.device_put(
@@ -1527,7 +1565,7 @@ def _stage_batches(arrays, mesh):
 
 
 def _run_epoch_scanned(scan_step, state, loader: WorkerBatches, epoch: int,
-                       rng, scan_chunk: Optional[int], ledger=None,
+                       rng, scan_chunk: Optional[int], spans, ledger=None,
                        label: str = "epoch_scan", mesh=None):
     """One epoch through the scanned step, whole-epoch or chunk-pipelined.
 
@@ -1538,59 +1576,65 @@ def _run_epoch_scanned(scan_step, state, loader: WorkerBatches, epoch: int,
     device executing segment k — a two-deep host→device pipeline without
     explicit double-buffering.  Metrics are weighted by segment length, so
     the epoch means are identical to the whole-epoch scan.
-    """
-    def observed(s, xs, ys):
-        """Dispatch one scanned segment, after the ledger (when on) has
-        costed its program — a chunked epoch's tail is a second compiled
-        shape and journals its own compile event."""
-        if ledger is not None:
-            ledger.observe(label, scan_step, s, xs, ys, rng)
-        return scan_step(s, xs, ys, rng)
 
-    batches = loader.epoch(epoch)
+    Every phase is a span of ``spans`` (``utils.profiling.SPAN_NAMES``);
+    a chunked epoch's carry ``segment=<i>``, one set per segment.
+    """
+    batches = loader.epoch(epoch)  # a generator: draining it is the gather
+
+    def run_segment(s, steps, **segment):
+        """Load, stack, put and dispatch the next ``steps`` batches as one
+        scanned segment, after the ledger (when on) has costed its program
+        — a chunked epoch's tail is a second compiled shape and journals
+        its own compile event."""
+        with spans.span("load_batches", **segment):
+            loaded = list(itertools.islice(batches, steps))
+        with spans.span("stack_batches", **segment):
+            xs, ys = (np.stack(arrays) for arrays in zip(*loaded))
+            # freed here, under the span that copied them: hundreds of MB
+            # of rows would else go at this frame's end, under no name
+            del loaded
+        with spans.span("h2d", bytes=xs.nbytes + ys.nbytes, **segment):
+            xs, ys = _put_batches(xs, mesh), _put_batches(ys, mesh)
+        if ledger is not None:
+            with spans.span("ledger_observe", **segment):
+                ledger.observe(label, scan_step, s, xs, ys, rng)
+        with spans.span("dispatch", steps=steps, **segment):
+            return scan_step(s, xs, ys, rng)
+
     if not scan_chunk:
-        xs, ys = zip(*batches)
-        state, metrics = observed(state, _stage_batches(xs, mesh),
-                                  _stage_batches(ys, mesh))
-        # graftcontract: sync — whole-epoch metrics readback: one forced
-        # materialization per epoch, after the scan returns
-        return state, {k: float(np.mean(v)) for k, v in metrics.items()}
+        state, metrics = run_segment(state, loader.batches_per_epoch)
+        with spans.span("wait_device"):
+            # graftcontract: sync — whole-epoch metrics readback: one forced
+            # materialization per epoch, after the scan returns
+            return state, {k: float(np.mean(v)) for k, v in metrics.items()}
 
     sums: Dict[str, float] = {}
     total = 0
-    seg_x: List[np.ndarray] = []
-    seg_y: List[np.ndarray] = []
     pending = None  # metrics of the in-flight segment (device may still run)
 
-    def flush(metrics, n):
+    def flush(metrics, n, segment):
         nonlocal total
-        for k, v in metrics.items():
-            # graftcontract: sync — per-chunk metrics force, deliberately
-            # AFTER the next segment's dispatch (the two-deep pipeline)
-            sums[k] = sums.get(k, 0.0) + float(np.sum(v))
+        with spans.span("wait_device", segment=segment):
+            for k, v in metrics.items():
+                # graftcontract: sync — per-chunk metrics force, deliberately
+                # AFTER the next segment's dispatch (the two-deep pipeline)
+                sums[k] = sums.get(k, 0.0) + float(np.sum(v))
         total += n
 
-    for xb, yb in batches:
-        seg_x.append(xb)
-        seg_y.append(yb)
-        if len(seg_x) == scan_chunk:
-            # stack + H2D + dispatch FIRST, then force the previous
-            # segment's metrics: the flush must not sit between the device
-            # going idle and the next segment's dispatch, or the promised
-            # overlap never happens (metrics are not donated, so reading
-            # them after the next dispatch is safe)
-            state, metrics = observed(state, _stage_batches(seg_x, mesh),
-                                      _stage_batches(seg_y, mesh))
-            if pending is not None:
-                flush(*pending)
-            pending = (metrics, len(seg_x))
-            seg_x, seg_y = [], []
-    if seg_x:  # tail segment (its own compiled shape, at most once per run)
-        state, metrics = observed(state, _stage_batches(seg_x, mesh),
-                                  _stage_batches(seg_y, mesh))
+    # (a tail segment is its own compiled shape, at most once per run)
+    starts = range(0, loader.batches_per_epoch, scan_chunk)
+    for segment, first in enumerate(starts):
+        steps = min(scan_chunk, loader.batches_per_epoch - first)
+        # load + stack + H2D + dispatch FIRST, then force the previous
+        # segment's metrics: the flush must not sit between the device
+        # going idle and the next segment's dispatch, or the promised
+        # overlap never happens (metrics are not donated, so reading them
+        # after the next dispatch is safe)
+        state, metrics = run_segment(state, steps, segment=segment)
         if pending is not None:
             flush(*pending)
-        pending = (metrics, len(seg_x))
+        pending = (metrics, steps, segment)
     if pending is not None:
         flush(*pending)
     return state, {k: v / total for k, v in sums.items()}

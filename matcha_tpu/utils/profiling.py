@@ -8,17 +8,22 @@ gossip is fused into the train step — so the framework offers two layers:
   series, reference-compatible CSVs), and
 * real ``jax.profiler`` traces for kernel-level attribution, via
   :func:`trace` — view in TensorBoard or Perfetto to see the Pallas gossip
-  kernel, the per-matching permutes, and the model's fwd/bwd separately.
+  kernel, the per-matching permutes, and the model's fwd/bwd separately;
+* the loop's own host phases (:class:`SpanRecorder`): every statement of
+  an epoch period under one name from ``SPAN_NAMES``, kept in memory for
+  the journal and, under a profiler session, on the device trace's clock.
 """
 
 from __future__ import annotations
 
 import contextlib
+import time
 from pathlib import Path
+from typing import List, Optional
 
 import jax
 
-__all__ = ["trace", "annotate", "device_span"]
+__all__ = ["trace", "SPAN_NAMES", "SpanRecorder", "device_span"]
 
 
 @contextlib.contextmanager
@@ -42,22 +47,94 @@ def trace(log_dir: str, *, create_perfetto_link: bool = False):
         jax.profiler.stop_trace()
 
 
-def annotate(name: str):
-    """Named span for the profiler timeline (``jax.profiler.TraceAnnotation``).
+#: Every span name the train loop records, in loop order (README
+#: "Profiling" says what code each covers).  ``membership_bootstrap`` and
+#: an on-demand or emergency ``checkpoint`` open inside another span
+#: (``prime``, ``boundary_hook``, ``divergence_check``) and are its
+#: children; every other name is a leaf of the epoch period.
+SPAN_NAMES = (
+    "boundary_hook", "prime", "membership_bootstrap", "snapshot",
+    "load_batches", "stack_batches", "h2d", "ledger_observe", "dispatch",
+    "epoch_python", "wait_device",
+    "divergence_check", "comm_split_timer", "evaluate", "record_epoch",
+    "telemetry_flush", "heartbeat", "recorder_flush", "checkpoint",
+)
 
-    Wrap host-side phases (data staging, checkpointing, the comm-split
-    timer) so they are attributable in the trace alongside device work.
+
+class SpanRecorder:
+    """Named host phases of one ``train()`` call, on two clocks at once.
+
+    :meth:`span` opens ``jax.profiler.TraceAnnotation("matcha/" + name)``
+    — so the phase lies on the device trace's clock whenever a profiler
+    session is open (a flag test when none is) — and keeps ``{name, t0,
+    t1, parent, **counts}`` in memory, ``t0``/``t1`` from
+    ``time.perf_counter()`` relative to the run's start (``origin`` is
+    what the run's clock read when the recorder was made).  ``parent``
+    names the epoch period the span belongs to (:meth:`begin`), or
+    ``"<period>/<name>"`` of the span it opened inside.
 
     **Host phases only.**  Inside a jitted function this bracket exists at
     *trace* time, not run time — XLA fuses the gossip into the step, so a
     wall-clock bracket around ``begin_mix`` would measure nothing (the
     round-1 lesson behind the two-program comm split).  For in-graph
-    phases use :func:`device_span`, whose name lands in the op metadata of
-    everything traced under it and therefore survives into the executed
-    kernels' profiler rows — spans, not wall-clock brackets, are the
-    source of truth for the compute/comm split.
+    phases use :func:`device_span`.
+
+    The profiler never sees a span inside another: a reduction that adds
+    each name's cover of a device gap would count a nested parent twice.
+    A span that opens inside another suspends the outer one's annotation
+    and reopens it when it closes, so the outer name shows there as two
+    events around the inner one.
     """
-    return jax.profiler.TraceAnnotation(name)
+
+    def __init__(self, origin: float = 0.0):
+        self._zero = time.perf_counter() - origin
+        self._period: Optional[dict] = None
+        self._open: List[list] = []  # [record, annotation] innermost last
+        self.spans: List[dict] = []  # since the last end(), in start order
+
+    def _now(self) -> float:
+        return time.perf_counter() - self._zero
+
+    def begin(self, period: str, **fields) -> None:
+        """Open the epoch period that the following spans belong to."""
+        self._period = {"period": period, **fields, "t0": self._now()}
+
+    def end(self, **counts) -> Optional[dict]:
+        """Close the period: ``{period, t0, t1, **counts, spans}`` with
+        the spans recorded since :meth:`begin`; None where none is open."""
+        if self._period is None:
+            return None
+        record = dict(self._period, t1=self._now(), **counts,
+                      spans=self.spans)
+        self._period, self.spans = None, []
+        return record
+
+    @contextlib.contextmanager
+    def span(self, name: str, **counts):
+        """One named phase around the ``with`` body."""
+        parent = self._period["period"] if self._period else None
+        if self._open:
+            outer, annotation = self._open[-1]
+            annotation.__exit__(None, None, None)
+            parent = "/".join(filter(None, (outer["parent"], outer["name"])))
+        record = {"name": name, "t0": self._now(), "t1": None,
+                  "parent": parent, **counts}
+        self.spans.append(record)
+        self._open.append([record, _annotate(name)])
+        try:
+            yield
+        finally:
+            self._open.pop()[1].__exit__(None, None, None)
+            record["t1"] = self._now()
+            if self._open:
+                self._open[-1][1] = _annotate(self._open[-1][0]["name"])
+
+
+def _annotate(name: str):
+    """An entered ``TraceAnnotation("matcha/<name>")``."""
+    annotation = jax.profiler.TraceAnnotation("matcha/" + name)
+    annotation.__enter__()
+    return annotation
 
 
 def device_span(name: str):

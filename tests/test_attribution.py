@@ -329,9 +329,10 @@ def test_timeline_roundtrips_reference_journal():
         names = sorted(e["name"] for e in trace["traceEvents"]
                        if e.get("args", {}).get("src") == f"journal:{i}")
         assert names == ["comm", "compute"]
-    # one host track + the journal track, named
+    # one host track + the journal track and its host-phases thread, named
     metas = [e for e in trace["traceEvents"] if e.get("ph") == "M"]
-    assert {m["args"]["name"] for m in metas} == {"journal", "host host0"}
+    assert {m["args"]["name"] for m in metas} == {
+        "journal", "host host0", "host phases"}
     assert "Perfetto" in render_timeline_summary(trace) or \
         "perfetto" in render_timeline_summary(trace)
 

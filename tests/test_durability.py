@@ -399,9 +399,9 @@ def test_tamper_bare_heartbeat_write_fires_gl303(tmp_path):
     scope, from the train root."""
     src = _tampered(
         tmp_path, "matcha_tpu/train/loop.py",
-        '                recorder.log_event("heartbeat", **hb)',
-        '                with open("heartbeat.json", "w") as f:\n'
-        '                    f.write(str(hb))')
+        '                    recorder.log_event("heartbeat", **hb)',
+        '                    with open("heartbeat.json", "w") as f:\n'
+        '                        f.write(str(hb))')
     vs = lint_source(src, list(DURABILITY_RULES))
     assert _ids(vs) == ["GL303"]
     assert "**epoch** scope" in vs[0].message

@@ -187,7 +187,8 @@ def test_reference_journal_validates_line_by_line():
     the rejoin re-fold) carrying the re-based drift prediction — which is
     exactly what keeps `obs_tpu drift` exit 0 on this journal
     (test_cli_drift_exit_codes): the replay re-bases at the swap like the
-    live monitor did.  ISSUE 18 re-pins at v7 with the recovery ladder:
+    live monitor did.  ISSUE 24 re-pins at v8 with the loop's `spans`
+    records.  ISSUE 18 re-pinned at v7 with the recovery ladder:
     the recipe checkpoints every epoch (`checkpoint` events + digest
     sidecars) and the regeneration script bit-flips the newest
     generation, lets the sidecar convict it, quarantines it through the
@@ -196,12 +197,18 @@ def test_reference_journal_validates_line_by_line():
     assert events, "reference journal is empty"
     for i, e in enumerate(events):
         assert validate_event(e) == [], f"line {i + 1}: {validate_event(e)}"
-    assert {e["v"] for e in events} == {7}
+    assert {e["v"] for e in events} == {8}
     kinds = {e["kind"] for e in events}
     assert {"run_start", "epoch", "telemetry", "compile",
             "membership", "heartbeat", "anomaly", "attribution",
             "backend", "control", "promotion", "checkpoint",
-            "recovery"} <= kinds
+            "recovery", "spans"} <= kinds
+    # v8 (ISSUE 24): one `spans` record an epoch period, the rejoin's
+    # bootstrap a child of `prime`
+    periods = [e for e in events if e["kind"] == "spans"]
+    assert [e["period"] for e in periods] == [f"{k}.0" for k in range(8)]
+    assert [s["parent"] for e in periods for s in e["spans"]
+            if s["name"] == "membership_bootstrap"] == ["5.0/prime"]
     leave, rejoin = [e for e in events if e["kind"] == "membership"]
     assert (leave["epoch"], rejoin["epoch"]) == (2, 5)
     assert [t["kind"] for t in leave["trigger"]] == ["leave"]
@@ -471,7 +478,7 @@ def test_v7_recovery_kind_is_versioned_and_v6_validates_verbatim():
         V7_KINDS,
     )
 
-    assert SCHEMA_VERSION == 7
+    assert SCHEMA_VERSION >= 7
     assert V7_KINDS == {"recovery"}
     assert V7_KINDS <= EVENT_KINDS
     recovery = {"v": 7, "kind": "recovery", "t": 1.0, "epoch": 3,
@@ -491,6 +498,37 @@ def test_v7_recovery_kind_is_versioned_and_v6_validates_verbatim():
                   "reason": "value-scope fields ['budget']",
                   "fields": {"budget": {"budget": 0.25}}}
     assert validate_event(v6_control) == []
+
+
+def test_v8_spans_kind_is_versioned_and_v7_validates_verbatim():
+    """The v7→v8 bump (ISSUE 24) is additive: `spans` is the one new kind,
+    it requires the period's identity, bounds, sample count and span list,
+    and a `spans` event claiming v<=7 is a lying envelope; a v7 `recovery`
+    event validates verbatim under the v8 reader."""
+    from matcha_tpu.obs.journal import (
+        EVENT_KINDS,
+        KIND_MIN_VERSION,
+        SCHEMA_VERSION,
+        V8_KINDS,
+    )
+
+    assert SCHEMA_VERSION == 8
+    assert V8_KINDS == {"spans"} and V8_KINDS <= EVENT_KINDS
+    assert KIND_MIN_VERSION["spans"] == 8
+    record = {"v": 8, "kind": "spans", "t": 9.0, "epoch": 3, "attempt": 1,
+              "period": "3.1", "t0": 4.0, "t1": 9.0, "samples": 4096,
+              "spans": [{"name": "h2d", "t0": 4.5, "t1": 4.75,
+                         "parent": "3.1", "bytes": 1 << 20}]}
+    assert validate_event(record) == []
+    for v in range(1, 8):
+        assert any("v8 kind" in p
+                   for p in validate_event({**record, "v": v}))
+    assert any("missing" in p for p in validate_event(
+        {k: v for k, v in record.items() if k != "spans"}))
+    v7_recovery = {"v": 7, "kind": "recovery", "t": 1.0, "scope": "io",
+                   "action": "degraded", "reason": "ENOSPC",
+                   "sink": "recorder"}
+    assert validate_event(v7_recovery) == []
 
 
 def test_read_journal_tail_is_bounded_and_exact(tmp_path):
@@ -737,7 +775,7 @@ def test_trace_creates_nonempty_trace_dir(tmp_path):
     assert any(p.stat().st_size > 0 for p in produced)
 
 
-def test_annotate_and_device_span_nest_in_jit_without_retrace():
+def test_host_span_and_device_span_nest_in_jit_without_retrace():
     """ISSUE 7 satellite: both span helpers must be trace-pure — a step
     using them compiles once and never again (the retrace sanitizer is
     the arbiter, same as for the production step)."""
@@ -745,7 +783,7 @@ def test_annotate_and_device_span_nest_in_jit_without_retrace():
     import jax.numpy as jnp
 
     from matcha_tpu.analysis.sanitizer import check_single_trace, retrace_guard
-    from matcha_tpu.utils import annotate, device_span
+    from matcha_tpu.utils import SpanRecorder, device_span
 
     def step(x):
         with device_span("test/phase_a"):
@@ -755,7 +793,7 @@ def test_annotate_and_device_span_nest_in_jit_without_retrace():
                 return jnp.sum(y)
 
     guarded, counter = retrace_guard(jax.jit(step))
-    with annotate("test/host_phase"):
+    with SpanRecorder().span("test/host_phase"):
         for _ in range(4):
             guarded(jnp.ones(8)).block_until_ready()
     check_single_trace(counter, "span step")

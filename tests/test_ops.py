@@ -102,10 +102,10 @@ def test_profiler_trace_writes_events(tmp_path):
     import jax
     import jax.numpy as jnp
 
-    from matcha_tpu.utils import annotate, trace
+    from matcha_tpu.utils import SpanRecorder, trace
 
     with trace(str(tmp_path)):
-        with annotate("tiny-matmul"):
+        with SpanRecorder().span("tiny-matmul"):
             out = jax.jit(lambda a: a @ a)(jnp.ones((8, 8)))
             jax.block_until_ready(out)
     # the profiler lays out <dir>/plugins/profile/<run>/*.xplane.pb
