@@ -15,6 +15,7 @@ The loader yields batches stacked over the worker axis — ``x: [N, B, ...]``,
 from __future__ import annotations
 
 import dataclasses
+import itertools
 from typing import Iterator, List, Tuple
 
 import numpy as np
@@ -276,6 +277,12 @@ def _augment_apply_python(
     return out
 
 
+def _crop_flip_draws(rng: np.random.Generator, n: int, pad: int = 4):
+    """What :func:`augment_crop_flip` takes from ``rng`` for ``n`` images:
+    (crop offsets ``[n, 2]``, flip mask ``[n]``)."""
+    return rng.integers(0, 2 * pad + 1, size=(n, 2)), rng.random(n) < 0.5
+
+
 def augment_crop_flip(
     x: np.ndarray,
     rng: np.random.Generator,
@@ -295,8 +302,7 @@ def augment_crop_flip(
     A RuntimeError from the kernel propagates: with draws generated here its
     invariant guards cannot legitimately fire, so one firing is a real bug."""
     n, _, _, c = x.shape
-    offs = rng.integers(0, 2 * pad + 1, size=(n, 2))
-    flip = rng.random(n) < 0.5
+    offs, flip = _crop_flip_draws(rng, n, pad)
 
     use_native = x.dtype == np.float32
     if use_native:
@@ -349,17 +355,58 @@ class WorkerBatches:
     def num_workers(self) -> int:
         return len(self.partitions)
 
-    def epoch(self, epoch: int) -> Iterator[Tuple[np.ndarray, np.ndarray]]:
+    def _epoch_rows(self, epoch: int) -> Iterator[np.ndarray]:
+        """``idx[N, B]`` of each step of the epoch: row ``w`` is the next
+        ``B`` examples of worker ``w``'s own shuffle of its partition."""
         B = self.batch_size
         orders = []
         for w, part in enumerate(self.partitions):
             rng = np.random.default_rng((self.seed, epoch, w))
             orders.append(part[rng.permutation(len(part))])
-        aug_rng = np.random.default_rng((self.seed, epoch, 10**6))
         for b in range(self.batches_per_epoch):
-            idx = np.stack([o[b * B : (b + 1) * B] for o in orders])  # [N, B]
+            yield np.stack([o[b * B : (b + 1) * B] for o in orders])
+
+    def _augment(self, xb: np.ndarray, aug_rng) -> np.ndarray:
+        flat = xb.reshape((-1,) + xb.shape[2:])
+        return augment_crop_flip(
+            flat, aug_rng, pad_value=self.pad_value).reshape(xb.shape)
+
+    def epoch(self, epoch: int) -> Iterator[Tuple[np.ndarray, np.ndarray]]:
+        aug_rng = np.random.default_rng((self.seed, epoch, 10**6))
+        for idx in self._epoch_rows(epoch):
             xb = self.x[idx]  # [N, B, ...]
             if self.augment:
-                flat = xb.reshape((-1,) + xb.shape[2:])
-                xb = augment_crop_flip(flat, aug_rng, pad_value=self.pad_value).reshape(xb.shape)
+                xb = self._augment(xb, aug_rng)
             yield xb, self.y[idx]
+
+    def epoch_into(self, epoch: int, xs_out: np.ndarray, ys_out: np.ndarray,
+                   first: int = 0) -> None:
+        """Write steps ``[first, first + len(xs_out))`` of the epoch into
+        ``xs_out[k]``, ``ys_out[k]``: bit for bit what :meth:`epoch` yields
+        at those steps, gathered straight into arrays the caller keeps
+        (``[steps, N, B, ...]``, C-contiguous, of ``x``'s and ``y``'s dtype)
+        instead of into a fresh ``[N, B, ...]`` array a step.
+
+        ``mode="clip"`` is what lets ``np.take`` write through ``out``: the
+        default ``"raise"`` gathers into a buffer of its own and copies.
+        Nothing is clipped: every index comes from the partitions."""
+        steps = len(xs_out)
+        if not 0 <= first <= first + steps <= self.batches_per_epoch \
+                or len(ys_out) != steps:
+            raise ValueError(
+                f"steps [{first}, {first + steps}) of an epoch of "
+                f"{self.batches_per_epoch}, into {len(ys_out)} label steps")
+        aug_rng = np.random.default_rng((self.seed, epoch, 10**6))
+        rows = itertools.islice(self._epoch_rows(epoch), first + steps)
+        for b, idx in enumerate(rows):
+            if b < first:
+                if self.augment:
+                    # one stream an epoch: the steps before ``first`` take
+                    # their draws from it all the same
+                    _crop_flip_draws(aug_rng, idx.size)
+                continue
+            x_out = xs_out[b - first]
+            np.take(self.x, idx, axis=0, out=x_out, mode="clip")
+            np.take(self.y, idx, axis=0, out=ys_out[b - first], mode="clip")
+            if self.augment:
+                x_out[...] = self._augment(x_out, aug_rng)

@@ -21,10 +21,9 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
-import itertools
 import os
 import time
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -157,6 +156,7 @@ def train(config: TrainConfig, resume_dir: Optional[str] = None,
         seed=config.seed, augment=config.augment,
         pad_value=normalized_zero(config.dataset),
     )
+    stacks = _HostStacks(loader, config.scan_chunk)  # kept for this call
     bpe = loader.batches_per_epoch
     total_steps = config.epochs * bpe
 
@@ -1001,7 +1001,8 @@ def train(config: TrainConfig, resume_dir: Optional[str] = None,
         with trace(config.trace_dir) if tracing else contextlib.nullcontext():
             if config.scan_epoch:
                 state, epoch_metrics = _run_epoch_scanned(
-                    e_scan, state, loader, epoch, rng, config.scan_chunk,
+                    e_scan, state, loader, stacks, epoch, rng,
+                    config.scan_chunk,
                     spans, ledger=cost_ledger, label=_step_label, mesh=mesh)
             else:
                 with spans.span("epoch_python"):
@@ -1564,7 +1565,49 @@ def _put_batches(stack, mesh):
         stack, NamedSharding(mesh, PartitionSpec(None, WORKER_AXIS)))
 
 
-def _run_epoch_scanned(scan_step, state, loader: WorkerBatches, epoch: int,
+class _HostStacks:
+    """The host ``[steps, N, B, ...]`` batch stacks of one ``train()`` call:
+    allocated at their first use and kept, so that every later epoch's
+    gather lands on pages the first one touched.  A fresh 604 MB stack an
+    epoch ran at a tenth of a copy's speed, for the page faults under it
+    (``PERF.md`` section 5).
+
+    **Lifetime rule.**  ``jnp.asarray`` / ``device_put`` return before the
+    copy to the device has read the host array (and the CPU backend may
+    not copy at all but alias it).  So a stack is written again only after
+    a readback of a program that consumed it: nothing else says that the
+    copy, and every read of an alias, is over.  The whole-epoch path has
+    one pair and meets the rule as the loop stands: the epoch's metrics
+    are read back before the next epoch, or a rollback's re-run, stages.
+    The chunked path stages segment k+1 while the device runs segment k,
+    so it has two pairs and takes them in turn: segment k+1 gets the pair
+    of segment k-1, whose metrics were forced after segment k's dispatch.
+    No ``block_until_ready`` exists for the buffers' sake."""
+
+    def __init__(self, loader: WorkerBatches, scan_chunk: Optional[int]):
+        steps = min(scan_chunk or loader.batches_per_epoch,
+                    loader.batches_per_epoch)
+        lead = (steps, loader.num_workers, loader.batch_size)
+        self._like = [(lead + a.shape[1:], a.dtype)
+                      for a in (loader.x, loader.y)]
+        self._pairs: Dict[int, Tuple[np.ndarray, np.ndarray]] = {}
+
+    def pair(self, turn: int,
+             steps: int) -> Tuple[np.ndarray, np.ndarray, int]:
+        """``(xs, ys, reused)``: the leading ``steps`` of the ``turn``-th
+        pair of stacks (a tail segment is shorter than its pair), and
+        whether an earlier segment has filled that pair already (1) or it
+        is allocated for this one (0)."""
+        reused = turn in self._pairs
+        if not reused:
+            self._pairs[turn] = tuple(np.empty(shape, dtype)
+                                      for shape, dtype in self._like)
+        xs, ys = self._pairs[turn]
+        return xs[:steps], ys[:steps], int(reused)
+
+
+def _run_epoch_scanned(scan_step, state, loader: WorkerBatches,
+                       stacks: _HostStacks, epoch: int,
                        rng, scan_chunk: Optional[int], spans, ledger=None,
                        label: str = "epoch_scan", mesh=None):
     """One epoch through the scanned step, whole-epoch or chunk-pipelined.
@@ -1572,28 +1615,29 @@ def _run_epoch_scanned(scan_step, state, loader: WorkerBatches, epoch: int,
     ``scan_chunk=None`` stages the full ``[steps, N, B, ...]`` stack (the
     round-3 behavior — cheapest dispatch, host memory ∝ epoch).  With a
     chunk, batches are staged ``[chunk, N, B, ...]`` at a time; because jax
-    dispatch is asynchronous, stacking segment k+1 on the host overlaps the
-    device executing segment k — a two-deep host→device pipeline without
-    explicit double-buffering.  Metrics are weighted by segment length, so
-    the epoch means are identical to the whole-epoch scan.
+    dispatch is asynchronous, staging segment k+1 on the host overlaps the
+    device executing segment k — a two-deep host→device pipeline, on the
+    two pairs of ``stacks`` in turn.  Metrics are weighted by segment
+    length, so the epoch means are identical to the whole-epoch scan.
 
     Every phase is a span of ``spans`` (``utils.profiling.SPAN_NAMES``);
     a chunked epoch's carry ``segment=<i>``, one set per segment.
     """
-    batches = loader.epoch(epoch)  # a generator: draining it is the gather
 
-    def run_segment(s, steps, **segment):
-        """Load, stack, put and dispatch the next ``steps`` batches as one
-        scanned segment, after the ledger (when on) has costed its program
-        — a chunked epoch's tail is a second compiled shape and journals
-        its own compile event."""
+    def run_segment(s, first, steps, **segment):
+        """Gather steps ``[first, first + steps)`` into a kept stack, put
+        it and dispatch it as one scanned segment, after the ledger (when
+        on) has costed its program — a chunked epoch's tail is a second
+        compiled shape and journals its own compile event."""
+        xs, ys, reused = stacks.pair(segment.get("segment", 0) % 2, steps)
         with spans.span("load_batches", **segment):
-            loaded = list(itertools.islice(batches, steps))
-        with spans.span("stack_batches", **segment):
-            xs, ys = (np.stack(arrays) for arrays in zip(*loaded))
-            # freed here, under the span that copied them: hundreds of MB
-            # of rows would else go at this frame's end, under no name
-            del loaded
+            loader.epoch_into(epoch, xs, ys, first)
+        with spans.span("stack_batches", reused=reused, **segment):
+            # nothing is left to stack: the gather wrote the rows where
+            # they go.  The span stays for its readers, and carries the
+            # counter that says the pages under the stack were touched
+            # before
+            pass
         with spans.span("h2d", bytes=xs.nbytes + ys.nbytes, **segment):
             xs, ys = _put_batches(xs, mesh), _put_batches(ys, mesh)
         if ledger is not None:
@@ -1603,7 +1647,7 @@ def _run_epoch_scanned(scan_step, state, loader: WorkerBatches, epoch: int,
             return scan_step(s, xs, ys, rng)
 
     if not scan_chunk:
-        state, metrics = run_segment(state, loader.batches_per_epoch)
+        state, metrics = run_segment(state, 0, loader.batches_per_epoch)
         with spans.span("wait_device"):
             # graftcontract: sync — whole-epoch metrics readback: one forced
             # materialization per epoch, after the scan returns
@@ -1631,7 +1675,7 @@ def _run_epoch_scanned(scan_step, state, loader: WorkerBatches, epoch: int,
         # going idle and the next segment's dispatch, or the promised
         # overlap never happens (metrics are not donated, so reading them
         # after the next dispatch is safe)
-        state, metrics = run_segment(state, steps, segment=segment)
+        state, metrics = run_segment(state, first, steps, segment=segment)
         if pending is not None:
             flush(*pending)
         pending = (metrics, steps, segment)

@@ -113,6 +113,52 @@ def test_worker_batches_layout_and_determinism():
     assert not np.array_equal(xb, xb3)
 
 
+@pytest.fixture(scope="module")
+def image_loaders():
+    """One loader an ``augment`` setting over the same 5-step epoch of four
+    workers, with what ``epoch(2)`` yields, stacked."""
+    ds = synthetic_images(num_train=4 * 8 * 5 + 7, num_test=8, seed=1)
+    parts = partition_uniform(len(ds.x_train), 4, seed=2)
+    out = {}
+    for augment in (False, True):
+        wb = WorkerBatches(ds.x_train, ds.y_train, parts, batch_size=8,
+                           seed=5, augment=augment, pad_value=-1.5)
+        assert wb.batches_per_epoch == 5
+        xs, ys = (np.stack(a) for a in zip(*wb.epoch(2)))
+        out[augment] = wb, xs, ys
+    assert not np.array_equal(out[False][1], out[True][1])
+    return out
+
+
+@pytest.mark.parametrize("augment", [False, True])
+@pytest.mark.parametrize("first,steps", [(0, 5), (0, 2), (2, 2), (4, 1)],
+                         ids=["whole", "head", "middle", "tail"])
+def test_worker_batches_epoch_into_is_epoch_bit_for_bit(
+        image_loaders, augment, first, steps):
+    """The write-into form against ``np.stack`` over ``epoch(e)``: the same
+    permutations and, augmenting, the same draws of the epoch's one stream
+    wherever the slice starts — into the leading ``steps`` of a longer
+    stack that is not empty, as the loop's kept stacks are."""
+    wb, xs, ys = image_loaders[augment]
+    xs_out = np.full((3 if steps < 5 else 5,) + xs.shape[1:], np.nan,
+                     xs.dtype)
+    ys_out = np.full(xs_out.shape[:3], -1, ys.dtype)
+    assert wb.epoch_into(2, xs_out[:steps], ys_out[:steps], first) is None
+    np.testing.assert_array_equal(xs_out[:steps], xs[first:first + steps])
+    np.testing.assert_array_equal(ys_out[:steps], ys[first:first + steps])
+    assert np.isnan(xs_out[steps:]).all() and (ys_out[steps:] == -1).all()
+
+
+@pytest.mark.parametrize("first,steps,label_steps", [(4, 2, 2), (-1, 2, 2),
+                                                     (0, 2, 3)])
+def test_worker_batches_epoch_into_rejects_steps_outside_the_epoch(
+        image_loaders, first, steps, label_steps):
+    wb, xs, ys = image_loaders[False]
+    with pytest.raises(ValueError, match="steps"):
+        wb.epoch_into(0, np.empty_like(xs[:steps]),
+                      np.empty_like(ys[:label_steps]), first)
+
+
 def test_worker_batches_rejects_oversized_batch():
     ds = synthetic_classification(num_train=64)
     parts = partition_uniform(64, 8)

@@ -126,12 +126,25 @@ def test_counts_at_the_boundaries(run):
                                         "segment"} for s in r["spans"]}
         assert {k: v for k, v in counted.items() if v} == (
             {} if path == "per_batch"
-            else {"h2d": {"bytes"}, "dispatch": {"steps"}})
+            else {"stack_batches": {"reused"}, "h2d": {"bytes"},
+                  "dispatch": {"steps"}})
         if path != "per_batch":
             assert sum(s["bytes"] for s in r["spans"]
                        if s["name"] == "h2d") == per_step * STEPS
             assert sum(s["steps"] for s in r["spans"]
                        if s["name"] == "dispatch") == STEPS
+
+
+@pytest.mark.parametrize("run", ["whole_epoch", "chunked"], indirect=True)
+def test_stack_batches_says_whether_the_stack_was_filled_before(run):
+    """``reused`` 0 where the segment's stack was allocated for it (the
+    first epoch's one, or the first use of each of the chunked path's two),
+    1 in every segment after: the kept stacks engaged."""
+    path, records, _, _ = run
+    reused = [[s["reused"] for s in r["spans"] if s["name"] == "stack_batches"]
+              for r in records]
+    assert reused == ([[0], [1], [1]] if path == "whole_epoch"
+                      else [[0, 0, 1], [1, 1, 1], [1, 1, 1]])
 
 
 @pytest.mark.parametrize("run", ["chunked"], indirect=True)

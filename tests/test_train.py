@@ -2,6 +2,7 @@
 synthetic data, 8 virtual workers — loss decreases AND replicas converge."""
 
 import dataclasses
+import itertools
 
 import jax
 import jax.numpy as jnp
@@ -91,6 +92,97 @@ def test_train_chunked_scan_matches_whole_epoch_scan():
     assert a["accuracy"] == pytest.approx(b["accuracy"], abs=1e-6)
     assert a["test_acc_mean"] == pytest.approx(b["test_acc_mean"], abs=1e-6)
     assert a["disagreement"] == pytest.approx(b["disagreement"], rel=1e-4, abs=1e-8)
+
+
+# ------------------------------------------------ kept host stacks (ISSUE 25)
+
+#: five steps an epoch: whole, and in segments of 2, 2 and a tail of 1
+STAGING_PATHS = {"whole_epoch": None, "chunked": 2}
+STAGING_STEPS, STAGING_EPOCHS = 5, 3
+
+
+def _stack_afresh(self, epoch, xs_out, ys_out, first=0):
+    """The staging before the stacks were kept, as the oracle: drain
+    ``epoch``, ``np.stack`` (the copy into ``*_out`` is the seam's)."""
+    batches = self.epoch(epoch)
+    loaded = list(itertools.islice(batches, first, first + len(xs_out)))
+    xs, ys = (np.stack(arrays) for arrays in zip(*loaded))
+    xs_out[...], ys_out[...] = xs, ys
+
+
+@pytest.fixture(scope="module", params=list(STAGING_PATHS))
+def kept_and_fresh(request):
+    """Three epochs of ``train()`` twice on one staging path: as shipped,
+    and with every segment staged the old way into arrays allocated for it.
+    (path, kept result, fresh result, the ``(address, steps)`` of every x
+    stack the kept run put on the device, in order)."""
+    from matcha_tpu.data import WorkerBatches
+    from matcha_tpu.train import loop
+
+    config = dataclasses.replace(
+        BASE, epochs=STAGING_EPOCHS, eval_every=0,
+        dataset_kwargs={"num_train": 8 * 16 * STAGING_STEPS, "num_test": 16},
+        scan_chunk=STAGING_PATHS[request.param])
+    put, pair, seen = loop._put_batches, loop._HostStacks.pair, []
+
+    def watching(stack, mesh):
+        if stack.ndim > 3:  # x: [steps, N, B, ...]; y is [steps, N, B]
+            seen.append((stack.ctypes.data, len(stack)))
+        return put(stack, mesh)
+
+    def forgetful(self, turn, steps):
+        self._pairs.clear()  # what was handed out lives on with its holder
+        return pair(self, turn, steps)
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(loop, "_put_batches", watching)
+        kept = train(config)
+        kept_seen = list(seen)
+        patch.setattr(loop._HostStacks, "pair", forgetful)
+        patch.setattr(WorkerBatches, "epoch_into", _stack_afresh)
+        fresh = train(config)
+    return request.param, kept, fresh, kept_seen
+
+
+def test_train_on_kept_stacks_matches_fresh_staging_bitwise(kept_and_fresh):
+    """Stacks that every epoch writes over give the very parameters that
+    fresh arrays give: the same rows reach the device in every epoch, and
+    no stack is written while a copy (on the CPU, an alias) of it is still
+    to be read."""
+    _, kept, fresh, _ = kept_and_fresh
+    assert int(kept.state.step) == STAGING_STEPS * STAGING_EPOCHS
+    leaves = jax.tree_util.tree_leaves
+    for a, b in zip(leaves(kept.state.params), leaves(fresh.state.params)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert [h["loss"] for h in kept.history] == \
+        [h["loss"] for h in fresh.history]
+
+
+def test_train_stages_every_epoch_into_the_same_stacks(kept_and_fresh):
+    """One stack for the whole-epoch path, epoch 0 to epoch 2; exactly two
+    for the chunked path, taken in turn inside an epoch (the tail is the
+    head of the first)."""
+    path, _, _, seen = kept_and_fresh
+    addresses = [address for address, _ in seen]
+    if path == "whole_epoch":
+        assert [steps for _, steps in seen] == [STAGING_STEPS] * STAGING_EPOCHS
+        assert len(set(addresses)) == 1
+    else:
+        assert [steps for _, steps in seen] == [2, 2, 1] * STAGING_EPOCHS
+        a, b = addresses[:2]
+        assert a != b and addresses == [a, b, a] * STAGING_EPOCHS
+
+
+def test_fresh_staging_oracle_allocates_every_segment(kept_and_fresh):
+    """The oracle is one: its run ends where the kept run does only
+    because the rows are the same, not because it took the same path."""
+    path, kept, fresh, _ = kept_and_fresh
+    reused = [[s["reused"] for e in result.recorder.events
+               if e["kind"] == "spans" for s in e["spans"]
+               if s["name"] == "stack_batches"] for result in (kept, fresh)]
+    segments = 1 if path == "whole_epoch" else 3
+    assert reused[1] == [0] * segments * STAGING_EPOCHS
+    assert sum(reused[0]) == segments * STAGING_EPOCHS - min(segments, 2)
 
 
 @pytest.mark.parametrize("communicator", ["decen", "choco", "centralized", "none"])
