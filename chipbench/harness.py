@@ -22,7 +22,6 @@ from pathlib import Path
 import numpy as np
 
 from . import catalog, check, tracered
-from .data import make_dataset
 from .peaks import peaks_of
 
 PROGRAM_EVENTS = ("compile", "retrace")
@@ -214,10 +213,9 @@ def stage_job(job, config_file, seed, steps, workdir):
     where ``train()`` reads it, and the ``TrainConfig`` that names it."""
     tc = job["train_config"]
     n_train = tc["num_workers"] * tc["batch_size"] * steps
-    data = make_dataset(seed, n_train,
-                        int(n_train * job["data"]["test_fraction"]),
-                        config_file["sizes"]["num_classes"],
-                        tuple(config_file["sizes"]["input_shape"]))
+    data = catalog.load_task(config_file).make(
+        seed, n_train, int(n_train * job["data"]["test_fraction"]),
+        config_file)
     workdir.mkdir(parents=True, exist_ok=True)
     np.savez(workdir / "data.npz", **data)
     return data, build_train_config(job, workdir, workdir / "data.npz")
@@ -396,7 +394,6 @@ def run_cell(args, bench, job, config_file, devices, device, peaks, cache,
     numbers.update(check.compare(hook.after, loss1, *run_reference(
         config_file, job, hook, data1, config1, "highest"), prefix="step1_"))
     ok, lines = check.verdict(numbers, job["limits"])
-    print("\n".join(lines))
     print(f"# check loss fell: {loss_fell}; the check took "
           f"{time.perf_counter() - t_ref:.1f} s after the window", flush=True)
     correct = bool(ok and loss_fell and failed == 0)
@@ -409,8 +406,11 @@ def run_cell(args, bench, job, config_file, devices, device, peaks, cache,
             metrics[m["name"]] = {"value": float(value), "unit": m["unit"]}
     line = {"correct": correct, "attempted": len(epochs),
             "failed": int(failed), "metrics": metrics, "device": device_line,
-            "check": {k: [v, job["limits"].get(k)] for k, v in numbers.items()},
             "loss": [losses[0], losses[-1]]}
     if run["trace"]:
         line["breakdown"] = run["trace"]["breakdown"]
+    # each number compared beside its limit: the run's last lines on
+    # standard error, and the result line's last key
+    print("\n".join(lines), file=sys.stderr, flush=True)
+    line["check"] = {k: [v, job["limits"].get(k)] for k, v in numbers.items()}
     return line

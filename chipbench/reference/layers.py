@@ -1,13 +1,21 @@
-"""The three layers both architectures are made of, in train mode."""
+"""The matrix products an architecture is handed, and the layers the two
+convolutional ones are made of, in train mode."""
+
+import collections
 
 import jax.numpy as jnp
 from jax import lax
 
 BN_EPS = 1e-5
 
+# What ``forward`` gets as ``ops``.  ``precision`` is for the products an
+# architecture writes itself: ``jnp.einsum(..., precision=ops.precision)``.
+Ops = collections.namedtuple("Ops", "conv dot precision")
 
-def make_ops(precision):
-    """``conv`` and ``dot`` of float32 operands at one MXU precision."""
+
+def make_ops(precision) -> Ops:
+    """``conv`` and ``dot`` (each adds the layer's bias) of float32 operands
+    at one MXU precision, and that precision."""
 
     def conv(x, p, name, stride, pad):
         y = lax.conv_general_dilated(
@@ -19,7 +27,7 @@ def make_ops(precision):
         return jnp.dot(x, p[name + "/kernel"], precision=precision) \
             + p[name + "/bias"]
 
-    return conv, dot
+    return Ops(conv, dot, precision)
 
 
 def batch_norm(x, p, stats, name, momentum):

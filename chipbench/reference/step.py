@@ -1,8 +1,12 @@
 """One step of decentralized momentum SGD over N workers, and an epoch of
-them: per-worker forward, softmax cross-entropy and gradients (one block of
+them: per-worker forward, the task's loss and gradients (one block of
 workers at a time), torch-style SGD (weight decay into the gradient, momentum
 trace, Nesterov look-ahead), then one gossip exchange
 ``x <- x - alpha * sum_j flag_j * L_j x`` with ``(L_j x)_i = x_i - x_perm_j(i)``.
+
+The configuration names the architecture (``reference``: what ``forward``
+computes) and the task (``task``: what a raw row becomes before it and what
+the loss is after it); neither is written here.
 
 State is flat ``{"path": array[N, ...]}`` trees.  ``compute`` names the
 precision of the forward and backward pass (:data:`COMPUTE`); parameters,
@@ -24,46 +28,43 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from .. import catalog
 from .layers import make_ops
 
 COMPUTE = {"highest": lax.Precision.HIGHEST, "stated": lax.Precision.DEFAULT}
 
 
 def make_epoch(config, job, perms, compute):
-    """``epoch(params, stats, images_u8, labels, idx, flags, alpha)`` ->
+    """``epoch(params, stats, x_raw, y_raw, idx, flags, alpha)`` ->
     (params, stats, momentum, losses[T, N]) after ``len(idx)`` steps.
 
-    ``images_u8``/``labels`` are the raw data set, ``idx[T, N, B]`` the rows
-    each worker takes at each step, ``flags[T, M]`` the matchings that fire.
-    The state stays on the device: trees of ``array[N, ...]``.
+    ``x_raw``/``y_raw`` are the task's raw training rows, ``idx[T, N, B]``
+    the rows each worker takes at each step, ``flags[T, M]`` the matchings
+    that fire.  The state stays on the device: trees of ``array[N, ...]``.
     """
     arch = importlib.import_module(f"{__package__}.{config['reference']}")
+    task = catalog.load_task(config)
     sizes = config["sizes"]
-    conv, dot = make_ops(COMPUTE[compute])
-    mean = np.asarray(config["input_mean"], np.float32)
-    std = np.asarray(config["input_std"], np.float32)
+    ops = make_ops(COMPUTE[compute])
     lr, mu, wd = job["lr"], job["momentum"], job["weight_decay"]
     perms = np.asarray(perms)
     block = int(job["reference_block"])
 
     def loss_fn(p, stats, x, y):
-        logits, new_stats = arch.forward(p, stats, x, sizes, conv, dot)
-        logp = logits - jax.scipy.special.logsumexp(
-            logits, axis=-1, keepdims=True)
-        nll = -jnp.take_along_axis(logp, y[:, None], axis=-1)[:, 0]
-        return jnp.mean(nll), new_stats
+        outputs, new_stats = arch.forward(p, stats, x, sizes, ops)
+        return task.loss(outputs, y), new_stats
 
     def one_worker(args):
-        p, stats, x_u8, y = args
-        x = (x_u8.astype(jnp.float32) / 255.0 - mean) / std
+        p, stats, x_raw, y_raw = args
+        x, y = task.prepare(x_raw, y_raw, config)
         (loss, new_stats), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(p, stats, x, y)
         return loss, grads, new_stats
 
     @functools.partial(jax.jit, donate_argnums=(0, 1, 2))
-    def step(params, stats, mom, x_u8, y, flags_t, alpha):
+    def step(params, stats, mom, x_raw, y_raw, flags_t, alpha):
         loss, grads, stats = lax.map(
-            one_worker, (params, stats, x_u8, y), batch_size=block)
+            one_worker, (params, stats, x_raw, y_raw), batch_size=block)
         new_p, new_m = {}, {}
         for k, x in params.items():
             g = grads[k] + wd * x
@@ -74,15 +75,15 @@ def make_epoch(config, job, perms, compute):
             new_p[k], new_m[k] = x - alpha * lap, m
         return new_p, stats, new_m, loss
 
-    def epoch(params, stats, images_u8, labels, idx, flags, alpha):
+    def epoch(params, stats, x_raw, y_raw, idx, flags, alpha):
         params = {k: jnp.array(v) for k, v in params.items()}
         stats = {k: jnp.array(v) for k, v in stats.items()}
         mom = {k: jnp.zeros_like(v) for k, v in params.items()}
         losses = []
         for t in range(len(idx)):
             params, stats, mom, loss = step(
-                params, stats, mom, jnp.asarray(images_u8[idx[t]]),
-                jnp.asarray(labels[idx[t]].astype(np.int32)),
+                params, stats, mom, jnp.asarray(x_raw[idx[t]]),
+                jnp.asarray(y_raw[idx[t]]),
                 jnp.asarray(flags[t], jnp.float32), jnp.float32(alpha))
             losses.append(np.asarray(loss))
         return params, stats, mom, np.stack(losses)

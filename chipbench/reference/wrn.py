@@ -8,7 +8,8 @@ import jax.numpy as jnp
 from .layers import batch_norm, relu
 
 
-def forward(p, stats, x, sizes, conv, dot):
+def forward(p, stats, x, sizes, ops):
+    conv, dot = ops.conv, ops.dot
     n = (sizes["depth"] - 4) // 6
     k = sizes["widen_factor"]
     new = {}

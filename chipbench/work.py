@@ -12,5 +12,5 @@ def forward_macs(config) -> int:
 
 def fwd_bwd_flops_per_step(config, workers: int, batch: int) -> float:
     """Forward plus backward (twice the forward) over every worker's batch:
-    3 x 2 x MACs x images."""
+    3 x 2 x MACs x samples."""
     return 3.0 * 2.0 * forward_macs(config) * workers * batch
