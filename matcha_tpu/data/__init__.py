@@ -5,7 +5,9 @@ from .datasets import (
     NORMALIZATION,
     WorkerBatches,
     augment_crop_flip,
+    judged_positions,
     load_npz,
+    load_tokens,
     normalize,
     normalized_zero,
     synthetic_classification,
@@ -25,7 +27,9 @@ __all__ = [
     "NORMALIZATION",
     "WorkerBatches",
     "augment_crop_flip",
+    "judged_positions",
     "load_npz",
+    "load_tokens",
     "normalize",
     "normalized_zero",
     "partition_fractions",

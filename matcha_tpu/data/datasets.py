@@ -27,6 +27,8 @@ __all__ = [
     "uci_digits",
     "photo_patches",
     "load_npz",
+    "load_tokens",
+    "judged_positions",
     "normalize",
     "augment_crop_flip",
     "WorkerBatches",
@@ -58,6 +60,9 @@ class Dataset:
     y_test: np.ndarray
     num_classes: int
     name: str = "dataset"
+    #: rows are ``[S + 1]`` token ids with document numbers as ``y``
+    #: (``load_tokens``), not an image and its label
+    token_rows: bool = False
 
 
 def normalize(x: np.ndarray, dataset: str) -> np.ndarray:
@@ -247,6 +252,34 @@ def load_npz(path: str, dataset: str = "cifar10", num_classes: int | None = None
         classes,
         name=dataset,
     )
+
+
+def load_tokens(path: str) -> Dataset:
+    """A token data set for next-token training: ``x_*`` int32 ``[n, S + 1]``
+    token ids and ``y_*`` int32 ``[n, S + 1]`` the number of the document
+    each position belongs to (documents packed end to end; the layout
+    ``chipbench/tasks/next_token.py:make`` writes).  Both are kept as they
+    are and fed row for row: the model cuts a row into ``S`` inputs and the
+    ``S`` next ids, and masks attention and the loss by the document
+    numbers.  ``num_classes`` is the largest id + 1."""
+    with np.load(path) as z:
+        split = {k: np.ascontiguousarray(z[k], dtype=np.int32)
+                 for k in ("x_train", "y_train", "x_test", "y_test")}
+    for half in ("train", "test"):
+        x, y = split["x_" + half], split["y_" + half]
+        if x.ndim != 2 or x.shape != y.shape or x.shape[1] < 2:
+            raise ValueError(
+                f"{path}: x_{half} {x.shape} and y_{half} {y.shape} must be "
+                f"the same [n, S + 1] ids and document numbers")
+    return Dataset(split["x_train"], split["y_train"], split["x_test"],
+                   split["y_test"], int(split["x_train"].max()) + 1,
+                   name="tokens", token_rows=True)
+
+
+def judged_positions(docs: np.ndarray) -> int:
+    """Positions of token rows ``docs[n, S + 1]`` that carry a loss: those
+    whose next id lies in the same document."""
+    return int(np.sum(docs[:, 1:] == docs[:, :-1]))
 
 
 def normalized_zero(dataset: str) -> np.ndarray:

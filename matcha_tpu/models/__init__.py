@@ -1,5 +1,7 @@
-"""Flax model zoo: CIFAR ResNets (incl. ResNet-20), VGG-BN, WideResNet, MLP."""
+"""Flax model zoo: CIFAR ResNets (incl. ResNet-20), VGG-BN, WideResNet, MLP,
+and a sparse decoder (Mellum2) for next-token training."""
 
+from .mellum2 import Mellum2
 from .mlp import MLP
 from .registry import (
     available_models,
@@ -13,6 +15,7 @@ from .wrn import WideResNet
 
 __all__ = [
     "MLP",
+    "Mellum2",
     "ResNet",
     "ResNetImageNet",
     "VGG",

@@ -1,0 +1,415 @@
+"""One chip's share of a Mellum2-style sparse decoder, for next-token
+training through the same ``train()`` as the image models.
+
+Pre-norm blocks ``h = x + Attn(RMSNorm(x))``, ``x' = h + MoE(RMSNorm(h))``;
+grouped-query attention that is sliding-window or full by ``layer_types``
+(plain RoPE on sliding layers, YaRN on full ones), masked to the row's own
+documents; a softmax router over all ``num_experts`` that keeps
+``experts_per_token`` and, with ``norm_topk_prob``, renormalises their
+weights; SwiGLU experts; a final RMSNorm and an untied head; no bias.
+
+**The share.**  ``sizes`` says what this chip holds of each layer:
+``q_heads_held`` query heads with the ``kv_heads_held`` KV heads they use,
+the experts ``experts_held`` (ids among the ``num_experts`` the router
+scores), ``vocab_held`` ids of the vocabulary.  The expert layer routes
+over all experts and computes its own experts' part of the sum; what the
+absent ones would add is left out and the partial sum goes on
+(``tests/test_mellum2.py`` adds the shares of 8 chips back up).
+
+**The expert layer drops nothing.**  The slots that landed on an expert
+held are laid out sorted by expert in ``moe_capacity`` rows
+(``ROWS_PER_EVEN_SLOT`` times what an even router would send here) and
+three grouped products (``jax.lax.ragged_dot``) run over them: the cost
+follows the number of slots, not the busiest expert, which matters because
+a row's tokens route alike (one expert held took 3.7 times its even share of a step
+at the published widths).  Slots past the rows go through every expert held
+under a 0/1 mask, in a branch that runs only in a step that has such slots.
+Shapes are static either way, and the counters say what it cost:
+``moe_rows_computed`` rows went through an expert for ``moe_slots_held``
+slots that were real.
+
+**The model supplies its loss** (:meth:`Mellum2.batch_loss`, which
+``train/state.py`` calls in place of cross-entropy over one label a row):
+the mean over judged positions of float32 softmax cross-entropy of the next
+id, where a position is judged unless the next id starts a new document.
+It is computed a chunk of positions at a time under ``jax.checkpoint``, so
+no ``[B, S, V]`` array of all positions exists.
+
+Matrix products run at the MXU's default precision, but the router's, which
+decides a discrete choice, at ``highest``; norms, RoPE, softmaxes and the
+loss are float32.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Any
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+
+from ..utils.profiling import device_span
+
+__all__ = ["Mellum2", "rope_inv_freq", "moe_capacity"]
+
+INIT_STD = 0.02
+#: rows of the grouped expert products over the slots an even router would
+#: send to the experts held: a layer took up to 1.41 times its even total of
+#: a step at the published widths (PERF.md section 6, PR 27)
+ROWS_PER_EVEN_SLOT = 2
+
+
+def rope_inv_freq(kind: str, sizes):
+    """(``inv_freq[head_dim / 2]``, the factor on cos and sin) of a layer
+    kind: ``theta^(-2i/d)`` on sliding layers; on full layers YaRN's blend
+    of that with itself over ``factor``, by a ramp between the dimensions
+    that turn ``beta_fast`` and ``beta_slow`` times in the original
+    context."""
+    d, theta = sizes["head_dim"], float(sizes["rope_theta"])
+    i = jnp.arange(d // 2, dtype=jnp.float32)
+    extrap = theta ** (-2.0 * i / d)
+    if kind == "sliding":
+        return extrap, 1.0
+    yarn = sizes["yarn"]
+
+    def turns(rotations):
+        return d * math.log(yarn["original_max_position_embeddings"]
+                            / (2 * math.pi * rotations)) / (
+                                2 * math.log(theta))
+
+    low = max(math.floor(turns(yarn["beta_fast"])), 0)
+    high = min(math.ceil(turns(yarn["beta_slow"])), d - 1)
+    ramp = jnp.clip((i - low) / (high - low), 0.0, 1.0)
+    return (extrap / yarn["factor"] * ramp + extrap * (1.0 - ramp),
+            yarn["attention_factor"])
+
+
+def moe_capacity(tokens: int, sizes) -> int:
+    """Rows the grouped expert products run over in a step:
+    ``ROWS_PER_EVEN_SLOT`` times the slots an even router would send to the
+    experts held, rounded up to 8 rows and never more than every slot there
+    can be."""
+    held = len(sizes["experts_held"])
+    even = tokens * sizes["experts_per_token"] * held / sizes["num_experts"]
+    rows = math.ceil(ROWS_PER_EVEN_SLOT * even / 8) * 8
+    return min(max(rows, 8), tokens * min(held, sizes["experts_per_token"]))
+
+
+def _rms_norm(x, scale, eps):
+    x = x.astype(jnp.float32)
+    return scale * x * lax.rsqrt(
+        jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps)
+
+
+def _rope(x, cos, sin):
+    """``x[B, S, heads, d]``, rotate-half pairs ``(i, i + d/2)``."""
+    half = x.shape[-1] // 2
+    a, b = x[..., :half], x[..., half:]
+    cos, sin = cos[None, :, None, :], sin[None, :, None, :]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+
+
+def _visible(q_pos, k_pos, q_docs, k_docs, window):
+    """``[B, q, k]``: position ``i`` sees ``j`` iff ``j <= i``, both lie in
+    one document and, on a sliding layer, ``i - j < window``."""
+    sees = (k_pos[None, :] <= q_pos[:, None])[None] \
+        & (q_docs[:, :, None] == k_docs[:, None, :])
+    if window is not None:
+        sees = sees & ((q_pos[:, None] - k_pos[None, :]) < window)[None]
+    return sees
+
+
+def _attention(p, h, docs, kind, sizes):
+    """Grouped-query attention of ``h[B, S, H]`` (already normed), a block
+    of query positions at a time against the keys its mask can reach."""
+    b, s, _ = h.shape
+    d, hq, hkv = sizes["head_dim"], sizes["q_heads_held"], \
+        sizes["kv_heads_held"]
+    group = hq // hkv
+    q = jnp.dot(h, p["wq"]).reshape(b, s, hkv, group, d)
+    k = jnp.dot(h, p["wk"]).reshape(b, s, hkv, d)
+    v = jnp.dot(h, p["wv"]).reshape(b, s, hkv, d)
+    inv_freq, factor = rope_inv_freq(kind, sizes)
+    angle = jnp.arange(s, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    cos, sin = jnp.cos(angle) * factor, jnp.sin(angle) * factor
+    q = _rope(q.reshape(b, s, hq, d), cos, sin).reshape(b, s, hkv, group, d)
+    k = _rope(k, cos, sin)
+
+    window = sizes["sliding_window"] if kind == "sliding" else None
+    block = sizes.get("attn_block", 1024)  # a test seam: blocks at S = 32
+    if s % block:
+        block = s
+    pos = jnp.arange(s)
+    outs = []
+    for start in range(0, s, block):
+        stop = start + block
+        # the first key a query of this block can see, on a 128 boundary
+        first = 0 if window is None else max(start - window + 1, 0) // 128 * 128
+        scores = jnp.einsum("bikgd,bjkd->bkgij", q[:, start:stop],
+                            k[:, first:stop]) / math.sqrt(d)
+        sees = _visible(pos[start:stop], pos[first:stop],
+                        docs[:, start:stop], docs[:, first:stop], window)
+        scores = jnp.where(sees[:, None, None], scores.astype(jnp.float32),
+                           -jnp.inf)
+        probs = jax.nn.softmax(scores, axis=-1)
+        outs.append(jnp.einsum("bkgij,bjkd->bikgd", probs, v[:, first:stop]))
+    out = jnp.concatenate(outs, axis=1).reshape(b, s, hq * d)
+    return jnp.dot(out, p["wo"])
+
+
+def _route(p, x, sizes):
+    """Router over all experts: (weights ``w[T, k]`` of the experts chosen,
+    their ids ``sel[T, k]``)."""
+    r = jnp.dot(x, p["router"], precision=lax.Precision.HIGHEST)
+    prob = jax.nn.softmax(r.astype(jnp.float32), axis=-1)
+    top, sel = lax.top_k(prob, sizes["experts_per_token"])
+    if sizes["norm_topk_prob"]:
+        top = top / jnp.sum(top, axis=-1, keepdims=True)
+    return top, sel
+
+
+def _one_bf16_pass() -> bool:
+    """Whether a float32 product at the default precision is one bfloat16
+    pass with float32 accumulation here, as it is on the TPU's MXU."""
+    return jax.default_backend() == "tpu" and \
+        jax.config.jax_default_matmul_precision in (None, "default",
+                                                    "bfloat16")
+
+
+@jax.custom_vjp
+def _grouped_bf16(lhs, weights, groups):
+    """``lax.ragged_dot`` of float32 operands as one bfloat16 pass with
+    float32 accumulation, in the backward products too: what a ``dot`` at
+    the default precision is on the MXU.  The grouped kernel takes its
+    operands as they come, so the cotangent has to be rounded by hand: left
+    float32, it makes half of the backward products float32 ones."""
+    return _grouped_bf16_fwd(lhs, weights, groups)[0]
+
+
+def _grouped_bf16_fwd(lhs, weights, groups):
+    lhs, weights = lhs.astype(jnp.bfloat16), weights.astype(jnp.bfloat16)
+    return lax.ragged_dot(lhs, weights, groups,
+                          preferred_element_type=jnp.float32), (
+                              lhs, weights, groups)
+
+
+def _grouped_bf16_bwd(kept, g):
+    lhs, weights, groups = kept
+    g = g.astype(jnp.bfloat16)
+    by_rows = lax.RaggedDotDimensionNumbers(
+        dot_dimension_numbers=(((0,), (0,)), ((), ())),
+        lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+    return (lax.ragged_dot(g, jnp.swapaxes(weights, 1, 2), groups,
+                           preferred_element_type=jnp.float32),
+            lax.ragged_dot_general(lhs, g, groups, by_rows,
+                                   preferred_element_type=jnp.float32),
+            np.zeros(groups.shape, jax.dtypes.float0))
+
+
+_grouped_bf16.defvjp(_grouped_bf16_fwd, _grouped_bf16_bwd)
+
+
+def _grouped_product(lhs, weights, groups):
+    """``lax.ragged_dot`` at the precision ``jnp.dot`` has by default.  On
+    the TPU float32 operands cost the grouped kernel several passes (4.8 ms
+    a weight-gradient product of 32,768 rows, 14% of the MXU's peak;
+    PERF.md section 6, PR 27)."""
+    if _one_bf16_pass():
+        return _grouped_bf16(lhs, weights, groups)
+    return lax.ragged_dot(lhs, weights, groups)
+
+
+def _swiglu(x, p, product):
+    """``x`` through SwiGLU experts, ``product(lhs, weights)`` saying which
+    rows meet which expert."""
+    inner = jax.nn.silu(product(x, p["gate"])) * product(x, p["up"])
+    return product(inner, p["down"])
+
+
+def _experts(p, x, w_held, took, sizes):
+    """The experts held on the tokens that chose them: ``x[B, S, H]``,
+    ``w_held[T, E]`` each token's weight on each expert held (0 where it
+    did not choose it), ``took[T, E]`` who chose whom.  Returns (their
+    weighted sum ``[B, S, H]``, rows that went through an expert).
+
+    The slots are laid out sorted by expert in ``rows`` rows (expert ``e``'s
+    queue starts where the queues before it end), and three grouped
+    products (``lax.ragged_dot``, one group an expert) run over them, so
+    the cost follows the slots and not the busiest expert.  Rows past the
+    last slot hold a token at weight 0 and count with the last expert."""
+    b, s, hidden = x.shape
+    tokens, held = took.shape
+    flat = x.reshape(tokens, hidden)
+    rows = moe_capacity(tokens, sizes)
+    count = jnp.sum(took, axis=0)
+    start = jnp.cumsum(count) - count  # where each expert's queue starts
+    place = start[None, :] + jnp.cumsum(took, axis=0) - 1
+    fits = took & (place < rows)
+    at = jnp.where(fits, place, rows)  # ``rows`` is out of bounds: dropped
+    token = jnp.full((rows,), tokens, jnp.int32).at[at].set(
+        jnp.arange(tokens, dtype=jnp.int32)[:, None], mode="drop")
+    w_rows = jnp.zeros((rows,), w_held.dtype).at[at].set(w_held, mode="drop")
+    ends = jnp.minimum(start + count, rows)
+    groups = (ends - jnp.minimum(start, rows)).astype(jnp.int32)
+    groups = groups.at[-1].add(rows - jnp.sum(groups))
+    grouped = lambda lhs, weights: _grouped_product(lhs, weights, groups)
+    y_rows = _swiglu(flat[jnp.minimum(token, tokens - 1)], p, grouped)
+    y = jnp.zeros_like(flat).at[token].add(y_rows * w_rows[:, None],
+                                          mode="drop")
+
+    # slots past the rows: every expert held on every token under the
+    # mask, a row of the batch at a time, and only in a step that has such
+    # slots
+    w_rest = jnp.where(took & ~fits, w_held, 0.0)
+
+    @jax.checkpoint
+    def every_expert(row):
+        x_row, w_row = row
+        each = _swiglu(jnp.broadcast_to(x_row, (held,) + x_row.shape), p,
+                       lambda lhs, weights: jnp.einsum(
+                           "erh,ehf->erf", lhs, weights))
+        return jnp.einsum("eth,te->th", each, w_row)
+
+    overflow = jnp.sum(count) > rows
+    y = y.reshape(b, s, hidden) + lax.cond(
+        overflow,
+        lambda: lax.map(every_expert, (x, w_rest.reshape(b, s, held))),
+        lambda: jnp.zeros_like(x))
+    computed = rows + jnp.where(overflow, held * tokens, 0)
+    return y, computed.astype(jnp.float32)
+
+
+def _moe(p, x, sizes):
+    """(the experts held's part of the layer's output, its counters)."""
+    b, s, hidden = x.shape
+    with device_span("matcha/moe_route"):
+        w, sel = _route(p, x.reshape(b * s, hidden), sizes)
+        chosen = sel[:, :, None] == jnp.asarray(
+            sizes["experts_held"], sel.dtype)[None, None, :]
+        w_held = jnp.sum(jnp.where(chosen, w[:, :, None], 0.0), axis=1)
+        took = jnp.any(chosen, axis=1)
+    with device_span("matcha/moe_experts"):
+        y, computed = _experts(p, x, w_held, took, sizes)
+    load = jnp.sum(took, axis=0).astype(jnp.float32)
+    return y, {"moe_slots_held": jnp.sum(load), "moe_rows_computed": computed,
+               "moe_load": load}
+
+
+def _block(p, h, docs, kind, sizes):
+    eps = sizes["rms_norm_eps"]
+    with device_span(f"matcha/attn_{'window' if kind == 'sliding' else 'full'}"):
+        h = h + _attention(p, _rms_norm(h, p["attn_norm"], eps), docs, kind,
+                           sizes)
+    y, counters = _moe(p, _rms_norm(h, p["moe_norm"], eps), sizes)
+    return h + y, counters
+
+
+class Mellum2(nn.Module):
+    """``sizes`` as in ``chipbench/configs/mellum2-12b-a2.5b.ep8-s4k.json``
+    (README "Training a language model" lists the keys)."""
+
+    sizes: Any
+    remat: bool = False
+
+    #: ``train/state.py`` calls :meth:`batch_loss` instead of a cross-entropy
+    #: over ``__call__``'s logits, and runs the workers one after another
+    #: (a ``vmap`` would turn the expert layer's ``cond`` into both branches)
+    supplies_loss = True
+
+    def setup(self):
+        z = self.sizes
+        hid, d, width = z["hidden"], z["head_dim"], z["expert_width"]
+        held = len(z["experts_held"])
+        normal = nn.initializers.normal(INIT_STD)
+        ones = nn.initializers.ones
+        self.embed = self.param("embed", normal, (z["vocab_held"], hid))
+        layers = []
+        for n in range(len(z["layer_types"])):
+            shapes = {
+                "attn_norm": (ones, (hid,)),
+                "wq": (normal, (hid, z["q_heads_held"] * d)),
+                "wk": (normal, (hid, z["kv_heads_held"] * d)),
+                "wv": (normal, (hid, z["kv_heads_held"] * d)),
+                "wo": (normal, (z["q_heads_held"] * d, hid)),
+                "moe_norm": (ones, (hid,)),
+                "router": (normal, (hid, z["num_experts"])),
+                "gate": (normal, (held, hid, width)),
+                "up": (normal, (held, hid, width)),
+                "down": (normal, (held, width, hid)),
+            }
+            layers.append({k: self.param(f"layer{n}_{k}", init, shape)
+                           for k, (init, shape) in shapes.items()})
+        self.layers = layers
+        self.final_norm = self.param("final_norm", ones, (hid,))
+        self.head = self.param("head", normal, (hid, z["vocab_held"]))
+
+    def dummy_input(self, input_shape):
+        """What ``init`` traces: parameters do not depend on the length."""
+        return jnp.zeros((1, 8), jnp.int32)
+
+    def hidden(self, ids, docs):
+        """(the final norm's output ``[B, S, H]``, the expert layers'
+        counters, ``moe_load[layer, expert held]``)."""
+        with device_span("matcha/lm_embed"):
+            h = self.embed[ids]
+        counters = []
+        for p, kind in zip(self.layers, self.sizes["layer_types"]):
+            block = lambda p, h, docs, kind=kind: _block(
+                p, h, docs, kind, self.sizes)
+            h, c = (jax.checkpoint(block) if self.remat else block)(
+                p, h, docs)
+            counters.append(c)
+        total = {k: sum(c[k] for c in counters)
+                 for k in ("moe_slots_held", "moe_rows_computed")}
+        total["moe_load"] = jnp.stack([c["moe_load"] for c in counters])
+        return _rms_norm(h, self.final_norm,
+                         self.sizes["rms_norm_eps"]), total
+
+    def logits(self, ids, docs):
+        """Float32 ``[B, S, V]`` of ids and document numbers ``[B, S]``."""
+        h, _ = self.hidden(ids, docs)
+        return jnp.dot(h, self.head).astype(jnp.float32)
+
+    def __call__(self, x, train: bool = True):
+        """Logits of token ids ``x[B, S]``, each row one document."""
+        ids = x.astype(jnp.int32)
+        return self.logits(ids, jnp.zeros_like(ids))
+
+    def batch_loss(self, x_raw, y_raw):
+        """``x_raw``/``y_raw``: int32 ``[B, S + 1]`` ids and document
+        numbers (``data.load_tokens``).  Returns (the mean over judged
+        positions of the next id's cross-entropy, ``{"accuracy", "counters"}``)."""
+        ids, docs = x_raw[:, :-1], y_raw[:, :-1]
+        targets = jnp.where(y_raw[:, 1:] == docs, x_raw[:, 1:], -1)
+        h, counters = self.hidden(ids, docs)
+        b, s, hidden = h.shape
+        chunk = self.sizes.get("loss_chunk", 1024)  # a test seam, as above
+        if s % chunk:
+            chunk = s
+        head = self.head
+
+        @jax.checkpoint
+        def of_chunk(part):
+            h_c, t_c = part  # [B, chunk, H], [B, chunk]
+            logits = jnp.dot(h_c, head).astype(jnp.float32)
+            judged = t_c >= 0
+            picked = jnp.take_along_axis(
+                logits, jnp.maximum(t_c, 0)[..., None], axis=-1)[..., 0]
+            nll = jax.scipy.special.logsumexp(logits, axis=-1) - picked
+            hit = jnp.argmax(logits, axis=-1) == t_c
+            return (jnp.sum(jnp.where(judged, nll, 0.0)),
+                    jnp.sum(judged & hit), jnp.sum(judged))
+
+        with device_span("matcha/lm_head_loss"):
+            split = lambda a: jnp.moveaxis(
+                a.reshape((b, s // chunk, chunk) + a.shape[2:]), 1, 0)
+            nll, hits, judged = lax.map(of_chunk, (split(h), split(targets)))
+            judged = jnp.sum(judged).astype(jnp.float32)
+            positions = jnp.maximum(judged, 1.0)
+            loss = jnp.sum(nll) / positions
+        counters["loss_positions"] = judged
+        return loss, {"accuracy": jnp.sum(hits) / positions,
+                      "counters": counters}

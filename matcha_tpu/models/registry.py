@@ -8,7 +8,9 @@ driver hard-codes ``num_class=100`` regardless of dataset (train_mpi.py:84);
 here the class count is derived from the dataset unless overridden.
 
 Also registers explicit names the reference cannot express: ``resnet20``
-(BASELINE.json's model), ``resnet32/44/56/110``, ``vgg11/13/19``.
+(BASELINE.json's model), ``resnet32/44/56/110``, ``vgg11/13/19``, and
+``mellum2``, a sparse decoder for next-token training whose sizes come as
+``sizes={...}`` (``models/mellum2.py``).
 """
 
 from __future__ import annotations
@@ -17,6 +19,7 @@ from typing import Any, Tuple
 
 import flax.linen as nn
 
+from .mellum2 import Mellum2
 from .mlp import MLP
 from .resnet import ResNet, ResNetImageNet
 from .vgg import VGG
@@ -68,12 +71,19 @@ def select_model(
     selection policy; explicit names ('resnet20', 'vgg16', ...) set the depth
     directly.
     """
-    classes = num_classes if num_classes is not None else dataset_num_classes(dataset)
     kw = dict(overrides)
     if dtype is not None:
         kw["dtype"] = dtype
 
     lname = name.lower()
+    if lname == "mellum2":
+        # a token model: its sizes come as ``sizes={...}`` (the vocabulary
+        # it holds among them), not from the data set's name
+        if "sizes" not in kw:
+            raise ValueError("model 'mellum2' needs sizes={...} "
+                             "(TrainConfig.model_kwargs)")
+        return Mellum2(**kw)
+    classes = num_classes if num_classes is not None else dataset_num_classes(dataset)
     if name == "res":  # reference depth policy (util.py:258-265)
         if dataset == "imagenet":  # torchvision resnet18 path (util.py:262)
             return ResNetImageNet(depth=18, num_classes=classes, **kw)
@@ -101,4 +111,5 @@ def select_model(
 
 
 def available_models():
-    return ["res", "resnet<depth>", "VGG", "vgg<depth>", "wrn", "wrn-<d>-<k>", "mlp"]
+    return ["res", "resnet<depth>", "VGG", "vgg<depth>", "wrn", "wrn-<d>-<k>", "mlp",
+            "mellum2"]

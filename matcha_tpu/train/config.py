@@ -32,6 +32,11 @@ class TrainConfig:
     # extra kwargs for the synthetic dataset builders (num_train, separation,
     # ...) — lets benchmarks size/condition hermetic data without new flags
     dataset_kwargs: Optional[dict] = None
+    # extra kwargs for the model's constructor, through
+    # ``models.select_model``: a model whose sizes are not in its name takes
+    # them here (``mellum2``: ``{"sizes": {...}}``, README "Training a
+    # language model")
+    model_kwargs: Optional[dict] = None
 
     # optimization (reference: --lr/--momentum/--epoch/--warmup/--nesterov + wd=5e-4)
     lr: float = 0.8

@@ -36,7 +36,9 @@ from ..obs.telemetry import make_telemetry_spec, telemetry_flush
 from ..utils import SpanRecorder, trace
 from ..data import (
     WorkerBatches,
+    judged_positions,
     load_npz,
+    load_tokens,
     normalized_zero,
     partition_indices,
     photo_patches,
@@ -53,7 +55,8 @@ from .checkpoint import restore_checkpoint, save_checkpoint
 from .config import TrainConfig
 from .lr import make_lr_schedule
 from .recorder import Recorder
-from .state import TrainState, init_train_state, make_eval_fn, make_optimizer, make_train_step
+from .state import (COUNTER_PREFIX, TrainState, init_train_state, make_eval_fn,
+                    make_optimizer, make_train_step)
 
 __all__ = ["build_schedule", "build_dataset", "train", "TrainResult",
            "TrainingDiverged"]
@@ -118,6 +121,8 @@ def build_dataset(config: TrainConfig):
             f"dataset '{config.dataset}' needs datasetRoot pointing at an .npz "
             f"file (torchvision downloads are unavailable in this environment)"
         )
+    if config.dataset == "tokens":
+        return load_tokens(config.datasetRoot)
     return load_npz(config.datasetRoot, dataset=config.dataset)
 
 
@@ -314,7 +319,8 @@ def train(config: TrainConfig, resume_dir: Optional[str] = None,
     communicator = _make_comm(config.compress_ratio)
 
     model = select_model(config.model, config.dataset,
-                         num_classes=dataset.num_classes, remat=config.remat)
+                         num_classes=dataset.num_classes, remat=config.remat,
+                         **(config.model_kwargs or {}))
 
     # lr_scale is the recovery backoff (1.0 until a rollback); everything
     # LR-derived is built through here so retries rebuild consistently
@@ -903,12 +909,14 @@ def train(config: TrainConfig, resume_dir: Optional[str] = None,
     epoch = start_epoch
     attempt = 0  # rollback retries of `epoch` so far
     period_samples = 0
+    period_counters = None
 
     def _journal_period():
         """The period that just ended (none before the first) as one
         `spans` record: loop top to loop top, a rollback's `continue` and
         the stop's `break` included."""
-        record = spans.end(samples=period_samples)
+        record = spans.end(samples=period_samples, **(
+            {"counters": period_counters} if period_counters else {}))
         if record is not None:
             recorder.log_event("spans", **record)
 
@@ -916,6 +924,7 @@ def train(config: TrainConfig, resume_dir: Optional[str] = None,
         _journal_period()
         spans.begin(f"{epoch}.{attempt}", epoch=epoch, attempt=attempt)
         period_samples = 0
+        period_counters = None
         with spans.span("boundary_hook"):
             # chaos barrier (no-op unless armed): the campaign's SIGKILL-at-
             # epoch-boundary injector fires here, before any of this epoch's
@@ -1003,10 +1012,12 @@ def train(config: TrainConfig, resume_dir: Optional[str] = None,
                 state, epoch_metrics = _run_epoch_scanned(
                     e_scan, state, loader, stacks, epoch, rng,
                     config.scan_chunk,
-                    spans, ledger=cost_ledger, label=_step_label, mesh=mesh)
+                    spans, ledger=cost_ledger, label=_step_label, mesh=mesh,
+                    token_rows=dataset.token_rows)
             else:
                 with spans.span("epoch_python"):
                     sums: Dict[str, float] = {}
+                    counts: Dict[str, np.ndarray] = {}
                     count = 0
                     for xb, yb in loader.epoch(epoch):
                         xb, yb = jnp.asarray(xb), jnp.asarray(yb)
@@ -1020,17 +1031,24 @@ def train(config: TrainConfig, resume_dir: Optional[str] = None,
                         # graftcontract: sync — the per-batch python path reads
                         # every step's metrics back by design (debug mode;
                         # scan_epoch=True is the zero-per-batch-sync path)
-                        m = {k: float(np.asarray(v)) for k, v in m.items()}
+                        m = {k: np.asarray(v)[None] for k, v in m.items()}
+                        m, step_counts = _split_counters(m)
                         for k, v in m.items():
-                            sums[k] = sums.get(k, 0.0) + v
+                            sums[k] = sums.get(k, 0.0) + float(v[0])
+                        for k, v in step_counts.items():
+                            counts[k] = counts.get(k, 0.0) + v
                         count += 1
-                    epoch_metrics = {k: v / count for k, v in sums.items()}
+                    epoch_metrics = _with_counters(
+                        {k: v / count for k, v in sums.items()}, counts)
             with spans.span("wait_device"):
                 # graftcontract: sync — THE one deliberate per-epoch barrier
                 # (wall-clock truth + everything below rides this sync)
                 jax.block_until_ready(state.params)
         epoch_time = time.time() - t0
         period_samples = bpe * config.num_workers * config.batch_size
+        # what the model counted over the epoch (``COUNTER_PREFIX``): it
+        # rides the period's ``spans`` record and the history, not the means
+        period_counters = epoch_metrics.pop("counters", None)
 
         with spans.span("divergence_check"):
             if config.halt_on_divergence:
@@ -1203,10 +1221,15 @@ def train(config: TrainConfig, resume_dir: Optional[str] = None,
         eval_alive = None
         if config.eval_every and (epoch + 1) % config.eval_every == 0:
             with spans.span("evaluate"):
-                eval_batch = config.eval_batch or max(16, 1024 // config.num_workers)
+                # image rows by the chip's memory; token rows are thousands
+                # of positions each, so a training batch of them at a time
+                eval_batch = config.eval_batch or (
+                    config.batch_size if dataset.token_rows
+                    else max(16, 1024 // config.num_workers))
                 test_loss, test_acc = _evaluate_in_batches(
                     evaluate, state, dataset.x_test, dataset.y_test,
-                    batch=eval_batch, ledger=cost_ledger
+                    batch=eval_batch, ledger=cost_ledger,
+                    weigh=judged_positions if dataset.token_rows else len,
                 )
                 if faults is not None or member_alive_np is not None:
                     # same quarantine exemption as the train-side metrics: a
@@ -1240,6 +1263,7 @@ def train(config: TrainConfig, resume_dir: Optional[str] = None,
             history.append({
                 "epoch": epoch,
                 **epoch_metrics,
+                **({"counters": period_counters} if period_counters else {}),
                 "test_acc_mean": _masked_mean(test_acc, eval_alive),
                 "test_loss_mean": _masked_mean(test_loss, eval_alive),
                 "epoch_time": epoch_time,
@@ -1609,7 +1633,8 @@ class _HostStacks:
 def _run_epoch_scanned(scan_step, state, loader: WorkerBatches,
                        stacks: _HostStacks, epoch: int,
                        rng, scan_chunk: Optional[int], spans, ledger=None,
-                       label: str = "epoch_scan", mesh=None):
+                       label: str = "epoch_scan", mesh=None,
+                       token_rows: bool = False):
     """One epoch through the scanned step, whole-epoch or chunk-pipelined.
 
     ``scan_chunk=None`` stages the full ``[steps, N, B, ...]`` stack (the
@@ -1623,6 +1648,14 @@ def _run_epoch_scanned(scan_step, state, loader: WorkerBatches,
     Every phase is a span of ``spans`` (``utils.profiling.SPAN_NAMES``);
     a chunked epoch's carry ``segment=<i>``, one set per segment.
     """
+
+    def tokens_of(xs):
+        """A token job's (``token_rows``: ``data.Dataset``) ``dispatch``
+        says how many positions it predicts: ``S`` of every ``[S + 1]`` row
+        of ids."""
+        if not token_rows:
+            return {}
+        return {"tokens": int(np.prod(xs.shape[:-1])) * (xs.shape[-1] - 1)}
 
     def run_segment(s, first, steps, **segment):
         """Gather steps ``[first, first + steps)`` into a kept stack, put
@@ -1643,27 +1676,33 @@ def _run_epoch_scanned(scan_step, state, loader: WorkerBatches,
         if ledger is not None:
             with spans.span("ledger_observe", **segment):
                 ledger.observe(label, scan_step, s, xs, ys, rng)
-        with spans.span("dispatch", steps=steps, **segment):
+        with spans.span("dispatch", steps=steps, **tokens_of(xs), **segment):
             return scan_step(s, xs, ys, rng)
 
     if not scan_chunk:
         state, metrics = run_segment(state, 0, loader.batches_per_epoch)
         with spans.span("wait_device"):
+            means, counts = _split_counters(metrics)
             # graftcontract: sync — whole-epoch metrics readback: one forced
             # materialization per epoch, after the scan returns
-            return state, {k: float(np.mean(v)) for k, v in metrics.items()}
+            means = {k: float(np.mean(v)) for k, v in means.items()}
+            return state, _with_counters(means, counts)
 
     sums: Dict[str, float] = {}
+    counts: Dict[str, np.ndarray] = {}
     total = 0
     pending = None  # metrics of the in-flight segment (device may still run)
 
     def flush(metrics, n, segment):
         nonlocal total
         with spans.span("wait_device", segment=segment):
-            for k, v in metrics.items():
+            means, segment_counts = _split_counters(metrics)
+            for k, v in means.items():
                 # graftcontract: sync — per-chunk metrics force, deliberately
                 # AFTER the next segment's dispatch (the two-deep pipeline)
                 sums[k] = sums.get(k, 0.0) + float(np.sum(v))
+            for k, v in segment_counts.items():
+                counts[k] = counts.get(k, 0.0) + v
         total += n
 
     # (a tail segment is its own compiled shape, at most once per run)
@@ -1681,11 +1720,35 @@ def _run_epoch_scanned(scan_step, state, loader: WorkerBatches,
         pending = (metrics, steps, segment)
     if pending is not None:
         flush(*pending)
-    return state, {k: v / total for k, v in sums.items()}
+    return state, _with_counters({k: v / total for k, v in sums.items()},
+                                 counts)
+
+
+def _split_counters(metrics):
+    """A segment's stacked step metrics ``{name: [steps, ...]}`` as (those
+    that are means, the counts ``{name: float64 array}`` summed over the
+    steps): a model that supplies its loss returns counts under
+    ``COUNTER_PREFIX`` (``train/state.py``)."""
+    counts = {}
+    for k, v in metrics.items():
+        if k.startswith(COUNTER_PREFIX):
+            # graftcontract: sync — the model's counts ride the readback of
+            # the segment's means: same place, same moment
+            counts[k[len(COUNTER_PREFIX):]] = np.asarray(v, np.float64).sum(0)
+    return ({k: v for k, v in metrics.items()
+             if not k.startswith(COUNTER_PREFIX)}, counts)
+
+
+def _with_counters(epoch_metrics, counts):
+    """The epoch's metrics, with its counts (where the model has any) as
+    plain numbers and lists under ``"counters"``."""
+    if counts:
+        epoch_metrics["counters"] = {k: v.tolist() for k, v in counts.items()}
+    return epoch_metrics
 
 
 def _evaluate_in_batches(evaluate, state, x_test, y_test, batch: int = 512,
-                         ledger=None):
+                         ledger=None, weigh=len):
     """Full-test-set eval (reference test() covers the partial tail batch too,
     util.py:422-432) — at most two compiled shapes: `batch` and the tail."""
     losses, accs, weights = [], [], []
@@ -1702,7 +1765,8 @@ def _evaluate_in_batches(evaluate, state, x_test, y_test, batch: int = 512,
         losses.append(np.asarray(l))
         # graftcontract: sync — second half of the same eval readback
         accs.append(np.asarray(a))
-        weights.append(len(yl))
+        # a batch's mean is over its rows, or over the positions judged
+        weights.append(weigh(y_test[i : i + batch]))
     # graftcontract: sync — host batch-size weights (never device values)
     w = np.asarray(weights, np.float64)[:, None]
     return (

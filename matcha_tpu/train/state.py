@@ -34,7 +34,12 @@ from ..ops import WorkerFlattener
 from ..parallel import allreduce_mean, worker_deviation_rows, worker_disagreement
 from ..utils import cross_entropy_loss, device_span, top_k_accuracy
 
-__all__ = ["TrainState", "init_train_state", "make_train_step", "make_eval_fn", "make_optimizer"]
+__all__ = ["TrainState", "init_train_state", "make_train_step", "make_eval_fn", "make_optimizer",
+           "COUNTER_PREFIX"]
+
+#: a step metric under this prefix is a count, not a mean: the loop sums it
+#: over the epoch's steps and journals it in the period's ``counters``
+COUNTER_PREFIX = "count/"
 
 
 class TrainState(struct.PyTreeNode):
@@ -130,19 +135,28 @@ def init_train_state(
     ``[N, K, D]`` pending ring plus its all-empty (−1) age counters;
     ``"off"`` leaves both the empty tuple so the eager state pytree (and
     its checkpoints) are unchanged."""
-    dummy = jnp.zeros((1,) + tuple(input_shape), jnp.float32)
+    # a model of hundreds of millions of parameters names its own dummy
+    # input (``models/mellum2.py``), and inits and syncs as two programs:
+    # run an operation at a time, each distinct leaf shape compiles its own
+    # draw, slice and cast, 363 s on the v5e for 2 x 267 M parameters
+    # (PERF.md section 6, PR 27).  The image models keep the eager path and
+    # its numbers to the bit.
+    own_input = hasattr(model, "dummy_input")
+    compiled = jax.jit if own_input else (lambda f: f)
+    dummy = (model.dummy_input(input_shape) if own_input
+             else jnp.zeros((1,) + tuple(input_shape), jnp.float32))
 
     def init_one(key):
         variables = model.init(key, dummy, train=False)
         return variables.get("params"), variables.get("batch_stats", {})
 
     keys = jax.random.split(jax.random.PRNGKey(seed), num_workers)
-    params, batch_stats = jax.vmap(init_one)(keys)
+    params, batch_stats = compiled(jax.vmap(init_one))(keys)
 
     flattener = WorkerFlattener(params)
     if sync_init:
-        flat = allreduce_mean(flattener.flatten(params))
-        params = flattener.unflatten(flat)
+        params = compiled(lambda p: flattener.unflatten(
+            allreduce_mean(flattener.flatten(p))))(params)
 
     if staleness < 1:
         raise ValueError(f"staleness must be >= 1, got {staleness}")
@@ -336,7 +350,20 @@ def make_train_step(
         raise ValueError(
             f"grad_chunk {grad_chunk} must divide num_workers {n_workers}")
 
+    # a model that supplies its loss (``models/mellum2.py``) is handed the
+    # raw batch, and returns the loss with its accuracy and counters; the
+    # label-a-row models below get cross-entropy over their logits
+    own_loss = getattr(model, "supplies_loss", False)
+    if own_loss and grad_chunk not in (None, 1):
+        raise ValueError(
+            f"grad_chunk {grad_chunk}: a model that supplies its loss runs "
+            f"its workers one after another (grad_chunk 1 or unset)")
+
     def loss_fn(params, batch_stats, x, y, rng):
+        if own_loss:
+            loss, aux = model.apply({"params": params}, x, y,
+                                    method="batch_loss")
+            return loss, (batch_stats, aux)
         variables = {"params": params}
         if batch_stats:
             variables["batch_stats"] = batch_stats
@@ -350,6 +377,11 @@ def make_train_step(
     grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 
     def all_grads(params, batch_stats, xb, yb, rngs):
+        if own_loss:
+            # one worker after another, and no vmap: under one, a ``cond``
+            # on the worker's own data would run both of its branches
+            return jax.lax.map(lambda worker: grad_fn(*worker),
+                               (params, batch_stats, xb, yb, rngs))
         if grad_chunk is None or grad_chunk == n_workers:
             return jax.vmap(grad_fn)(params, batch_stats, xb, yb, rngs)
         slabs = n_workers // grad_chunk
@@ -374,7 +406,7 @@ def make_train_step(
         # so named scopes, not wall-clock brackets, are how the comp/comm
         # split stays attributable (DESIGN.md §14)
         with device_span("matcha/fwd_bwd"):
-            (loss, (new_stats, logits)), grads = all_grads(
+            (loss, (new_stats, outputs)), grads = all_grads(
                 state.params, state.batch_stats, xb, yb, rngs
             )
 
@@ -610,11 +642,17 @@ def make_train_step(
 
         metrics = {
             "loss": _fleet_mean(loss),
-            "accuracy": _fleet_mean(top_k_accuracy(logits, yb)),
+            "accuracy": _fleet_mean(outputs["accuracy"] if own_loss
+                                    else top_k_accuracy(outputs, yb)),
             "disagreement": worker_disagreement(flat, alive),
             "lr": lr_schedule(state.step) if lr_schedule else jnp.asarray(0.0),
             "active_matchings": jnp.sum(flags_arr[t]),
         }
+        if own_loss:
+            # the model's counters, summed over the workers: the loop sums
+            # them over the epoch's steps into the period's ``counters``
+            metrics.update({COUNTER_PREFIX + k: jnp.sum(v, axis=0)
+                            for k, v in outputs["counters"].items()})
         if faults is not None or member is not None:
             metrics["healed"] = jnp.sum(healed)
             metrics["alive_workers"] = jnp.sum(alive)
@@ -673,6 +711,18 @@ def make_eval_fn(model):
     """Build ``evaluate(params, batch_stats, x, y) -> (loss[N], acc[N])`` —
     every worker evaluates the full batch (matching the reference's
     every-rank-evaluates pattern, train_mpi.py:152, but in one vmap)."""
+
+    if getattr(model, "supplies_loss", False):
+        @jax.jit
+        def evaluate_own(params, batch_stats, x, y):
+            def one(worker):
+                loss, aux = model.apply({"params": worker[0]}, x, y,
+                                        method="batch_loss")
+                return loss, aux["accuracy"]
+
+            return jax.lax.map(one, (params, batch_stats))
+
+        return evaluate_own
 
     @jax.jit
     def evaluate(params, batch_stats, x, y):

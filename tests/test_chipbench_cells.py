@@ -1,0 +1,73 @@
+"""The token cell rehearsed tiny on the CPU from ``stage_job`` to
+``check.compare``, as ``chipbench/tests/test_cells_on_cpu.py`` rehearses
+every cell of ``BENCHMARK.json`` outside tier-1 (the two conv cells take a
+minute and half a minute there; this one fits here), and the control that
+has to fail."""
+
+import importlib.util
+import json
+from pathlib import Path
+
+import pytest
+
+from chipbench import catalog
+
+CELL = "mellum2-12b-a2.5b.ep8-s4k.w2-matcha"
+
+_spec = importlib.util.spec_from_file_location(
+    "chipbench_tests_cells_on_cpu", Path(__file__).resolve().parents[1]
+    / "chipbench" / "tests" / "test_cells_on_cpu.py")
+_cells_on_cpu = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(_cells_on_cpu)
+rehearse = _cells_on_cpu.rehearse  # its one rehearsal run, not its tests
+
+
+@pytest.fixture(scope="module")
+def line():
+    return rehearse(CELL, trace=1)
+
+
+def test_program_agrees_with_reference(line):
+    assert line["correct"], line["check"]
+    assert line["attempted"] >= 1 and line["failed"] == 0
+    assert line["loss"][1] < line["loss"][0]
+    json.dumps(line)
+
+
+def test_counters_fill_the_cells_own_metrics(line):
+    """A traced run's line: on the CPU no device trace is read, and the
+    metrics that read the program's counters and spans still report."""
+    bench = catalog.benchmark()
+    mine = {m["name"] for m in bench["per_layer"]
+            if CELL in m.get("workloads", ())}
+    assert mine == {"moe_load_max_over_mean", "moe_slot_fill_pct",
+                    "loss_positions_pct"}
+    got = {k: v["value"] for k, v in line["metrics"].items()}
+    assert mine <= set(got)
+    assert 0 < got["moe_slot_fill_pct"] <= 100
+    assert 1 <= got["moe_load_max_over_mean"] <= 2  # 2 experts held
+    assert 90 < got["loss_positions_pct"] <= 100
+    assert {"stage_gb_per_s", "comm_timer_ms", "stage_ms",
+            "span_cover_pct"} <= set(got)
+
+
+def test_readers_return_nothing_for_a_program_without_counters(line):
+    """The parent's program journals no ``counters`` and no ``tokens``: the
+    readers give None, and the line leaves the metric out."""
+    run = {"events": [{"kind": "spans", "epoch": 0, "samples": 8,
+                       "period": "0.0", "spans": [
+                           {"name": "dispatch", "t0": 0, "t1": 1, "steps": 2,
+                            "parent": "0.0"}]}],
+           "epochs": [{"epoch": 0}], "traced": None}
+    for name in ("moe_load_max_over_mean", "moe_slot_fill_pct",
+                 "loss_positions_pct"):
+        assert catalog.load_reader(name)(run) is None
+
+
+def test_control_exchange_left_out_is_not_correct():
+    def no_gossip(job):
+        job["train_config"]["communicator"] = "none"
+
+    line = rehearse(CELL, edit=no_gossip)
+    assert not line["correct"]
+    assert line["check"]["disagree_gap"][0] > line["check"]["disagree_gap"][1]

@@ -33,6 +33,16 @@ from matcha_tpu.ops import COMPRESSOR_NAMES
 from matcha_tpu.train import TrainConfig, train
 
 
+def _json_argument(text):
+    """A JSON object given inline or as the path of a file; None stays."""
+    if text is None:
+        return None
+    if not text.lstrip().startswith("{"):
+        with open(text) as f:
+            text = f.read()
+    return json.loads(text)
+
+
 def parse_args(argv=None) -> TrainConfig:
     """The TrainConfig a command line describes (no side effects)."""
     return _parse(argv)[0]
@@ -46,7 +56,12 @@ def _parse(argv=None):
     p.add_argument("--name", default="experiment")
     p.add_argument("--description", default="matcha_tpu run")
     p.add_argument("--model", default="resnet20",
-                   help="res|resnet<d>|VGG|vgg<d>|wrn|wrn-<d>-<k>|mlp")
+                   help="res|resnet<d>|VGG|vgg<d>|wrn|wrn-<d>-<k>|mlp|mellum2")
+    p.add_argument("--model-kwargs", default=None, dest="model_kwargs",
+                   help="JSON (or the path of a JSON file) of keyword "
+                        "arguments for the model: a model whose sizes are "
+                        "not in its name takes them here, e.g. mellum2's "
+                        "'{\"sizes\": {...}}'")
     p.add_argument("--lr", type=float, default=0.8)
     p.add_argument("--momentum", type=float, default=0.9)
     p.add_argument("--epoch", type=int, default=200, dest="epochs")
@@ -67,9 +82,10 @@ def _parse(argv=None):
     p.add_argument("--numworkers", type=int, default=8)
     p.add_argument("--dataset", default="synthetic",
                    help="synthetic|synthetic_image|digits|photo_patches|"
-                        "cifar10|cifar100|emnist|imagenet (the last four "
-                        "need --datasetRoot; digits/photo_patches are real "
-                        "pixels bundled in-image)")
+                        "cifar10|cifar100|emnist|imagenet|tokens (the last "
+                        "five need --datasetRoot; digits/photo_patches are "
+                        "real pixels bundled in-image; tokens is int32 ids "
+                        "and document numbers for next-token training)")
     p.add_argument("--datasetRoot", default=None, help=".npz path for real datasets")
     p.add_argument("--noniid", action="store_true", help="label-skew partition")
     p.add_argument("--augment", action="store_true")
@@ -283,6 +299,7 @@ def _parse(argv=None):
         name=args.name, description=args.description, model=args.model,
         dataset=args.dataset, batch_size=args.bs, non_iid=args.noniid,
         augment=args.augment, datasetRoot=args.datasetRoot,
+        model_kwargs=_json_argument(args.model_kwargs),
         lr=args.lr, momentum=args.momentum, nesterov=args.nesterov,
         epochs=args.epochs, warmup=args.warmup,
         num_workers=args.numworkers,
