@@ -28,6 +28,7 @@ import numpy as np
 import jax.numpy as jnp
 
 from ..parallel import (
+    dense_exchange_form,
     dense_gossip_fn,
     gossip_mix,
     gossip_mix_skip,
@@ -54,24 +55,37 @@ def resolve_gossip_backend(schedule, mesh=None, requested: str = "auto",
     per-backend cost ledger gated on the roofline's measured-vs-ceiling
     ratio.  One resolver on purpose: :func:`make_decen` and the train loop
     both call it, so the journaled decision is definitionally the backend
-    that compiled.
+    that compiled.  Where that backend's per-step mix is the dense exchange
+    (``dense``, ``fused``), the record's ``exchange`` names the form it
+    compiles to at this worker count (``parallel.gossip.
+    dense_exchange_form``: ``streamed`` or ``mxu``, with the N and the
+    crossover it was chosen from).
     """
     if requested != "auto":
-        return {"requested": requested, "chosen": requested,
-                "reason": "explicit config; no selection ran"}
-    if mesh is not None and mesh.size > 1:
-        return {"requested": "auto", "chosen": "shard_map",
-                "reason": f"multi-device mesh ({mesh.size} devices): "
-                          f"worker-folded ppermute plan rides ICI"}
-    from ..plan.cost import choose_gossip_backend
+        record = {"requested": requested, "chosen": requested,
+                  "reason": "explicit config; no selection ran"}
+    elif not _single_chip(mesh):
+        record = {"requested": "auto", "chosen": "shard_map",
+                  "reason": f"multi-device mesh ({mesh.size} devices): "
+                            f"worker-folded ppermute plan rides ICI"}
+    else:
+        from ..plan.cost import choose_gossip_backend
 
-    return choose_gossip_backend(
-        schedule.num_workers, schedule.num_matchings, dim=dim,
-        wire_dtype=wire_dtype,
-        budget=float(np.mean(np.asarray(schedule.probs)))
-        if len(schedule.probs) else None,
-        topology=getattr(schedule, "name", None),
-        measured_vs_ceiling=measured_vs_ceiling)
+        record = choose_gossip_backend(
+            schedule.num_workers, schedule.num_matchings, dim=dim,
+            wire_dtype=wire_dtype,
+            budget=float(np.mean(np.asarray(schedule.probs)))
+            if len(schedule.probs) else None,
+            topology=getattr(schedule, "name", None),
+            measured_vs_ceiling=measured_vs_ceiling)
+    if record["chosen"] in ("dense", "fused"):
+        record["exchange"] = dense_exchange_form(
+            schedule.num_workers, _single_chip(mesh))
+    return record
+
+
+def _single_chip(mesh) -> bool:
+    return mesh is None or mesh.size == 1
 
 
 def make_decen(
@@ -87,8 +101,12 @@ def make_decen(
     """Build the gossip communicator for a schedule.
 
     ``backend``:
-      * ``"dense"``     — one MXU matmul per step (W_t @ x); the single-chip /
-                          feature-sharded fast path.
+      * ``"dense"``     — ``x ← W_t x`` once a step, in the form the worker
+                          count asks for (``parallel.gossip.
+                          gossip_mix_dense``): on one chip up to
+                          ``STREAM_MAX_WORKERS`` rows one streamed
+                          vector-unit pass over the state, in place; above
+                          that, or under a mesh, one MXU matmul.
       * ``"fused"``     — dense per-step, plus the Pallas multi-step kernel
                           (VMEM-resident state, streamed W_t stack) for whole
                           flag streams — the bench configuration.
@@ -158,8 +176,12 @@ def make_decen(
     backends this rides the existing ``compute_dtype``/``mxu_precision``
     seam: bf16 wire ⇒ one native bf16 MXU pass with f32 accumulation
     (``preferred_element_type``); f32 wire keeps the exact HIGHEST-precision
-    program.  An explicit ``compute_dtype`` below f32 wins over the wire
-    knob (the bench passes bf16 state directly).
+    program.  The streamed small-N form of the dense exchange reads the
+    same values (``W_t`` and the state rounded to the wire dtype, float32
+    products and sums) but moves no fewer bytes: it rounds the float32 state
+    as it reads it, on one chip, where nothing crosses a wire.  An explicit
+    ``compute_dtype`` below f32 wins over the wire knob (the bench passes
+    bf16 state directly).
     """
     perms = np.asarray(schedule.perms)
     alpha = float(schedule.alpha)
@@ -183,7 +205,7 @@ def make_decen(
             f"w_window the fused one; backend '{backend}' ignores them. "
             f"Note the fused kernel runs multi-step *chains* "
             f"(Communicator.run / the comm-split timer) — the per-step "
-            f"training mix is a single dense matmul either way.",
+            f"training mix is the dense exchange either way.",
             stacklevel=2,
         )
 
@@ -212,7 +234,8 @@ def make_decen(
             mix = lambda x, w, alive=None: gossip_mix_skip(
                 x, perms, w, alive, wire_dtype=wire)
     elif backend == "dense":
-        mix = dense_gossip_fn(schedule.laplacians(), compute_dtype=compute_dtype)
+        mix = dense_gossip_fn(schedule.laplacians(), compute_dtype=compute_dtype,
+                              single_chip=_single_chip(mesh))
     elif backend == "fused":
         from ..parallel import (
             build_mixing_stack,
@@ -221,7 +244,8 @@ def make_decen(
         )
         from ..parallel.pallas_gossip import check_fused_fits, pallas_interpret
 
-        mix = dense_gossip_fn(schedule.laplacians(), compute_dtype=compute_dtype)
+        mix = dense_gossip_fn(schedule.laplacians(), compute_dtype=compute_dtype,
+                              single_chip=_single_chip(mesh))
         laplacians = schedule.laplacians()
         interpret = pallas_interpret()
 

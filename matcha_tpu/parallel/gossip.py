@@ -63,6 +63,8 @@ __all__ = [
     "gossip_mix",
     "gossip_mix_skip",
     "gossip_mix_dense",
+    "dense_exchange_form",
+    "STREAM_MAX_WORKERS",
     "masked_laplacians",
     "matching_wire_bytes",
     "dense_gossip_fn",
@@ -255,44 +257,88 @@ def masked_laplacians(laplacians: jax.Array, alive: jax.Array) -> jax.Array:
     return jnp.einsum("mn,nk->mnk", deg, eye) - adj
 
 
+#: Largest worker count at which the one-chip exchange ``x ← W_t x`` runs as
+#: the streamed vector-unit pass (``pallas_gossip.stream_mix``) and not as
+#: the ``[N, N] x [N, D]`` MXU product.  Set from gossip-only 16-step chains
+#: on one v5e chip, ms a step over a 2.34 GB float32 state (2.14 GB at
+#: N = 2; my chip runs, PR 28, PERF.md section 6), product / streamed:
+#:
+#:     N =   2     8     16    24    32    48    64    128
+#:         24.98 14.10 14.30 14.29 14.28 14.25 14.26 14.31   product
+#:          6.71  7.20  7.22  8.04  9.36 12.74 22.53 52.98   streamed
+#:
+#: The product is bound by bytes at every N: a 7.1 ms pass and, because it
+#: cannot write over the operand it reads, a 7.1 ms copy of the state beside
+#: it, in a scanned chain and inside the train step alike (cell 1's traced
+#: epoch program: ``copy.3059`` + ``fusion.1``, 14.2 ms a step; the token
+#: cell's 24.9).  The streamed pass works in place; it is N multiply-adds an
+#: element and leaves the bytes' shadow between N = 16 and 24.  It still wins
+#: by a tenth at 48 (one chunk width tried) and loses at 64: 32 is the
+#: largest N read with room to spare.  The cells run N = 2, 16 and 128.
+STREAM_MAX_WORKERS = 32
+
+
+def dense_exchange_form(n: int, single_chip: bool = True) -> dict:
+    """Which form the dense exchange takes at worker count ``n``: the
+    decision record the ``backend`` event carries.  ``streamed`` up to
+    :data:`STREAM_MAX_WORKERS` rows on one chip; ``mxu`` above it, and
+    wherever a mesh shards the state (a kernel is not partitioned; XLA's
+    product is)."""
+    streamed = single_chip and n <= STREAM_MAX_WORKERS
+    return {"form": "streamed" if streamed else "mxu", "n": int(n),
+            "crossover": STREAM_MAX_WORKERS, "single_chip": bool(single_chip)}
+
+
 def gossip_mix_dense(
     x: jax.Array,
     laplacians: jax.Array,
     weights: jax.Array,
     compute_dtype=jnp.float32,
     alive: jax.Array | None = None,
+    single_chip: bool = True,
 ) -> jax.Array:
-    """One gossip step as a single MXU matmul: ``x ← W_t @ x`` with
-    ``W_t = I − Σ_j weights[j]·L_j`` built on the fly from the flag weights.
+    """One gossip step ``x ← W_t x`` with ``W_t = I − Σ_j weights[j]·L_j``
+    built on the fly from the flag weights, in the form the static worker
+    count ``N = x.shape[0]`` asks for (:func:`dense_exchange_form`):
 
-    Why this backend exists (the TPU-first redesign of the hot path): the
-    gather form walks the state once *per matching* — M full HBM passes per
-    step — while the dense form is two passes plus MXU work, and W_t
-    (``N×N``, ≤ 131 KB at N=256 bf16) is negligible.  At the north-star scale
-    (256 workers × ResNet-20) the matmul formulation is the difference
-    between ~50 and >2000 gossip-steps/sec on one chip.  With the worker
-    state sharded along the *feature* axis the matmul is embarrassingly
-    chip-local — gossip then costs zero collectives (the mixing axis N is
-    fully resident per chip).
+    * ``N <= STREAM_MAX_WORKERS`` on one chip — **streamed**: one pass that
+      reads each element of ``x`` once and writes each output once, ``N``
+      float32 multiply-adds an element on the vector unit
+      (``pallas_gossip.stream_mix``, the state aliased in place).  At
+      N = 2 or 16 the product would fill 2 or 16 of the MXU's 128 rows six
+      times over (``highest``) for arithmetic that the bytes' own stream
+      hides.
+    * above it — **mxu**: a single ``[N, N] x [N, D]`` matmul.  The gather
+      form walks the state once *per matching*; the product is two passes
+      plus MXU work that 128 or 256 rows fill, and W_t (``N×N``) is
+      negligible.  With the worker state sharded along the *feature* axis
+      the matmul is chip-local (``single_chip=False`` keeps this form at
+      every N: XLA partitions a product, not a kernel).
 
     ``laplacians``: ``f32[M, N, N]`` stack (trace-time constant).
-    ``compute_dtype``: bf16 uses the MXU's native precision with f32
-    accumulation; f32 is bit-faithful to the oracle.  On TPU, DEFAULT
-    matmul precision degrades f32 operands to one bf16 MXU pass — invisible
-    on the CPU test mesh but ~4e-2 rel err vs the exact gather path after 20
-    steps on hardware (r4 TPU gate finding) — so f32 explicitly requests
-    HIGHEST to mean what it says on every backend.
+    ``compute_dtype``: below float32 (the bf16 wire) both forms round ``W_t``
+    and ``x`` to it and accumulate in float32; at float32 the streamed form
+    is exact float32 arithmetic, and the product requests HIGHEST — on TPU,
+    DEFAULT degrades f32 operands to one bf16 MXU pass, invisible on the CPU
+    test mesh but ~4e-2 rel err vs the exact gather path after 20 steps on
+    hardware (r4 TPU gate finding).
 
     ``alive`` rebuilds the Laplacian stack through :func:`masked_laplacians`
     before forming ``W_t`` — two extra ``[M, N, N]`` elementwise passes, tiny
-    next to the ``[N, D]`` matmul.
+    next to the ``[N, D]`` state; both forms then mix with the masked W.
     """
     n = x.shape[0]
     if alive is not None:
         laplacians = masked_laplacians(laplacians, alive)
     W = jnp.eye(n, dtype=jnp.float32) - jnp.tensordot(weights, laplacians, axes=1)
+    W = W.astype(compute_dtype)
+    if dense_exchange_form(n, single_chip)["form"] == "streamed":
+        from .pallas_gossip import pallas_interpret, stream_mix
+
+        return stream_mix(x, W, wire_dtype=compute_dtype,
+                          interpret=pallas_interpret())
     out = jax.lax.dot(
-        W.astype(compute_dtype),
+        W,
         x.astype(compute_dtype),
         precision=mxu_precision(compute_dtype),
         preferred_element_type=jnp.float32,
@@ -300,13 +346,14 @@ def gossip_mix_dense(
     return out.astype(x.dtype)
 
 
-def dense_gossip_fn(laplacians: np.ndarray, compute_dtype=jnp.float32):
+def dense_gossip_fn(laplacians: np.ndarray, compute_dtype=jnp.float32,
+                    single_chip: bool = True):
     """Build ``(x, weights[, alive]) -> x`` closing over the Laplacian stack."""
     L = jnp.asarray(np.asarray(laplacians), jnp.float32)
 
     def fn(x, weights, alive=None):
         return gossip_mix_dense(x, L, weights, compute_dtype=compute_dtype,
-                                alive=alive)
+                                alive=alive, single_chip=single_chip)
 
     return fn
 

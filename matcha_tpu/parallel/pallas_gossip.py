@@ -1,8 +1,9 @@
-"""Pallas TPU kernel: multi-step gossip with VMEM-resident state.
+"""Pallas TPU kernels: multi-step gossip with VMEM-resident state, and the
+one-step streamed exchange at small N (``stream_mix``, further down).
 
-The dense gossip backend (``gossip_mix_dense``) runs one MXU matmul
-``x ← W_t @ x`` per step, which is HBM-bound: every step re-reads and
-re-writes the full ``[N, D]`` worker state (~280 MB round trip at the
+The dense gossip backend (``gossip_mix_dense``) above its small-N crossover
+runs one MXU matmul ``x ← W_t @ x`` per step, which is HBM-bound: every step
+re-reads and re-writes the full ``[N, D]`` worker state (~280 MB round trip at the
 north-star scale, 256 workers × ResNet-20).  But the per-step mixing matrix
 ``W_t = I − Σ_j α·flag[t,j]·L_j`` is tiny (256×256 bf16 = 131 KB), so a whole
 *sequence* of gossip steps — the reference's outer iteration loop over
@@ -79,6 +80,7 @@ __all__ = [
     "involution_tables",
     "pallas_interpret",
     "perm_gossip_run",
+    "stream_mix",
 ]
 
 
@@ -295,6 +297,95 @@ def fused_gossip_run(
         out_shape=jax.ShapeDtypeStruct((n, d), x.dtype),
         interpret=interpret,
     )(x, mixing_stack)
+
+
+# ---------------------------------------------------------------------------
+# Streamed small-N exchange (one gossip step, vector unit, in place)
+# ---------------------------------------------------------------------------
+
+#: bytes of one resident ``[N, block_d]`` float32 block of ``stream_mix``:
+#: in and out blocks, each double-buffered, keep 8 MiB of the 16 MiB scoped
+#: VMEM (4 MiB blocks are refused by Mosaic's allocator).  On the v5e a
+#: 16 x 36.5 M chain read 7.49 ms a step at 1 MiB and 7.22 at 2 MiB
+#: (PERF.md section 6, PR 28).
+_STREAM_BLOCK_BYTES = 2 * 2 ** 20
+
+#: float32 elements of the ``[N, chunk]`` accumulator one pass of the
+#: kernel's inner loop keeps in vector registers: the loop's own cost is per
+#: pass, so a narrow chunk pays it often (N = 16: 21.4 ms a step at 256
+#: columns, 10.9 at 512, 7.75 at 1,024, 7.22 at 2,048, 7.55 at 4,096; N = 2:
+#: 47.1 at 512, 12.6 at 2,048, 6.71 at 8,192; N = 32: 13.5 at 512, 9.36 at
+#: 1,024, 13.2 at 2,048; same runs)
+_STREAM_CHUNK_ELEMENTS = 32768
+
+#: widest chunk (N = 2 and 3 reach it)
+_STREAM_CHUNK_MAX = 8192
+
+
+def _make_stream_kernel(n: int, block_d: int, chunk: int, wire):
+    """Kernel body: ``o[:, c] = Σ_j W[:, j] · x[j, c]`` over one resident
+    ``[N, block_d]`` block, ``chunk`` columns at a time: every term is a
+    lane-broadcast column of ``W`` times a sublane-broadcast row of the
+    block, multiplied and added in float32 on the vector unit — no MXU
+    pass, and the N-term sum is unrolled at trace time."""
+
+    def _kernel(w_ref, x_ref, o_ref):
+        def body(c, carry):
+            cols = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+            xc = x_ref[:, cols]
+            if wire is not None:
+                xc = xc.astype(wire)  # the wire's rounding, once an element
+            xc = xc.astype(jnp.float32)
+            acc = w_ref[:, 0:1] * xc[0:1, :]
+            for j in range(1, n):
+                acc = acc + w_ref[:, j:j + 1] * xc[j:j + 1, :]
+            o_ref[:, cols] = acc.astype(o_ref.dtype)
+            return carry
+
+        jax.lax.fori_loop(0, block_d // chunk, body, 0)
+
+    return _kernel
+
+
+def stream_mix(x: jax.Array, w: jax.Array, *, wire_dtype=None,
+               interpret: bool = False) -> jax.Array:
+    """One gossip step ``x ← W x`` as one streaming pass over ``x``.
+
+    ``x``: ``[N, D]`` worker state; ``w``: ``f32[N, N]`` mixing matrix (a
+    traced value: built from the step's flag row, masked or not).  The grid
+    tiles D only: each ``[N, block_d]`` block is read once, mixed in VMEM
+    with float32 multiply-adds on the vector unit, and written once over
+    the block it was read from (``input_output_aliases``: where ``x`` is
+    dead after the exchange, as in the train step and in a scanned chain,
+    the step needs no second state-sized buffer and no copy).  Every
+    product and sum is float32, so the result is at least as tight as the
+    six-pass ``highest`` MXU product it replaces at small N.
+
+    ``wire_dtype`` (through :func:`~matcha_tpu.parallel.gossip.
+    resolve_wire_dtype`): the state is rounded to the wire dtype as it is
+    read — the caller rounds ``w`` — and products of two bfloat16 values are
+    exact in float32, so a bf16 wire reads what one bf16 MXU pass with
+    float32 accumulation reads.  A last block past the end of D is padded on
+    the read and clipped on the write; columns never mix.
+    """
+    n, d = x.shape
+    if w.shape != (n, n):
+        raise ValueError(f"mixing matrix {w.shape} vs state {x.shape}")
+    wire = resolve_wire_dtype(wire_dtype)
+    chunk = min(_STREAM_CHUNK_MAX,
+                max(_STREAM_CHUNK_ELEMENTS // n // 128, 1) * 128, d)
+    block_d = min(max(_STREAM_BLOCK_BYTES // (4 * n) // chunk, 1),
+                  pl.cdiv(d, chunk)) * chunk
+    block = pl.BlockSpec((n, block_d), lambda i: (0, i))
+    return pl.pallas_call(
+        _make_stream_kernel(n, block_d, chunk, wire),
+        grid=(pl.cdiv(d, block_d),),
+        in_specs=[pl.BlockSpec((n, n), lambda i: (0, 0)), block],
+        out_specs=block,
+        out_shape=jax.ShapeDtypeStruct((n, d), x.dtype),
+        input_output_aliases={1: 0},
+        interpret=interpret,
+    )(w.astype(jnp.float32), x)
 
 
 # ---------------------------------------------------------------------------

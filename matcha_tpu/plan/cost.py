@@ -234,9 +234,14 @@ def gossip_backend_entries(n: int, num_matchings: int,
     (``ceil(D/block_d)`` visits); without it they are per-D-block-visit
     units — the fused/perm *ratio* is D-independent either way.  The dense
     per-step path (training regime: state streams every step) rides along
-    for completeness when ``dim`` is known.
+    for completeness when ``dim`` is known, in the form that runs at this
+    ``n`` on one chip (``parallel.gossip.dense_exchange_form``): streamed,
+    the float32 state is read once and written once in place whatever the
+    wire (the kernel rounds as it reads); as the MXU product, the operand
+    pass and the result are at the wire's width.
     """
-    from ..parallel.gossip import resolve_wire_dtype as _resolve
+    from ..parallel.gossip import (dense_exchange_form,
+                                   resolve_wire_dtype as _resolve)
 
     wire = _resolve(wire_dtype)
     wire_bytes = 4 if wire is None else np.dtype(wire).itemsize
@@ -249,10 +254,13 @@ def gossip_backend_entries(n: int, num_matchings: int,
                  "table_bytes": float(num_matchings * n * (4 + 4))},
     }
     if dim is not None:
+        form = dense_exchange_form(n)["form"]
+        state_bytes = 4 if form == "streamed" else wire_bytes
         entries["dense"] = {
-            "stream_bytes_per_step": float((2.0 * n * dim + n * n)
-                                           * wire_bytes),
+            "stream_bytes_per_step": float(2.0 * n * dim * state_bytes
+                                           + n * n * wire_bytes),
             "streamed": "full [N, D] state + W_t",
+            "form": form,
         }
     return entries
 

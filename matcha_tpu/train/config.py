@@ -77,7 +77,8 @@ class TrainConfig:
     # addresses the compressed-consensus cold start that leaves 64-worker
     # top-k-10% runs far behind their uncompressed control early on.
     compress_warmup_epochs: int = 0
-    # gossip backend: dense (MXU matmul/step), fused (Pallas W-stack
+    # gossip backend: dense (W_t x once a step: one streamed pass at small
+    # N, an MXU matmul above), fused (Pallas W-stack
     # multi-step kernel), perm (permutation-form Pallas kernel — reads
     # only the [T, M] flag array), gather, skip,
     # shard_map, or auto (shard_map on a real mesh; single-chip the

@@ -17,6 +17,7 @@ from matcha_tpu.parallel import (
     build_mixing_stack,
     fused_gossip_run,
     perm_gossip_run,
+    stream_mix,
 )
 from matcha_tpu.schedule import fixed_schedule, matcha_schedule
 
@@ -146,6 +147,12 @@ def _kernel_program(kernel, n, wire, masked=False):
     f32 state, compiled (``interpret=False``)."""
     f32 = jnp.float32
     x = jax.ShapeDtypeStruct((n, RESNET20_DIM), f32)
+    if kernel == "stream":
+        # one exchange, in place, at the cells' own shapes (cell 1's D is no
+        # multiple of 128) and an odd N
+        x = jax.ShapeDtypeStruct((n, STREAM_DIMS[n]), f32)
+        return (lambda x, w: stream_mix(x, w, wire_dtype=wire)), \
+            (x, jax.ShapeDtypeStruct((n, n), f32))
     if kernel == "fused":
         stack = jax.ShapeDtypeStruct(
             (CHAIN, n, n), f32 if wire == "f32" else jnp.bfloat16)
@@ -162,10 +169,14 @@ def _kernel_program(kernel, n, wire, masked=False):
         x, w, pi, pr, wire_dtype=wire)), args
 
 
+STREAM_DIMS = {16: 36_546_980, 2: 267_211_008, 3: 100_000}
+
 KERNEL_CASES = [(k, n, w, masked)
                 for k in ("fused", "perm") for n in (16, 256)
                 for w in ("f32", "bf16")
-                for masked in ((False, True) if k == "perm" else (False,))]
+                for masked in ((False, True) if k == "perm" else (False,))] \
+    + [("stream", 16, "f32", False), ("stream", 16, "bf16", False),
+       ("stream", 2, "f32", False), ("stream", 3, "f32", False)]
 
 
 @pytest.mark.perm
@@ -202,7 +213,13 @@ def _compile_all_for_v5e() -> int:
         fn, args = _kernel_program(*case)
         args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
                 for a in args]
-        jax.jit(fn).lower(*args).compile()
+        donate = (0,) if case[0] == "stream" else ()
+        compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+        if case[0] == "stream":
+            # in place: no product, no second state-sized buffer
+            assert "convolution(" not in compiled.as_text(), case
+            state = 4 * args[0].shape[0] * args[0].shape[1]
+            assert compiled.memory_analysis().temp_size_in_bytes < state // 8
         print("COMPILED", *case)
     return 0
 

@@ -315,10 +315,15 @@ def test_auto_backend_resolution_and_gate():
     # representability wall: forced perm, measurement or not
     d = choose_gossip_backend(PERM_FORCED_WORKERS, 10)
     assert d["chosen"] == "perm" and "unrepresentable" in d["reason"]
-    # explicit requests pass through verbatim
+    # explicit requests pass through verbatim; a backend whose per-step mix
+    # is the dense exchange also says which form that compiles to (PR 28)
     d = resolve_gossip_backend(sched, None, requested="fused")
+    assert d.pop("exchange")["form"] in ("streamed", "mxu")
     assert d == {"requested": "fused", "chosen": "fused",
                  "reason": "explicit config; no selection ran"}
+    assert resolve_gossip_backend(sched, None, requested="perm") == {
+        "requested": "perm", "chosen": "perm",
+        "reason": "explicit config; no selection ran"}
     # the byte ledger: flag stream ≪ W stack, ratio carried in the record
     d = resolve_gossip_backend(sched, None)
     assert d["stream_ratio_fused_over_perm"] > 1

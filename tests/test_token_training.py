@@ -16,6 +16,7 @@ from matcha_tpu.data import (WorkerBatches, judged_positions, load_tokens,
 from matcha_tpu.obs.journal import validate_event
 from matcha_tpu.train import TrainConfig, train
 from matcha_tpu.train.loop import _HostStacks
+from matcha_tpu.utils.profiling import SPAN_NAMES
 
 SEQ, VOCAB, WORKERS, BATCH, STEPS, EPOCHS = 32, 48, 2, 2, 3, 4
 SIZES = {
@@ -133,14 +134,27 @@ def test_spans_cover_the_period_and_dispatch_counts_tokens(run):
     path, result, _ = run
     records = [e for e in result.recorder.events if e["kind"] == "spans"]
     assert len(records) == EPOCHS
+    gaps = []
     for r in records:
         leaves = [s for s in r["spans"] if s["parent"] == r["period"]]
-        covered = sum(s["t1"] - s["t0"] for s in leaves)
-        assert covered >= 0.95 * (r["t1"] - r["t0"])
+        # the leaves tile the period: known names, in start order, none
+        # over another, none outside the period.  What lies between two of
+        # them is the few statements that close one span and open the next,
+        # so the gaps are judged by their median over the run and not by a
+        # share of a millisecond-sized epoch's wall clock, which one
+        # descheduled moment under the test workers breaks
+        assert {s["name"] for s in leaves} <= set(SPAN_NAMES)
+        assert {"record_epoch", "epoch_python" if path == "per_batch"
+                else "dispatch"} <= {s["name"] for s in leaves}
+        edges = [r["t0"]] + [t for s in leaves for t in (s["t0"], s["t1"])] \
+            + [r["t1"]]
+        assert edges == sorted(edges)
+        gaps += [b - a for a, b in zip(edges[0::2], edges[1::2])]
         tokens = sum(s.get("tokens", 0) for s in leaves
                      if s["name"] == "dispatch")
         assert tokens == (0 if path == "per_batch"
                           else STEPS * WORKERS * BATCH * SEQ)
+    assert float(np.median(gaps)) < 2e-3
 
 
 def test_counters_ride_the_period_and_the_history(run):
