@@ -27,10 +27,9 @@ timing stops on a scalar the host has read back (dispatch is asynchronous).
 
 Flags:
   --smoke        tiny sizes for a CPU sanity run
-  --backend B    fused|dense|perm|gather|shard_map|choco   (default fused —
+  --backend B    fused|dense|gather|shard_map|choco   (default fused —
                  the Pallas VMEM-resident multi-step W-stack kernel; dense
-                 is the per-step MXU path; perm streams only the [T, M]
-                 flag array — the A/B cell vs fused)
+                 is the per-step MXU path)
   --dtype D      bf16|f32                     (default bf16)
   --steps N      scan length per timing rep
   --chunk S      chain-composition chunk for the secondary chunked number
@@ -105,7 +104,7 @@ def time_backend(backend, sched, x, steps, dtype, chunk=1, block_d=None,
                           compute_dtype=compute_dtype, chunk=chunk,
                           block_d=block_d, w_window=w_window)
     flags = jnp.asarray(sched.flags, jnp.float32)
-    if backend in ("dense", "fused", "perm"):
+    if backend in ("dense", "fused"):
         x = x.astype(compute_dtype)  # state rides in the wire dtype end-to-end
 
     # Timing stops on a (tiny) device->host readback: dispatch is
@@ -249,7 +248,7 @@ def staleness_grid(sched, x, steps, n, dim, backend="dense",
     return cells
 
 
-def elision_grid(sched, x, steps, n, dim, backends=("skip", "dense", "perm"),
+def elision_grid(sched, x, steps, n, dim, backends=("skip", "dense"),
                  local_steps=(1, 4), reps=2):
     """The universal-elision A/B (ISSUE 19): backend × local_every cells,
     each carrying the *measured* chain rate and the compiled-cost ledger's
@@ -258,8 +257,8 @@ def elision_grid(sched, x, steps, n, dim, backends=("skip", "dense", "perm"),
 
     The A/B by construction: ``skip`` runs its historical flag-thinned
     stream through ``Communicator.run`` — thinning at the flag level, the
-    only backend that elided before the restructure — while ``dense`` and
-    ``perm`` run ``Communicator.run_elided``, the chain-level twin of the
+    only backend that elided before the restructure — while ``dense``
+    runs ``Communicator.run_elided``, the chain-level twin of the
     restructured epoch's cond-in-body scan.  At L=4 every backend's bytes
     column must show the thinned steps' traffic *gone* (≥2× vs L=1, the
     acceptance pin), and the measured column shows what that buys in
@@ -318,25 +317,20 @@ def elision_grid(sched, x, steps, n, dim, backends=("skip", "dense", "perm"),
     return cells
 
 
-def roofline(backend, value, n, dim, dtype, block_d=2048, chunk=1, m=0):
+def roofline(backend, value, n, dim, dtype, block_d=2048, chunk=1):
     """Per-step FLOP and HBM-byte model for the Pallas/MXU backends,
     evaluated at the measured rate.  The fused kernel's traffic model is
     derived in matcha_tpu/parallel/pallas_gossip.py:1-23: per chain of T
     steps the state moves once (2·N·D) and the W_t stack streams per
     D-block ((D/block_d)·T·N²); per step that amortizes to
-    2·N·D/T + ceil(D/bd)·N².  The perm backend reads only the [T, M]
-    flag rows (M·4 bytes/step) and spends (4·M+2)·N·D VPU flops/step
-    (partner-subtract, gate-scale, f32 accumulate per matching; ``m`` is
-    the matching count).  The dense backend re-materializes the state
+    2·N·D/T + ceil(D/bd)·N².  The dense backend re-materializes the state
     every step (2·N·D + N²).
 
     With chunked composition (chunk=S > 1) each *original* step costs
     2·N²·D/S apply-FLOPs on the MXU plus ~2·N³ f32 compose-FLOPs (the
     [N,N]×[N,N] chunk products), and the streamed-W traffic shrinks ×S —
     FLOPs/bytes below count the work actually executed, so MFU stays an
-    honest utilization figure, not an algorithmic speedup claim.  Perm's
-    MFU divides VPU flops by the MXU peak — a deliberate *under*statement
-    (the VPU peak is far lower), so a perm MFU can never inflate a claim.
+    honest utilization figure, not an algorithmic speedup claim.
 
     Utilization is against the device's row of the one chip table
     (``obs.costs.CHIP_PEAKS``); a device that is not in it raises.  An
@@ -354,9 +348,6 @@ def roofline(backend, value, n, dim, dtype, block_d=2048, chunk=1, m=0):
             flops_per_step = flops_per_step / chunk + 2.0 * n**3
             # compose reads the full f32 W stack once and writes 1/S of it
             bytes_per_step = bytes_per_step / chunk + (1 + 1 / chunk) * n * n * 4
-    elif backend == "perm":
-        flops_per_step = (4.0 * m + 2.0) * n * dim  # VPU, not MXU
-        bytes_per_step = m * 4.0  # the flag rows are the only stream
     else:
         bytes_per_step = (2.0 * n * dim + n * n) * bytes_el
     achieved_tflops = flops_per_step * value / 1e12
@@ -405,12 +396,8 @@ def measure(args, device) -> int:
     n = x.shape[0]
 
     if args.backend != "fused":
-        # single-backend mode (diagnostics): time it per-step and report.
-        # perm takes the Pallas D-block knob (the record reports exactly
-        # the executed configuration); the other backends ignore it
-        kb = ({"block_d": args.block_d or 2048}
-              if args.backend == "perm" else {})
-        value = time_backend(args.backend, sched, x, steps, args.dtype, **kb)
+        # single-backend mode (diagnostics): time it per-step and report
+        value = time_backend(args.backend, sched, x, steps, args.dtype)
         record = {
             "metric": f"gossip-steps/sec @ {n} virtual workers, "
                       f"D={dim} (ResNet-20), MATCHA budget 0.5, {args.dtype}, "
@@ -423,21 +410,6 @@ def measure(args, device) -> int:
         }
         if args.backend == "dense":
             record.update(roofline("dense", value, n, dim, args.dtype))
-        elif args.backend == "perm":
-            from matcha_tpu.parallel import matching_wire_bytes
-
-            record.update(roofline("perm", value, n, dim, args.dtype,
-                                   block_d=kb["block_d"],
-                                   m=len(sched.probs)))
-            record["block_d"] = kb["block_d"]
-            # the logical exchanged-row account (what telemetry counts):
-            # expected wire bytes per step = E[flags] · per-matching bytes
-            # — reported next to the HBM flag-stream model so the two byte
-            # meanings can never be conflated
-            wire = matching_wire_bytes(sched.decomposed, dim,
-                                       wire_dtype=args.dtype)
-            record["wire_bytes_per_step"] = float(
-                np.asarray(sched.probs) @ wire)
         print(json.dumps(record))
         sys.stdout.flush()
         if args.backend == "dense":
@@ -603,11 +575,8 @@ def main(argv=None) -> int:
     p = argparse.ArgumentParser()
     p.add_argument("--smoke", action="store_true")
     p.add_argument("--backend", default="fused",
-                   help="fused|dense|perm|gather|shard_map|choco; perm is "
-                        "the permutation-form flag-stream kernel (A/B cell "
-                        "vs fused — its record carries the flag-stream "
-                        "bytes_per_step and the matching_wire_bytes "
-                        "exchanged-row account); gather and choco run "
+                   help="fused|dense|gather|shard_map|choco; gather and "
+                        "choco run "
                         "orders of magnitude slower per step — pair them "
                         "with --steps 200 or a rep takes minutes")
     p.add_argument("--dtype", default="bf16", choices=["bf16", "f32"])
@@ -656,7 +625,7 @@ def main(argv=None) -> int:
     p.add_argument("--elision-grid-steps", type=int, default=120,
                    dest="elision_grid_steps",
                    help="chain length per universal-elision grid cell "
-                        "(backend in {skip,dense,perm} x local_every in "
+                        "(backend in {skip,dense} x local_every in "
                         "{1,4}; 0 disables): measured elided-chain rate + "
                         "the compiled-cost ledger's per-epoch gossip-"
                         "attributed boundary bytes (the ISSUE 19 A/B)")

@@ -15,12 +15,12 @@ Phases, each of which fails the run if it fails:
    taken, finite falling loss, finite replica disagreement, no ``retrace``
    event, and that ``auto`` used every device: ``dense`` on one chip,
    ``shard_map`` with one parameter shard per chip on several.
-2. **kernels** — both Pallas gossip kernels, compiled for the device
+2. **kernels** — the Pallas gossip kernels, compiled for the device
    through ``make_decen`` (never the interpreter on an accelerator), on a
    20-step flag stream at 16 x 273,258 and 256 x 273,258, f32 state with
-   f32 and bf16 wire, each against its oracle on the device: ``fused``
-   against the per-step ``dense`` scan, ``perm`` against the compiled
-   ``gather`` scan.
+   f32 and bf16 wire: the ``fused`` chain against the per-step ``dense``
+   scan on the device (the streamed exchange at 16 rows, the MXU product
+   at 256).
 3. **fold** (more than one device) — one gossip step of the worker-folded
    ``shard_map`` plan: ``collective-permute`` in its compiled HLO, and the
    result equal to the single-chip ``dense`` step.
@@ -50,7 +50,7 @@ CHAIN_STEPS = 20
 # HIGHEST, FMA contraction), a few ulps a step.  bf16 wire: an ulp of
 # difference before a step's quantization can move a value one bf16 step
 # (2^-8 relative), so the chain carries the repo's own per-step wire budget
-# (tests/test_perm_backend.py).
+# (tests/test_overlap.py).
 TOL_F32 = 1e-5
 TOL_BF16 = CHAIN_STEPS * 2.0 ** -8
 
@@ -144,8 +144,6 @@ def _chain_schedule(n: int):
 
 
 def kernel_phase(dim: int) -> list:
-    import warnings
-
     import jax
     import jax.numpy as jnp
 
@@ -169,32 +167,28 @@ def kernel_phase(dim: int) -> list:
         flags = jnp.asarray(sched.flags[:CHAIN_STEPS], jnp.float32)
         x = jax.random.normal(jax.random.PRNGKey(n), (n, dim), jnp.float32)
         for wire in ("f32", "bf16"):
-            for kernel, oracle in (("fused", "dense"), ("perm", "gather")):
-                with warnings.catch_warnings():
-                    # gather warns that it is slow at N >= 64: it is the
-                    # oracle here, not a path anyone trains on
-                    warnings.simplefilter("ignore")
-                    comms = [make_decen(sched, backend=b, wire_dtype=wire)
-                             for b in (kernel, oracle)]
-                assert comms[0].multi_step is not None, kernel
-                got, compile_s, run_s = timed(
-                    jax.jit(lambda v, c=comms[0]: c.run(v, flags)[0]), x)
-                want, _, oracle_s = timed(
-                    jax.jit(lambda v, c=comms[1]: c.run(v, flags)[0]), x)
-                err = float(jnp.max(jnp.abs(got - want))
-                            / jnp.max(jnp.abs(want)))
-                moved = float(jnp.max(jnp.abs(want - x)))
-                tol = TOL_F32 if wire == "f32" else TOL_BF16
-                row = {"kernel": kernel, "oracle": oracle, "n": n,
-                       "dim": dim, "wire": wire, "rel_err": err, "tol": tol,
-                       "first_call_seconds": round(compile_s, 2),
-                       "chain_seconds": round(run_s, 4),
-                       "oracle_chain_seconds": round(oracle_s, 4)}
-                rows.append(row)
-                print(f"# kernel {json.dumps(row)}", flush=True)
-                assert bool(jnp.isfinite(got).all()), row
-                assert moved > 0.0, f"flag stream mixed nothing: {row}"
-                assert err <= tol, row
+            kernel, oracle = "fused", "dense"
+            comms = [make_decen(sched, backend=b, wire_dtype=wire)
+                     for b in (kernel, oracle)]
+            assert comms[0].multi_step is not None, kernel
+            got, compile_s, run_s = timed(
+                jax.jit(lambda v, c=comms[0]: c.run(v, flags)[0]), x)
+            want, _, oracle_s = timed(
+                jax.jit(lambda v, c=comms[1]: c.run(v, flags)[0]), x)
+            err = float(jnp.max(jnp.abs(got - want))
+                        / jnp.max(jnp.abs(want)))
+            moved = float(jnp.max(jnp.abs(want - x)))
+            tol = TOL_F32 if wire == "f32" else TOL_BF16
+            row = {"kernel": kernel, "oracle": oracle, "n": n,
+                   "dim": dim, "wire": wire, "rel_err": err, "tol": tol,
+                   "first_call_seconds": round(compile_s, 2),
+                   "chain_seconds": round(run_s, 4),
+                   "oracle_chain_seconds": round(oracle_s, 4)}
+            rows.append(row)
+            print(f"# kernel {json.dumps(row)}", flush=True)
+            assert bool(jnp.isfinite(got).all()), row
+            assert moved > 0.0, f"flag stream mixed nothing: {row}"
+            assert err <= tol, row
     return rows
 
 

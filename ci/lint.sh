@@ -47,12 +47,6 @@
 #  11. attribution lane  link-level attribution plane (per-matching cost
 #                    estimator, link-costs artifact, timeline export,
 #                    critical path), as pytest (marker: attribution)
-#  11.5 perm lane + smoke  permutation-form gossip backend (flag-stream
-#                    kernel parity vs the gather oracle, alive-mask
-#                    composition, overlap drain, backend selection), as
-#                    pytest (marker: perm); then the probe's --smoke
-#                    interpret-mode A/B — the production perm kernel must
-#                    reproduce the fused W-stack kernel in f32
 #  11.6 overlap fixture smoke  the profile renderer must reproduce the
 #                    pinned 95.0% overlap on the dbuf trace fixture
 #                    (>75% acceptance floor)
@@ -181,19 +175,6 @@ rm -rf "$HEALTH_DIR"
 echo "== attribution pytest lane =="
 JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python -m pytest tests/ -q \
     -m attribution -p no:cacheprovider || rc=1
-
-echo "== perm backend pytest lane =="
-JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python -m pytest tests/ -q \
-    -m perm -p no:cacheprovider || rc=1
-
-echo "== perm interpret-mode parity smoke (probe correctness gate) =="
-# the probe re-exports the production perm kernel; its --smoke run is the
-# CPU A/B correctness gate — "valid": true means the flag-stream
-# kernel reproduced the dense W-stack kernel in f32 on the interpret path
-PERM_OUT="$(JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python \
-    benchmarks/perm_probe.py --smoke --reps 1)" || rc=1
-grep -q '"valid": true' <<<"$PERM_OUT" || { \
-    echo "perm smoke: correctness gate FAILED: $PERM_OUT"; rc=1; }
 
 echo "== overlap fixture smoke (pinned fixture overlap) =="
 # the profile renderer on the dbuf trace fixture must reproduce the

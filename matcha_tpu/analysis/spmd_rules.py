@@ -73,14 +73,14 @@ def _perm_arg(call: ast.Call) -> Optional[ast.AST]:
 
 
 def _involution_arg(call: ast.Call) -> Optional[ast.AST]:
-    """The ``perms`` argument of ``perm_gossip_run(x, weights, perms,
-    partnered, ...)`` — the static involution table stack the kernel's row
-    gathers execute."""
+    """The ``perms`` argument of ``gossip_mix(x, perms, weights, ...)`` /
+    ``gossip_mix_skip`` — the static involution table stack the exchange's
+    row gathers execute."""
     for kw in call.keywords:
         if kw.arg == "perms":
             return kw.value
-    if len(call.args) >= 3:
-        return call.args[2]
+    if len(call.args) >= 2:
+        return call.args[1]
     return None
 
 
@@ -120,9 +120,9 @@ def _check_involutions(tables) -> Optional[str]:
 
     Validity per row: every entry an in-range int and ``π[π[i]] == i`` for
     all i — a matching pairs slots symmetrically (fixed points map to
-    self).  A non-involution gather does not error in VMEM any more than a
-    one-sided ppermute errors on ICI: the asymmetric row silently double-
-    or zero-weights someone's state, the same corruption class.
+    self).  A non-involution gather does not error on the chip any more
+    than a one-sided ppermute errors on ICI: the asymmetric row silently
+    double- or zero-weights someone's state, the same corruption class.
     """
     try:
         rows = [[int(v) for v in row] for row in list(tables)]
@@ -153,11 +153,11 @@ class GL101PermutationTables(Rule):
     invariant = (
         "Every lax.ppermute perm table must be a permutation (pairwise "
         "distinct sources, pairwise distinct dests, senders == receivers) "
-        "and every perm_gossip_run involution stack must be total "
-        "involutions (π∘π = id, in-range).  Neither errors at runtime — a "
+        "and every gossip_mix / gossip_mix_skip involution stack must be "
+        "total involutions (π∘π = id, in-range).  Neither errors at runtime — a "
         "one-sided ppermute entry zeroes the unmatched receiver's block on "
-        "ICI, a non-involution gather double-weights someone's rows in "
-        "VMEM — and gossip silently averages against garbage either way.  "
+        "ICI, a non-involution gather double-weights someone's rows "
+        "— and gossip silently averages against garbage either way.  "
         "Tables are verified by constant-folding the building expression; "
         "tables closing over runtime values carry a `# graftverify: bind "
         "NAME=lo..hi` hint and are verified for every binding in the "
@@ -167,14 +167,17 @@ class GL101PermutationTables(Rule):
         "reason."
     )
 
+    #: what the row-gather exchanges' ``perms`` are held to
+    _INVOLUTION_SITE = (_involution_arg, _check_involutions,
+                        "involution table stack",
+                        "is not a valid involution stack")
     #: call leaf name -> (table-arg extractor, folded-value checker,
     #: table label, failure phrase)
     _TABLE_SITES = {
         "ppermute": (_perm_arg, _check_pairs, "perm table",
                      "is not a permutation"),
-        "perm_gossip_run": (_involution_arg, _check_involutions,
-                            "involution table stack",
-                            "is not a valid involution stack"),
+        "gossip_mix": _INVOLUTION_SITE,
+        "gossip_mix_skip": _INVOLUTION_SITE,
     }
     #: sanctioned runtime validator for involution stacks: a table bound
     #: from this call is checked at build time (raises on non-involution),
@@ -201,7 +204,7 @@ class GL101PermutationTables(Rule):
                 continue
             out.extend(self._verify(source, graph, hints, node, table,
                                     checker, label, bad,
-                                    seam=(leaf == "perm_gossip_run")))
+                                    seam=(site is self._INVOLUTION_SITE)))
         return out
 
     def _is_validator_call(self, expr: ast.AST) -> bool:
@@ -217,10 +220,10 @@ class GL101PermutationTables(Rule):
         """True when ``name`` is bound exactly once in the *outermost*
         enclosing scope, from an ``involution_tables(...)`` call (plain or
         tuple-unpacked: ``pi, pr = involution_tables(perms)``), and never
-        mutated.  Outermost, not innermost: the kernel call typically sits
-        inside a closure (``mix``/``multi_step``) while the tables are
-        built once in the backend factory around it; the single-binding +
-        no-mutation requirement keeps the widened search conservative."""
+        mutated.  Outermost, not innermost: the exchange call typically
+        sits inside a closure (``mix``) while the tables are built once in
+        the backend factory around it; the single-binding + no-mutation
+        requirement keeps the widened search conservative."""
         search: ast.AST = graph.source.tree
         line = getattr(call, "lineno", None)
         outer_lo = None

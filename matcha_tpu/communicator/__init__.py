@@ -36,7 +36,7 @@ def select_communicator(
     (AllReduce baseline), ``none``.  ``compressor`` selects CHOCO's message
     compressor from the ops registry (``matcha_tpu.ops.COMPRESSOR_NAMES``);
     ``seed`` seeds the stochastic compressors' PRNG carry.  ``block_d`` and
-    ``w_window`` tune the fused / permutation-form Pallas kernels (decen
+    ``w_window`` tune the fused Pallas kernel (decen
     only; see :func:`make_decen`).  ``wire_dtype`` (``"f32"``/``"bf16"``) narrows the
     exchanged tensors at the gossip boundary for every communicator except
     ``none`` (which exchanges nothing)."""

@@ -68,12 +68,9 @@ class Communicator:
     ``multi_step``, when present, runs a whole flag stream in one fused
     launch (e.g. the Pallas VMEM-resident gossip kernel) — arithmetically
     equivalent to scanning ``step``, used by ``run`` for consensus-only
-    phases and the micro-benchmark.  ``multi_step_masked`` is its
-    survivor-aware twin ``(flat, carry, flags[T,M], alive[N]) -> (flat,
-    carry)`` for backends whose fused form composes the mask per edge
-    in-kernel (the permutation-form kernel does; the W-stack kernel cannot
-    — its mixing matrices are precomputed maskless).  ``run`` uses it for
-    constant-``alive`` chains; per-step ``[T, N]`` masks always scan.
+    phases and the micro-benchmark.  It knows no survivors (the W-stack
+    kernel's mixing matrices are precomputed maskless), so ``run`` scans
+    ``step`` for every masked chain.
 
     ``encode_probe``, when present, is a scan-compatible stand-in for the
     per-step message *encode* work (CHOCO's compress path) —
@@ -87,7 +84,6 @@ class Communicator:
     init: Callable[[jax.Array], Any]
     step: StepFn
     multi_step: Any = None  # Optional[(flat, carry, flags[T,M]) -> (flat, carry)]
-    multi_step_masked: Any = None  # Optional[(flat, carry, flags, alive[N])]
     encode_probe: Any = None  # Optional[(flat, probe_state) -> probe_state]
 
     def begin_mix(self, flat: jax.Array, carry: Any, flags_t: jax.Array,
@@ -320,13 +316,10 @@ class Communicator:
 
         ``alive``: optional survivor mask — ``f32[N]`` (held constant for
         the chain) or ``f32[T, N]`` (per-step, scanned alongside the flags).
-        A constant mask uses ``multi_step_masked`` when the backend offers
-        one (the permutation-form kernel gates edges in-kernel, so masked
-        chains keep the fused launch); otherwise masked chains take the
-        per-step scan — ``multi_step`` fusions like the Pallas W-stack
-        kernel precompute mixing matrices that do not know about
-        survivors, so bypassing them is a correctness requirement, not a
-        missing optimization."""
+        Masked chains take the per-step scan — ``multi_step`` fusions like
+        the Pallas W-stack kernel precompute mixing matrices that do not
+        know about survivors, so bypassing them is a correctness
+        requirement, not a missing optimization."""
         import jax.numpy as jnp
         from jax import lax
 
@@ -350,8 +343,6 @@ class Communicator:
             return x, c
 
         alive = jnp.asarray(alive, jnp.float32)
-        if alive.ndim == 1 and self.multi_step_masked is not None:
-            return self.multi_step_masked(flat, carry, flags, alive)
         if alive.ndim == 1:
             def body_const(state, flags_t):
                 x, c = state

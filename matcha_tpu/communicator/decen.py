@@ -23,8 +23,6 @@ from __future__ import annotations
 from typing import Callable
 
 import jax
-import numpy as np
-
 import jax.numpy as jnp
 
 from ..parallel import (
@@ -32,34 +30,35 @@ from ..parallel import (
     dense_gossip_fn,
     gossip_mix,
     gossip_mix_skip,
+    involution_tables,
     resolve_wire_dtype,
     shard_map_gossip_fn,
 )
 from ..schedule import Schedule
 from .base import Communicator
 
-__all__ = ["make_decen", "resolve_gossip_backend"]
+__all__ = ["GOSSIP_BACKENDS", "make_decen", "resolve_gossip_backend"]
+
+#: every name ``TrainConfig.gossip_backend`` / :func:`make_decen` takes
+GOSSIP_BACKENDS = ("auto", "dense", "fused", "gather", "skip", "shard_map")
 
 
-def resolve_gossip_backend(schedule, mesh=None, requested: str = "auto",
-                           dim=None, wire_dtype=None,
-                           measured_vs_ceiling=None) -> dict:
+def resolve_gossip_backend(schedule, mesh=None,
+                           requested: str = "auto") -> dict:
     """Resolve a ``gossip_backend`` request to the backend actually built,
-    returning the full decision record for journaling.
+    returning the decision record for journaling.
 
-    Non-``auto`` requests pass through verbatim (the record says so).
-    ``auto`` keeps the historical multi-device answer — ``shard_map`` when
-    a real mesh exists (physical decentralization: ICI carries only gossip
-    edges) — and on a single chip delegates the perm-vs-dense call to
-    :func:`matcha_tpu.plan.cost.choose_gossip_backend`, the planner's
-    per-backend cost ledger gated on the roofline's measured-vs-ceiling
-    ratio.  One resolver on purpose: :func:`make_decen` and the train loop
+    An explicit request passes through verbatim (the record says so).
+    ``auto`` is ``shard_map`` when a real mesh exists (physical
+    decentralization: ICI carries only gossip edges) and ``dense`` on one
+    chip.  One resolver on purpose: :func:`make_decen` and the train loop
     both call it, so the journaled decision is definitionally the backend
     that compiled.  Where that backend's per-step mix is the dense exchange
     (``dense``, ``fused``), the record's ``exchange`` names the form it
     compiles to at this worker count (``parallel.gossip.
     dense_exchange_form``: ``streamed`` or ``mxu``, with the N and the
-    crossover it was chosen from).
+    crossover it was chosen from) — the one choice the one-chip exchange
+    has, made from the static N inside ``gossip_mix_dense``.
     """
     if requested != "auto":
         record = {"requested": requested, "chosen": requested,
@@ -69,15 +68,9 @@ def resolve_gossip_backend(schedule, mesh=None, requested: str = "auto",
                   "reason": f"multi-device mesh ({mesh.size} devices): "
                             f"worker-folded ppermute plan rides ICI"}
     else:
-        from ..plan.cost import choose_gossip_backend
-
-        record = choose_gossip_backend(
-            schedule.num_workers, schedule.num_matchings, dim=dim,
-            wire_dtype=wire_dtype,
-            budget=float(np.mean(np.asarray(schedule.probs)))
-            if len(schedule.probs) else None,
-            topology=getattr(schedule, "name", None),
-            measured_vs_ceiling=measured_vs_ceiling)
+        record = {"requested": "auto", "chosen": "dense",
+                  "reason": "one chip: the dense exchange, in the form its "
+                            "worker count asks for"}
     if record["chosen"] in ("dense", "fused"):
         record["exchange"] = dense_exchange_form(
             schedule.num_workers, _single_chip(mesh))
@@ -109,22 +102,11 @@ def make_decen(
                           that, or under a mesh, one MXU matmul.
       * ``"fused"``     — dense per-step, plus the Pallas multi-step kernel
                           (VMEM-resident state, streamed W_t stack) for whole
-                          flag streams — the bench configuration.
-      * ``"perm"``      — the permutation-form Pallas kernel for *every*
-                          phase: each step is per-row partner copies +
-                          weighted adds on a VMEM-resident state block,
-                          reading only the ``[T, M]`` flag array (SMEM;
-                          ~2000× less than the fused W stack at N=256).
-                          Its resident blocks fit the scoped VMEM up to
-                          ~5,400 workers.  Alive masks compose in-kernel
-                          (per-edge ``alive_i·alive_{π_j(i)}`` gates), so
-                          masked chains keep the fused launch
-                          (``multi_step_masked``); bf16 wire rides the
-                          ``resolve_wire_dtype`` seam with f32
-                          accumulation.  Both Pallas backends compile for
-                          the device on every platform but ``cpu``, where
-                          they run under the Pallas interpreter (the
-                          tier-1 mesh) — an accelerator never interprets.
+                          flag streams — the bench configuration.  The
+                          kernel compiles for the device on every platform
+                          but ``cpu``, where it runs under the Pallas
+                          interpreter (the tier-1 mesh) — an accelerator
+                          never interprets.
       * ``"gather"``    — per-matching static gathers (any N under jit).
       * ``"skip"``      — per-matching ``lax.cond``: inactive matchings are
                           not executed, so the MATCHA budget buys back real
@@ -139,14 +121,10 @@ def make_decen(
       * ``"shard_map"`` — explicit ppermute plan over ``mesh`` (worker-sharded,
                           the physical-decentralization path where ICI carries
                           only gossip edges).
-      * ``"auto"``      — shard_map on a multi-device mesh; single-chip the
-                          perm-vs-dense choice runs through
-                          ``plan.cost.choose_gossip_backend`` (forced perm
-                          beyond the representability wall, gated on the
-                          roofline's measured-vs-ceiling ratio otherwise —
-                          dense when no measurement exists).  The train
+      * ``"auto"``      — shard_map on a multi-device mesh, dense on one
+                          chip (:func:`resolve_gossip_backend`).  The train
                           loop journals the decision record (``backend``
-                          event) so drift can score it.
+                          event).
 
     ``chunk`` (fused backend only): collapse runs of ``chunk`` consecutive
     mixing matrices into their product before the Pallas kernel — exactly the
@@ -155,8 +133,8 @@ def make_decen(
     materialized, so keep the default 1 for training loops that interleave
     gossip with SGD; raise it for consensus-only chains and the bench.
 
-    ``block_d`` (fused/perm backends): the Pallas kernel's resident D-block
-    size; None keeps the kernel's default.  For fused, per-step W-stream
+    ``block_d`` (fused backend only): the Pallas kernel's resident D-block
+    size; None keeps the kernel's default.  Per-step W-stream
     traffic is ``ceil(D/block_d)·N²``, so bigger blocks cut HBM traffic
     linearly until the [N, block_d] in+out blocks stop fitting the 16 MiB
     scoped VMEM — a request that cannot fit raises
@@ -183,7 +161,9 @@ def make_decen(
     ``compute_dtype`` below f32 wins over the wire knob (the bench passes
     bf16 state directly).
     """
-    perms = np.asarray(schedule.perms)
+    # the one validator of schedule-built tables (GL101's runtime half):
+    # every row gather below reads what it returns
+    perms, _ = involution_tables(schedule.perms)
     alpha = float(schedule.alpha)
     wire = resolve_wire_dtype(wire_dtype)
     state_itemsize = jnp.dtype(compute_dtype).itemsize
@@ -193,25 +173,21 @@ def make_decen(
         compute_dtype = wire
 
     if backend == "auto":
-        backend = resolve_gossip_backend(schedule, mesh,
-                                         wire_dtype=wire_dtype)["chosen"]
+        backend = resolve_gossip_backend(schedule, mesh)["chosen"]
 
-    if (backend not in ("fused", "perm") and block_d is not None) \
-            or (backend != "fused" and w_window != 1):
+    if backend != "fused" and (block_d is not None or w_window != 1):
         import warnings
 
         warnings.warn(
-            f"block_d tunes the fused/perm backends' Pallas kernels and "
-            f"w_window the fused one; backend '{backend}' ignores them. "
+            f"block_d and w_window tune the fused backend's Pallas kernel; "
+            f"backend '{backend}' ignores them. "
             f"Note the fused kernel runs multi-step *chains* "
             f"(Communicator.run / the comm-split timer) — the per-step "
             f"training mix is the dense exchange either way.",
             stacklevel=2,
         )
 
-
     multi_step = None
-    multi_step_masked = None
     if backend == "gather":
         if perms.shape[1] >= 64:
             import warnings
@@ -268,38 +244,13 @@ def make_decen(
             return fused_gossip_run(flat, stack, interpret=interpret,
                                     **kernel_kwargs), carry
 
-    elif backend == "perm":
-        from ..parallel import involution_tables, perm_gossip_run
-        from ..parallel.pallas_gossip import pallas_interpret
-
-        perms_i32, partnered = involution_tables(perms)
-        kernel_kwargs = {"wire_dtype": wire_dtype, "block_d": block_d,
-                         "interpret": pallas_interpret()}
-
-        # ONE kernel for every phase: the per-step training mix is the same
-        # program at T=1 (`mix` receives the already-α-scaled weight row —
-        # a [1, M] stream), and the chain forms scale the raw flags by α
-        # exactly like gossip_mix's caller does, so step/multi_step/
-        # masked-multi_step are the same arithmetic at every entry point.
-        def mix(x, w, alive=None):
-            return perm_gossip_run(x, w[None, :], perms_i32, partnered,
-                                   alive=alive, **kernel_kwargs)
-
-        def multi_step(flat, carry, flags):
-            return perm_gossip_run(flat, alpha * flags, perms_i32,
-                                   partnered, **kernel_kwargs), carry
-
-        def multi_step_masked(flat, carry, flags, alive):
-            return perm_gossip_run(flat, alpha * flags, perms_i32,
-                                   partnered, alive=alive,
-                                   **kernel_kwargs), carry
-
     elif backend == "shard_map":
         if mesh is None:
             raise ValueError("shard_map backend needs a mesh")
         mix = shard_map_gossip_fn(perms, mesh, wire_dtype=wire)
     else:
-        raise KeyError(f"unknown gossip backend '{backend}'")
+        raise KeyError(f"unknown gossip backend '{backend}'; "
+                       f"have {list(GOSSIP_BACKENDS)}")
 
     def init(flat: jax.Array):
         return ()
@@ -312,5 +263,5 @@ def make_decen(
     wire_tag = "" if wire is None else f",wire={jnp.dtype(wire).name}"
     return Communicator(
         name=f"decen[{backend}{wire_tag}]", init=init, step=step,
-        multi_step=multi_step, multi_step_masked=multi_step_masked,
+        multi_step=multi_step,
     )

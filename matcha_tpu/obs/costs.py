@@ -48,8 +48,7 @@ __all__ = ["ChipSpec", "CHIP_PEAKS", "CPU_PROVISIONAL", "UnknownChipError",
            "analyze_program", "CostLedger", "Roofline", "gossip_step_costs",
            "gossip_chain_costs", "elision_epoch_costs", "flat_param_dim",
            "roofline_report",
-           "roofline_compare", "capacity_report",
-           "render_roofline_markdown", "render_roofline_compare_markdown",
+           "capacity_report", "render_roofline_markdown",
            "render_capacity_markdown"]
 
 
@@ -81,7 +80,7 @@ CHIP_PEAKS: Dict[str, ChipSpec] = {
 }
 
 #: The CPU row: the roofline's *relative* arithmetic (which bound binds,
-#: fused-vs-perm byte ratios) must still produce finite ceilings on the
+#: boundary-byte ratios) must still produce finite ceilings on the
 #: CPU test host — order-of-magnitude placeholders for one server core
 #: (AVX f32 matmul, DDR stream), flagged provisional in every report so
 #: they can never be read as a hardware claim.  Returned only when the
@@ -348,90 +347,55 @@ def gossip_step_costs(n: int, dim: int, decomposed: Sequence[Sequence[tuple]],
 
 
 def gossip_chain_costs(n: int, dim: int, decomposed,
-                       backend: str = "fused", wire_dtype: str = "bf16",
+                       wire_dtype: str = "bf16",
                        t_steps: int = 200, block_d: int = 2048) -> Dict:
     """Extracted per-step costs of a T-step *chain* program — the fused
-    W-stack kernel or the permutation-form flag-stream kernel, amortized
-    over its ``t_steps`` (the regime both kernels exist for: the state is
-    read and written once per chain, and only the streamed operand — W
-    stack vs flag array — scales with T).
+    W-stack kernel, amortized over its ``t_steps`` (the regime the kernel
+    exists for: the state is read and written once per chain, and only the
+    streamed ``[T, N, N]`` W stack scales with T).
 
     Compiled abstractly (``.lower().compile()``; interpret mode on the CPU
     only — the same program text tier-1 tests execute): ``hbm_bytes`` is the
-    program-boundary argument+output traffic, so the fused chain's bytes
-    carry the ``[T, N, N]`` stack and the perm chain's carry the ``[T, M]``
-    weights + the two ``[M, N]`` tables — the flag-stream-vs-W-stack
-    comparison straight from XLA's own statement of what must cross HBM.
-    Per-step fields divide by ``t_steps``.
+    program-boundary argument+output traffic, so the chain's bytes carry
+    the ``[T, N, N]`` stack straight from XLA's own statement of what must
+    cross HBM.  Per-step fields divide by ``t_steps``.
 
     ``stream_hbm_bytes_per_step`` subtracts the exactly-known one-time
     state read+write (``2·N·D·state_bytes``) before amortizing: it is the
-    *streamed operand* — per step, ``N²·w`` of W stack for fused vs
-    ``M·4`` of flag row (+ the involution tables, amortized ÷T) for perm —
-    the quantity the backend choice compares, stripped of the term both
-    kernels share.  Note the boundary counts each operand ONCE per
-    program; the physical per-D-block re-stream (``ceil(D/bd)×``) is
-    realized traffic and shows up in ``bytes_accessed``, exactly the
-    boundary-vs-realized split the module docstring defines.
-    ``model_*`` fields carry the hand model the extraction is checked
-    against (fused: ``2·N²·D`` MXU FLOPs/step; perm: ``(4·M+2)·N·D`` VPU
-    FLOPs/step — gather-subtract, gate-scale, and the two f32 accumulate
-    ops per matching).
+    *streamed operand* — per step, ``N²·w`` of W stack.  Note the boundary
+    counts each operand ONCE per program; the physical per-D-block
+    re-stream (``ceil(D/bd)×``) is realized traffic and shows up in
+    ``bytes_accessed``, exactly the boundary-vs-realized split the module
+    docstring defines.  ``model_*`` fields carry the hand model the
+    extraction is checked against (``2·N²·D`` MXU FLOPs/step).
     """
     import jax
     import jax.numpy as jnp
 
+    from ..parallel import fused_gossip_run
     from ..parallel.gossip import resolve_wire_dtype
-    from ..topology import matchings_to_perms
+    from ..parallel.pallas_gossip import pallas_interpret
 
     wire = resolve_wire_dtype(None if wire_dtype == "f32" else wire_dtype)
     wire_bytes = 4 if wire is None else jnp.dtype(wire).itemsize
     state_dtype = jnp.float32 if wire is None else wire
-    from ..parallel.pallas_gossip import pallas_interpret
-
     interpret = pallas_interpret()
-    m = len(decomposed)
     x = jax.ShapeDtypeStruct((n, dim), state_dtype)
-    if backend == "fused":
-        from ..parallel import fused_gossip_run
-
-        stack = jax.ShapeDtypeStruct((t_steps, n, n), state_dtype)
-        # re-jit a closure over the static kwargs: analyze_program needs a
-        # bare .lower(*arrays) surface, and jit-of-jit lowers to the same
-        # program (the inner call inlines)
-        fn = jax.jit(lambda xx, ss: fused_gossip_run(
-            xx, ss, block_d=block_d, interpret=interpret))
-        costs = analyze_program(
-            fn, x, stack, label=f"gossip_chain_fused_{wire_dtype}")
-        # boundary stream: the W stack crosses HBM once per program —
-        # N²·w per step (pad rows for T % w_window ride along upstream)
-        model_stream = float(n * n * wire_bytes)
-        model_flops = 2.0 * n * n * dim
-    elif backend == "perm":
-        from ..parallel import involution_tables, perm_gossip_run
-
-        perms = matchings_to_perms([list(g) for g in decomposed], n)
-        pi, pr = involution_tables(perms)
-        w = jax.ShapeDtypeStruct((t_steps, m), jnp.float32)
-        wd = wire_dtype if wire is not None else None
-        # the lambda's table params shadow the validated pi/pr on purpose:
-        # they are exactly what analyze_program passes, and the GL101 seam
-        # check resolves the names to the involution_tables binding above
-        fn = jax.jit(lambda xx, ww, pi, pr: perm_gossip_run(
-            xx, ww, pi, pr, block_d=block_d, wire_dtype=wd,
-            interpret=interpret))
-        costs = analyze_program(
-            fn, x, w, pi, pr, label=f"gossip_chain_perm_{wire_dtype}")
-        # boundary stream: M·4 of flag row per step + the two [M, N]
-        # involution tables, read once per program (÷T)
-        model_stream = float(m * 4 + 2.0 * m * n * 4 / t_steps)
-        model_flops = float((4 * m + 2) * n * dim)
-    else:
-        raise ValueError(f"unknown chain backend {backend!r} (fused|perm)")
+    stack = jax.ShapeDtypeStruct((t_steps, n, n), state_dtype)
+    # re-jit a closure over the static kwargs: analyze_program needs a
+    # bare .lower(*arrays) surface, and jit-of-jit lowers to the same
+    # program (the inner call inlines)
+    fn = jax.jit(lambda xx, ss: fused_gossip_run(
+        xx, ss, block_d=block_d, interpret=interpret))
+    costs = analyze_program(
+        fn, x, stack, label=f"gossip_chain_fused_{wire_dtype}")
+    # boundary stream: the W stack crosses HBM once per program —
+    # N²·w per step (pad rows for T % w_window ride along upstream)
+    model_stream = float(n * n * wire_bytes)
     state_bytes = 2.0 * n * dim * jnp.dtype(state_dtype).itemsize
     per_step = {
-        "backend": backend, "t_steps": int(t_steps),
-        "block_d": int(block_d), "matchings": m,
+        "backend": "fused", "t_steps": int(t_steps),
+        "block_d": int(block_d), "matchings": len(decomposed),
         "flops_per_step": costs["flops"] / t_steps,
         "hbm_bytes_per_step": costs["hbm_bytes"] / t_steps,
         "stream_hbm_bytes_per_step":
@@ -442,7 +406,7 @@ def gossip_chain_costs(n: int, dim: int, decomposed,
         # number should match
         "model_hbm_bytes": model_stream + state_bytes / t_steps,
         "model_stream_hbm_bytes": model_stream,
-        "model_flops": model_flops,
+        "model_flops": 2.0 * n * n * dim,
     }
     return {**costs, **per_step}
 
@@ -464,11 +428,11 @@ def elision_epoch_costs(n: int, dim: int, decomposed,
     - ``dense``: the per-step ``W_t @ x`` program's boundary ``hbm_bytes``
       (:func:`gossip_step_costs` — state in+out and the flag row, each a
       real program boundary every executed step) × executed steps.
-    - ``fused`` / ``perm``: one chain program over the executed steps
+    - ``fused``: one chain program over the executed steps
       (:func:`gossip_chain_costs` at ``t_steps = ceil(T/L)``), minus the
       one-time state read+write both an L=1 and an L=4 epoch pay once —
       i.e. the *streamed operand* bytes, the term elision actually thins
-      (W-stack rows for fused, flag rows + amortized tables for perm).
+      (W-stack rows).
 
     Returns the underlying program costs plus ``exec_steps``,
     ``gossip_hbm_bytes_per_epoch``, and ``gossip_hbm_bytes_per_step``
@@ -488,14 +452,14 @@ def elision_epoch_costs(n: int, dim: int, decomposed,
         # same program either way
         costs = gossip_step_costs(n, dim, decomposed, wire_dtype=wire_dtype)
         per_epoch = costs["hbm_bytes"] * exec_steps
-    elif backend in ("fused", "perm"):
+    elif backend == "fused":
         costs = gossip_chain_costs(
-            n, dim, decomposed, backend=backend, wire_dtype=wire_dtype,
+            n, dim, decomposed, wire_dtype=wire_dtype,
             t_steps=exec_steps, block_d=block_d)
         per_epoch = costs["stream_hbm_bytes_per_step"] * exec_steps
     else:
         raise ValueError(
-            f"unknown elision backend {backend!r} (dense|skip|fused|perm)")
+            f"unknown elision backend {backend!r} (dense|skip|fused)")
     return {
         **costs,
         "backend": backend,
@@ -540,25 +504,21 @@ def roofline_report(n: int, dim: int, decomposed, wire_dtype: str = "bf16",
                     backend: str = "dense") -> Dict:
     """The automatic roofline: extracted per-step costs + the pinned chip
     peaks → ceilings, hand-model deltas, and (when a measured rate is
-    supplied) the measured-vs-ceiling ratio — the gate number the backend
-    promotion reads.
+    supplied) the measured-vs-ceiling ratio.
 
     ``backend`` selects whose program is priced: ``"dense"`` compiles the
-    per-step matmul (the historical report), ``"fused"`` and ``"perm"``
-    compile their multi-step chain kernels and amortize per step —
-    ``perm``'s boundary bytes carry the ``[T, M]`` flag stream where
-    ``fused``'s carry the ``[T, N, N]`` W stack, so the two reports ARE
-    the flag-stream-vs-W-stack comparison.  Every ratio derived from a
-    measured rate records ``measured_vs_ceiling_backend`` — the promotion
-    gate number must name its denominator (a perm rate quoted against the
-    dense ceiling, or vice versa, is the mis-citation this field exists
-    to prevent).
+    per-step matmul (the historical report), ``"fused"`` compiles the
+    multi-step chain kernel and amortizes per step (its boundary bytes
+    carry the ``[T, N, N]`` W stack).  Every ratio derived from a measured
+    rate records ``measured_vs_ceiling_backend`` — the ratio must name its
+    denominator (a fused rate quoted against the dense ceiling, or vice
+    versa, is the mis-citation this field exists to prevent).
     """
-    if backend in ("fused", "perm"):
-        costs = gossip_chain_costs(n, dim, decomposed, backend=backend,
+    if backend == "fused":
+        costs = gossip_chain_costs(n, dim, decomposed,
                                    wire_dtype=wire_dtype)
         # XLA's cost_analysis does not multiply a scanned grid's body by
-        # its trip count (the chain kernels lower to a grid scan), so the
+        # its trip count (the chain kernel lowers to a grid scan), so the
         # extracted chain FLOPs undercount by ~T× — the hand model is the
         # floor of work the formulation must issue, so the ceiling uses
         # whichever is larger; the raw extraction is kept alongside.
@@ -587,7 +547,7 @@ def roofline_report(n: int, dim: int, decomposed, wire_dtype: str = "bf16",
         extra = {"bytes_accessed_per_step": costs["bytes_accessed"]}
     else:
         raise ValueError(f"unknown roofline backend {backend!r} "
-                         f"(dense|fused|perm)")
+                         f"(dense|fused)")
     name, spec = resolve_chip(chip)
     report = {
         "n": int(n), "dim": int(dim), "wire_dtype": wire_dtype,
@@ -602,7 +562,7 @@ def roofline_report(n: int, dim: int, decomposed, wire_dtype: str = "bf16",
     report.update(
         model_flops=model_flops, model_hbm_bytes=model_hbm,
         # the model-check ratio always uses the RAW extraction — for the
-        # chain backends flops_per_step is the max(extracted, model)
+        # chain backend flops_per_step is the max(extracted, model)
         # ceiling floor, and a ratio of that against the model would read
         # 1.0 exactly when the extraction undercounts, silently disabling
         # the low-side check this field exists for
@@ -616,8 +576,8 @@ def roofline_report(n: int, dim: int, decomposed, wire_dtype: str = "bf16",
         report["measured_vs_ceiling"] = (
             float(measured_steps_per_sec) / report["ceiling_steps_per_sec"])
         # name the denominator: which backend's ceiling this ratio was
-        # computed against (the promotion gate consumes this number — it
-        # must be impossible to quote it against the wrong kernel)
+        # computed against (it must be impossible to quote it against the
+        # wrong kernel)
         report["measured_vs_ceiling_backend"] = backend
         # the Pallas-promotion gate ratio: the fused kernel removes the
         # dense HBM wall (ROOFLINE.md), so its honest ceiling is the
@@ -627,42 +587,6 @@ def roofline_report(n: int, dim: int, decomposed, wire_dtype: str = "bf16",
             float(measured_steps_per_sec)
             / report["compute_bound_steps_per_sec"])
     return report
-
-
-def roofline_compare(n: int, dim: int, decomposed, wire_dtype: str = "bf16",
-                     chip: Optional[str] = None,
-                     measured_steps_per_sec: Optional[float] = None,
-                     measured_backend: str = "perm") -> Dict:
-    """Perm-vs-fused ceilings side by side, from extracted compiled costs.
-
-    The headline number is ``hbm_ratio_fused_over_perm`` — how many times
-    more HBM traffic the W-stack chain moves per step than the flag-stream
-    chain (≈``N²·wire_bytes / (M·4)``, ~2000× at the config-3 / north-star
-    shape).  A measured rate attaches only to ``measured_backend``'s
-    report — one rate, one denominator, named.
-    """
-    reports = {
-        b: roofline_report(
-            n, dim, decomposed, wire_dtype=wire_dtype, chip=chip,
-            measured_steps_per_sec=(measured_steps_per_sec
-                                    if b == measured_backend else None),
-            backend=b)
-        for b in ("fused", "perm")
-    }
-    perm_stream = reports["perm"]["stream_hbm_bytes_per_step"]
-    return {
-        "n": int(n), "dim": int(dim), "wire_dtype": wire_dtype,
-        "chip": reports["perm"]["chip"],
-        "fused": reports["fused"], "perm": reports["perm"],
-        # the headline: streamed-operand bytes, state term stripped (both
-        # kernels read+write the state exactly once per chain)
-        "hbm_ratio_fused_over_perm":
-            reports["fused"]["stream_hbm_bytes_per_step"]
-            / max(perm_stream, 1.0),
-        "ceiling_ratio_perm_over_fused":
-            reports["perm"]["ceiling_steps_per_sec"]
-            / max(reports["fused"]["ceiling_steps_per_sec"], 1e-30),
-    }
 
 
 def _state_update_program(n: int, dim: int, communicator: str):
@@ -732,12 +656,10 @@ def _gb(x: float) -> str:
 _MODEL_LABELS = {
     "dense": ("2·N²·D", "2·N·D·w"),
     "fused": ("2·N²·D", "N²·w + 2·N·D·w/T"),
-    "perm": ("(4·M+2)·N·D", "M·4 + 2·M·N·4/T + 2·N·D·w/T"),
 }
 _BACKEND_TITLES = {
     "dense": "dense per-step gossip",
     "fused": "fused W-stack chain (per step)",
-    "perm": "permutation-form flag-stream chain (per step)",
 }
 
 
@@ -791,56 +713,6 @@ def render_roofline_markdown(report: Dict, source: str = "") -> str:
                       f"the **{report.get('measured_vs_ceiling_backend', backend)}** "
                       f"ceiling (the ratio's denominator — quote it against "
                       f"no other backend's)."]
-    if source:
-        lines += ["", f"Source: `{source}`"]
-    lines.append("")
-    return "\n".join(lines)
-
-
-def render_roofline_compare_markdown(report: Dict, source: str = "") -> str:
-    """The perm-vs-fused comparison artifact (`roofline --backend both`)."""
-    f, p = report["fused"], report["perm"]
-    lines = [
-        f"# Perm vs fused roofline @ N={report['n']}, D={report['dim']}, "
-        f"{report['wire_dtype']} wire ({report['chip']})", "",
-        f"Streamed-operand comparison from extracted compiled costs: the "
-        f"fused chain moves the `[T, N, N]` W stack, the perm chain only "
-        f"the `[T, M]` flag array — "
-        f"**{report['hbm_ratio_fused_over_perm']:.0f}× less streamed HBM "
-        f"traffic per step** at this shape (state read+write, identical "
-        f"in both, stripped).", "",
-        "| per step | fused (W stack) | perm (flag stream) |",
-        "|---|---:|---:|",
-        f"| streamed HBM bytes | {f['stream_hbm_bytes_per_step']:.4g} "
-        f"| {p['stream_hbm_bytes_per_step']:.4g} |",
-        f"| HBM bytes (boundary, incl. state) "
-        f"| {f['hbm_bytes_per_step']:.4g} "
-        f"| {p['hbm_bytes_per_step']:.4g} |",
-        f"| FLOPs | {f['flops_per_step']:.4g} | {p['flops_per_step']:.4g} |",
-        f"| compute-bound steps/s | {f['compute_bound_steps_per_sec']:.1f} "
-        f"| {p['compute_bound_steps_per_sec']:.1f} |",
-        f"| HBM-bound steps/s | {f['hbm_bound_steps_per_sec']:.1f} "
-        f"| {p['hbm_bound_steps_per_sec']:.1f} |",
-        f"| **ceiling (binding: {f['bound']} / {p['bound']})** "
-        f"| **{f['ceiling_steps_per_sec']:.1f}** "
-        f"| **{p['ceiling_steps_per_sec']:.1f}** |",
-        "",
-        f"Ceiling ratio perm/fused: "
-        f"**{report['ceiling_ratio_perm_over_fused']:.2f}×**.  (Perm's "
-        f"FLOPs run on the VPU, but the pinned peak is the chip's matmul "
-        f"rate — its compute row is an upper bound, not a promise; the "
-        f"realizable rate is the probe's question "
-        f"(`benchmarks/perm_probe.py`, measure don't assume).  Fewer "
-        f"bytes only wins where the fused MXU form has no headroom left — "
-        f"that is the `plan.cost.choose_gossip_backend` gate.)",
-    ]
-    for rep in (f, p):
-        if "measured_steps_per_sec" in rep:
-            lines += ["", f"Measured {rep['backend']}: "
-                          f"**{rep['measured_steps_per_sec']:.1f} steps/s**"
-                          f" = {rep['measured_vs_ceiling']:.1%} of the "
-                          f"{rep['measured_vs_ceiling_backend']} ceiling."]
-            break  # one measured rate; it annotates its own backend once
     if source:
         lines += ["", f"Source: `{source}`"]
     lines.append("")

@@ -50,10 +50,9 @@ __all__ = ["SCHEMA_VERSION", "ACCEPTED_VERSIONS", "EVENT_KINDS",
 #: with an attributed cause).  v4 (ISSUE 11) adds ``attribution`` — the
 #: link-level cost estimator's per-matching seconds fit (obs.attribution).
 #: v5 (ISSUE 13) adds ``backend`` — the gossip-backend selection record
-#: ``gossip_backend="auto"`` resolves through (plan.cost
-#: choose_gossip_backend: chosen backend, per-backend byte models, the
-#: measured-vs-ceiling gate inputs), journaled so drift replay can score
-#: the choice against what the run measured.  v6 (ISSUE 17) adds the run
+#: ``gossip_backend="auto"`` resolves through (communicator.decen
+#: resolve_gossip_backend: requested, chosen, reason, and the form the
+#: dense exchange compiles to).  v6 (ISSUE 17) adds the run
 #: controller's plane (matcha_tpu.serve): ``control`` — one hot-swap
 #: decision per control document (applied or rejected, with the reason and
 #: the epoch boundary it landed on), and ``promotion`` — one checkpoint-
@@ -172,8 +171,7 @@ REQUIRED_FIELDS: Dict[str, frozenset] = {
                               "base_seconds", "per_matching_seconds",
                               "source"}),
     # v5 (ISSUE 13): one per gossip-backend resolution (communicator.decen
-    # resolve_gossip_backend) — what `auto` chose and why, with the
-    # planner's per-backend byte models when the selection actually ran
+    # resolve_gossip_backend) — what `auto` chose and why
     "backend": frozenset({"requested", "chosen", "reason"}),
     # v6 (ISSUE 17): one per control-document decision (serve.control) —
     # ``action`` names what the doc asked for (budget / local_steps /

@@ -63,6 +63,7 @@ __all__ = [
     "gossip_mix",
     "gossip_mix_skip",
     "gossip_mix_dense",
+    "involution_tables",
     "dense_exchange_form",
     "STREAM_MAX_WORKERS",
     "masked_laplacians",
@@ -135,6 +136,44 @@ def matching_wire_bytes(decomposed, dim: int, wire_dtype=None) -> np.ndarray:
 def _rows(mask: jax.Array, x: jax.Array) -> jax.Array:
     """Broadcast a per-row ``[R]`` mask over the trailing dims of ``[R, ...]``."""
     return mask.reshape(mask.shape + (1,) * (x.ndim - 1))
+
+
+def involution_tables(perms) -> tuple[np.ndarray, np.ndarray]:
+    """THE table seam of the row-gather exchanges (:func:`gossip_mix`,
+    :func:`gossip_mix_skip`): validate + normalize matchings.
+
+    ``perms``: ``int[M, N]`` — one total involution per matching (partner
+    index, or self for unmatched slots), exactly ``Schedule.perms``.
+    Returns ``(perms int32[M, N], partnered f32[M, N])`` with
+    ``partnered[j, i] = 1`` iff slot ``i`` has a partner in matching ``j``.
+
+    Every row is checked to be a *total involution* (``π[π[i]] == i`` with
+    in-range entries) and a :class:`ValueError` names the first offender
+    otherwise.  This is the runtime half of the GL101 contract: static
+    tables are proven parametrically by graftverify; schedule-built tables
+    are routed through this validator, so a gather against a non-involution
+    — which would silently double- or zero-weight rows, the same corruption
+    class as a one-sided ``ppermute`` — cannot reach the exchange either way.
+    """
+    p = np.asarray(perms)
+    if p.ndim != 2:
+        raise ValueError(f"perms must be [M, N], got shape {p.shape}")
+    m, n = p.shape
+    if not np.issubdtype(p.dtype, np.integer):
+        raise ValueError(f"perms must be integer partner indices, "
+                         f"got dtype {p.dtype}")
+    if m and ((p < 0).any() or (p >= n).any()):
+        j = int(np.argwhere((p < 0) | (p >= n))[0][0])
+        raise ValueError(f"matching {j}: partner index out of range [0, {n})")
+    rows = np.arange(n)
+    for j in range(m):
+        if not np.array_equal(p[j][p[j]], rows):
+            bad = int(np.argwhere(p[j][p[j]] != rows)[0][0])
+            raise ValueError(
+                f"matching {j} is not an involution: "
+                f"π(π({bad})) = {int(p[j][p[j]][bad])} != {bad} — a matching "
+                f"must pair slots symmetrically (fixed points map to self)")
+    return p.astype(np.int32), (p != rows[None, :]).astype(np.float32)
 
 
 def gossip_mix(x: jax.Array, perms: np.ndarray, weights: jax.Array,

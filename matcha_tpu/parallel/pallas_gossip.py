@@ -22,37 +22,8 @@ accumulation, state cast to the wire dtype after every step), so intermediate
 iterates match the per-step backend; only their HBM materialization is
 elided.
 
-The permutation-form backend (``perm_gossip_run``)
---------------------------------------------------
-The fused kernel above still *streams* the dense ``[T, N, N]`` W stack —
-the dominant HBM term of its roofline once the state is resident.  But
-``W_t = I − α·Σ_j flag[t,j]·L_j`` over perfect matchings is structurally a
-sum of **static involutions**: per row,
-
-    (W_t x)_i = x_i + Σ_j α·flag[t,j]·(x_{π_j(i)} − x_i)
-
-with the ``π_j`` trace-time constants (fixed points map to themselves, so
-their delta is exactly zero).  ``perm_gossip_run`` applies each step as
-per-row partner copies + weighted adds on the VPU and reads only the
-``[T, M]`` weight array — ~``N²·wire_bytes / (M·4)`` ≈ 2,000× less
-per-step traffic than the W stack at the north-star shape.  The weights,
-the involution tables and the edge gates are scalars to the kernel and
-sit in SMEM; the partner of row ``i`` is a dynamic single-row load indexed
-by a scalar read, which is the gather form the TPU compiler accepts.
-
-Contracts (all pinned by ``tests/test_perm_backend.py``): f32-exact parity
-with the :func:`~matcha_tpu.parallel.gossip.gossip_mix` gather oracle,
-alive-mask composition through per-edge ``alive_i·alive_{π_j(i)}`` gates
-(realized mixing stays doubly stochastic over survivors), bf16 wire with
-f32 accumulation via the ``resolve_wire_dtype`` seam, an
-``interpret=True`` path so the whole backend runs on the CPU tier-1 mesh,
-and a TPU cross-lowering of both kernels at the train shapes.
-Involution tables enter through exactly one seam —
-:func:`involution_tables` — which validates ``π∘π = id`` at build time
-(the runtime half of the GL101 static proof).
-
-Both kernels size their resident blocks against the chip's on-core
-memories before Mosaic sees them: a block that cannot fit raises
+The fused kernel sizes its resident blocks against the chip's VMEM before
+Mosaic sees them: a block that cannot fit raises
 :class:`GossipKernelResourceError` naming the shape, instead of a
 compiler allocation dump.
 """
@@ -66,7 +37,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from .gossip import mxu_precision, resolve_wire_dtype
 
@@ -77,9 +47,7 @@ __all__ = [
     "compose_mixing_stack",
     "fused_gossip_run",
     "check_fused_fits",
-    "involution_tables",
     "pallas_interpret",
-    "perm_gossip_run",
     "stream_mix",
 ]
 
@@ -89,23 +57,10 @@ __all__ = [
 #: pipeline double-buffers included, must fit under it.
 SCOPED_VMEM_BYTES = 16 * 2 ** 20
 
-#: SMEM the perm kernel lets its scalar operands take: the v5e has 1 MiB
-#: (compiler message: "Used 1.04M of 1.00M smem"), and a quarter stays free
-#: for Mosaic's own scalars.
-_PERM_SMEM_BYTES = 768 * 2 ** 10
-
 
 class GossipKernelResourceError(ValueError):
-    """A Pallas gossip kernel's resident blocks exceed the chip's VMEM or
-    SMEM at the requested shape — raised at trace time, before Mosaic."""
-
-
-def _check_vmem(kernel: str, need_bytes: int, what: str) -> None:
-    if need_bytes > SCOPED_VMEM_BYTES:
-        raise GossipKernelResourceError(
-            f"{kernel} kernel: {what} keeps {need_bytes / 2 ** 20:.1f} MiB "
-            f"resident in VMEM, over the {SCOPED_VMEM_BYTES / 2 ** 20:.0f} "
-            f"MiB scoped limit — ask for a smaller block")
+    """A Pallas gossip kernel's resident blocks exceed the chip's VMEM at
+    the requested shape — raised at trace time, before Mosaic."""
 
 
 def pallas_interpret() -> bool:
@@ -115,7 +70,7 @@ def pallas_interpret() -> bool:
     return jax.default_backend() == "cpu"
 
 
-#: default resident D-block width of both kernels
+#: default resident D-block width of the fused kernel
 _BLOCK_D = 2048
 
 
@@ -127,10 +82,14 @@ def check_fused_fits(n: int, *, block_d: int = _BLOCK_D, w_window: int = 1,
     double-buffered by the Pallas pipeline.  Slightly conservative at the
     edge (Mosaic accepts bf16 N=256 block_d=8192, 16.25 MiB by this
     count) — the point is a named refusal before the allocator's dump."""
-    _check_vmem("fused",
-                4 * n * block_d * state_itemsize
-                + 2 * w_window * n * n * stack_itemsize,
-                f"N={n} rows x block_d={block_d}, w_window={w_window}")
+    need_bytes = (4 * n * block_d * state_itemsize
+                  + 2 * w_window * n * n * stack_itemsize)
+    if need_bytes > SCOPED_VMEM_BYTES:
+        raise GossipKernelResourceError(
+            f"fused kernel: N={n} rows x block_d={block_d}, "
+            f"w_window={w_window} keeps {need_bytes / 2 ** 20:.1f} MiB "
+            f"resident in VMEM, over the {SCOPED_VMEM_BYTES / 2 ** 20:.0f} "
+            f"MiB scoped limit — ask for a smaller block")
 
 
 def build_mixing_stack(
@@ -386,209 +345,3 @@ def stream_mix(x: jax.Array, w: jax.Array, *, wire_dtype=None,
         input_output_aliases={1: 0},
         interpret=interpret,
     )(w.astype(jnp.float32), x)
-
-
-# ---------------------------------------------------------------------------
-# Permutation-form backend: stream the [T, M] weights, not the W stack
-# ---------------------------------------------------------------------------
-
-def involution_tables(perms) -> tuple[np.ndarray, np.ndarray]:
-    """THE table seam of the perm backend: validate + normalize matchings.
-
-    ``perms``: ``int[M, N]`` — one total involution per matching (partner
-    index, or self for unmatched slots), exactly ``Schedule.perms``.
-    Returns ``(perms int32[M, N], partnered f32[M, N])`` with
-    ``partnered[j, i] = 1`` iff slot ``i`` has a partner in matching ``j``.
-
-    Every row is checked to be a *total involution* (``π[π[i]] == i`` with
-    in-range entries) and a :class:`ValueError` names the first offender
-    otherwise.  This is the runtime half of the GL101 contract: static
-    tables are proven parametrically by graftverify; schedule-built tables
-    are routed through this validator, so a gather against a non-involution
-    — which would silently double- or zero-weight rows, the same corruption
-    class as a one-sided ``ppermute`` — cannot reach the kernel either way.
-    """
-    p = np.asarray(perms)
-    if p.ndim != 2:
-        raise ValueError(f"perms must be [M, N], got shape {p.shape}")
-    m, n = p.shape
-    if not np.issubdtype(p.dtype, np.integer):
-        raise ValueError(f"perms must be integer partner indices, "
-                         f"got dtype {p.dtype}")
-    if m and ((p < 0).any() or (p >= n).any()):
-        j = int(np.argwhere((p < 0) | (p >= n))[0][0])
-        raise ValueError(f"matching {j}: partner index out of range [0, {n})")
-    rows = np.arange(n)
-    for j in range(m):
-        if not np.array_equal(p[j][p[j]], rows):
-            bad = int(np.argwhere(p[j][p[j]] != rows)[0][0])
-            raise ValueError(
-                f"matching {j} is not an involution: "
-                f"π(π({bad})) = {int(p[j][p[j]][bad])} != {bad} — a matching "
-                f"must pair slots symmetrically (fixed points map to self)")
-    return p.astype(np.int32), (p != rows[None, :]).astype(np.float32)
-
-
-def _make_perm_kernel(t_steps: int, num_matchings: int, n: int, wire):
-    """Kernel body: one VMEM-resident state block × the whole flag stream.
-
-    Scalars live where the TPU keeps scalars: the α-scaled flag rows, the
-    involution tables and the per-slot edge gates are flat SMEM arrays, and
-    each matching's exchange is a per-row copy ``xw[π_j(i)]`` whose source
-    row is a scalar read — the form Mosaic lowers (a vector ``jnp.take``
-    row gather is refused by its gather rule).  Per step: quantize the
-    resident block to the wire dtype once into ``xw_ref``, then for every
-    row accumulate ``w_j · gate_j[i] · (xw[π_j(i)] − xw[i])`` over the
-    matchings in f32, in ``gossip_mix``'s order, so the f32 path is bitwise
-    the gather oracle (tests pin it); fixed points contribute a delta of
-    exactly zero, which is why no degree bookkeeping appears.
-    """
-
-    def _kernel(w_ref, pi_ref, gate_ref, x_ref, o_ref, xw_ref, acc_ref):
-        o_ref[...] = x_ref[...]
-
-        def step(t, carry):
-            cur = o_ref[...]
-            curf = cur.astype(jnp.float32)
-            # wire image: quantized ONCE per step, read by both endpoints
-            # of every edge — edge-pairwise cancellation (exact worker-mean
-            # preservation) survives the narrow wire, same proof as
-            # gossip_mix.  f32 wire keeps the state untouched.
-            xw_ref[...] = (curf if wire is None
-                           else cur.astype(wire).astype(jnp.float32))
-
-            def row(i, carry):
-                xi = xw_ref[pl.ds(i, 1), :]
-                acc = jnp.zeros_like(xi)
-                # python unroll over matchings, like gossip_mix: the add
-                # chain is then the same expression the oracle compiles
-                for j in range(num_matchings):
-                    k = j * n + i
-                    partner = xw_ref[pl.ds(pi_ref[k], 1), :]
-                    # graftlint: disable=GL001 — weights, not values: the
-                    # 0/1 edge gate scales this edge's *weight*; non-finite
-                    # rows are sealed upstream (gossip_quarantined)
-                    acc = acc + (w_ref[t * num_matchings + j]
-                                 * gate_ref[k]) * (partner - xi)
-                acc_ref[pl.ds(i, 1), :] = acc
-                return carry
-
-            jax.lax.fori_loop(0, n, row, 0)
-            o_ref[...] = (curf + acc_ref[...]).astype(o_ref.dtype)
-            return carry
-
-        jax.lax.fori_loop(0, t_steps, step, 0)
-
-    return _kernel
-
-
-@functools.partial(
-    jax.jit, static_argnames=("block_d", "wire_dtype", "interpret"))
-def perm_gossip_run(
-    x: jax.Array,
-    weights: jax.Array,
-    perms: jax.Array,
-    partnered: jax.Array,
-    *,
-    alive: jax.Array | None = None,
-    block_d: int | None = None,
-    wire_dtype=None,
-    interpret: bool = False,
-) -> jax.Array:
-    """Apply ``T`` gossip steps in permutation form, streaming only weights.
-
-    ``x``: ``[N, D]`` worker state.  ``weights``: ``f32[T, M]`` — the
-    α-scaled activation flags (``alpha * flags``); this is the ONLY per-step
-    operand (``M·4`` bytes per step vs the fused kernel's
-    ``N²·wire_bytes``).  ``perms``/``partnered``: the ``[M, N]`` static
-    involution tables from :func:`involution_tables`.  All three are
-    scalars to the kernel and are placed whole in SMEM (1 MiB on the v5e);
-    a flag stream too long for it runs as consecutive launches of at most
-    ``_PERM_SMEM_BYTES`` worth of rows, the state round-tripping HBM once
-    per launch.  The grid tiles D-blocks only: each ``[N, block_d]`` state
-    block is read once, mixed for all T steps in VMEM, and written once —
-    the structure that removes the fused kernel's dominant W-stack stream.
-
-    ``alive``: optional traced ``f32[N]`` survivor mask.  Each matching's
-    per-slot gate becomes ``partnered_j · alive · alive[π_j]`` (computed
-    in-graph — ``[M, N]``, negligible), so an edge is realized only when
-    both endpoints live and the realized mixing stays doubly stochastic
-    over survivors, identically to every other backend (``parallel.gossip``
-    module docstring; non-finite dead rows are sealed upstream by the
-    resilience runtime, the same NaN contract as ``gossip_mix``).  The
-    mask is a plain traced input: membership changes never retrace.
-
-    ``wire_dtype`` — resolved through
-    :func:`~matcha_tpu.parallel.gossip.resolve_wire_dtype`, the one GL004
-    quantization seam every exchange narrows through:
-    the exchanged operand is quantized once per step before the exchange;
-    accumulation is always f32 regardless of state dtype.
-
-    ``block_d``: resident D-block width.  ``None`` takes the widest
-    multiple of 128 up to 2048 whose six ``[N, block_d]`` buffers (in and
-    out blocks double-buffered, the f32 wire image and accumulator) fit
-    the scoped VMEM; an explicit width that does not fit raises
-    :class:`GossipKernelResourceError`.  It retiles columns only, never
-    arithmetic.  ``interpret=True`` runs the Pallas interpreter — the CPU
-    tier-1 path.
-
-    Parity contract (pinned by ``tests/test_perm_backend.py``): bitwise
-    equal in f32 — masked or not, any wire — to a *compiled* ``lax.scan``
-    over :func:`~matcha_tpu.parallel.gossip.gossip_mix` (the gather
-    oracle; an eager op-by-op chain differs from any compiled form at the
-    1-ulp FMA-contraction scale, which is XLA, not this kernel).
-    """
-    n, d = x.shape
-    if weights.ndim != 2:
-        raise ValueError(f"weights must be [T, M], got {weights.shape}")
-    t_steps, m = weights.shape
-    if perms.shape != (m, n) or partnered.shape != (m, n):
-        raise ValueError(
-            f"tables {perms.shape}/{partnered.shape} incompatible with "
-            f"weights {weights.shape} and state {x.shape}")
-    if t_steps == 0 or m == 0:
-        return x
-    wire = resolve_wire_dtype(wire_dtype)
-    col_bytes = n * (4 * x.dtype.itemsize + 8)  # 2 in + 2 out + xw + acc
-    if block_d is None:
-        block_d = max(128, min(_BLOCK_D,
-                               SCOPED_VMEM_BYTES // col_bytes // 128 * 128))
-    # operator.index: static_argnames int, see canonical_chunk
-    block_d = min(operator.index(block_d), d)
-    _check_vmem("perm", col_bytes * block_d,
-                f"N={n} rows x block_d={block_d}")
-    max_steps = (_PERM_SMEM_BYTES - 8 * m * n) // (4 * m)
-    if max_steps < 1:
-        raise GossipKernelResourceError(
-            f"perm kernel: the two [M={m}, N={n}] involution tables need "
-            f"{8 * m * n} bytes of SMEM, over the {_PERM_SMEM_BYTES} byte "
-            f"budget — this worker count needs another gossip backend")
-    weights = weights.astype(jnp.float32)
-    gate = jnp.asarray(partnered, jnp.float32)
-    if alive is not None:
-        av = jnp.asarray(alive, jnp.float32)
-        # both-endpoints edge gate, folded into the static partnered mask
-        # outside the kernel ([M, N] — tiny next to the state); 0/1 alive
-        # keeps the product algebra exact, so masked parity with the
-        # gather oracle stays bitwise in f32
-        # graftlint: disable=GL001 — weights, not values: the alive
-        # product scales each edge's *weight*; non-finite rows are sealed
-        # upstream (resilience.runtime.gossip_quarantined)
-        gate = gate * av[None, :] * av[jnp.asarray(perms)]
-    pi_flat = jnp.asarray(perms, jnp.int32).reshape(-1)
-    gate_flat = gate.reshape(-1)
-    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-    block = pl.BlockSpec((n, block_d), lambda i: (0, i))
-    for start in range(0, t_steps, max_steps):
-        seg = weights[start:start + max_steps]
-        x = pl.pallas_call(
-            _make_perm_kernel(seg.shape[0], m, n, wire),
-            grid=(pl.cdiv(d, block_d),),
-            in_specs=[smem, smem, smem, block],
-            out_specs=block,
-            out_shape=jax.ShapeDtypeStruct((n, d), x.dtype),
-            scratch_shapes=[pltpu.VMEM((n, block_d), jnp.float32),
-                            pltpu.VMEM((n, block_d), jnp.float32)],
-            interpret=interpret,
-        )(seg.reshape(-1), pi_flat, gate_flat, x)
-    return x

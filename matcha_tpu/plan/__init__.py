@@ -46,7 +46,6 @@ from .cost import (
     expected_comm_units,
     load_measured_comm_times,
     load_measured_link_costs,
-    load_measured_vs_ceiling,
     matching_comm_units,
     simulate_fleet_wallclock,
     straggler_step_times,
@@ -90,7 +89,6 @@ __all__ = [
     "load_fault_ledger",
     "load_measured_comm_times",
     "load_measured_link_costs",
-    "load_measured_vs_ceiling",
     "load_plan",
     "load_recorder_disagreement",
     "local_step_breakeven",

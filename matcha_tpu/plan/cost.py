@@ -37,16 +37,11 @@ from ..topology import matchings_to_perms
 
 __all__ = [
     "CostModel",
-    "GOSSIP_BACKEND_GATE",
-    "PERM_FORCED_WORKERS",
     "matching_comm_units",
     "expected_comm_units",
     "calibrate_cost_model",
-    "choose_gossip_backend",
-    "gossip_backend_entries",
     "load_measured_comm_times",
     "load_measured_link_costs",
-    "load_measured_vs_ceiling",
     "simulate_fleet_wallclock",
     "straggler_step_times",
 ]
@@ -202,139 +197,6 @@ def calibrate_cost_model(
                      fit=provenance)
 
 
-# ---------------------------------------------------------------------------
-# Per-gossip-backend cost entries + the perm-vs-fused selection gate
-# ---------------------------------------------------------------------------
-
-#: Measured-vs-ceiling ratio above which the dense/fused formulation has no
-#: implementation headroom left and only a *structural* change (streaming
-#: the [T, M] flags instead of the [T, N, N] W stack) can buy more speed.
-#: PR 8's roofline put the fused kernel at ~91% of its MXU ceiling — the
-#: observation this gate encodes (`obs_tpu.py roofline --backend fused`).
-GOSSIP_BACKEND_GATE = 0.85
-
-#: Worker count beyond which the dense W-stack is treated as
-#: unrepresentable regardless of any measurement: an [N, N] f32 matrix at
-#: 4096 workers is 64 MB *per step of the stack*.  (The perm kernel's own
-#: VMEM-resident blocks top out near 5,400 workers — ROADMAP D2.)
-PERM_FORCED_WORKERS = 4096
-
-
-def gossip_backend_entries(n: int, num_matchings: int,
-                           dim: Optional[int] = None,
-                           wire_dtype=None, block_d: int = 2048) -> dict:
-    """Per-backend streamed-operand HBM bytes for one gossip step of the
-    fused multi-step chain — the planner's ledger the backend choice reads.
-
-    The state block is VMEM-resident in both kernels, so the *streamed*
-    per-step operand is what separates them: the fused kernel re-reads
-    ``N²·wire_bytes`` of W per D-block visit, the permutation kernel reads
-    ``M·4`` bytes of flag row (its involution tables are replicated once,
-    not per step).  With ``dim`` the entries are absolute bytes/step
-    (``ceil(D/block_d)`` visits); without it they are per-D-block-visit
-    units — the fused/perm *ratio* is D-independent either way.  The dense
-    per-step path (training regime: state streams every step) rides along
-    for completeness when ``dim`` is known, in the form that runs at this
-    ``n`` on one chip (``parallel.gossip.dense_exchange_form``): streamed,
-    the float32 state is read once and written once in place whatever the
-    wire (the kernel rounds as it reads); as the MXU product, the operand
-    pass and the result are at the wire's width.
-    """
-    from ..parallel.gossip import (dense_exchange_form,
-                                   resolve_wire_dtype as _resolve)
-
-    wire = _resolve(wire_dtype)
-    wire_bytes = 4 if wire is None else np.dtype(wire).itemsize
-    visits = 1 if dim is None else -(-int(dim) // int(block_d))
-    entries = {
-        "fused": {"stream_bytes_per_step": float(visits * n * n * wire_bytes),
-                  "streamed": "[T, N, N] mixing stack"},
-        "perm": {"stream_bytes_per_step": float(visits * num_matchings * 4),
-                 "streamed": "[T, M] flag array",
-                 "table_bytes": float(num_matchings * n * (4 + 4))},
-    }
-    if dim is not None:
-        form = dense_exchange_form(n)["form"]
-        state_bytes = 4 if form == "streamed" else wire_bytes
-        entries["dense"] = {
-            "stream_bytes_per_step": float(2.0 * n * dim * state_bytes
-                                           + n * n * wire_bytes),
-            "streamed": "full [N, D] state + W_t",
-            "form": form,
-        }
-    return entries
-
-
-def choose_gossip_backend(
-    n: int,
-    num_matchings: int,
-    dim: Optional[int] = None,
-    wire_dtype=None,
-    block_d: int = 2048,
-    budget: Optional[float] = None,
-    topology: Optional[str] = None,
-    measured_vs_ceiling: Optional[float] = None,
-    gate: float = GOSSIP_BACKEND_GATE,
-) -> dict:
-    """Resolve ``gossip_backend="auto"`` on a single chip: perm vs fused.
-
-    The decision is **gated on evidence**, not on the byte model alone: the
-    flag stream is always ~2000× smaller than the W stack, but the fused
-    kernel is MXU-bound, so less traffic only wins once the dense form has
-    no headroom left.  Three-step rule, in order:
-
-    1. ``n >= PERM_FORCED_WORKERS`` → ``perm`` (the W stack is
-       unrepresentable; no measurement needed).
-    2. ``measured_vs_ceiling >= gate`` (the roofline's measured/ceiling
-       ratio for the dense/fused formulation — ``obs_tpu.py roofline``
-       extracts it) → ``perm``: the structural lever is the only one left.
-    3. otherwise → ``dense`` (the committed per-step training path; the
-       fused multi-step chain rides the same W-stack form).  With no
-       measurement at all this is always the answer — ``auto`` never
-       promotes an unmeasured kernel, the same discipline as the probe's
-       correctness-gated ratio.
-
-    Returns the full decision record (chosen backend, reason, both byte
-    models, the stream ratio, and the gate inputs) so the caller can
-    journal it — ``obs_tpu.py drift`` then scores the choice against what
-    the run actually measured.
-    """
-    entries = gossip_backend_entries(n, num_matchings, dim=dim,
-                                     wire_dtype=wire_dtype, block_d=block_d)
-    perm_b = entries["perm"]["stream_bytes_per_step"]
-    fused_b = entries["fused"]["stream_bytes_per_step"]
-    ratio = fused_b / max(perm_b, 1.0)
-    record = {
-        "requested": "auto",
-        "n": int(n), "matchings": int(num_matchings),
-        "dim": None if dim is None else int(dim),
-        "budget": budget, "topology": topology,
-        "entries": entries,
-        "stream_ratio_fused_over_perm": round(float(ratio), 2),
-        "measured_vs_ceiling": measured_vs_ceiling,
-        "gate": float(gate),
-    }
-    if n >= PERM_FORCED_WORKERS:
-        record.update(chosen="perm", reason=(
-            f"N={n} >= {PERM_FORCED_WORKERS}: the [N, N] W-stack form is "
-            f"unrepresentable at this scale; only the flag-stream "
-            f"permutation form remains"))
-    elif measured_vs_ceiling is not None and measured_vs_ceiling >= gate:
-        record.update(chosen="perm", reason=(
-            f"measured/ceiling {measured_vs_ceiling:.2f} >= gate "
-            f"{gate:.2f}: the dense formulation is at its roofline, and "
-            f"the perm form streams {ratio:.0f}x fewer bytes/step"))
-    else:
-        why = ("no measured-vs-ceiling ratio supplied"
-               if measured_vs_ceiling is None else
-               f"measured/ceiling {measured_vs_ceiling:.2f} < gate "
-               f"{gate:.2f}: headroom remains in the dense form")
-        record.update(chosen="dense", reason=(
-            f"{why}; auto keeps the committed W-stack path (pass "
-            f"gossip_backend='perm' to force the flag-stream kernel)"))
-    return record
-
-
 def load_measured_link_costs(data) -> Tuple[dict, str]:
     """Normalize a ``measured_link_costs.json`` input: a path or the parsed
     dict; returns ``(data, label)`` and validates the format tag."""
@@ -348,76 +210,6 @@ def load_measured_link_costs(data) -> Tuple[dict, str]:
         raise ValueError(f"{label}: format {fmt!r} is not a "
                          f"matcha_tpu.link_costs artifact")
     return data, label
-
-
-def load_measured_vs_ceiling(source: str) -> Tuple[float, dict]:
-    """Extract the dense/fused formulation's measured-vs-ceiling ratio from
-    a committed artifact — the :func:`choose_gossip_backend` gate input,
-    without an operator transcribing numbers (the ISSUE 13 follow-on).
-
-    Three source shapes resolve, newest record winning:
-
-    * a run-journal JSONL whose ``bench`` events carry a roofline report
-      (``obs_tpu.py roofline --journal``): the report's
-      ``measured_vs_ceiling`` + ``measured_vs_ceiling_backend``;
-    * a ``{"record": {...}}``-wrapped or raw bench record: the fused/dense kernel's ``mfu`` — the fused chain is
-      MXU-bound, so its compute-bound MFU *is* the measured/ceiling ratio;
-    * a raw roofline-report JSON (the ``roofline_report`` dict).
-
-    Only dense/fused-backend ratios qualify (a perm rate against the perm
-    ceiling says nothing about the dense form's headroom — the denominator
-    mis-citation ``measured_vs_ceiling_backend`` exists to prevent).
-    Returns ``(ratio, provenance)``; raises ``ValueError`` when the source
-    has no usable ratio — ``auto`` must never promote on a measurement
-    that silently failed to load.
-    """
-    def _from_report(rep: dict, where: str):
-        if not isinstance(rep, dict):
-            return None
-        ratio = rep.get("measured_vs_ceiling")
-        backend = rep.get("measured_vs_ceiling_backend",
-                          rep.get("backend"))
-        if ratio is None:
-            ratio = rep.get("mfu")  # bench records: compute-bound MFU
-        if ratio is None or backend not in ("dense", "fused"):
-            return None
-        return float(ratio), {"path": source, "record": where,
-                              "backend": str(backend),
-                              "measured_vs_ceiling": float(ratio)}
-
-    with open(source) as f:
-        text = f.read()
-    candidates = []
-    try:
-        data = json.loads(text)
-        if isinstance(data, dict):
-            candidates = [data.get("record", data), data,
-                          data.get("roofline", {})]
-    except json.JSONDecodeError:
-        # JSONL journal: scan every event, newest last
-        for line in text.splitlines():
-            line = line.strip()
-            if not line:
-                continue
-            try:
-                e = json.loads(line)
-            except json.JSONDecodeError:
-                continue
-            rec = e.get("record", e) if isinstance(e, dict) else {}
-            if isinstance(rec, dict):
-                candidates.append(rec.get("roofline", rec))
-    hit = None
-    for i, cand in enumerate(candidates):
-        got = _from_report(cand, f"entry {i}")
-        if got is not None:
-            hit = got  # keep scanning: the newest usable record wins
-    if hit is None:
-        raise ValueError(
-            f"{source}: no dense/fused measured-vs-ceiling ratio found "
-            f"(want a roofline report's measured_vs_ceiling or a bench "
-            f"record's mfu with backend dense|fused) — refusing to gate "
-            f"the backend choice on a missing measurement")
-    return hit
 
 
 # ---------------------------------------------------------------------------

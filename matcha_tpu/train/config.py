@@ -77,34 +77,12 @@ class TrainConfig:
     # addresses the compressed-consensus cold start that leaves 64-worker
     # top-k-10% runs far behind their uncompressed control early on.
     compress_warmup_epochs: int = 0
-    # gossip backend: dense (W_t x once a step: one streamed pass at small
-    # N, an MXU matmul above), fused (Pallas W-stack
-    # multi-step kernel), perm (permutation-form Pallas kernel — reads
-    # only the [T, M] flag array), gather, skip,
-    # shard_map, or auto (shard_map on a real mesh; single-chip the
-    # perm-vs-dense choice runs through plan.cost.choose_gossip_backend
-    # and the decision is journaled as a `backend` event)
+    # gossip backend (communicator.decen.GOSSIP_BACKENDS): dense (W_t x
+    # once a step: one streamed pass at small N, an MXU matmul above),
+    # fused (dense per step + the Pallas W-stack kernel for chains),
+    # gather, skip, shard_map, or auto (shard_map on a real mesh, dense on
+    # one chip; the decision is journaled as a `backend` event)
     gossip_backend: str = "auto"
-    gossip_block_d: Optional[int] = None  # fused/perm D-block (None = default)
-    gossip_w_window: int = 1  # fused/perm steps per D-block visit (exact)
-    # the auto gate's measured input: the dense-formulation
-    # measured-vs-ceiling ratio from `obs_tpu.py roofline` (the
-    # measured_vs_ceiling field of a prior round's report).  None = no
-    # measurement, so auto never promotes perm below the N>=4096
-    # representability wall; feeding a measured ratio here is how an
-    # operator closes the
-    # roofline->selection loop for a real run.  Journaled in the
-    # `backend` decision event either way.
-    gossip_measured_vs_ceiling: Optional[float] = None
-    # ... or extract that ratio from an artifact instead of typing it: a
-    # run journal carrying `bench` roofline records (obs_tpu.py roofline
-    # --journal), a wrapped or raw bench record, or a raw roofline-report
-    # JSON (plan.cost.load_measured_vs_ceiling resolves all three; the
-    # provenance is journaled in the `backend` decision event).  An
-    # unusable artifact raises — auto must never promote on a ratio that
-    # silently failed to load.  The explicit ratio flag wins when both
-    # are set.
-    gossip_measured_source: Optional[str] = None
     # overlapped gossip pipeline (DESIGN.md §11): "1step" issues each step's
     # exchange via begin_mix and consumes it at the next step, so XLA can
     # hide ICI traffic under the next forward/backward; "off" is the eager
@@ -261,6 +239,11 @@ class TrainConfig:
         if self.compressor not in COMPRESSOR_NAMES:
             raise ValueError(f"bad compressor '{self.compressor}'; "
                              f"have {sorted(COMPRESSOR_NAMES)}")
+        from ..communicator.decen import GOSSIP_BACKENDS
+
+        if self.gossip_backend not in GOSSIP_BACKENDS:
+            raise ValueError(f"bad gossip_backend '{self.gossip_backend}'; "
+                             f"have {list(GOSSIP_BACKENDS)}")
         if self.num_workers < 2:
             raise ValueError("need at least 2 virtual workers")
         if not 0 <= self.budget <= 1:
@@ -292,12 +275,6 @@ class TrainConfig:
         if self.wire_dtype not in ("f32", "bf16"):
             raise ValueError(
                 f"wire_dtype must be 'f32' or 'bf16', got {self.wire_dtype!r}")
-        if self.gossip_measured_vs_ceiling is not None \
-                and not self.gossip_measured_vs_ceiling >= 0:
-            raise ValueError(
-                f"gossip_measured_vs_ceiling must be >= 0 (a "
-                f"measured/ceiling ratio), got "
-                f"{self.gossip_measured_vs_ceiling}")
         if self.compress_warmup_epochs < 0:
             raise ValueError("compress_warmup_epochs must be >= 0")
         if self.compress_warmup_epochs and self.communicator != "choco":

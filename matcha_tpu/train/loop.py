@@ -198,7 +198,7 @@ def train(config: TrainConfig, resume_dir: Optional[str] = None,
         # and the comm-split timer honest (a zero row counts zero wire
         # bytes), and the step itself now *elides* thinned steps — the
         # gossip call compiles inside a lax.cond keyed on the step cursor
-        # (make_train_step's local_steps), so dense/perm/fused stop
+        # (make_train_step's local_steps), so dense/fused stop
         # executing the identity mix instead of multiplying by it.
         # The schedule fingerprint stays the as-built stream: thinning is
         # config-derived, so a resume re-derives it identically.
@@ -276,35 +276,17 @@ def train(config: TrainConfig, resume_dir: Optional[str] = None,
         else:
             fold_dims(config.num_workers, mesh)
 
-    # gossip-backend resolution (ISSUE 13): resolve `auto` ONCE, here, via
-    # the planner's per-backend cost ledger, and hand the concrete backend
-    # to every _make_comm rebuild — the decision record is journaled next
-    # to run_start (a v5 `backend` event) so drift replay can score the
-    # choice against what the run measured.  Non-decen communicators have
-    # no gossip backend to resolve; their record says a pass-through.
+    # gossip-backend resolution: resolve `auto` ONCE, here, and hand the
+    # concrete backend to every _make_comm rebuild — the decision record is
+    # journaled next to run_start (a `backend` event).  Non-decen
+    # communicators have no gossip backend to resolve.
     backend_decision = None
     gossip_backend = config.gossip_backend
     if config.communicator == "decen":
         from ..communicator.decen import resolve_gossip_backend
 
-        # the gate's measured input: the explicit ratio flag, else the
-        # ratio extracted from a --gossip-measured-source artifact (a
-        # journal's roofline records, a bench record, or a raw
-        # roofline report) — the PR 13 follow-on that closes the
-        # roofline→selection loop without an operator transcribing numbers
-        measured = config.gossip_measured_vs_ceiling
-        measured_src = None
-        if measured is None and config.gossip_measured_source:
-            from ..plan.cost import load_measured_vs_ceiling
-
-            measured, measured_src = load_measured_vs_ceiling(
-                config.gossip_measured_source)
         backend_decision = resolve_gossip_backend(
-            schedule, mesh, requested=config.gossip_backend,
-            wire_dtype=config.wire_dtype,
-            measured_vs_ceiling=measured)
-        if measured_src is not None:
-            backend_decision["measured_source"] = measured_src
+            schedule, mesh, requested=config.gossip_backend)
         gossip_backend = backend_decision["chosen"]
 
     def _make_comm(ratio: float):
@@ -312,8 +294,7 @@ def train(config: TrainConfig, resume_dir: Optional[str] = None,
             config.communicator, schedule, mesh=mesh,
             ratio=ratio, consensus_lr=config.consensus_lr,
             backend=gossip_backend, compressor=config.compressor,
-            seed=config.seed, block_d=config.gossip_block_d,
-            w_window=config.gossip_w_window, wire_dtype=config.wire_dtype,
+            seed=config.seed, wire_dtype=config.wire_dtype,
         )
 
     communicator = _make_comm(config.compress_ratio)

@@ -33,19 +33,17 @@ Commands
 
 Performance observability (DESIGN.md §15):
 
-``roofline [--backend dense|fused|perm|both] [--workers N] [--dim D |
+``roofline [--backend dense|fused] [--workers N] [--dim D |
 --model M] [--chip C] [--measured R | --source SRC] [--md PATH]``
     The automatic roofline: compile the selected gossip program at the
-    requested shape — the dense per-step matmul, the fused W-stack chain,
-    the permutation-form flag-stream chain, or the perm-vs-fused
-    comparison (``both``) — extract FLOPs/HBM-bytes from the compiled
+    requested shape — the dense per-step matmul or the fused W-stack
+    chain — extract FLOPs/HBM-bytes from the compiled
     cost analysis, and emit compute-bound / HBM-bound steps/s ceilings
     against the pinned chip peaks (CPU gets explicit provisional
     placeholders) — machine-checking benchmarks/ROOFLINE.md.
     ``--measured`` (or a bench record via ``--source``) adds the
-    measured-vs-ceiling ratio the backend-promotion gate reads; the
-    report names which backend's ceiling the ratio divides by.  Exit 1
-    when any requested ceiling is non-finite (perm included).
+    measured-vs-ceiling ratio; the report names which backend's ceiling
+    the ratio divides by.  Exit 1 when a ceiling is non-finite.
 
 ``capacity [--dim D | --model M] [--workers N,N] [--chip C] [--md PATH]``
     Re-derive the DESIGN.md §9 HBM capacity table from the compiled
@@ -231,7 +229,7 @@ def _normalize_measured_backend(label):
     if label is None:
         return None
     label = str(label)
-    for key in ("perm", "fused", "dense"):
+    for key in ("fused", "dense"):
         if key in label:
             return key
     return None
@@ -240,12 +238,7 @@ def _normalize_measured_backend(label):
 def cmd_roofline(args) -> int:
     import math
 
-    from matcha_tpu.obs.costs import (
-        render_roofline_compare_markdown,
-        render_roofline_markdown,
-        roofline_compare,
-        roofline_report,
-    )
+    from matcha_tpu.obs.costs import render_roofline_markdown, roofline_report
     from matcha_tpu.topology import decompose, graph_size, make_graph, \
         select_graph
 
@@ -265,53 +258,29 @@ def cmd_roofline(args) -> int:
     m_backend = args.measured_backend or _normalize_measured_backend(
         measured_from)
 
-    def finite(rep) -> bool:
-        return all(math.isfinite(rep[k]) and rep[k] > 0 for k in
-                   ("flops_per_step", "hbm_bytes_per_step",
-                    "compute_bound_steps_per_sec",
-                    "hbm_bound_steps_per_sec"))
-
-    if args.backend == "both":
-        if measured is not None and m_backend not in ("fused", "perm"):
-            print(f"# measured rate came from backend "
-                  f"{measured_from!r} — not a chain kernel; comparison "
-                  f"emitted without a measured row (pass "
-                  f"--measured-backend to override)", file=sys.stderr)
-            measured = None
-        report = roofline_compare(n, dim, decomposed,
-                                  wire_dtype=args.wire_dtype,
-                                  chip=args.chip,
-                                  measured_steps_per_sec=measured,
-                                  measured_backend=m_backend or "perm")
-        md = render_roofline_compare_markdown(report,
-                                              source=args.source or "")
-        # a non-finite PERM ceiling fails exactly like the historical
-        # dense path: the comparison is only evidence when both sides
-        # extracted real numbers
-        ok = finite(report["fused"]) and finite(report["perm"])
-        journal_payload = {"roofline_compare": report,
-                           "unit": "roofline_compare"}
-    else:
-        report = roofline_report(n, dim, decomposed,
-                                 wire_dtype=args.wire_dtype,
-                                 chip=args.chip,
-                                 measured_steps_per_sec=measured,
-                                 backend=args.backend)
-        if measured is not None and m_backend is not None:
-            # origin of the rate, recorded next to the denominator: a
-            # fused rate against the dense report is the intended
-            # formulation-gate pairing (same 2·N²·D compute bound), but
-            # the record must say so rather than imply a same-backend
-            # measurement
-            report["measured_backend"] = m_backend
-            if m_backend != args.backend:
-                print(f"# note: measured rate comes from the "
-                      f"{m_backend!r} backend; this report's ceilings "
-                      f"price {args.backend!r} (the record carries both "
-                      f"labels)", file=sys.stderr)
-        md = render_roofline_markdown(report, source=args.source or "")
-        ok = finite(report)
-        journal_payload = {"roofline": report, "unit": "roofline_report"}
+    report = roofline_report(n, dim, decomposed,
+                             wire_dtype=args.wire_dtype,
+                             chip=args.chip,
+                             measured_steps_per_sec=measured,
+                             backend=args.backend)
+    if measured is not None and m_backend is not None:
+        # origin of the rate, recorded next to the denominator: a
+        # fused rate against the dense report is the intended
+        # formulation pairing (same 2·N²·D compute bound), but
+        # the record must say so rather than imply a same-backend
+        # measurement
+        report["measured_backend"] = m_backend
+        if m_backend != args.backend:
+            print(f"# note: measured rate comes from the "
+                  f"{m_backend!r} backend; this report's ceilings "
+                  f"price {args.backend!r} (the record carries both "
+                  f"labels)", file=sys.stderr)
+    md = render_roofline_markdown(report, source=args.source or "")
+    ok = all(math.isfinite(report[k]) and report[k] > 0 for k in
+             ("flops_per_step", "hbm_bytes_per_step",
+              "compute_bound_steps_per_sec",
+              "hbm_bound_steps_per_sec"))
+    journal_payload = {"roofline": report, "unit": "roofline_report"}
     print(md)
     if args.md:
         with open(args.md, "w") as f:
@@ -533,24 +502,20 @@ def main(argv=None) -> int:
     s.add_argument("--wire-dtype", default="bf16", choices=["f32", "bf16"],
                    dest="wire_dtype")
     s.add_argument("--backend", default="dense",
-                   choices=["dense", "fused", "perm", "both"],
+                   choices=["dense", "fused"],
                    help="whose program to price: the dense per-step matmul "
-                        "(historical default), the fused W-stack chain, "
-                        "the permutation-form flag-stream chain, or the "
-                        "perm-vs-fused comparison (exit 1 when any ceiling "
-                        "is non-finite, perm included)")
+                        "(historical default) or the fused W-stack chain "
+                        "(exit 1 when a ceiling is non-finite)")
     s.add_argument("--measured", type=float, default=None,
                    help="measured steps/s for the vs-ceiling ratio")
     s.add_argument("--measured-backend", default=None,
-                   choices=["dense", "fused", "perm"],
+                   choices=["dense", "fused"],
                    dest="measured_backend",
                    help="which backend produced the measured rate "
                         "(default: the --source record's own `backend` "
-                        "field).  `--backend both` withholds the measured "
-                        "row for non-chain (dense) sources; "
-                        "single-backend reports always emit the ratio but "
-                        "record BOTH labels (measured_backend + "
-                        "measured_vs_ceiling_backend) and note "
+                        "field).  The report always emits the ratio but "
+                        "records BOTH labels (measured_backend + "
+                        "measured_vs_ceiling_backend) and notes "
                         "cross-backend pairings")
     s.add_argument("--source", default=None,
                    help="bench journal / BENCH_r*.json / run dir to read "

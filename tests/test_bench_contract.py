@@ -51,13 +51,12 @@ def test_elision_grid_cells_shape_and_byte_monotonicity():
     finally:
         sys.path.remove(REPO)
     assert [(c["backend"], c["local_every"]) for c in cells] == [
-        ("skip", 1), ("skip", 4), ("dense", 1), ("dense", 4),
-        ("perm", 1), ("perm", 4)]
+        ("skip", 1), ("skip", 4), ("dense", 1), ("dense", 4)]
     by_key = {(c["backend"], c["local_every"]): c for c in cells}
     for c in cells:
         assert c["unit"] == "gossip_steps_per_sec" and c["value"] > 0
         assert c["hbm_bytes_per_epoch"] > 0
-    for backend in ("skip", "dense", "perm"):
+    for backend in ("skip", "dense"):
         l1 = by_key[(backend, 1)]
         l4 = by_key[(backend, 4)]
         assert l4["hbm_bytes_per_epoch"] < l1["hbm_bytes_per_epoch"]

@@ -359,6 +359,46 @@ def test_fused_knobs_warn_on_other_backends():
         make_decen(sched, backend="fused", w_window=4, block_d=512)
 
 
+def _refused_config():
+    from matcha_tpu.train import TrainConfig
+
+    TrainConfig(gossip_backend="perm")
+
+
+def _refused_make_decen():
+    make_decen(fixed_schedule(tp.select_graph(5), 8, iterations=2),
+               backend="perm")
+
+
+def _refused_cli(*argv):
+    import train_tpu
+
+    return lambda: train_tpu.parse_args(list(argv))
+
+
+@pytest.mark.parametrize("call,error,names", [
+    (_refused_config, ValueError, "perm.*dense.*fused.*gather"),
+    (_refused_make_decen, KeyError, "perm.*dense.*fused.*gather"),
+    (_refused_cli("--backend", "perm"), ValueError,
+     "perm.*dense.*fused.*gather"),
+    # argparse refuses an option it does not know with exit status 2
+    (_refused_cli("--gossip-measured-ratio", "0.9"), SystemExit, "2"),
+    (_refused_cli("--gossip-measured-vs-ceiling", "0.9"), SystemExit, "2"),
+    (_refused_cli("--gossip-measured-source", "x.json"), SystemExit, "2"),
+    (_refused_cli("--block-d", "4096"), SystemExit, "2"),
+    (_refused_cli("--w-window", "4"), SystemExit, "2"),
+], ids=["TrainConfig", "make_decen", "cli-backend", "cli-measured-ratio",
+        "cli-measured-vs-ceiling", "cli-measured-source", "cli-block-d",
+        "cli-w-window"])
+def test_what_pr29_removed_is_refused_by_name(call, error, names):
+    """The permutation-form backend, the measurement its gate asked the
+    user for and the two kernel-tuning flags are gone (PR 29): each is
+    refused where an unknown name is refused, and an unknown backend is
+    told which there are."""
+    with pytest.raises(error, match=names):
+        call()
+
+
 def test_choco_approx_topk_contracts():
     """CHOCO with the TPU-native approximate top-k (``top_k_approx``): the
     compressor is deterministic (no PRNG carry needed) and still a

@@ -264,17 +264,17 @@ def test_gl101_suppression(tmp_path):
 
 
 # ============================================== GL101: involution tables
-# (ISSUE 13: the permutation-form gossip kernel's row-gather tables are the
+# (the row-gather exchanges' tables — gossip_mix / gossip_mix_skip — are the
 # same silent-corruption class as a one-sided ppermute — verified statically
 # where foldable, parametrically under bind hints, and accepted through the
 # involution_tables runtime-validator seam otherwise.)
 
 def test_gl101_fires_on_non_involution_literal(tmp_path):
     vs = _lint(tmp_path, """
-        from matcha_tpu.parallel import perm_gossip_run
+        from matcha_tpu.parallel import gossip_mix
 
         def f(x, w, gate):
-            return perm_gossip_run(x, w, [[1, 2, 0]], gate)
+            return gossip_mix(x, [[1, 2, 0]], w, gate)
     """)
     assert _ids(vs) == ["GL101"]
     assert "not an involution" in vs[0].message  # names the asymmetry
@@ -284,12 +284,12 @@ def test_gl101_fires_on_broken_involution_under_binding(tmp_path):
     # π(i) = (i + d) % n is an involution only when 2·d ≡ 0 (mod n):
     # the d=1 binding must break the parametric proof and be named
     vs = _lint(tmp_path, """
-        from matcha_tpu.parallel import perm_gossip_run
+        from matcha_tpu.parallel import gossip_mix
 
         def f(x, w, gate, n, d):
             # graftverify: bind n=4 d=1,2
             tables = [[(i + d) % n for i in range(n)]]
-            return perm_gossip_run(x, w, tables, gate)
+            return gossip_mix(x, tables, w, gate)
     """)
     assert _ids(vs) == ["GL101"]
     assert "involution" in vs[0].message
@@ -298,18 +298,17 @@ def test_gl101_fires_on_broken_involution_under_binding(tmp_path):
 
 def test_gl101_silent_on_hinted_involution_and_pair_swap(tmp_path):
     vs = _lint(tmp_path, """
-        from matcha_tpu.parallel import perm_gossip_run
+        from matcha_tpu.parallel import gossip_mix
 
         def shifted(x, w, gate, n):
             # the n/2 shift pairs i with its antipode: a real involution
             # for every even binding
             # graftverify: bind n=2,4,8
             tables = [[(i + n // 2) % n for i in range(n)]]
-            return perm_gossip_run(x, w, tables, gate)
+            return gossip_mix(x, tables, w, gate)
 
         def literal(x, w, gate):
-            return perm_gossip_run(x, w, [[1, 0, 3, 2], [0, 2, 1, 3]],
-                                   gate)
+            return gossip_mix(x, [[1, 0, 3, 2], [0, 2, 1, 3]], w, gate)
     """)
     assert vs == []
 
@@ -320,13 +319,13 @@ def test_gl101_accepts_involution_tables_seam(tmp_path):
     # the sanctioned seam — including tuple unpacking and closure use,
     # the shape the production backend factory has
     vs = _lint(tmp_path, """
-        from matcha_tpu.parallel import involution_tables, perm_gossip_run
+        from matcha_tpu.parallel import gossip_mix_skip, involution_tables
 
         def make(schedule):
             pi, pr = involution_tables(schedule.perms)
 
             def mix(x, w):
-                return perm_gossip_run(x, w, pi, pr)
+                return gossip_mix_skip(x, pi, w)
 
             return mix
     """)
@@ -336,11 +335,11 @@ def test_gl101_accepts_involution_tables_seam(tmp_path):
 def test_gl101_fires_on_unvalidated_runtime_tables(tmp_path):
     vs = _lint(tmp_path, """
         import numpy as np
-        from matcha_tpu.parallel import perm_gossip_run
+        from matcha_tpu.parallel import gossip_mix
 
         def f(x, w, gate, schedule):
             pi = np.asarray(schedule.perms, np.int32)
-            return perm_gossip_run(x, w, pi, gate)
+            return gossip_mix(x, perms=pi, weights=w, alive=gate)
     """)
     assert _ids(vs) == ["GL101"]
     assert "involution_tables" in vs[0].message  # the fix is the seam

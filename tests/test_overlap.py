@@ -441,16 +441,15 @@ def test_run_elided_offset_and_traced_every():
 
 
 def test_elision_ledger_2x_reduction():
-    """Acceptance pin (ISSUE 19): for dense and perm at L=4, the compiled-
-    cost ledger's per-epoch gossip-attributed boundary bytes drop ≥2× vs
-    L=1 — the thinned steps' programs are *gone*, not multiplied by I.
-    The ratio is exactly T/ceil(T/L) for dense (every executed step pays
-    the same per-step program) and slightly under L for perm (the [M, N]
-    involution tables amortize over fewer executed steps)."""
+    """Acceptance pin (ISSUE 19): for dense and the fused chain at L=4,
+    the compiled-cost ledger's per-epoch gossip-attributed boundary bytes
+    drop ≥2× vs L=1 — the thinned steps' programs are *gone*, not
+    multiplied by I.  The ratio is exactly T/ceil(T/L) (every executed
+    step pays the same per-step program, or the same W-stack row)."""
     from matcha_tpu.obs.costs import elision_epoch_costs
 
     t_steps = 40
-    for backend in ("dense", "perm"):
+    for backend in ("dense", "fused"):
         c1 = elision_epoch_costs(SIZE, 1024, SCHED.decomposed,
                                  backend=backend, t_steps=t_steps,
                                  local_every=1)

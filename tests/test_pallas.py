@@ -13,10 +13,10 @@ import pytest
 from matcha_tpu import topology as tp
 from matcha_tpu.communicator import make_decen
 from matcha_tpu.parallel import (
+    STREAM_MAX_WORKERS,
     GossipKernelResourceError,
     build_mixing_stack,
     fused_gossip_run,
-    perm_gossip_run,
     stream_mix,
 )
 from matcha_tpu.schedule import fixed_schedule, matcha_schedule
@@ -133,59 +133,52 @@ def test_w_window_bitwise_matches_window1():
 
 
 # ------------------------------------------------- the TPU compiler's view
-# Both kernels only ever ran under the interpreter in tier-1, which accepts
+# The kernels only ever ran under the interpreter in tier-1, which accepts
 # programs Mosaic refuses (a vector row gather, an (1, 8) block).  These
 # lower — and, where libtpu offers a compile-only v5e topology, compile —
 # the real kernels at the train shapes, from the CPU host.
 
 RESNET20_DIM = 273_258  # not a multiple of 128: the last D-block is ragged
 CHAIN = 20
+#: elements of the 2.34 GB float32 state of PR 28's gossip-only chains
+#: (16 x 36,547,072; PERF.md section 6)
+CHAIN_STATE_ELEMENTS = 16 * 36_547_072
 
 
-def _kernel_program(kernel, n, wire, masked=False):
-    """``(fn, abstract args)`` of one kernel chain at ``[n, RESNET20_DIM]``,
-    f32 state, compiled (``interpret=False``)."""
+def _kernel_program(kernel, n, wire, dim):
+    """``(fn, abstract args)`` of one kernel program at ``[n, dim]``, f32
+    state, compiled (``interpret=False``): one in-place exchange
+    (``stream``) or a ``CHAIN``-step chain (``fused``)."""
     f32 = jnp.float32
-    x = jax.ShapeDtypeStruct((n, RESNET20_DIM), f32)
+    x = jax.ShapeDtypeStruct((n, dim), f32)
     if kernel == "stream":
-        # one exchange, in place, at the cells' own shapes (cell 1's D is no
-        # multiple of 128) and an odd N
-        x = jax.ShapeDtypeStruct((n, STREAM_DIMS[n]), f32)
         return (lambda x, w: stream_mix(x, w, wire_dtype=wire)), \
             (x, jax.ShapeDtypeStruct((n, n), f32))
-    if kernel == "fused":
-        stack = jax.ShapeDtypeStruct(
-            (CHAIN, n, n), f32 if wire == "f32" else jnp.bfloat16)
-        return (lambda x, stack: fused_gossip_run(x, stack)), (x, stack)
-    m = 8 if n == 16 else 27  # the zoo ER graph / the bench geometric graph
-    args = (x, jax.ShapeDtypeStruct((CHAIN, m), f32),
-            jax.ShapeDtypeStruct((m, n), jnp.int32),
-            jax.ShapeDtypeStruct((m, n), f32))
-    if masked:
-        return (lambda x, w, pi, pr, alive: perm_gossip_run(
-            x, w, pi, pr, alive=alive, wire_dtype=wire)), \
-            args + (jax.ShapeDtypeStruct((n,), f32),)
-    return (lambda x, w, pi, pr: perm_gossip_run(
-        x, w, pi, pr, wire_dtype=wire)), args
+    stack = jax.ShapeDtypeStruct(
+        (CHAIN, n, n), f32 if wire == "f32" else jnp.bfloat16)
+    return (lambda x, stack: fused_gossip_run(x, stack)), (x, stack)
 
 
-STREAM_DIMS = {16: 36_546_980, 2: 267_211_008, 3: 100_000}
+KERNEL_CASES = [("fused", n, w, RESNET20_DIM)
+                for n in (16, 256) for w in ("f32", "bf16")] \
+    + [  # the cells' own shapes (cell 1's D is no multiple of 128), an odd N
+       ("stream", 16, "f32", 36_546_980), ("stream", 16, "bf16", 36_546_980),
+       ("stream", 2, "f32", 267_211_008), ("stream", 3, "f32", 100_000)] \
+    + [  # both sides of every chunk width up to the crossover: 32 is the
+         # last N that ships streamed (1,024 columns a pass); 24 is no power
+         # of two, and neither is its pass of 1,280 columns
+       ("stream", n, w, CHAIN_STATE_ELEMENTS // n)
+       for n in (8, 24, STREAM_MAX_WORKERS) for w in ("f32", "bf16")] \
+    + [("stream", n, "bf16", CHAIN_STATE_ELEMENTS // n) for n in (2, 3)]
 
-KERNEL_CASES = [(k, n, w, masked)
-                for k in ("fused", "perm") for n in (16, 256)
-                for w in ("f32", "bf16")
-                for masked in ((False, True) if k == "perm" else (False,))] \
-    + [("stream", 16, "f32", False), ("stream", 16, "bf16", False),
-       ("stream", 2, "f32", False), ("stream", 3, "f32", False)]
 
-
-@pytest.mark.perm
-@pytest.mark.parametrize("kernel,n,wire,masked", KERNEL_CASES)
-def test_pallas_kernels_cross_lower_for_tpu(kernel, n, wire, masked):
-    """The Pallas TPU lowering accepts both kernels at 16 x 273,258 and
-    256 x 273,258, f32 and bf16 wire — no chip needed, and the next
-    construct it refuses fails here."""
-    fn, args = _kernel_program(kernel, n, wire, masked)
+@pytest.mark.parametrize("kernel,n,wire,dim", KERNEL_CASES)
+def test_pallas_kernels_cross_lower_for_tpu(kernel, n, wire, dim):
+    """The Pallas TPU lowering accepts the fused chain at 16 x 273,258 and
+    256 x 273,258 and the streamed exchange at the cells' shapes and at
+    every N up to its crossover, f32 and bf16 wire — no chip needed, and
+    the next construct it refuses fails here."""
+    fn, args = _kernel_program(kernel, n, wire, dim)
     lowered = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
     assert "tpu_custom_call" in lowered.as_text()
 
@@ -224,10 +217,9 @@ def _compile_all_for_v5e() -> int:
     return 0
 
 
-@pytest.mark.perm
 def test_pallas_kernels_compile_for_v5e():
-    """Mosaic itself (layout inference, VMEM and SMEM allocation) compiles
-    both kernels for the v5e at the train shapes, ahead of time.  In a
+    """Mosaic itself (layout inference, VMEM allocation) compiles the
+    kernels for the v5e at the train shapes, ahead of time.  In a
     child process: loading libtpu here would hang a TPU plane on every
     later profiler trace of this one."""
     import os
@@ -260,16 +252,6 @@ def test_kernel_blocks_that_cannot_fit_are_refused_by_name():
     with pytest.raises(GossipKernelResourceError, match="fused"):
         make_decen(sched, backend="fused", block_d=8192)
     make_decen(sched, backend="fused")  # the default block fits
-    # perm sizes its own block to fit, and refuses an explicit one that
-    # cannot
-    pi = jax.ShapeDtypeStruct((2, n), jnp.int32)
-    pr = jax.ShapeDtypeStruct((2, n), jnp.float32)
-    w = jax.ShapeDtypeStruct((CHAIN, 2), jnp.float32)
-    jax.eval_shape(lambda x, w, pi, pr: perm_gossip_run(x, w, pi, pr),
-                   x, w, pi, pr)
-    with pytest.raises(GossipKernelResourceError, match="perm"):
-        jax.eval_shape(lambda x, w, pi, pr: perm_gossip_run(
-            x, w, pi, pr, block_d=8192), x, w, pi, pr)
 
 
 if __name__ == "__main__":

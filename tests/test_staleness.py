@@ -41,6 +41,7 @@ import pytest
 
 from matcha_tpu import topology as tp
 from matcha_tpu.communicator import make_centralized, make_choco, make_decen
+from matcha_tpu.parallel import STREAM_MAX_WORKERS
 from matcha_tpu.schedule import matcha_schedule
 from matcha_tpu.schedule.solvers import (
     solve_activation_probabilities,
@@ -54,10 +55,21 @@ pytestmark = getattr(pytest.mark, "async")
 SIZE = tp.graph_size(0)
 SCHED = matcha_schedule(tp.select_graph(0), SIZE, iterations=12, budget=0.5,
                         seed=3)
-ALIVE = np.array([1, 1, 0, 1, 1, 1, 1, 1], np.float32)[:SIZE]
 
-BACKENDS = ["gather", "dense", "skip", "fused", "perm", "choco",
+# `dense` at SIZE = 8 rows is the streamed form of the one-chip exchange;
+# `dense-mxu` is the same backend on a ring wide enough that
+# gossip_mix_dense takes the MXU product (the form cell 2 trains on)
+MXU_SIZE = STREAM_MAX_WORKERS + 8
+MXU_SCHED = matcha_schedule(
+    tp.decompose(tp.ring_graph(MXU_SIZE), MXU_SIZE, seed=0), MXU_SIZE,
+    iterations=12, budget=0.5, seed=3)
+
+BACKENDS = ["gather", "dense", "skip", "fused", "dense-mxu", "choco",
             "centralized"]
+
+
+def _sched(backend):
+    return MXU_SCHED if backend == "dense-mxu" else SCHED
 
 
 def _make(backend, wire=None):
@@ -65,16 +77,24 @@ def _make(backend, wire=None):
         return make_choco(SCHED, ratio=0.5, consensus_lr=0.3, wire_dtype=wire)
     if backend == "centralized":
         return make_centralized(wire_dtype=wire)
-    return make_decen(SCHED, backend=backend, wire_dtype=wire)
+    return make_decen(_sched(backend), backend=backend.split("-")[0],
+                      wire_dtype=wire)
 
 
-def _x0(d=21, seed=0):
+def _alive(backend):
+    """Worker 2 dead, at the backend's own worker count."""
+    alive = np.ones(_sched(backend).num_workers, np.float32)
+    alive[2] = 0.0
+    return alive
+
+
+def _x0(d=21, seed=0, n=SIZE):
     return jnp.asarray(
-        np.random.default_rng(seed).normal(size=(SIZE, d)).astype(np.float32))
+        np.random.default_rng(seed).normal(size=(n, d)).astype(np.float32))
 
 
-def _thinned_flags(local_steps: int, reps: int = 1):
-    flags = np.tile(np.asarray(SCHED.flags, np.float32), (reps, 1))
+def _thinned_flags(local_steps: int, reps: int = 1, sched=SCHED):
+    flags = np.tile(np.asarray(sched.flags, np.float32), (reps, 1))
     flags[np.arange(len(flags)) % local_steps != 0] = 0.0
     return flags
 
@@ -88,12 +108,13 @@ def test_ring_k1_bitwise_matches_overlapped(backend, wire, masked):
     """staleness=1 IS the committed one-step pipeline, bit-for-bit, on
     every backend × alive mask × wire dtype — state AND carry."""
     comm = _make(backend, wire)
-    alive = ALIVE if masked else None
-    x0 = _x0()
+    sched = _sched(backend)
+    alive = _alive(backend) if masked else None
+    x0 = _x0(n=sched.num_workers)
     ov, co = jax.jit(
-        lambda x: comm.run_overlapped(x, SCHED.flags, alive=alive))(x0)
+        lambda x: comm.run_overlapped(x, sched.flags, alive=alive))(x0)
     pp, cp = jax.jit(
-        lambda x: comm.run_pipelined(x, SCHED.flags, alive=alive,
+        lambda x: comm.run_pipelined(x, sched.flags, alive=alive,
                                      staleness=1))(x0)
     np.testing.assert_array_equal(np.asarray(ov), np.asarray(pp))
     for a, b in zip(jax.tree_util.tree_leaves(co),
@@ -104,7 +125,7 @@ def test_ring_k1_bitwise_matches_overlapped(backend, wire, masked):
 @pytest.mark.parametrize("masked", [False, True], ids=["full", "alive-mask"])
 @pytest.mark.parametrize("k", [2, 3])
 @pytest.mark.parametrize("backend",
-                         ["gather", "dense", "skip", "fused", "perm",
+                         ["gather", "dense", "skip", "fused", "dense-mxu",
                           "choco"])
 def test_kdeep_drain_telescopes_when_thinned(backend, k, masked):
     """local_steps ≥ K: every delta is consumed before the next is issued,
@@ -112,9 +133,10 @@ def test_kdeep_drain_telescopes_when_thinned(backend, k, masked):
     stream (the constructive consume-before-reissue argument).  All
     flag-driven backends; centralized ignores flags by design."""
     comm = _make(backend)
-    alive = ALIVE if masked else None
-    flags = _thinned_flags(local_steps=k, reps=2)
-    x0 = _x0(d=13, seed=5)
+    sched = _sched(backend)
+    alive = _alive(backend) if masked else None
+    flags = _thinned_flags(local_steps=k, reps=2, sched=sched)
+    x0 = _x0(d=13, seed=5, n=sched.num_workers)
     eager, _ = jax.jit(lambda x: comm.run(x, flags, alive=alive))(x0)
     piped, _ = jax.jit(
         lambda x: comm.run_pipelined(x, flags, alive=alive, staleness=k))(x0)
@@ -524,61 +546,3 @@ def test_kdeep_with_fault_plan(tmp_path):
     dropped = sum(e["stale_dropped"] for e in events
                   if e["kind"] == "telemetry")
     assert dropped >= 2  # the healed worker's K in-flight deltas
-
-
-# ------------------------------------------------------------ backend source
-
-def test_load_measured_vs_ceiling(tmp_path):
-    from matcha_tpu.plan import load_measured_vs_ceiling
-
-    # bench_live capture shape: {"record": {...}} with a fused mfu
-    live = tmp_path / "bench_live.json"
-    live.write_text(json.dumps(
-        {"record": {"backend": "fused", "mfu": 0.91, "value": 5005.7}}))
-    ratio, prov = load_measured_vs_ceiling(str(live))
-    assert ratio == pytest.approx(0.91)
-    assert prov["backend"] == "fused"
-    # journal shape: bench events carrying roofline reports; newest wins
-    journal = tmp_path / "events.jsonl"
-    journal.write_text("\n".join([
-        json.dumps({"kind": "bench", "record": {"roofline": {
-            "backend": "dense", "measured_vs_ceiling": 0.5,
-            "measured_vs_ceiling_backend": "dense"}}}),
-        json.dumps({"kind": "bench", "record": {"roofline": {
-            "backend": "dense", "measured_vs_ceiling": 0.88,
-            "measured_vs_ceiling_backend": "dense"}}}),
-    ]))
-    ratio, prov = load_measured_vs_ceiling(str(journal))
-    assert ratio == pytest.approx(0.88)
-    # a perm-ratio-only artifact must refuse (wrong denominator)
-    bad = tmp_path / "perm.json"
-    bad.write_text(json.dumps({"record": {"backend": "perm", "mfu": 0.4}}))
-    with pytest.raises(ValueError, match="dense/fused"):
-        load_measured_vs_ceiling(str(bad))
-
-
-def test_backend_auto_promotes_from_source(tmp_path):
-    """The auto gate consumes --gossip-measured-source: a committed fused
-    MFU past the gate promotes perm, with the provenance journaled in the
-    backend decision event."""
-    from matcha_tpu.train import TrainConfig, train
-
-    src = tmp_path / "bench_live.json"
-    src.write_text(json.dumps(
-        {"record": {"backend": "fused", "mfu": 0.91}}))
-    cfg = TrainConfig(
-        name="src", model="mlp", dataset="synthetic",
-        dataset_kwargs={"num_train": 256, "num_test": 64},
-        num_workers=8, graphid=5, matcha=False, epochs=1, lr=0.05,
-        batch_size=16, eval_every=0, save=True, savePath=str(tmp_path),
-        measure_comm_split=False, gossip_backend="auto",
-        gossip_measured_source=str(src),
-        devices=1)  # single-chip: the gate (not shard_map) resolves auto
-    r = train(cfg)
-    assert np.isfinite(r.history[-1]["loss"])
-    events = [json.loads(line) for line in
-              open(os.path.join(tmp_path, "src_mlp", "events.jsonl"))]
-    dec = next(e for e in events if e["kind"] == "backend")
-    assert dec["chosen"] == "perm"
-    assert dec["measured_vs_ceiling"] == pytest.approx(0.91)
-    assert dec["measured_source"]["path"] == str(src)

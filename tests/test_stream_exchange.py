@@ -5,9 +5,16 @@
   the MXU product read, masked or not, at float32 and bf16 wire, through
   ``step``, ``begin_mix``/``apply_mix`` and ``Communicator.run``; one N above
   the crossover takes the product and reads the same.
-* **The decision record** — the three cells' job files resolve to the form
-  ISSUE 28 names (2 and 16 streamed, 128 the product), and the ``backend``
-  event that ``train()`` journals carries it.
+* **What the exchange keeps, on both sides of the crossover** (N = 16
+  streamed, N = ``ABOVE`` the product; PR 29) — the worker mean, a doubly
+  stochastic realized ``W_t`` over survivors under any ``alive`` mask, the
+  state bitwise through an empty chain or an all-zero flag row, and one
+  compiled step for every ``alive`` value and flag row.
+* **The decision record** — ``resolve_gossip_backend`` answers by itself
+  (explicit: as asked; ``auto``: ``shard_map`` on several devices, ``dense``
+  on one chip), the three cells' job files resolve to the form ISSUE 28
+  names (2 and 16 streamed, 128 the product), and the ``backend`` event
+  that ``train()`` journals carries it.
 * **Lowering** — the streamed exchange lowers with no ``dot_general`` over
   the state; above the crossover ``dense`` lowers to the text of the product
   it was before.  (That the kernel compiles for a described v5e at the
@@ -26,6 +33,7 @@ import pytest
 from jax import lax
 
 from matcha_tpu import topology as tp
+from matcha_tpu.analysis import check_single_trace, retrace_guard
 from matcha_tpu.communicator import make_decen
 from matcha_tpu.communicator.decen import resolve_gossip_backend
 from matcha_tpu.parallel import (STREAM_MAX_WORKERS, dense_exchange_form,
@@ -147,6 +155,130 @@ def test_streamed_blocks_ragged_or_not_never_mix_columns(n):
                    interpret=True)
 
 
+# ------------------------------------- what the exchange keeps, at both forms
+
+BOTH_FORMS = [16, ABOVE]
+
+
+@pytest.mark.parametrize("wire", [None, "bf16"], ids=["f32", "bf16"])
+@pytest.mark.parametrize("n", BOTH_FORMS)
+def test_realized_mixing_is_doubly_stochastic_over_survivors(n, wire):
+    """Under three drawn ``alive`` masks every step's realized ``W_t`` (the
+    exchange of the identity) has unit row and column sums and is
+    symmetric, a dead worker's row and column are a self-loop, and the
+    worker mean of a random state is kept: mass moves between survivors,
+    never appears or disappears."""
+    sched = _schedule(n)
+    comm = make_decen(sched, backend="dense", wire_dtype=wire)
+    step = jax.jit(lambda x, f, a: comm.step(x, (), f, a)[0])
+    rng = np.random.default_rng(7)
+    eye = jnp.eye(n, dtype=jnp.float32)
+    # a bf16 wire rounds W_t and the state once each: 2^-9 an entry
+    tol = 2e-6 if wire is None else n * 2.0 ** -9
+    for trial in range(3):
+        alive = (rng.random(n) > rng.uniform(0.1, 0.6)).astype(np.float32)
+        dead = np.flatnonzero(alive == 0)
+        x = jnp.asarray(rng.normal(size=(n, 40)), jnp.float32)
+        for t in range(STEPS):
+            flags_t = jnp.asarray(sched.flags[t], jnp.float32)
+            W = np.asarray(step(eye, flags_t, jnp.asarray(alive)))
+            np.testing.assert_allclose(W.sum(0), 1.0, rtol=0, atol=tol)
+            np.testing.assert_allclose(W.sum(1), 1.0, rtol=0, atol=tol)
+            np.testing.assert_allclose(W, W.T, rtol=0, atol=1e-7)
+            np.testing.assert_array_equal(W[dead], np.eye(n)[dead])
+            out = np.asarray(step(x, flags_t, jnp.asarray(alive)))
+            np.testing.assert_allclose(
+                out.mean(0), np.asarray(x).mean(0), rtol=0,
+                atol=tol * float(jnp.abs(x).max()))
+            if wire is None:  # a self-loop leaves the row alone, bitwise
+                np.testing.assert_array_equal(out[dead], np.asarray(x)[dead])
+
+
+@pytest.mark.parametrize("path", ["step", "run"])
+@pytest.mark.parametrize("n", BOTH_FORMS)
+def test_empty_chain_and_zero_flags_return_the_state_bitwise(n, path):
+    """No flag fired: ``W_t`` is the identity and the float32 state comes
+    back bitwise (float32 wire: nothing is rounded on the way) — through
+    ``step`` on an all-zero row, through ``run`` on a ``T = 0`` chain and on
+    a chain of all-zero rows, masked or not."""
+    sched = _schedule(n)
+    comm = make_decen(sched, backend="dense")
+    x = jnp.asarray(np.random.default_rng(n).normal(size=(n, 300)),
+                    jnp.float32)
+    m = sched.num_matchings
+    if path == "step":
+        out = jax.jit(lambda x, f: comm.step(x, (), f)[0])(
+            x, jnp.zeros((m,), jnp.float32))
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(x))
+        return
+    for steps in (0, 3):
+        flags = np.zeros((steps, m), np.float32)
+        out, _ = jax.jit(lambda x: comm.run(x, flags))(x)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(x))
+        out, _ = jax.jit(lambda x, a: comm.run(x, flags, alive=a))(
+            x, jnp.asarray(_alive(n)))
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(x))
+
+
+@pytest.mark.parametrize("n", BOTH_FORMS)
+def test_one_compiled_step_serves_every_alive_mask_and_flag_row(n):
+    """``alive`` and the flag row are traced inputs of the exchange:
+    membership churn and the schedule's next row never compile a second
+    program."""
+    sched = _schedule(n)
+    comm = make_decen(sched, backend="dense")
+    guarded, counter = retrace_guard(
+        jax.jit(lambda x, f, a: comm.step(x, (), f, a)[0]))
+    x = jnp.asarray(np.random.default_rng(1).normal(size=(n, 64)),
+                    jnp.float32)
+    other = np.ones(n, np.float32)
+    other[[0, n - 1]] = 0.0
+    every = np.ones(sched.num_matchings, np.float32)
+    first = np.zeros_like(every)
+    first[0] = 1.0
+    outs = [np.asarray(guarded(x, jnp.asarray(flags_t), jnp.asarray(alive)))
+            for flags_t, alive in ((every, _alive(n)), (every, other),
+                                   (first, other))]
+    check_single_trace(counter, label=f"dense_step_n{n}")
+    assert counter.count == 1
+    # each call mixed with its own mask and row, not the first call's
+    assert not np.array_equal(outs[0], outs[1])
+    assert not np.array_equal(outs[1], outs[2])
+
+
+# --------------------------------------------------------- the decision record
+
+class _Mesh:
+    def __init__(self, size):
+        self.size = size
+
+
+@pytest.mark.parametrize("requested", ["auto", "fused"])
+@pytest.mark.parametrize("devices", [None, 1, 4],
+                         ids=["no-mesh", "one-device", "four-devices"])
+def test_resolve_gossip_backend_table(devices, requested):
+    """Explicit: as asked.  ``auto``: ``shard_map`` on several devices,
+    ``dense`` on one chip.  The record is the journal's pinned triple and,
+    where the per-step mix is the dense exchange, the form it compiles to;
+    nothing else."""
+    sched = _schedule(16)
+    single = devices in (None, 1)
+    record = resolve_gossip_backend(
+        sched, None if devices is None else _Mesh(devices),
+        requested=requested)
+    chosen = requested if requested != "auto" else \
+        ("dense" if single else "shard_map")
+    assert (record["requested"], record["chosen"]) == (requested, chosen)
+    assert record["reason"]
+    if chosen == "shard_map":
+        assert set(record) == {"requested", "chosen", "reason"}
+    else:
+        assert set(record) == {"requested", "chosen", "reason", "exchange"}
+        assert record["exchange"] == dense_exchange_form(16, single)
+        assert record["exchange"]["form"] == \
+            ("streamed" if single else "mxu")
+
+
 CELLS = {"wrn28-10-c100.w16-matcha": (16, "streamed"),
          "mellum2-12b-a2.5b.ep8-s4k.w2-matcha": (2, "streamed"),
          "resnet20-c10.w128-matcha": (128, "mxu")}
@@ -162,8 +294,7 @@ def test_job_files_resolve_to_the_form_the_issue_names(cell):
     class Sched:  # what the resolver reads of a schedule
         num_workers, num_matchings, probs, name = n, 3, [0.5] * 3, None
 
-    record = resolve_gossip_backend(Sched, None, requested="auto",
-                                    wire_dtype=fields["wire_dtype"])
+    record = resolve_gossip_backend(Sched, None, requested="auto")
     assert record["chosen"] == "dense"
     assert record["exchange"] == {"form": form, "n": n, "single_chip": True,
                                   "crossover": STREAM_MAX_WORKERS}
@@ -195,6 +326,27 @@ def test_backend_event_names_the_form_that_compiled(tmp_path, backend, form):
 
     assert resolve_gossip_backend(sched, Mesh, requested="dense")[
         "exchange"]["form"] == "mxu"
+
+
+def test_train_journal_carries_backend_decision(tmp_path):
+    """An auto run journals its backend choice as a `backend` event that
+    the journal's schema validates, read back from the file."""
+    from matcha_tpu.obs.journal import read_journal, validate_event
+
+    train(TrainConfig(
+        name="auto", model="mlp", dataset="synthetic",
+        dataset_kwargs={"num_train": 64, "num_test": 32},
+        num_workers=4, devices=1, graphid=None, topology="ring", batch_size=8,
+        epochs=1, lr=0.05, warmup=False, eval_every=1,
+        measure_comm_split=False, save=True, savePath=str(tmp_path),
+        health=False))
+    events = read_journal(str(tmp_path / "auto_mlp" / "events.jsonl"))
+    backend_events = [e for e in events if e["kind"] == "backend"]
+    assert len(backend_events) == 1
+    e = backend_events[0]
+    assert validate_event(e) == []
+    assert e["requested"] == "auto" and e["chosen"] == "dense"
+    assert "reason" in e and e["exchange"]["form"] == "streamed"
 
 
 def _step_text(comm, sched, masked=False):

@@ -108,29 +108,13 @@ def _parse(argv=None):
     p.add_argument("--centralized", action="store_true", help="AllReduce baseline")
     p.add_argument("--randomSeed", type=int, default=9001, dest="seed")
     p.add_argument("--backend", default="auto",
-                   help="gossip backend: fused|dense|perm|gather|skip|"
-                        "shard_map|auto (perm = permutation-form Pallas "
-                        "kernel reading only the [T, M] flags; "
-                        "skip = per-matching lax.cond; "
+                   help="gossip backend: fused|dense|gather|skip|"
+                        "shard_map|auto (skip = per-matching lax.cond; "
                         "inactive matchings cost nothing, so budget < 1 "
                         "buys real time; gather is a small-N debugging "
                         "path — ~60x slower than dense/fused at N>=64 and "
-                        "warns there; auto journals its perm-vs-dense "
-                        "decision as a `backend` event)")
-    p.add_argument("--block-d", type=int, default=None, dest="block_d",
-                   help="fused/perm-backend Pallas D-block size "
-                        "(default: kernel's)")
-    p.add_argument("--w-window", type=int, default=1, dest="w_window",
-                   help="fused-backend steps per D-block VMEM visit "
-                        "(exact per-step arithmetic, amortizes grid overhead)")
-    p.add_argument("--gossip-measured-ratio", type=float, default=None,
-                   dest="gossip_measured_vs_ceiling",
-                   help="measured-vs-ceiling ratio from `obs_tpu.py "
-                        "roofline` fed to the --backend auto gate: >= 0.85 "
-                        "means the dense form is at its roofline and auto "
-                        "promotes the perm flag-stream kernel (decision "
-                        "journaled as a `backend` event); default None — "
-                        "auto stays on the committed dense path")
+                        "warns there; auto = shard_map on several devices, "
+                        "dense on one chip, journaled as a `backend` event)")
     p.add_argument("--overlap", default="off", choices=["off", "1step"],
                    help="software-pipelined gossip: '1step' issues each "
                         "step's exchange (begin_mix) and consumes it at the "
@@ -155,15 +139,6 @@ def _parse(argv=None):
                         "contracts at rho^(1/L) per step; composes with "
                         "--staleness (delays count in exchange units "
                         "ceil(K/L))")
-    p.add_argument("--gossip-measured-source", default=None,
-                   dest="gossip_measured_source",
-                   help="artifact to extract the auto gate's measured-vs-"
-                        "ceiling ratio from (instead of typing "
-                        "--gossip-measured-ratio): a run journal with "
-                        "roofline records (obs_tpu.py roofline --journal), "
-                        "a wrapped or raw bench record, or a raw roofline-"
-                        "report JSON; provenance journaled in the "
-                        "`backend` event")
     p.add_argument("--wire-dtype", default="f32", choices=["f32", "bf16"],
                    dest="wire_dtype",
                    help="dtype of the exchanged tensors at the gossip "
@@ -309,10 +284,7 @@ def _parse(argv=None):
         compress_ratio=args.ratio, compressor=args.compressor,
         consensus_lr=args.consensus_lr,
         compress_warmup_epochs=args.compress_warmup_epochs,
-        gossip_backend=args.backend, gossip_block_d=args.block_d,
-        gossip_w_window=args.w_window,
-        gossip_measured_vs_ceiling=args.gossip_measured_vs_ceiling,
-        gossip_measured_source=args.gossip_measured_source,
+        gossip_backend=args.backend,
         overlap=args.overlap, staleness=args.staleness,
         local_steps=args.local_steps,
         wire_dtype=args.wire_dtype, save=args.save, savePath=args.savePath,
