@@ -48,7 +48,8 @@ the v2→v3 bump of ISSUE 10 added ``heartbeat`` and ``anomaly``; the
 v3→v4 bump of ISSUE 11 added ``attribution``; the v5→v6 bump of
 ISSUE 17 added ``control`` and ``promotion``; the v6→v7 bump of
 ISSUE 18 added ``recovery``; the v7→v8 bump of ISSUE 24 added ``spans``,
-one record an epoch period, the rejoin's bootstrap among them):
+one record an epoch period, the rejoin's bootstrap among them; the v8→v9
+bump of ISSUE 30 added ``fwd_bwd``, one record a run):
 
     JAX_PLATFORMS=cpu python benchmarks/make_reference_journal.py
 """

@@ -13,6 +13,16 @@ TPU notes: convolutions carry bias like the reference (bias=True); BatchNorm
 statistics are **per virtual worker** — the module is vmapped over the worker
 axis by the trainer, so no cross-worker stat syncing can occur (SURVEY.md §7
 "BatchNorm under decentralized DP").
+
+The packed form (``ResNet.packed_apply``, PERF.md section 6, PR 30): a
+16-channel activation fills an eighth of the TPU's 128 lanes, and under the
+trainer's ``vmap`` the compiler lays such a model out with the batch or a
+half-empty channel dimension in the lanes, every activation stored two to
+four times padded.  P workers side by side in the channel dimension are one
+ResNet P times as wide whose convolution kernels are block diagonal: the
+same modules, the same parameter and ``batch_stats`` trees (leaves
+``[P, ...]``), lanes full.  Batch norm is per channel, so every worker
+keeps its own statistics; the zero blocks multiply zeros.
 """
 
 from __future__ import annotations
@@ -20,9 +30,13 @@ from __future__ import annotations
 from typing import Any, Callable, Sequence, Tuple
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 __all__ = ["ResNet", "ResNetImageNet", "resnet_config", "resnet_imagenet_config"]
+
+#: planes of the three stages of the CIFAR layout; the stem has the first
+STAGE_PLANES = (16, 32, 64)
 
 
 def resnet_config(depth: int) -> Tuple[str, Sequence[int]]:
@@ -111,21 +125,97 @@ class ResNet(nn.Module):
     dtype: Any = jnp.float32
     remat: bool = False
 
+    #: the narrowest width (the stem and stage 0): workers side by side
+    #: fill the lanes when P x this width reaches 128
+    pack_width = STAGE_PLANES[0]
+
     @nn.compact
     def __call__(self, x, train: bool = True):
-        kind, blocks = resnet_config(self.depth)
-        block: Callable = BasicBlock if kind == "basic" else Bottleneck
-        if self.remat:
-            block = _remat_block(block)
-        x = nn.Conv(16, (3, 3), padding=1, use_bias=True, dtype=self.dtype, name="stem")(x)
-        x = nn.relu(nn.BatchNorm(use_running_average=not train, momentum=0.9,
-                                 dtype=self.dtype, name="stem_bn")(x))
-        for stage, (planes, stride) in enumerate(zip((16, 32, 64), (1, 2, 2))):
-            for b in range(blocks[stage]):
-                x = block(planes=planes, stride=stride if b == 0 else 1,
-                          dtype=self.dtype, name=f"stage{stage}_block{b}")(x, train)
-        x = jnp.mean(x, axis=(1, 2))  # global average over the 8x8 map
+        x = _cifar_trunk(x, train, self.depth, self.dtype, self.remat)
         return nn.Dense(self.num_classes, dtype=jnp.float32, name="head")(x)
+
+    def packed_apply(self, params, batch_stats, x):
+        """The training-mode forward of P workers as one network P times as
+        wide: ``params`` and ``batch_stats`` are this model's own trees with
+        leaves ``[P, ...]``, ``x`` is ``[P, B, H, W, C]``.  Returns
+        ``(logits [P, B, classes], new batch_stats)`` with leaves ``[P, ...]``:
+        what ``vmap`` of ``apply(..., train=True, mutable=["batch_stats"])``
+        over the P workers returns, to float32 summation order.
+
+        Workers are not isolated from each other here: a zero block times a
+        non-finite activation is NaN, so one worker's overflow reaches the
+        others of its pack.  Callers that quarantine workers keep ``vmap``.
+        """
+        workers, batch, height, width, channels = x.shape
+        net = _PackedTrunk(depth=self.depth, dtype=self.dtype, workers=workers,
+                           parent=None)
+        trunk = lambda tree: _pack_variables(
+            {k: v for k, v in tree.items() if k != "head"})
+        features, mutated = net.apply(
+            {"params": trunk(params), "batch_stats": trunk(batch_stats)},
+            jnp.moveaxis(x, 0, 3).reshape(batch, height, width,
+                                          workers * channels),
+            mutable=["batch_stats"])
+        # each worker's own head over its own channels, as nn.Dense in float32
+        features = features.reshape(batch, workers, -1).astype(jnp.float32)
+        head = params["head"]
+        logits = jnp.einsum("bpc,pck->pbk", features, head["kernel"])
+        return (logits + head["bias"][:, None],
+                jax.tree.map(lambda a: a.reshape(workers, -1),
+                             mutated["batch_stats"]))
+
+
+def _cifar_trunk(x, train: bool, depth: int, dtype, remat: bool, workers: int = 1):
+    """Stem, three stages and the global average pool of the CIFAR layout,
+    as submodules of the module whose ``__call__`` is running; ``workers``
+    multiplies every width (the packed form)."""
+    kind, blocks = resnet_config(depth)
+    block: Callable = BasicBlock if kind == "basic" else Bottleneck
+    if remat:
+        block = _remat_block(block)
+    x = nn.Conv(STAGE_PLANES[0] * workers, (3, 3), padding=1, use_bias=True,
+                dtype=dtype, name="stem")(x)
+    x = nn.relu(nn.BatchNorm(use_running_average=not train, momentum=0.9,
+                             dtype=dtype, name="stem_bn")(x))
+    for stage, (planes, stride) in enumerate(zip(STAGE_PLANES, (1, 2, 2))):
+        for b in range(blocks[stage]):
+            x = block(planes=planes * workers, stride=stride if b == 0 else 1,
+                      dtype=dtype, name=f"stage{stage}_block{b}")(x, train)
+    return jnp.mean(x, axis=(1, 2))  # global average over the 8x8 map
+
+
+class _PackedTrunk(nn.Module):
+    """The trunks of ``workers`` ResNets as one: ``[B, H, W, workers x C]``
+    (worker major in the channels) to the pooled features ``[B, workers x
+    C']``, in training mode.  Applied to ``_pack_variables`` of the
+    workers' own trees, never initialised."""
+
+    depth: int
+    dtype: Any
+    workers: int
+
+    @nn.compact
+    def __call__(self, x):
+        return _cifar_trunk(x, True, self.depth, self.dtype, False, self.workers)
+
+
+def _pack_variables(tree):
+    """The trunk's parameters or statistics, leaves ``[P, ...]``, as
+    ``_PackedTrunk``'s: a convolution kernel ``[P, kh, kw, Cin, Cout]``
+    becomes the block-diagonal ``[kh, kw, P Cin, P Cout]`` (a ``where``, not
+    a product with the identity: exact forward, and the gradient of a
+    worker's block is a selection), per-channel vectors are laid end to
+    end."""
+    def leaf(a):
+        if a.ndim == 2:
+            return a.reshape(-1)
+        workers, kh, kw, cin, cout = a.shape
+        own = jnp.eye(workers, dtype=bool)[None, None, :, None, :, None]
+        blocks = jnp.moveaxis(a, 0, 2)[:, :, :, :, None, :]  # [kh, kw, P, Cin, 1, Cout]
+        return jnp.where(own, blocks, jnp.zeros((), a.dtype)).reshape(
+            kh, kw, workers * cin, workers * cout)
+
+    return jax.tree.map(leaf, tree)
 
 
 def resnet_imagenet_config(depth: int) -> Tuple[str, Sequence[int]]:

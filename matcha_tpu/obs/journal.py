@@ -35,7 +35,7 @@ from typing import Dict, Iterable, List, Optional, Sequence
 
 __all__ = ["SCHEMA_VERSION", "ACCEPTED_VERSIONS", "EVENT_KINDS",
            "FAULT_KINDS", "V2_KINDS", "V3_KINDS", "V4_KINDS", "V5_KINDS",
-           "V6_KINDS", "V7_KINDS", "V8_KINDS", "KIND_MIN_VERSION",
+           "V6_KINDS", "V7_KINDS", "V8_KINDS", "V9_KINDS", "KIND_MIN_VERSION",
            "REQUIRED_FIELDS",
            "make_event", "validate_event", "Journal", "read_journal",
            "salvage_journal", "read_journal_tail", "count_journal_lines",
@@ -59,9 +59,10 @@ __all__ = ["SCHEMA_VERSION", "ACCEPTED_VERSIONS", "EVENT_KINDS",
 #: promotion pipeline decision (promote / rollback / retain with the
 #: gating held-out metric).  Every pre-bump event validates verbatim under
 #: the v6 reader — old journals stay first-class sources.  v8 (ISSUE 24)
-#: adds ``spans``: the host phases of one epoch period.
-SCHEMA_VERSION = 8
-ACCEPTED_VERSIONS = frozenset({1, 2, 3, 4, 5, 6, 7, 8})
+#: adds ``spans``: the host phases of one epoch period.  v9 (ISSUE 30) adds
+#: ``fwd_bwd``: how the step's forward/backward runs, and why.
+SCHEMA_VERSION = 9
+ACCEPTED_VERSIONS = frozenset({1, 2, 3, 4, 5, 6, 7, 8, 9})
 
 #: Every kind a journal may contain.  The five fault kinds keep their
 #: historical ``faults.json`` names so the view stays a pure filter.
@@ -110,18 +111,23 @@ V7_KINDS = frozenset({"recovery"})
 #: later in the same period may already have written it, and a rolled-back
 #: attempt has no ``epoch`` event at all.
 V8_KINDS = frozenset({"spans"})
+#: Kinds introduced by schema v9 (ISSUE 30) — ``fwd_bwd`` carries one
+#: ``train.state.fwd_bwd_plan`` record a run: whether the forward/backward
+#: packs workers side by side into the lanes, how many a pack and packs a
+#: slab, or the condition that kept ``vmap`` over workers (``reason``).
+V9_KINDS = frozenset({"fwd_bwd"})
 #: Minimum envelope version per kind — the generalized "a vK kind claiming
 #: an earlier v is a lying envelope" rule.
 KIND_MIN_VERSION: Dict[str, int] = {
     **{k: 2 for k in V2_KINDS}, **{k: 3 for k in V3_KINDS},
     **{k: 4 for k in V4_KINDS}, **{k: 5 for k in V5_KINDS},
     **{k: 6 for k in V6_KINDS}, **{k: 7 for k in V7_KINDS},
-    **{k: 8 for k in V8_KINDS}}
+    **{k: 8 for k in V8_KINDS}, **{k: 9 for k in V9_KINDS}}
 EVENT_KINDS = frozenset({
     "run_start", "resume", "epoch", "telemetry", "drift", "checkpoint",
     "retrace", "bench",
 }) | FAULT_KINDS | V2_KINDS | V3_KINDS | V4_KINDS | V5_KINDS | V6_KINDS \
-    | V7_KINDS | V8_KINDS
+    | V7_KINDS | V8_KINDS | V9_KINDS
 
 #: Kind-specific payload keys an event must carry to validate.  Kinds not
 #: listed need only the envelope (v / kind / t).
@@ -199,6 +205,9 @@ REQUIRED_FIELDS: Dict[str, frozenset] = {
     # ``bytes``, ``dispatch`` ``steps``, a chunked epoch's ``segment``)
     "spans": frozenset({"epoch", "attempt", "period", "t0", "t1",
                         "samples", "spans"}),
+    # v9 (ISSUE 30): one per run, beside ``backend`` — a run on the
+    # per-worker path also carries ``reason``
+    "fwd_bwd": frozenset({"packed", "workers_per_pack", "packs_per_slab"}),
 }
 
 

@@ -56,7 +56,7 @@ from .config import TrainConfig
 from .lr import make_lr_schedule
 from .recorder import Recorder
 from .state import (COUNTER_PREFIX, TrainState, init_train_state, make_eval_fn,
-                    make_optimizer, make_train_step)
+                    fwd_bwd_plan, make_optimizer, make_train_step)
 
 __all__ = ["build_schedule", "build_dataset", "train", "TrainResult",
            "TrainingDiverged"]
@@ -275,6 +275,7 @@ def train(config: TrainConfig, resume_dir: Optional[str] = None,
             mesh = None  # single chip: no worker axis to shard
         else:
             fold_dims(config.num_workers, mesh)
+    worker_shards = 1 if mesh is None else mesh.size
 
     # gossip-backend resolution: resolve `auto` ONCE, here, and hand the
     # concrete backend to every _make_comm rebuild — the decision record is
@@ -453,6 +454,7 @@ def train(config: TrainConfig, resume_dir: Optional[str] = None,
             elastic=elastic_ctl is not None,
             control=control_knobs is not None,
             local_steps=config.local_steps,
+            worker_shards=worker_shards,
         )
 
     step_fn = None  # populated by _build_programs() below
@@ -750,6 +752,12 @@ def train(config: TrainConfig, resume_dir: Optional[str] = None,
         # backend compiled and why — journaled unconditionally so a
         # questionable `auto` choice is always auditable post-hoc
         recorder.log_event("backend", **backend_decision)
+    # how the forward/backward runs (packs of workers side by side in the
+    # lanes, or vmap over workers) and, where it is the latter, why
+    recorder.log_event("fwd_bwd", **fwd_bwd_plan(
+        model, config.num_workers, config.grad_chunk,
+        faults=faults is not None, elastic=elastic_ctl is not None,
+        worker_shards=worker_shards))
     rng = jax.random.PRNGKey(config.seed)
     history: List[Dict] = []
 

@@ -35,11 +35,15 @@ from ..parallel import allreduce_mean, worker_deviation_rows, worker_disagreemen
 from ..utils import cross_entropy_loss, device_span, top_k_accuracy
 
 __all__ = ["TrainState", "init_train_state", "make_train_step", "make_eval_fn", "make_optimizer",
-           "COUNTER_PREFIX"]
+           "fwd_bwd_plan", "COUNTER_PREFIX"]
 
 #: a step metric under this prefix is a count, not a mean: the loop sums it
 #: over the epoch's steps and journals it in the period's ``counters``
 COUNTER_PREFIX = "count/"
+
+#: the minor dimension of a TPU vector register and of an HBM tile: channels
+#: narrower than this leave lanes, and the bytes behind them, empty
+LANES = 128
 
 
 class TrainState(struct.PyTreeNode):
@@ -178,6 +182,47 @@ def init_train_state(
     return state, flattener
 
 
+def fwd_bwd_plan(model, num_workers: int, grad_chunk: Optional[int] = None, *,
+                 dropout: bool = False, faults: bool = False,
+                 elastic: bool = False, worker_shards: int = 1) -> dict:
+    """How ``make_train_step`` runs the forward/backward, and why: the
+    payload of the journal's ``fwd_bwd`` event.
+
+    Packed (``models/resnet.py:ResNet.packed_apply``): ``workers_per_pack``
+    workers share one network that many times as wide, so a narrow model's
+    channels fill the lanes; the ``packs_per_slab`` packs of a slab run one
+    after another.  P is the largest count with ``P x model.pack_width <=
+    LANES`` that divides the slab (``grad_chunk``, or all N).  Taken only
+    where nothing else is asked of the per-worker path: packing gives up
+    the isolation of workers (a zero block times a non-finite activation is
+    NaN, so one worker's overflow reaches its pack), which quarantine and
+    heal rely on; a model that already fills the lanes would pay P times
+    the FLOPs for nothing; and a loop over packs cannot run over a worker
+    axis that a mesh shards (``worker_shards`` devices).  ``reason`` names
+    the first condition that keeps ``vmap`` over workers.
+    """
+    slab = grad_chunk or num_workers
+    plan = {"packed": False, "workers_per_pack": 1, "packs_per_slab": slab}
+    width = getattr(model, "pack_width", None)
+    if width is None:
+        return {**plan, "reason": f"{type(model).__name__} has no packed form"}
+    for blocked, reason in (
+            (getattr(model, "remat", False), "remat"),
+            (dropout, "dropout"),
+            (faults, "a fault plan needs workers isolated from each other"),
+            (elastic, "elastic membership needs workers isolated from each other"),
+            (worker_shards > 1,
+             f"the worker axis is sharded over {worker_shards} devices")):
+        if blocked:
+            return {**plan, "reason": reason}
+    workers = next((p for p in range(LANES // width, 1, -1) if slab % p == 0), 1)
+    if workers < 2:
+        return {**plan, "reason": f"no P >= 2 with P x {width} <= {LANES} "
+                                  f"divides the slab of {slab}"}
+    return {"packed": True, "workers_per_pack": workers,
+            "packs_per_slab": slab // workers}
+
+
 def make_train_step(
     model,
     optimizer: optax.GradientTransformation,
@@ -195,6 +240,7 @@ def make_train_step(
     elastic: bool = False,
     control: bool = False,
     local_steps: int = 1,
+    worker_shards: int = 1,
 ):
     """Build ``step(state, xb, yb[, rng]) -> (state, metrics)``.
 
@@ -305,6 +351,9 @@ def make_train_step(
     unconditional (a thinned step parks a zero delta, so the consume is a
     no-op add exactly as the zero-weight path produced), only the *issue*
     — the expensive exchange — is elided.
+
+    ``worker_shards``: devices the worker axis is sharded over (the mesh's
+    size; 1 on one chip).  Read by ``fwd_bwd_plan`` alone.
     """
     flags_arr = jnp.asarray(np.asarray(flags), jnp.float32)  # [T, M]
     n_workers = flattener.num_workers
@@ -376,6 +425,37 @@ def make_train_step(
 
     grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 
+    def pack_grad_fn(params, batch_stats, x, y):
+        # one pack: leaves [P, ...].  A worker's loss reads its own
+        # parameters alone, so the gradient of the sum is every worker's
+        # own gradient
+        def summed(params):
+            logits, stats = model.packed_apply(params, batch_stats, x)
+            loss = cross_entropy_loss(logits, y)  # [P]
+            return jnp.sum(loss), (loss, stats, logits)
+
+        (_, (loss, stats, logits)), grads = jax.value_and_grad(
+            summed, has_aux=True)(params)
+        return (loss, (stats, logits)), grads
+
+    plan = fwd_bwd_plan(model, n_workers, grad_chunk, dropout=dropout,
+                        faults=faults is not None, elastic=elastic,
+                        worker_shards=worker_shards)
+
+    def packed_slab_grads(params, batch_stats, xb, yb, rngs):
+        del rngs  # no dropout on this path
+        per_pack, packs = plan["workers_per_pack"], plan["packs_per_slab"]
+        # one pack after another: under ``vmap`` the packs' convolutions
+        # become one grouped convolution, and cell 2's step took 105 ms
+        # against 86 (PERF.md section 6, PR 30)
+        out = jax.lax.map(lambda pack: pack_grad_fn(*pack), jax.tree.map(
+            lambda a: a.reshape((packs, per_pack) + a.shape[1:]),
+            (params, batch_stats, xb, yb)))
+        return jax.tree.map(
+            lambda a: a.reshape((packs * per_pack,) + a.shape[2:]), out)
+
+    slab_grads = packed_slab_grads if plan["packed"] else jax.vmap(grad_fn)
+
     def all_grads(params, batch_stats, xb, yb, rngs):
         if own_loss:
             # one worker after another, and no vmap: under one, a ``cond``
@@ -383,12 +463,12 @@ def make_train_step(
             return jax.lax.map(lambda worker: grad_fn(*worker),
                                (params, batch_stats, xb, yb, rngs))
         if grad_chunk is None or grad_chunk == n_workers:
-            return jax.vmap(grad_fn)(params, batch_stats, xb, yb, rngs)
+            return slab_grads(params, batch_stats, xb, yb, rngs)
         slabs = n_workers // grad_chunk
         split = lambda tree: jax.tree.map(
             lambda a: a.reshape((slabs, grad_chunk) + a.shape[1:]), tree)
         out = jax.lax.map(
-            lambda slab: jax.vmap(grad_fn)(*slab),
+            lambda slab: slab_grads(*slab),
             tuple(split(t) for t in (params, batch_stats, xb, yb, rngs)),
         )
         return jax.tree.map(

@@ -416,7 +416,8 @@ def test_gl202_registry_extraction_folds_the_real_registry():
     assert reg["KIND_MIN_VERSION"]["control"] == 6
     assert reg["KIND_MIN_VERSION"]["promotion"] == 6
     assert reg["KIND_MIN_VERSION"]["recovery"] == 7
-    assert reg["KIND_MIN_VERSION"]["spans"] == reg["SCHEMA_VERSION"]
+    assert reg["KIND_MIN_VERSION"]["spans"] == 8
+    assert reg["KIND_MIN_VERSION"]["fwd_bwd"] == reg["SCHEMA_VERSION"]
     assert set(reg["REQUIRED_FIELDS"]) <= set(reg["EVENT_KINDS"])
 
 
@@ -440,7 +441,7 @@ def test_gl202_new_kind_without_min_version_fires(tmp_path):
 
 def test_gl202_min_version_beyond_schema_version_fires(tmp_path):
     src = _tampered_journal(
-        tmp_path, '**{k: 8 for k in V8_KINDS}}', '**{k: 9 for k in V8_KINDS}}')
+        tmp_path, '**{k: 9 for k in V9_KINDS}}', '**{k: 10 for k in V9_KINDS}}')
     vs = lint_source(src, list(CONTRACT_RULES))
     assert any("SCHEMA_VERSION" in v.message and v.rule == "GL202"
                for v in vs)
@@ -448,13 +449,13 @@ def test_gl202_min_version_beyond_schema_version_fires(tmp_path):
 
 def test_gl202_version_bump_without_a_new_kind_fires(tmp_path):
     src = _tampered_journal(
-        tmp_path, "SCHEMA_VERSION = 8\nACCEPTED_VERSIONS = "
-                  "frozenset({1, 2, 3, 4, 5, 6, 7, 8})",
-        "SCHEMA_VERSION = 9\nACCEPTED_VERSIONS = "
-        "frozenset({1, 2, 3, 4, 5, 6, 7, 8, 9})")
+        tmp_path, "SCHEMA_VERSION = 9\nACCEPTED_VERSIONS = "
+                  "frozenset({1, 2, 3, 4, 5, 6, 7, 8, 9})",
+        "SCHEMA_VERSION = 10\nACCEPTED_VERSIONS = "
+        "frozenset({1, 2, 3, 4, 5, 6, 7, 8, 9, 10})")
     vs = lint_source(src, list(CONTRACT_RULES))
     assert _ids(vs) == ["GL202"]
-    assert "no kind is introduced at v9" in vs[0].message
+    assert "no kind is introduced at v10" in vs[0].message
 
 
 # ===================================================================== GL203

@@ -187,8 +187,8 @@ def test_reference_journal_validates_line_by_line():
     the rejoin re-fold) carrying the re-based drift prediction — which is
     exactly what keeps `obs_tpu drift` exit 0 on this journal
     (test_cli_drift_exit_codes): the replay re-bases at the swap like the
-    live monitor did.  ISSUE 24 re-pins at v8 with the loop's `spans`
-    records.  ISSUE 18 re-pinned at v7 with the recovery ladder:
+    live monitor did.  ISSUE 30 re-pins at v9 with the step's `fwd_bwd`
+    record; ISSUE 24 re-pinned at v8 with the loop's `spans` records.  ISSUE 18 re-pinned at v7 with the recovery ladder:
     the recipe checkpoints every epoch (`checkpoint` events + digest
     sidecars) and the regeneration script bit-flips the newest
     generation, lets the sidecar convict it, quarantines it through the
@@ -197,12 +197,17 @@ def test_reference_journal_validates_line_by_line():
     assert events, "reference journal is empty"
     for i, e in enumerate(events):
         assert validate_event(e) == [], f"line {i + 1}: {validate_event(e)}"
-    assert {e["v"] for e in events} == {8}
+    assert {e["v"] for e in events} == {9}
     kinds = {e["kind"] for e in events}
     assert {"run_start", "epoch", "telemetry", "compile",
             "membership", "heartbeat", "anomaly", "attribution",
             "backend", "control", "promotion", "checkpoint",
-            "recovery", "spans"} <= kinds
+            "recovery", "spans", "fwd_bwd"} <= kinds
+    # v9 (ISSUE 30): one `fwd_bwd` record a run; this run's model is an MLP
+    # under a fault plan and a membership trace, so it names a reason
+    (plan,) = [e for e in events if e["kind"] == "fwd_bwd"]
+    assert plan["packed"] is False and plan["workers_per_pack"] == 1
+    assert "no packed form" in plan["reason"]
     # v8 (ISSUE 24): one `spans` record an epoch period, the rejoin's
     # bootstrap a child of `prime`
     periods = [e for e in events if e["kind"] == "spans"]
@@ -512,7 +517,7 @@ def test_v8_spans_kind_is_versioned_and_v7_validates_verbatim():
         V8_KINDS,
     )
 
-    assert SCHEMA_VERSION == 8
+    assert SCHEMA_VERSION >= 8
     assert V8_KINDS == {"spans"} and V8_KINDS <= EVENT_KINDS
     assert KIND_MIN_VERSION["spans"] == 8
     record = {"v": 8, "kind": "spans", "t": 9.0, "epoch": 3, "attempt": 1,
@@ -529,6 +534,37 @@ def test_v8_spans_kind_is_versioned_and_v7_validates_verbatim():
                    "action": "degraded", "reason": "ENOSPC",
                    "sink": "recorder"}
     assert validate_event(v7_recovery) == []
+
+
+def test_v9_fwd_bwd_kind_is_versioned_and_v8_validates_verbatim():
+    """The v8→v9 bump (ISSUE 30) is additive: `fwd_bwd` is the one new
+    kind, it requires the three numbers of the plan (`reason` rides along
+    on the per-worker path), and a `fwd_bwd` event claiming v<=8 is a lying
+    envelope; a v8 `spans` event validates verbatim under the v9 reader."""
+    from matcha_tpu.obs.journal import (
+        EVENT_KINDS,
+        KIND_MIN_VERSION,
+        SCHEMA_VERSION,
+        V9_KINDS,
+    )
+
+    assert SCHEMA_VERSION == 9
+    assert V9_KINDS == {"fwd_bwd"} and V9_KINDS <= EVENT_KINDS
+    assert KIND_MIN_VERSION["fwd_bwd"] == 9
+    record = {"v": 9, "kind": "fwd_bwd", "t": 0.5, "packed": True,
+              "workers_per_pack": 8, "packs_per_slab": 8}
+    assert validate_event(record) == []
+    assert validate_event({**record, "packed": False, "workers_per_pack": 1,
+                           "packs_per_slab": 64, "reason": "remat"}) == []
+    for v in range(1, 9):
+        assert any("v9 kind" in p
+                   for p in validate_event({**record, "v": v}))
+    assert any("missing" in p for p in validate_event(
+        {k: v for k, v in record.items() if k != "workers_per_pack"}))
+    v8_spans = {"v": 8, "kind": "spans", "t": 9.0, "epoch": 3, "attempt": 1,
+                "period": "3.1", "t0": 4.0, "t1": 9.0, "samples": 4096,
+                "spans": []}
+    assert validate_event(v8_spans) == []
 
 
 def test_read_journal_tail_is_bounded_and_exact(tmp_path):

@@ -183,9 +183,68 @@ def test_pallas_kernels_cross_lower_for_tpu(kernel, n, wire, dim):
     assert "tpu_custom_call" in lowered.as_text()
 
 
+#: one pack of cell 2 (PERF.md section 6, PR 30): 8 workers x batch 32 of
+#: ResNet-20, one whole train step, update and exchange included.  The
+#: cell's slab of 64 is 8 such packs, one after another.
+PACK_WORKERS, PACK_BATCH = 8, 32
+
+
+def _step_readings(packed: bool, n: int, sharding) -> dict:
+    """Compile one train step of ``n`` workers of ResNet-20 for the
+    described device and read XLA's own counts and the layouts it chose
+    for the activations."""
+    import re
+
+    from matcha_tpu.models import ResNet
+    from matcha_tpu.ops import WorkerFlattener
+    from matcha_tpu.train import make_lr_schedule
+    from matcha_tpu.train.state import (
+        init_train_state,
+        make_optimizer,
+        make_train_step,
+    )
+
+    class PerWorkerResNet(ResNet):
+        pack_width = None  # the packed form hidden: the parent's program
+
+    model = (ResNet if packed else PerWorkerResNet)(depth=20, num_classes=10)
+    sched = fixed_schedule(tp.decompose(tp.ring_graph(n), n, seed=0), n,
+                           iterations=4)
+    comm = make_decen(sched, backend="dense")
+    lr = make_lr_schedule(0.002, 12, warmup=False)
+    optimizer = make_optimizer(lr)
+    state = jax.eval_shape(lambda: init_train_state(
+        model, (32, 32, 3), n, optimizer, comm, seed=0)[0])
+    step = make_train_step(model, optimizer, comm,
+                           WorkerFlattener(state.params), sched.flags,
+                           lr_schedule=lr)
+    on_chip = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                             sharding=sharding)
+    compiled = step.lower(
+        jax.tree.map(on_chip, state),
+        on_chip(jax.ShapeDtypeStruct((n, PACK_BATCH, 32, 32, 3), jnp.float32)),
+        on_chip(jax.ShapeDtypeStruct((n, PACK_BATCH), jnp.int32))).compile()
+    # float32 arrays of rank 4 (a pack's) or 5 (``vmap`` over workers) of
+    # half a million elements and more with no dimension of 1 or 3 (a
+    # kernel's window, the images' channels) are activations: the size of
+    # the dimension the layout puts in the lanes
+    lanes = {}
+    for dims, layout in set(re.findall(
+            r"f32\[((?:\d+,){3,4}\d+)\]\{(\d),", compiled.as_text())):
+        shape = [int(d) for d in dims.split(",")]
+        if np.prod(shape) >= 500_000 and not {1, 3} & set(shape):
+            lanes[dims] = shape[int(layout)]
+    return {"packed": packed, "workers": n,
+            "bytes_accessed": compiled.cost_analysis()["bytes accessed"],
+            "temp_bytes": compiled.memory_analysis().temp_size_in_bytes,
+            "activation_lanes": lanes}
+
+
 def _compile_all_for_v5e() -> int:
-    """Child-process body of the test below: compile every kernel case for
-    one device of a compile-only v5e topology (libtpu, no hardware)."""
+    """Child-process body of the tests below: compile every kernel case,
+    and one pack of cell 2 packed and per worker, for one device of a
+    compile-only v5e topology (libtpu, no hardware)."""
+    import json
     import os
 
     # no metadata server here: describe the host to libtpu by hand
@@ -214,14 +273,16 @@ def _compile_all_for_v5e() -> int:
             state = 4 * args[0].shape[0] * args[0].shape[1]
             assert compiled.memory_analysis().temp_size_in_bytes < state // 8
         print("COMPILED", *case)
+    for packed in (True, False):
+        print("STEP", json.dumps(_step_readings(packed, PACK_WORKERS, sharding)))
     return 0
 
 
-def test_pallas_kernels_compile_for_v5e():
-    """Mosaic itself (layout inference, VMEM allocation) compiles the
-    kernels for the v5e at the train shapes, ahead of time.  In a
-    child process: loading libtpu here would hang a TPU plane on every
-    later profiler trace of this one."""
+@pytest.fixture(scope="module")
+def v5e_child():
+    """One child process for every test that needs the v5e's compiler:
+    loading libtpu here would hang a TPU plane on every later profiler
+    trace of this one."""
     import os
     import subprocess
     import sys
@@ -229,12 +290,39 @@ def test_pallas_kernels_compile_for_v5e():
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     proc = subprocess.run(
         [sys.executable, os.path.abspath(__file__)], cwd=repo,
-        capture_output=True, text=True, timeout=600,
+        capture_output=True, text=True, timeout=900,
         env={**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": repo})
     if "NO-TOPOLOGY" in proc.stdout:
         pytest.skip(f"no compile-only TPU topology here: {proc.stdout[-300:]}")
     assert proc.returncode == 0, proc.stderr[-3000:]
-    assert proc.stdout.count("COMPILED") == len(KERNEL_CASES), proc.stdout
+    return proc.stdout
+
+
+def test_pallas_kernels_compile_for_v5e(v5e_child):
+    """Mosaic itself (layout inference, VMEM allocation) compiles the
+    kernels for the v5e at the train shapes, ahead of time."""
+    assert v5e_child.count("COMPILED") == len(KERNEL_CASES), v5e_child
+
+
+def test_packed_step_compiles_for_v5e_with_full_lanes(v5e_child):
+    """One pack of cell 2's workers, as the v5e's compiler lays its step
+    out (counts and layouts; nothing runs, so no time).  Per worker,
+    activations sit with the 32-image batch or 32 or 64 channels in the 128
+    lanes; packed, every activation has 128 or more, XLA counts under half
+    the bytes, and the temporaries are an eighth of the 4 GB a slab of 8
+    packs may take (the packs run one after another, so a slab's are one
+    pack's)."""
+    import json
+
+    pack, per_worker = (json.loads(line.split(" ", 1)[1])
+                        for line in v5e_child.splitlines()
+                        if line.startswith("STEP "))
+    assert pack["packed"] and not per_worker["packed"]
+    assert min(per_worker["activation_lanes"].values()) < 128
+    assert len(pack["activation_lanes"]) >= 3
+    assert min(pack["activation_lanes"].values()) >= 128, pack
+    assert pack["temp_bytes"] < 4e9 / 8
+    assert pack["bytes_accessed"] < 0.5 * per_worker["bytes_accessed"]
 
 
 def test_kernel_blocks_that_cannot_fit_are_refused_by_name():
