@@ -1,6 +1,7 @@
 """Flax model zoo: CIFAR ResNets (incl. ResNet-20), VGG-BN, WideResNet, MLP,
-and a sparse decoder (Mellum2) for next-token training."""
+and two sparse decoders (Mellum2, KeyeVL2) for next-token training."""
 
+from .keye_vl2 import KeyeVL2
 from .mellum2 import Mellum2
 from .mlp import MLP
 from .registry import (
@@ -14,6 +15,7 @@ from .vgg import VGG, vgg_config
 from .wrn import WideResNet
 
 __all__ = [
+    "KeyeVL2",
     "MLP",
     "Mellum2",
     "ResNet",

@@ -18,7 +18,8 @@ absent ones would add is left out and the partial sum goes on
 
 **The expert layer drops nothing.**  The slots that landed on an expert
 held are laid out sorted by expert in ``moe_capacity`` rows
-(``ROWS_PER_EVEN_SLOT`` times what an even router would send here) and
+(``ROWS_PER_EVEN_SLOT`` times what an even router would send here, or
+``sizes["moe_rows_per_even_slot"]`` times where a configuration says so) and
 three grouped products (``jax.lax.ragged_dot``) run over them: the cost
 follows the number of slots, not the busiest expert, which matters because
 a row's tokens route alike (one expert held took 3.7 times its even share of a step
@@ -53,13 +54,15 @@ from jax import lax
 
 from ..utils.profiling import device_span
 
-__all__ = ["Mellum2", "rope_inv_freq", "moe_capacity"]
+__all__ = ["Mellum2", "TokenDecoder", "rope_inv_freq", "moe_capacity"]
 
 INIT_STD = 0.02
 #: rows of the grouped expert products over the slots an even router would
 #: send to the experts held: a layer took up to 1.41 times its even total of
 #: a step at the published widths (PERF.md section 6, PR 27)
 ROWS_PER_EVEN_SLOT = 2
+#: what the expert layer counts beside ``moe_load``, summed over the layers
+MOE_COUNTERS = ("moe_slots_held", "moe_rows_computed")
 
 
 def rope_inv_freq(kind: str, sizes):
@@ -90,11 +93,13 @@ def rope_inv_freq(kind: str, sizes):
 def moe_capacity(tokens: int, sizes) -> int:
     """Rows the grouped expert products run over in a step:
     ``ROWS_PER_EVEN_SLOT`` times the slots an even router would send to the
-    experts held, rounded up to 8 rows and never more than every slot there
-    can be."""
+    experts held (``sizes["moe_rows_per_even_slot"]`` times, where a
+    configuration whose share is small enough to swing further gives it),
+    rounded up to 8 rows and never more than every slot there can be."""
     held = len(sizes["experts_held"])
     even = tokens * sizes["experts_per_token"] * held / sizes["num_experts"]
-    rows = math.ceil(ROWS_PER_EVEN_SLOT * even / 8) * 8
+    per_even = sizes.get("moe_rows_per_even_slot", ROWS_PER_EVEN_SLOT)
+    rows = math.ceil(per_even * even / 8) * 8
     return min(max(rows, 8), tokens * min(held, sizes["experts_per_token"]))
 
 
@@ -307,9 +312,48 @@ def _block(p, h, docs, kind, sizes):
     return h + y, counters
 
 
-class Mellum2(nn.Module):
-    """``sizes`` as in ``chipbench/configs/mellum2-12b-a2.5b.ep8-s4k.json``
-    (README "Training a language model" lists the keys)."""
+def _next_ids(x_raw, y_raw):
+    """(ids, document numbers, the next id or -1 where it starts a new
+    document and is not judged), each ``[B, S]``, of raw rows ``[B, S + 1]``."""
+    ids, docs = x_raw[:, :-1], y_raw[:, :-1]
+    return ids, docs, jnp.where(y_raw[:, 1:] == docs, x_raw[:, 1:], -1)
+
+
+def _head_loss(h, head, targets, sizes):
+    """The untied head and its loss over ``h[B, S, H]``, a chunk of positions
+    at a time under ``jax.checkpoint``: (the mean over judged positions of
+    float32 softmax cross-entropy, token accuracy over them, how many)."""
+    b, s, _ = h.shape
+    chunk = sizes.get("loss_chunk", 1024)  # a test seam, as ``attn_block``
+    if s % chunk:
+        chunk = s
+
+    @jax.checkpoint
+    def of_chunk(part):
+        h_c, t_c = part  # [B, chunk, H], [B, chunk]
+        logits = jnp.dot(h_c, head).astype(jnp.float32)
+        judged = t_c >= 0
+        picked = jnp.take_along_axis(
+            logits, jnp.maximum(t_c, 0)[..., None], axis=-1)[..., 0]
+        nll = jax.scipy.special.logsumexp(logits, axis=-1) - picked
+        hit = jnp.argmax(logits, axis=-1) == t_c
+        return (jnp.sum(jnp.where(judged, nll, 0.0)),
+                jnp.sum(judged & hit), jnp.sum(judged))
+
+    with device_span("matcha/lm_head_loss"):
+        split = lambda a: jnp.moveaxis(
+            a.reshape((b, s // chunk, chunk) + a.shape[2:]), 1, 0)
+        nll, hits, judged = lax.map(of_chunk, (split(h), split(targets)))
+        judged = jnp.sum(judged).astype(jnp.float32)
+        positions = jnp.maximum(judged, 1.0)
+        loss = jnp.sum(nll) / positions
+    return loss, jnp.sum(hits) / positions, judged
+
+
+class TokenDecoder(nn.Module):
+    """What the token models share around their blocks.  A subclass declares
+    its parameters in ``setup`` (:meth:`declare`) and gives ``hidden(ids,
+    docs)`` and ``batch_loss(x_raw, y_raw)``."""
 
     sizes: Any
     remat: bool = False
@@ -319,30 +363,32 @@ class Mellum2(nn.Module):
     #: (a ``vmap`` would turn the expert layer's ``cond`` into both branches)
     supplies_loss = True
 
-    def setup(self):
+    def declare(self, num_layers, more=None):
+        """``embed``, ``layer<n>_<name>`` of the attention and the expert
+        layer (and of ``more``: ``{name: shape}`` of normal weights), the
+        final norm and the untied head."""
         z = self.sizes
         hid, d, width = z["hidden"], z["head_dim"], z["expert_width"]
         held = len(z["experts_held"])
         normal = nn.initializers.normal(INIT_STD)
         ones = nn.initializers.ones
         self.embed = self.param("embed", normal, (z["vocab_held"], hid))
-        layers = []
-        for n in range(len(z["layer_types"])):
-            shapes = {
-                "attn_norm": (ones, (hid,)),
-                "wq": (normal, (hid, z["q_heads_held"] * d)),
-                "wk": (normal, (hid, z["kv_heads_held"] * d)),
-                "wv": (normal, (hid, z["kv_heads_held"] * d)),
-                "wo": (normal, (z["q_heads_held"] * d, hid)),
-                "moe_norm": (ones, (hid,)),
-                "router": (normal, (hid, z["num_experts"])),
-                "gate": (normal, (held, hid, width)),
-                "up": (normal, (held, hid, width)),
-                "down": (normal, (held, width, hid)),
-            }
-            layers.append({k: self.param(f"layer{n}_{k}", init, shape)
-                           for k, (init, shape) in shapes.items()})
-        self.layers = layers
+        shapes = {
+            "attn_norm": (ones, (hid,)),
+            "wq": (normal, (hid, z["q_heads_held"] * d)),
+            "wk": (normal, (hid, z["kv_heads_held"] * d)),
+            "wv": (normal, (hid, z["kv_heads_held"] * d)),
+            "wo": (normal, (z["q_heads_held"] * d, hid)),
+            "moe_norm": (ones, (hid,)),
+            "router": (normal, (hid, z["num_experts"])),
+            "gate": (normal, (held, hid, width)),
+            "up": (normal, (held, hid, width)),
+            "down": (normal, (held, width, hid)),
+            **{k: (normal, shape) for k, shape in (more or {}).items()},
+        }
+        self.layers = [{k: self.param(f"layer{n}_{k}", init, shape)
+                        for k, (init, shape) in shapes.items()}
+                       for n in range(num_layers)]
         self.final_norm = self.param("final_norm", ones, (hid,))
         self.head = self.param("head", normal, (hid, z["vocab_held"]))
 
@@ -350,20 +396,10 @@ class Mellum2(nn.Module):
         """What ``init`` traces: parameters do not depend on the length."""
         return jnp.zeros((1, 8), jnp.int32)
 
-    def hidden(self, ids, docs):
-        """(the final norm's output ``[B, S, H]``, the expert layers'
-        counters, ``moe_load[layer, expert held]``)."""
-        with device_span("matcha/lm_embed"):
-            h = self.embed[ids]
-        counters = []
-        for p, kind in zip(self.layers, self.sizes["layer_types"]):
-            block = lambda p, h, docs, kind=kind: _block(
-                p, h, docs, kind, self.sizes)
-            h, c = (jax.checkpoint(block) if self.remat else block)(
-                p, h, docs)
-            counters.append(c)
-        total = {k: sum(c[k] for c in counters)
-                 for k in ("moe_slots_held", "moe_rows_computed")}
+    def normed(self, h, counters, summed=MOE_COUNTERS):
+        """(the final norm's output ``[B, S, H]``, the layers' counters
+        ``summed`` and ``moe_load[layer, expert held]`` stacked)."""
+        total = {k: sum(c[k] for c in counters) for k in summed}
         total["moe_load"] = jnp.stack([c["moe_load"] for c in counters])
         return _rms_norm(h, self.final_norm,
                          self.sizes["rms_norm_eps"]), total
@@ -378,38 +414,34 @@ class Mellum2(nn.Module):
         ids = x.astype(jnp.int32)
         return self.logits(ids, jnp.zeros_like(ids))
 
+
+class Mellum2(TokenDecoder):
+    """``sizes`` as in ``chipbench/configs/mellum2-12b-a2.5b.ep8-s4k.json``
+    (README "Training a language model" lists the keys)."""
+
+    def setup(self):
+        self.declare(len(self.sizes["layer_types"]))
+
+    def hidden(self, ids, docs):
+        """(the final norm's output ``[B, S, H]``, the expert layers'
+        counters, ``moe_load[layer, expert held]``)."""
+        with device_span("matcha/lm_embed"):
+            h = self.embed[ids]
+        counters = []
+        for p, kind in zip(self.layers, self.sizes["layer_types"]):
+            block = lambda p, h, docs, kind=kind: _block(
+                p, h, docs, kind, self.sizes)
+            h, c = (jax.checkpoint(block) if self.remat else block)(
+                p, h, docs)
+            counters.append(c)
+        return self.normed(h, counters)
+
     def batch_loss(self, x_raw, y_raw):
         """``x_raw``/``y_raw``: int32 ``[B, S + 1]`` ids and document
         numbers (``data.load_tokens``).  Returns (the mean over judged
         positions of the next id's cross-entropy, ``{"accuracy", "counters"}``)."""
-        ids, docs = x_raw[:, :-1], y_raw[:, :-1]
-        targets = jnp.where(y_raw[:, 1:] == docs, x_raw[:, 1:], -1)
+        ids, docs, targets = _next_ids(x_raw, y_raw)
         h, counters = self.hidden(ids, docs)
-        b, s, hidden = h.shape
-        chunk = self.sizes.get("loss_chunk", 1024)  # a test seam, as above
-        if s % chunk:
-            chunk = s
-        head = self.head
-
-        @jax.checkpoint
-        def of_chunk(part):
-            h_c, t_c = part  # [B, chunk, H], [B, chunk]
-            logits = jnp.dot(h_c, head).astype(jnp.float32)
-            judged = t_c >= 0
-            picked = jnp.take_along_axis(
-                logits, jnp.maximum(t_c, 0)[..., None], axis=-1)[..., 0]
-            nll = jax.scipy.special.logsumexp(logits, axis=-1) - picked
-            hit = jnp.argmax(logits, axis=-1) == t_c
-            return (jnp.sum(jnp.where(judged, nll, 0.0)),
-                    jnp.sum(judged & hit), jnp.sum(judged))
-
-        with device_span("matcha/lm_head_loss"):
-            split = lambda a: jnp.moveaxis(
-                a.reshape((b, s // chunk, chunk) + a.shape[2:]), 1, 0)
-            nll, hits, judged = lax.map(of_chunk, (split(h), split(targets)))
-            judged = jnp.sum(judged).astype(jnp.float32)
-            positions = jnp.maximum(judged, 1.0)
-            loss = jnp.sum(nll) / positions
-        counters["loss_positions"] = judged
-        return loss, {"accuracy": jnp.sum(hits) / positions,
-                      "counters": counters}
+        loss, accuracy, counters["loss_positions"] = _head_loss(
+            h, self.head, targets, self.sizes)
+        return loss, {"accuracy": accuracy, "counters": counters}

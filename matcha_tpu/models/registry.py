@@ -8,9 +8,10 @@ driver hard-codes ``num_class=100`` regardless of dataset (train_mpi.py:84);
 here the class count is derived from the dataset unless overridden.
 
 Also registers explicit names the reference cannot express: ``resnet20``
-(BASELINE.json's model), ``resnet32/44/56/110``, ``vgg11/13/19``, and
-``mellum2``, a sparse decoder for next-token training whose sizes come as
-``sizes={...}`` (``models/mellum2.py``).
+(BASELINE.json's model), ``resnet32/44/56/110``, ``vgg11/13/19``, and the
+sparse decoders for next-token training whose sizes come as ``sizes={...}``:
+``mellum2`` (``models/mellum2.py``) and ``keye_vl2``, whose attention reads
+a learned choice of keys (``models/keye_vl2.py``).
 """
 
 from __future__ import annotations
@@ -19,6 +20,7 @@ from typing import Any, Tuple
 
 import flax.linen as nn
 
+from .keye_vl2 import KeyeVL2
 from .mellum2 import Mellum2
 from .mlp import MLP
 from .resnet import ResNet, ResNetImageNet
@@ -46,6 +48,9 @@ DATASET_SHAPES = {
     "synthetic": (28, 28, 1),
     "synthetic_image": (32, 32, 3),
 }
+
+
+TOKEN_MODELS = {"mellum2": Mellum2, "keye_vl2": KeyeVL2}
 
 
 def dataset_num_classes(dataset: str) -> int:
@@ -76,13 +81,13 @@ def select_model(
         kw["dtype"] = dtype
 
     lname = name.lower()
-    if lname == "mellum2":
+    if lname in TOKEN_MODELS:
         # a token model: its sizes come as ``sizes={...}`` (the vocabulary
         # it holds among them), not from the data set's name
         if "sizes" not in kw:
-            raise ValueError("model 'mellum2' needs sizes={...} "
+            raise ValueError(f"model '{lname}' needs sizes={{...}} "
                              "(TrainConfig.model_kwargs)")
-        return Mellum2(**kw)
+        return TOKEN_MODELS[lname](**kw)
     classes = num_classes if num_classes is not None else dataset_num_classes(dataset)
     if name == "res":  # reference depth policy (util.py:258-265)
         if dataset == "imagenet":  # torchvision resnet18 path (util.py:262)
@@ -112,4 +117,4 @@ def select_model(
 
 def available_models():
     return ["res", "resnet<depth>", "VGG", "vgg<depth>", "wrn", "wrn-<d>-<k>", "mlp",
-            "mellum2"]
+            *TOKEN_MODELS]

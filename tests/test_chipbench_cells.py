@@ -1,8 +1,8 @@
-"""The token cell rehearsed tiny on the CPU from ``stage_job`` to
+"""The two token cells rehearsed tiny on the CPU from ``stage_job`` to
 ``check.compare``, as ``chipbench/tests/test_cells_on_cpu.py`` rehearses
 every cell of ``BENCHMARK.json`` outside tier-1 (the two conv cells take a
-minute and half a minute there; this one fits here), and the control that
-has to fail."""
+minute and half a minute there; these fit here), and the control that has
+to fail."""
 
 import importlib.util
 import json
@@ -12,7 +12,14 @@ import pytest
 
 from chipbench import catalog
 
-CELL = "mellum2-12b-a2.5b.ep8-s4k.w2-matcha"
+#: each token cell, and the counters' metrics that are its own
+CELLS = {
+    "mellum2-12b-a2.5b.ep8-s4k.w2-matcha": {
+        "moe_load_max_over_mean", "moe_slot_fill_pct", "loss_positions_pct"},
+    "keye-vl2-30b-a3b.ep16-s8k.w2-matcha": {
+        "dsa_selecting_pct", "dsa_keys_kept_pct", "dsa_indexer_kl"},
+}
+COUNTER_METRICS = sorted(set().union(*CELLS.values()))
 
 _spec = importlib.util.spec_from_file_location(
     "chipbench_tests_cells_on_cpu", Path(__file__).resolve().parents[1]
@@ -22,9 +29,14 @@ _spec.loader.exec_module(_cells_on_cpu)
 rehearse = _cells_on_cpu.rehearse  # its one rehearsal run, not its tests
 
 
+@pytest.fixture(scope="module", params=list(CELLS))
+def cell(request):
+    return request.param
+
+
 @pytest.fixture(scope="module")
-def line():
-    return rehearse(CELL, trace=1)
+def line(cell):
+    return rehearse(cell, trace=1)
 
 
 def test_program_agrees_with_reference(line):
@@ -34,19 +46,24 @@ def test_program_agrees_with_reference(line):
     json.dumps(line)
 
 
-def test_counters_fill_the_cells_own_metrics(line):
+def test_counters_fill_the_cells_own_metrics(cell, line):
     """A traced run's line: on the CPU no device trace is read, and the
-    metrics that read the program's counters and spans still report."""
+    metrics that read the program's counters and spans still report, each
+    in the cell that lists it and in no other."""
     bench = catalog.benchmark()
     mine = {m["name"] for m in bench["per_layer"]
-            if CELL in m.get("workloads", ())}
-    assert mine == {"moe_load_max_over_mean", "moe_slot_fill_pct",
-                    "loss_positions_pct"}
+            if cell in m.get("workloads", ())}
+    assert mine == CELLS[cell]
     got = {k: v["value"] for k, v in line["metrics"].items()}
-    assert mine <= set(got)
-    assert 0 < got["moe_slot_fill_pct"] <= 100
-    assert 1 <= got["moe_load_max_over_mean"] <= 2  # 2 experts held
-    assert 90 < got["loss_positions_pct"] <= 100
+    assert mine <= set(got) and not (set(COUNTER_METRICS) - mine) & set(got)
+    if "moe_slot_fill_pct" in mine:
+        assert 0 < got["moe_slot_fill_pct"] <= 100
+        assert 1 <= got["moe_load_max_over_mean"] <= 2  # 2 experts held
+        assert 90 < got["loss_positions_pct"] <= 100
+    else:  # 64 positions, 16 kept: most queries see more than they keep
+        assert 0 < got["dsa_selecting_pct"] < 100
+        assert 0 < got["dsa_keys_kept_pct"] < 100
+        assert got["dsa_indexer_kl"] > 0
     assert {"stage_gb_per_s", "comm_timer_ms", "stage_ms",
             "span_cover_pct"} <= set(got)
 
@@ -59,15 +76,14 @@ def test_readers_return_nothing_for_a_program_without_counters(line):
                            {"name": "dispatch", "t0": 0, "t1": 1, "steps": 2,
                             "parent": "0.0"}]}],
            "epochs": [{"epoch": 0}], "traced": None}
-    for name in ("moe_load_max_over_mean", "moe_slot_fill_pct",
-                 "loss_positions_pct"):
+    for name in COUNTER_METRICS:
         assert catalog.load_reader(name)(run) is None
 
 
-def test_control_exchange_left_out_is_not_correct():
+def test_control_exchange_left_out_is_not_correct(cell):
     def no_gossip(job):
         job["train_config"]["communicator"] = "none"
 
-    line = rehearse(CELL, edit=no_gossip)
+    line = rehearse(cell, edit=no_gossip)
     assert not line["correct"]
     assert line["check"]["disagree_gap"][0] > line["check"]["disagree_gap"][1]

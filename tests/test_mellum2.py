@@ -151,6 +151,24 @@ def test_program_agrees_with_the_plain_reference(kind, documents, window,
     assert counters["loss_positions"] == np.sum(judged[:, 1:] == judged[:, :-1])
 
 
+@pytest.mark.parametrize("per_even, past_the_rows", [(2, True), (3, True),
+                                                     (4, False)])
+def test_rows_per_even_slot_of_the_sizes(per_even, past_the_rows):
+    """``sizes["moe_rows_per_even_slot"]`` sets the grouped products' rows:
+    with both experts held chosen by every token (4 times the even total)
+    the slots fit 4 times the even rows and no fewer, and the result is the
+    reference's either way."""
+    counters = compare("full", SEQ, "packed", "both", program_sizes=(
+        ("moe_rows_per_even_slot", per_even),))
+    tokens = 3 * SEQ
+    room = mellum2.moe_capacity(tokens, sizes_of(
+        moe_rows_per_even_slot=per_even))
+    assert room == per_even * tokens // 2
+    assert counters["moe_slots_held"] == 2 * tokens
+    assert counters["moe_rows_computed"] == room + (
+        2 * tokens if past_the_rows else 0)
+
+
 def drop_last_slot(real):
     def route(p, x, sizes):
         w, sel = real(p, x, sizes)

@@ -188,24 +188,35 @@ def test_evaluation_gives_held_out_loss_and_token_accuracy(run):
         for e in result.recorder.events)
 
 
-def test_job_file_hands_the_program_the_configurations_sizes():
-    """The harness passes ``TrainConfig`` fields only, so the cell's job
+@pytest.mark.parametrize("conf_name, cell", [
+    ("mellum2-12b-a2.5b.ep8-s4k", "mellum2-12b-a2.5b.ep8-s4k.w2-matcha"),
+    ("keye-vl2-30b-a3b.ep16-s8k", "keye-vl2-30b-a3b.ep16-s8k.w2-matcha")])
+def test_job_file_hands_the_program_the_configurations_sizes(conf_name, cell):
+    """The harness passes ``TrainConfig`` fields only, so a token cell's job
     file repeats the configuration's sizes: they must not drift apart."""
     root = Path(__file__).resolve().parents[1] / "chipbench"
-    conf = json.loads((root / "configs" / "mellum2-12b-a2.5b.ep8-s4k.json")
-                      .read_text())
-    job = json.loads((root / "workloads"
-                      / "mellum2-12b-a2.5b.ep8-s4k.w2-matcha.json").read_text())
+    conf = json.loads((root / "configs" / f"{conf_name}.json").read_text())
+    job = json.loads((root / "workloads" / f"{cell}.json").read_text())
     assert job["train_config"]["model_kwargs"]["sizes"] == conf["sizes"]
     small = dict(conf["sizes"], **job["rehearsal"]["sizes"])
     assert job["rehearsal"]["train_config"]["model_kwargs"]["sizes"] == small
     sizes = conf["sizes"]
-    assert (conf["num_hidden_layers"], conf["num_attention_heads"],
-            conf["num_key_value_heads"], conf["vocab_size"],
-            conf["num_experts"], conf["num_experts_per_tok"],
-            conf["moe_intermediate_size"], conf["hidden_size"],
-            conf["head_dim"], conf["sliding_window"]) == (
-        len(sizes["layer_types"]), sizes["q_heads_held"],
-        sizes["kv_heads_held"], sizes["vocab_held"], sizes["num_experts"],
-        sizes["experts_per_token"], sizes["expert_width"], sizes["hidden"],
-        sizes["head_dim"], sizes["sliding_window"])
+    assert (conf["num_attention_heads"], conf["num_key_value_heads"],
+            conf["vocab_size"], conf["num_experts"],
+            conf["num_experts_per_tok"], conf["moe_intermediate_size"],
+            conf["hidden_size"], conf["head_dim"],
+            conf["num_experts_held"]) == (
+        sizes["q_heads_held"], sizes["kv_heads_held"], sizes["vocab_held"],
+        sizes["num_experts"], sizes["experts_per_token"],
+        sizes["expert_width"], sizes["hidden"], sizes["head_dim"],
+        len(sizes["experts_held"]))
+    if "layer_types" in sizes:
+        assert (conf["num_hidden_layers"], conf["sliding_window"]) == (
+            len(sizes["layer_types"]), sizes["sliding_window"])
+    else:
+        indexer = conf["sa_config"]
+        assert (conf["num_hidden_layers"], conf["rope_theta"],
+                indexer["indexer_num_heads"], indexer["indexer_head_dim"],
+                indexer["indexer_num_kv_heads"], indexer["topk"]) == (
+            sizes["num_layers"], sizes["rope_theta"], sizes["indexer_heads"],
+            sizes["indexer_head_dim"], 1, sizes["index_topk"])
