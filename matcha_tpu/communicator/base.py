@@ -72,6 +72,12 @@ class Communicator:
     kernel's mixing matrices are precomputed maskless), so ``run`` scans
     ``step`` for every masked chain.
 
+    ``leaves_step``, when present, is ``step`` over the parameter leaves
+    where they lie (``parallel.pallas_gossip.tree_mix``): the same ``W_t``
+    from the same flag row, and the squares the disagreement needs from the
+    same pass.  ``train/state.py:exchange_plan`` decides whether a step may
+    take it.
+
     ``encode_probe``, when present, is a scan-compatible stand-in for the
     per-step message *encode* work (CHOCO's compress path) —
     ``(flat, probe_state) -> probe_state`` with ``probe_state0 =
@@ -85,6 +91,13 @@ class Communicator:
     step: StepFn
     multi_step: Any = None  # Optional[(flat, carry, flags[T,M]) -> (flat, carry)]
     encode_probe: Any = None  # Optional[(flat, probe_state) -> probe_state]
+    # the tree form of ``step`` (the one-chip streamed exchange of the dense
+    # decen communicator alone): (leaves, carry, flags_t) -> (leaves', carry,
+    # sq[N]) over the list of ``[N, ...]`` parameter leaves, no flat copy
+    # built; ``sq`` is each worker's squared distance from the worker mean.
+    # ``leaves_refusal`` says why a communicator has none.
+    leaves_step: Any = None
+    leaves_refusal: Any = None
 
     def begin_mix(self, flat: jax.Array, carry: Any, flags_t: jax.Array,
                   alive: Any = None):

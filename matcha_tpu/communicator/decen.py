@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from ..parallel import (
     dense_exchange_form,
     dense_gossip_fn,
+    dense_gossip_leaves_fn,
     gossip_mix,
     gossip_mix_skip,
     involution_tables,
@@ -260,8 +261,36 @@ def make_decen(
             return mix(flat, alpha * flags_t), carry
         return mix(flat, alpha * flags_t, alive), carry
 
+    # the tree form beside ``step``: the dense exchange where it is the
+    # streamed pass (one chip, N <= STREAM_MAX_WORKERS) runs on the leaves
+    leaves_step = None
+    leaves_refusal = _leaves_refusal(backend, perms.shape[1], mesh)
+    if leaves_refusal is None:
+        mix_leaves = dense_gossip_leaves_fn(schedule.laplacians(),
+                                            compute_dtype=compute_dtype)
+
+        def leaves_step(leaves, carry, flags_t: jax.Array):
+            mixed, sq = mix_leaves(leaves, alpha * flags_t)
+            return mixed, carry, sq
+
     wire_tag = "" if wire is None else f",wire={jnp.dtype(wire).name}"
     return Communicator(
         name=f"decen[{backend}{wire_tag}]", init=init, step=step,
-        multi_step=multi_step,
+        multi_step=multi_step, leaves_step=leaves_step,
+        leaves_refusal=leaves_refusal,
     )
+
+
+def _leaves_refusal(backend: str, n: int, mesh) -> str | None:
+    """Why a decen communicator's per-step exchange has no leaf form, or
+    ``None`` where it has: the leaf form is the streamed pass, so it exists
+    exactly where ``dense_exchange_form`` says ``streamed``."""
+    if backend not in ("dense", "fused"):
+        return f"gossip backend '{backend}' is not the dense exchange"
+    form = dense_exchange_form(n, _single_chip(mesh))
+    if form["form"] == "streamed":
+        return None
+    if not form["single_chip"]:
+        return f"a mesh of {mesh.size} devices shards the state"
+    return (f"N = {n} > {form['crossover']}: the exchange is the MXU "
+            f"product over the flat state")

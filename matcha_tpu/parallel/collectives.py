@@ -14,7 +14,7 @@ import jax.numpy as jnp
 
 __all__ = ["allreduce_mean", "broadcast_worker0", "masked_mean_rows",
            "masked_allreduce_mean", "worker_disagreement",
-           "worker_deviation_rows"]
+           "worker_deviation_rows", "worker_square_rows"]
 
 
 def allreduce_mean(x: jax.Array) -> jax.Array:
@@ -100,3 +100,16 @@ def worker_deviation_rows(x: jax.Array,
                              jnp.zeros_like(x))
     sq = (centered * centered).reshape(x.shape[0], -1)
     return jnp.sqrt(jnp.mean(sq, axis=1))
+
+
+def worker_square_rows(leaves) -> jax.Array:
+    """``f32[N]`` — worker i's ``sum((x_i - mean_j x_j)^2)`` over a list of
+    ``[N, ...]`` leaves: what :func:`worker_deviation_rows` and
+    :func:`worker_disagreement` reduce from the flat ``[N, D]`` state, a
+    leaf at a time and with no flat copy."""
+    total = jnp.zeros((leaves[0].shape[0],), jnp.float32)
+    for leaf in leaves:
+        x = leaf.reshape(leaf.shape[0], -1).astype(jnp.float32)
+        centered = x - jnp.mean(x, axis=0, keepdims=True)
+        total = total + jnp.sum(centered * centered, axis=1)
+    return total

@@ -69,6 +69,7 @@ __all__ = [
     "masked_laplacians",
     "matching_wire_bytes",
     "dense_gossip_fn",
+    "dense_gossip_leaves_fn",
     "FoldedPlan",
     "build_folded_plan",
     "gossip_mix_folded",
@@ -328,6 +329,16 @@ def dense_exchange_form(n: int, single_chip: bool = True) -> dict:
             "crossover": STREAM_MAX_WORKERS, "single_chip": bool(single_chip)}
 
 
+def _mixing_matrix(laplacians, weights, compute_dtype, alive=None):
+    """``W_t = I - sum_j weights[j] L_j`` in ``compute_dtype``, the
+    Laplacians masked by ``alive`` where given."""
+    if alive is not None:
+        laplacians = masked_laplacians(laplacians, alive)
+    n = laplacians.shape[-1]
+    W = jnp.eye(n, dtype=jnp.float32) - jnp.tensordot(weights, laplacians, axes=1)
+    return W.astype(compute_dtype)
+
+
 def gossip_mix_dense(
     x: jax.Array,
     laplacians: jax.Array,
@@ -367,10 +378,7 @@ def gossip_mix_dense(
     next to the ``[N, D]`` state; both forms then mix with the masked W.
     """
     n = x.shape[0]
-    if alive is not None:
-        laplacians = masked_laplacians(laplacians, alive)
-    W = jnp.eye(n, dtype=jnp.float32) - jnp.tensordot(weights, laplacians, axes=1)
-    W = W.astype(compute_dtype)
+    W = _mixing_matrix(laplacians, weights, compute_dtype, alive)
     if dense_exchange_form(n, single_chip)["form"] == "streamed":
         from .pallas_gossip import pallas_interpret, stream_mix
 
@@ -393,6 +401,25 @@ def dense_gossip_fn(laplacians: np.ndarray, compute_dtype=jnp.float32,
     def fn(x, weights, alive=None):
         return gossip_mix_dense(x, L, weights, compute_dtype=compute_dtype,
                                 alive=alive, single_chip=single_chip)
+
+    return fn
+
+
+def dense_gossip_leaves_fn(laplacians: np.ndarray, compute_dtype=jnp.float32):
+    """The leaf form of :func:`dense_gossip_fn` at the worker counts whose
+    exchange is ``streamed``: ``(leaves, weights) -> (leaves', sq)`` over a
+    list of ``[N, ...]`` parameter leaves, the same ``W_t`` from the same
+    weights, every large leaf mixed in place where it lies and each worker's
+    squared distance from the mean returned beside it
+    (``pallas_gossip.tree_mix``).  No ``alive``: quarantine and heal work
+    on rows of the flat state."""
+    L = jnp.asarray(np.asarray(laplacians), jnp.float32)
+
+    def fn(leaves, weights):
+        from .pallas_gossip import pallas_interpret, tree_mix
+
+        return tree_mix(leaves, _mixing_matrix(L, weights, compute_dtype),
+                        wire_dtype=compute_dtype, interpret=pallas_interpret())
 
     return fn
 

@@ -55,8 +55,9 @@ from .checkpoint import restore_checkpoint, save_checkpoint
 from .config import TrainConfig
 from .lr import make_lr_schedule
 from .recorder import Recorder
-from .state import (COUNTER_PREFIX, TrainState, init_train_state, make_eval_fn,
-                    fwd_bwd_plan, make_optimizer, make_train_step)
+from .state import (COUNTER_PREFIX, TrainState, exchange_plan,
+                    init_train_state, make_eval_fn, fwd_bwd_plan,
+                    make_optimizer, make_train_step)
 
 __all__ = ["build_schedule", "build_dataset", "train", "TrainResult",
            "TrainingDiverged"]
@@ -750,7 +751,15 @@ def train(config: TrainConfig, resume_dir: Optional[str] = None,
     if backend_decision is not None:
         # the auto-resolution record (or the explicit pass-through): what
         # backend compiled and why — journaled unconditionally so a
-        # questionable `auto` choice is always auditable post-hoc
+        # questionable `auto` choice is always auditable post-hoc.  Where the
+        # per-step mix is the dense exchange, its record also says where the
+        # step runs it: on the parameter leaves where they lie or on a flat
+        # copy of the state, with how many kernels and, on `flat`, why
+        if "exchange" in backend_decision:
+            backend_decision["exchange"].update(exchange_plan(
+                communicator, flattener, overlap=config.overlap,
+                staleness=config.staleness, faults=faults is not None,
+                elastic=elastic_ctl is not None))
         recorder.log_event("backend", **backend_decision)
     # how the forward/backward runs (packs of workers side by side in the
     # lanes, or vmap over workers) and, where it is the latter, why

@@ -31,11 +31,12 @@ from flax import struct
 from ..communicator import Communicator
 from ..obs.telemetry import telemetry_step
 from ..ops import WorkerFlattener
-from ..parallel import allreduce_mean, worker_deviation_rows, worker_disagreement
+from ..parallel import (allreduce_mean, leaf_views, worker_deviation_rows,
+                        worker_disagreement, worker_square_rows)
 from ..utils import cross_entropy_loss, device_span, top_k_accuracy
 
 __all__ = ["TrainState", "init_train_state", "make_train_step", "make_eval_fn", "make_optimizer",
-           "fwd_bwd_plan", "COUNTER_PREFIX"]
+           "fwd_bwd_plan", "exchange_plan", "COUNTER_PREFIX"]
 
 #: a step metric under this prefix is a count, not a mean: the loop sums it
 #: over the epoch's steps and journals it in the period's ``counters``
@@ -221,6 +222,55 @@ def fwd_bwd_plan(model, num_workers: int, grad_chunk: Optional[int] = None, *,
                                   f"divides the slab of {slab}"}
     return {"packed": True, "workers_per_pack": workers,
             "packs_per_slab": slab // workers}
+
+
+def exchange_plan(communicator: Communicator, flattener: WorkerFlattener, *,
+                  overlap: str = "off", staleness: int = 1,
+                  faults: bool = False, elastic: bool = False) -> dict:
+    """Where ``make_train_step`` runs the exchange, and why: what the
+    journal's ``backend`` event carries in its ``exchange`` record beside
+    the form (``parallel.gossip.dense_exchange_form``).
+
+    ``layout`` ``leaves``: the step hands the updated parameter tree to the
+    communicator's ``leaves_step``; every leaf ``leaf_views`` takes
+    (``leaves_in_place`` of them) is mixed where it lies, the others
+    (``small_buffer_elements``, all workers') as one small flat vector, and
+    the disagreement comes from the squares the same pass returns.
+    ``kernel_sites`` counts the Pallas kernels the step's program holds for
+    it: one a distinct leaf shape and one for the small buffer.  ``flat``:
+    the step builds the ``[N, D]`` state, runs ``step`` on it and un-builds
+    it.  Separated at the level of the step, and not adapted: the leaf form
+    is the eager exchange of the dense decen communicator where that is the
+    streamed pass, and whatever keeps rows of the flat state as its own data
+    (the pending delta, the ring, CHOCO's carry, quarantine and heal) keeps
+    the flat step; ``reason`` names the first such thing.  The two share the
+    construction of ``W_t`` and nothing else.
+    """
+    n = flattener.num_workers
+    # a flat step whose exchange is the streamed pass holds that one kernel
+    plan = {"layout": "flat",
+            "kernel_sites": int(communicator.leaves_step is not None),
+            "leaves_in_place": 0, "small_buffer_elements": 0}
+    for blocked, reason in (
+            (communicator.leaves_step is None,
+             communicator.leaves_refusal
+             or f"communicator '{communicator.name}' carries flat state"),
+            (staleness > 1,
+             f"the staleness ring ages [N, {staleness}, D] deltas"),
+            (overlap != "off", "overlap parks the exchange as an [N, D] delta"),
+            (faults, "a fault plan heals and quarantines rows of the flat state"),
+            (elastic, "elastic membership masks rows of the flat state")):
+        if blocked:
+            return {**plan, "reason": reason}
+    views = leaf_views(n, flattener.shapes, flattener.dtypes)
+    in_place = [(view[:2], size) for view, size in zip(views, flattener.sizes)
+                if not isinstance(view, str)]
+    if not in_place:
+        return {**plan, "reason": "no leaf passes the shape rule"}
+    small = n * (flattener.dim - sum(size for _, size in in_place))
+    return {"layout": "leaves",
+            "kernel_sites": len({rc for rc, _ in in_place}) + (small > 0),
+            "leaves_in_place": len(in_place), "small_buffer_elements": small}
 
 
 def make_train_step(
@@ -456,6 +506,10 @@ def make_train_step(
 
     slab_grads = packed_slab_grads if plan["packed"] else jax.vmap(grad_fn)
 
+    on_leaves = exchange_plan(
+        communicator, flattener, overlap=overlap, staleness=staleness,
+        faults=faults is not None, elastic=elastic)["layout"] == "leaves"
+
     def all_grads(params, batch_stats, xb, yb, rngs):
         if own_loss:
             # one worker after another, and no vmap: under one, a ``cond``
@@ -495,8 +549,9 @@ def make_train_step(
                                                   state.params)
             params = optax.apply_updates(state.params, updates)
 
-        # consensus transform on the flattened parameter stack
-        flat = flattener.flatten(params)
+        # consensus transform on the flattened parameter stack, or, where
+        # the plan says so, on the leaves where they lie (no flat copy)
+        flat = None if on_leaves else flattener.flatten(params)
         t = jnp.minimum(state.step, flags_arr.shape[0] - 1)
         comm_carry = state.comm_carry
         mix_pending = state.mix_pending
@@ -659,13 +714,34 @@ def make_train_step(
                     gate=row_finite)
 
             with device_span("comm/step"):
-                if do_mix is None:
+                if on_leaves:
+                    def _leaves_mix(ls, c):
+                        return communicator.leaves_step(ls, c, comm_flags_t)
+
+                    leaves = flattener.treedef.flatten_up_to(params)
+                    if do_mix is None:
+                        leaves, carry, sq_rows = _leaves_mix(leaves, comm_carry)
+                    else:
+                        # a thinned step mixes nothing and still measures
+                        leaves, carry, sq_rows = jax.lax.cond(
+                            do_mix, _leaves_mix,
+                            lambda ls, c: (ls, c, worker_square_rows(ls)),
+                            leaves, comm_carry)
+                elif do_mix is None:
                     flat, carry = _eager_mix(flat, comm_carry)
                 else:
                     flat, carry = jax.lax.cond(
                         do_mix, _eager_mix, lambda f, c: (f, c),
                         flat, comm_carry)
-        params = flattener.unflatten(flat)
+        if on_leaves:
+            params = flattener.treedef.unflatten(leaves)
+            # what worker_disagreement and worker_deviation_rows reduce
+            # from the flat state, from the squares the exchange's own pass
+            # summed: sq_rows[i] = sum((x_i - mean_j x_j)^2)
+            disagreement = jnp.sqrt(jnp.sum(sq_rows) / (n * flattener.dim))
+            deviation_rows = jnp.sqrt(sq_rows / flattener.dim)
+        else:
+            params = flattener.unflatten(flat)
         if member is not None:
             # vacant slots are frozen at their leave-time values: the SPMD
             # program computed their updates (static shapes — it cannot
@@ -724,7 +800,8 @@ def make_train_step(
             "loss": _fleet_mean(loss),
             "accuracy": _fleet_mean(outputs["accuracy"] if own_loss
                                     else top_k_accuracy(outputs, yb)),
-            "disagreement": worker_disagreement(flat, alive),
+            "disagreement": (disagreement if on_leaves
+                             else worker_disagreement(flat, alive)),
             "lr": lr_schedule(state.step) if lr_schedule else jnp.asarray(0.0),
             "active_matchings": jnp.sum(flags_arr[t]),
         }
@@ -768,7 +845,8 @@ def make_train_step(
                 # who participated this step, and each row's deviation
                 # from consensus — fused adds like every other counter
                 worker_alive=alive,
-                worker_disagreement=worker_deviation_rows(flat, alive),
+                worker_disagreement=(deviation_rows if on_leaves else
+                                     worker_deviation_rows(flat, alive)),
             )
         return (
             state.replace(

@@ -9,6 +9,18 @@ import os
 os.environ.setdefault("JAX_ENABLE_X64", "0")
 
 import jax
+import pytest
 
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_num_cpu_devices", 8)
+
+
+@pytest.fixture
+def small_leaves(monkeypatch):
+    """The leaves route's shape rule with its size and padding constants
+    opened up, so that the leaves of a test-sized model pass it (what a
+    shape must hold of the tree, ``_LEAF_SHAPE_SHARE``, stays)."""
+    from matcha_tpu.parallel import pallas_gossip
+
+    monkeypatch.setattr(pallas_gossip, "_LEAF_MIN_ELEMENTS", 1)
+    monkeypatch.setattr(pallas_gossip, "_LEAF_PAD_SHARE", 1e9)

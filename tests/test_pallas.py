@@ -17,7 +17,11 @@ from matcha_tpu.parallel import (
     GossipKernelResourceError,
     build_mixing_stack,
     fused_gossip_run,
+    leaf_mix,
     stream_mix,
+    tree_mix,
+    worker_deviation_rows,
+    worker_disagreement,
 )
 from matcha_tpu.schedule import fixed_schedule, matcha_schedule
 
@@ -147,9 +151,15 @@ CHAIN_STATE_ELEMENTS = 16 * 36_547_072
 
 def _kernel_program(kernel, n, wire, dim):
     """``(fn, abstract args)`` of one kernel program at ``[n, dim]``, f32
-    state, compiled (``interpret=False``): one in-place exchange
-    (``stream``) or a ``CHAIN``-step chain (``fused``)."""
+    state, compiled (``interpret=False``): one in-place exchange of the
+    flat state (``stream``) or of one leaf ``[n, r, c]`` with its sums
+    (``leaf``; ``dim`` is ``(r, c)``), or a ``CHAIN``-step chain
+    (``fused``)."""
     f32 = jnp.float32
+    if kernel == "leaf":
+        return (lambda x, w: leaf_mix(x, w, wire_dtype=wire)), \
+            (jax.ShapeDtypeStruct((n,) + dim, f32),
+             jax.ShapeDtypeStruct((n, n), f32))
     x = jax.ShapeDtypeStruct((n, dim), f32)
     if kernel == "stream":
         return (lambda x, w: stream_mix(x, w, wire_dtype=wire)), \
@@ -169,7 +179,21 @@ KERNEL_CASES = [("fused", n, w, RESNET20_DIM)
          # of two, and neither is its pass of 1,280 columns
        ("stream", n, w, CHAIN_STATE_ELEMENTS // n)
        for n in (8, 24, STREAM_MAX_WORKERS) for w in ("f32", "bf16")] \
-    + [("stream", n, "bf16", CHAIN_STATE_ELEMENTS // n) for n in (2, 3)]
+    + [("stream", n, "bf16", CHAIN_STATE_ELEMENTS // n) for n in (2, 3)] \
+    + [  # the leaf form (PR 34) at the cells' leaves as ``leaf_view`` reads
+         # them: Mellum's experts collapsed and its head, the
+         # sparse-attention cell's head lanes-first and its experts, a
+         # router lanes-first, cell 1's 640-wide stage and its 320-wide one
+         # in 384 lanes (a chunk and a tail at N = 16, one tail at N = 8);
+         # then an odd N and the loop over the terms j above 4 workers
+       ("leaf", 2, "f32", (18432, 896)), ("leaf", 2, "bf16", (2304, 12288)),
+       ("leaf", 2, "f32", (18992, 2048)), ("leaf", 2, "f32", (6144, 2048)),
+       ("leaf", 2, "f32", (64, 2304)),
+       ("leaf", 16, "f32", (5760, 640)), ("leaf", 16, "bf16", (5760, 640)),
+       ("leaf", 16, "f32", (2880, 320)), ("leaf", 8, "f32", (2880, 320)),
+       ("leaf", 3, "f32", (1000, 384)),
+       ("leaf", STREAM_MAX_WORKERS, "f32", (4096, 1024)),
+       ("leaf", STREAM_MAX_WORKERS, "bf16", (4096, 1024))]
 
 
 @pytest.mark.parametrize("kernel,n,wire,dim", KERNEL_CASES)
@@ -240,6 +264,58 @@ def _step_readings(packed: bool, n: int, sharding) -> dict:
             "activation_lanes": lanes}
 
 
+#: one layer's share of the Mellum cell's tree at N = 2, with the embedding
+#: and the head (PERF.md section 4): 2 x 100.6 M elements, 0.8 GB
+TOKEN_TREE = {
+    "embed": (12288, 2304), "head": (2304, 12288),
+    "up": (8, 2304, 896), "gate": (8, 2304, 896), "down": (8, 896, 2304),
+    "q": (2304, 512), "o": (512, 2304), "k": (2304, 128), "v": (2304, 128),
+    "router": (2304, 64), "norm_a": (2304,), "norm_b": (2304,),
+}
+
+
+def _exchange_readings(on: str, sharding) -> dict:
+    """Compile the update, the exchange and the disagreement's sums over
+    ``TOKEN_TREE`` at N = 2 for the described device, on the leaves or
+    through the flat state as ``train/state.py:step`` has each, and read
+    XLA's own counts."""
+    import optax
+
+    from matcha_tpu.ops import WorkerFlattener
+    from matcha_tpu.train.state import make_optimizer
+
+    n = 2
+    spec = lambda shape: jax.ShapeDtypeStruct((n,) + shape, jnp.float32,
+                                              sharding=sharding)
+    params = {name: spec(shape) for name, shape in TOKEN_TREE.items()}
+    optimizer = make_optimizer(lambda step: 0.001)
+    opt_state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        jax.eval_shape(optimizer.init, params))
+    flattener = WorkerFlattener(params)
+
+    def step(params, opt_state, grads, w):
+        updates, opt_state = optimizer.update(grads, opt_state, params)
+        params = optax.apply_updates(params, updates)
+        if on == "leaves":
+            leaves, sq = tree_mix(flattener.treedef.flatten_up_to(params), w)
+            return (flattener.treedef.unflatten(leaves), opt_state,
+                    jnp.sqrt(jnp.sum(sq) / (n * flattener.dim)),
+                    jnp.sqrt(sq / flattener.dim))
+        flat = stream_mix(flattener.flatten(params), w)
+        return (flattener.unflatten(flat), opt_state,
+                worker_disagreement(flat), worker_deviation_rows(flat))
+
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+        params, opt_state, params,
+        jax.ShapeDtypeStruct((n, n), jnp.float32, sharding=sharding)).compile()
+    return {"on": on, "state_bytes": 4 * n * flattener.dim,
+            "kernels": compiled.as_text().count(
+                'custom_call_target="tpu_custom_call"'),
+            "bytes_accessed": compiled.cost_analysis()["bytes accessed"],
+            "temp_bytes": compiled.memory_analysis().temp_size_in_bytes}
+
+
 def _compile_all_for_v5e() -> int:
     """Child-process body of the tests below: compile every kernel case,
     and one pack of cell 2 packed and per worker, for one device of a
@@ -265,16 +341,18 @@ def _compile_all_for_v5e() -> int:
         fn, args = _kernel_program(*case)
         args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
                 for a in args]
-        donate = (0,) if case[0] == "stream" else ()
+        donate = (0,) if case[0] in ("stream", "leaf") else ()
         compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
-        if case[0] == "stream":
+        if donate:
             # in place: no product, no second state-sized buffer
             assert "convolution(" not in compiled.as_text(), case
-            state = 4 * args[0].shape[0] * args[0].shape[1]
+            state = 4 * int(np.prod(args[0].shape))
             assert compiled.memory_analysis().temp_size_in_bytes < state // 8
         print("COMPILED", *case)
     for packed in (True, False):
         print("STEP", json.dumps(_step_readings(packed, PACK_WORKERS, sharding)))
+    for on in ("leaves", "flat"):
+        print("EXCHANGE", json.dumps(_exchange_readings(on, sharding)))
     return 0
 
 
@@ -323,6 +401,26 @@ def test_packed_step_compiles_for_v5e_with_full_lanes(v5e_child):
     assert min(pack["activation_lanes"].values()) >= 128, pack
     assert pack["temp_bytes"] < 4e9 / 8
     assert pack["bytes_accessed"] < 0.5 * per_worker["bytes_accessed"]
+
+
+def test_exchange_on_the_leaves_compiles_for_v5e_with_half_the_bytes(v5e_child):
+    """Update, exchange and the disagreement's sums over a token-cell-shaped
+    tree at N = 2, as the v5e's compiler counts them (nothing runs, so no
+    time): on the leaves, the five leaves whose shapes hold 1/32 of the tree
+    in place (four shapes: ``up`` and ``gate`` share a site) and the other
+    seven as one small buffer, under half the bytes of the flat path and no
+    state-sized temporary; the flat path holds the flat copy."""
+    import json
+
+    leaves, flat = (json.loads(line.split(" ", 1)[1])
+                    for line in v5e_child.splitlines()
+                    if line.startswith("EXCHANGE "))
+    assert (leaves["on"], flat["on"]) == ("leaves", "flat")
+    assert (leaves["kernels"], flat["kernels"]) == (6, 1)
+    assert leaves["bytes_accessed"] < 0.5 * flat["bytes_accessed"], (leaves,
+                                                                     flat)
+    assert leaves["temp_bytes"] < leaves["state_bytes"] // 8
+    assert flat["temp_bytes"] >= flat["state_bytes"]
 
 
 def test_kernel_blocks_that_cannot_fit_are_refused_by_name():

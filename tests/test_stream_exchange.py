@@ -315,9 +315,15 @@ def test_backend_event_names_the_form_that_compiled(tmp_path, backend, form):
     if form is None:
         assert "exchange" not in events[0]
     else:
+        # the form, and since PR 34 where the step runs it: this model's
+        # leaves are under the shape rule's size, so on the flat state, which
+        # holds the streamed pass's one kernel
         assert events[0]["exchange"] == {
             "form": form, "n": 8, "single_chip": True,
-            "crossover": STREAM_MAX_WORKERS}
+            "crossover": STREAM_MAX_WORKERS, "layout": "flat",
+            "kernel_sites": 1, "leaves_in_place": 0,
+            "small_buffer_elements": 0,
+            "reason": "no leaf passes the shape rule"}
     # a mesh keeps the product at every N
     sched = build_schedule(config, 4)
 
