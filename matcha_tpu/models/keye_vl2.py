@@ -61,7 +61,8 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..utils.profiling import device_span
 from .mellum2 import (MOE_COUNTERS, TokenDecoder, _head_loss, _moe,
-                      _next_ids, _rms_norm, _rope, _visible, rope_inv_freq)
+                      _next_ids, _rms_norm, _rope, _visible,
+                      attention_weights, expert_weights, rope_inv_freq)
 
 __all__ = ["KeyeVL2"]
 
@@ -232,9 +233,9 @@ class KeyeVL2(TokenDecoder):
         z = self.sizes
         hid, heads, di = z["hidden"], z["indexer_heads"], \
             z["indexer_head_dim"]
-        self.declare(z["num_layers"], {"idx_wq": (hid, heads * di),
-                                       "idx_wk": (hid, di),
-                                       "idx_ww": (hid, heads)})
+        self.declare([{**attention_weights(z), **expert_weights(z),
+                       "idx_wq": (hid, heads * di), "idx_wk": (hid, di),
+                       "idx_ww": (hid, heads)}] * z["num_layers"])
 
     def hidden(self, ids, docs):
         """(the final norm's output ``[B, S, H]``, the layers' counters

@@ -39,6 +39,13 @@ no ``[B, S, V]`` array of all positions exists.
 Matrix products run at the MXU's default precision, but the router's, which
 decides a discrete choice, at ``highest``; norms, RoPE, softmaxes and the
 loss are float32.
+
+**What the token models share** lives here: :class:`TokenDecoder` (the
+embedding, the final norm, the untied head, the counters' sums), the expert
+layer, the head and its loss.  ``TokenDecoder.declare`` takes one parameter
+set a layer (:func:`attention_weights`, :func:`expert_weights` are this
+model's), so a model's layers may be of kinds with different ones
+(``models/qwen3_next.py``: linear and softmax attention 3:1).
 """
 
 from __future__ import annotations
@@ -54,7 +61,8 @@ from jax import lax
 
 from ..utils.profiling import device_span
 
-__all__ = ["Mellum2", "TokenDecoder", "rope_inv_freq", "moe_capacity"]
+__all__ = ["Mellum2", "TokenDecoder", "rope_inv_freq", "moe_capacity",
+           "attention_weights", "expert_weights"]
 
 INIT_STD = 0.02
 #: rows of the grouped expert products over the slots an even router would
@@ -350,9 +358,32 @@ def _head_loss(h, head, targets, sizes):
     return loss, jnp.sum(hits) / positions, judged
 
 
+def attention_weights(z) -> dict:
+    """``{name: shape | (init, shape)}`` of a softmax-attention layer's own
+    parameters, in the order they are declared."""
+    hid, d = z["hidden"], z["head_dim"]
+    return {"attn_norm": (nn.initializers.ones, (hid,)),
+            "wq": (hid, z["q_heads_held"] * d),
+            "wk": (hid, z["kv_heads_held"] * d),
+            "wv": (hid, z["kv_heads_held"] * d),
+            "wo": (z["q_heads_held"] * d, hid)}
+
+
+def expert_weights(z, norm_init=nn.initializers.ones) -> dict:
+    """As :func:`attention_weights`, of the expert layer every token model
+    shares (:func:`_moe`)."""
+    hid, width, held = z["hidden"], z["expert_width"], len(z["experts_held"])
+    return {"moe_norm": (norm_init, (hid,)),
+            "router": (hid, z["num_experts"]),
+            "gate": (held, hid, width),
+            "up": (held, hid, width),
+            "down": (held, width, hid)}
+
+
 class TokenDecoder(nn.Module):
     """What the token models share around their blocks.  A subclass declares
-    its parameters in ``setup`` (:meth:`declare`) and gives ``hidden(ids,
+    its parameters in ``setup`` (:meth:`declare`: a parameter set a layer,
+    so layers may be of kinds with different ones) and gives ``hidden(ids,
     docs)`` and ``batch_loss(x_raw, y_raw)``."""
 
     sizes: Any
@@ -363,33 +394,30 @@ class TokenDecoder(nn.Module):
     #: (a ``vmap`` would turn the expert layer's ``cond`` into both branches)
     supplies_loss = True
 
-    def declare(self, num_layers, more=None):
-        """``embed``, ``layer<n>_<name>`` of the attention and the expert
-        layer (and of ``more``: ``{name: shape}`` of normal weights), the
-        final norm and the untied head."""
+    #: the final norm and its weight's initial value: ``x * w`` from 1 here;
+    #: a model whose norms are ``x * (1 + w)`` from 0 gives its own
+    norm = staticmethod(_rms_norm)
+    norm_init = staticmethod(nn.initializers.ones)
+
+    def declare(self, layers):
+        """``embed``, then ``layer<n>_<name>`` for each name of
+        ``layers[n]`` (``{name: shape}`` of normal weights, or ``{name:
+        (init, shape)}``) in its order, the final norm and the untied head.
+        The order is part of the model: a parameter's initial value follows
+        its place among the calls."""
         z = self.sizes
-        hid, d, width = z["hidden"], z["head_dim"], z["expert_width"]
-        held = len(z["experts_held"])
+        hid = z["hidden"]
         normal = nn.initializers.normal(INIT_STD)
-        ones = nn.initializers.ones
         self.embed = self.param("embed", normal, (z["vocab_held"], hid))
-        shapes = {
-            "attn_norm": (ones, (hid,)),
-            "wq": (normal, (hid, z["q_heads_held"] * d)),
-            "wk": (normal, (hid, z["kv_heads_held"] * d)),
-            "wv": (normal, (hid, z["kv_heads_held"] * d)),
-            "wo": (normal, (z["q_heads_held"] * d, hid)),
-            "moe_norm": (ones, (hid,)),
-            "router": (normal, (hid, z["num_experts"])),
-            "gate": (normal, (held, hid, width)),
-            "up": (normal, (held, hid, width)),
-            "down": (normal, (held, width, hid)),
-            **{k: (normal, shape) for k, shape in (more or {}).items()},
-        }
-        self.layers = [{k: self.param(f"layer{n}_{k}", init, shape)
-                        for k, (init, shape) in shapes.items()}
-                       for n in range(num_layers)]
-        self.final_norm = self.param("final_norm", ones, (hid,))
+
+        def declared(name, spec):
+            init, shape = spec if callable(spec[0]) else (normal, spec)
+            return self.param(name, init, shape)
+
+        self.layers = [{k: declared(f"layer{n}_{k}", spec)
+                        for k, spec in weights.items()}
+                       for n, weights in enumerate(layers)]
+        self.final_norm = self.param("final_norm", self.norm_init, (hid,))
         self.head = self.param("head", normal, (hid, z["vocab_held"]))
 
     def dummy_input(self, input_shape):
@@ -401,7 +429,7 @@ class TokenDecoder(nn.Module):
         ``summed`` and ``moe_load[layer, expert held]`` stacked)."""
         total = {k: sum(c[k] for c in counters) for k in summed}
         total["moe_load"] = jnp.stack([c["moe_load"] for c in counters])
-        return _rms_norm(h, self.final_norm,
+        return self.norm(h, self.final_norm,
                          self.sizes["rms_norm_eps"]), total
 
     def logits(self, ids, docs):
@@ -420,7 +448,9 @@ class Mellum2(TokenDecoder):
     (README "Training a language model" lists the keys)."""
 
     def setup(self):
-        self.declare(len(self.sizes["layer_types"]))
+        z = self.sizes
+        self.declare([{**attention_weights(z), **expert_weights(z)}]
+                     * len(z["layer_types"]))
 
     def hidden(self, ids, docs):
         """(the final norm's output ``[B, S, H]``, the expert layers'

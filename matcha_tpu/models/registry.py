@@ -10,8 +10,10 @@ here the class count is derived from the dataset unless overridden.
 Also registers explicit names the reference cannot express: ``resnet20``
 (BASELINE.json's model), ``resnet32/44/56/110``, ``vgg11/13/19``, and the
 sparse decoders for next-token training whose sizes come as ``sizes={...}``:
-``mellum2`` (``models/mellum2.py``) and ``keye_vl2``, whose attention reads
-a learned choice of keys (``models/keye_vl2.py``).
+``mellum2`` (``models/mellum2.py``), ``keye_vl2``, whose attention reads a
+learned choice of keys (``models/keye_vl2.py``), and ``qwen3_next``, three
+layers of linear attention to one of gated softmax attention
+(``models/qwen3_next.py``).
 """
 
 from __future__ import annotations
@@ -23,6 +25,7 @@ import flax.linen as nn
 from .keye_vl2 import KeyeVL2
 from .mellum2 import Mellum2
 from .mlp import MLP
+from .qwen3_next import Qwen3Next
 from .resnet import ResNet, ResNetImageNet
 from .vgg import VGG
 from .wrn import WideResNet
@@ -50,7 +53,8 @@ DATASET_SHAPES = {
 }
 
 
-TOKEN_MODELS = {"mellum2": Mellum2, "keye_vl2": KeyeVL2}
+TOKEN_MODELS = {"mellum2": Mellum2, "keye_vl2": KeyeVL2,
+                "qwen3_next": Qwen3Next}
 
 
 def dataset_num_classes(dataset: str) -> int:

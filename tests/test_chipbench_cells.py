@@ -1,4 +1,4 @@
-"""The two token cells rehearsed tiny on the CPU from ``stage_job`` to
+"""The three token cells rehearsed tiny on the CPU from ``stage_job`` to
 ``check.compare``, as ``chipbench/tests/test_cells_on_cpu.py`` rehearses
 every cell of ``BENCHMARK.json`` outside tier-1 (the two conv cells take a
 minute and half a minute there; these fit here), and the control that has
@@ -18,6 +18,8 @@ CELLS = {
         "moe_load_max_over_mean", "moe_slot_fill_pct", "loss_positions_pct"},
     "keye-vl2-30b-a3b.ep16-s8k.w2-matcha": {
         "dsa_selecting_pct", "dsa_keys_kept_pct", "dsa_indexer_kl"},
+    "qwen3-next-80b-a3b.ep64-s8k.w2-matcha": {
+        "gdn_chunks_reset_pct", "gdn_decay_mean"},
 }
 COUNTER_METRICS = sorted(set().union(*CELLS.values()))
 
@@ -60,6 +62,9 @@ def test_counters_fill_the_cells_own_metrics(cell, line):
         assert 0 < got["moe_slot_fill_pct"] <= 100
         assert 1 <= got["moe_load_max_over_mean"] <= 2  # 2 experts held
         assert 90 < got["loss_positions_pct"] <= 100
+    elif "gdn_decay_mean" in mine:  # 8 chunks a row, a start in a few
+        assert 0 < got["gdn_chunks_reset_pct"] < 50
+        assert 0 < got["gdn_decay_mean"] < 1
     else:  # 64 positions, 16 kept: most queries see more than they keep
         assert 0 < got["dsa_selecting_pct"] < 100
         assert 0 < got["dsa_keys_kept_pct"] < 100

@@ -190,7 +190,8 @@ def test_evaluation_gives_held_out_loss_and_token_accuracy(run):
 
 @pytest.mark.parametrize("conf_name, cell", [
     ("mellum2-12b-a2.5b.ep8-s4k", "mellum2-12b-a2.5b.ep8-s4k.w2-matcha"),
-    ("keye-vl2-30b-a3b.ep16-s8k", "keye-vl2-30b-a3b.ep16-s8k.w2-matcha")])
+    ("keye-vl2-30b-a3b.ep16-s8k", "keye-vl2-30b-a3b.ep16-s8k.w2-matcha"),
+    ("qwen3-next-80b-a3b.ep64-s8k", "qwen3-next-80b-a3b.ep64-s8k.w2-matcha")])
 def test_job_file_hands_the_program_the_configurations_sizes(conf_name, cell):
     """The harness passes ``TrainConfig`` fields only, so a token cell's job
     file repeats the configuration's sizes: they must not drift apart."""
@@ -213,6 +214,18 @@ def test_job_file_hands_the_program_the_configurations_sizes(conf_name, cell):
     if "layer_types" in sizes:
         assert (conf["num_hidden_layers"], conf["sliding_window"]) == (
             len(sizes["layer_types"]), sizes["sliding_window"])
+    elif "gdn_chunk" in sizes:
+        assert (conf["num_hidden_layers"], conf["full_attention_interval"],
+                conf["rope_theta"], conf["linear_num_key_heads"],
+                conf["linear_num_value_heads"], conf["linear_key_head_dim"],
+                conf["linear_value_head_dim"], conf["linear_conv_kernel_dim"],
+                conf["shared_expert_intermediate_size"],
+                conf["partial_rotary_factor"] * conf["head_dim"]) == (
+            sizes["num_layers"], sizes["full_attention_interval"],
+            sizes["rope_theta"], sizes["linear_key_heads_held"],
+            sizes["linear_value_heads_held"], sizes["linear_key_dim"],
+            sizes["linear_value_dim"], sizes["conv_kernel"],
+            sizes["shared_expert_width"], sizes["rotary_dim"])
     else:
         indexer = conf["sa_config"]
         assert (conf["num_hidden_layers"], conf["rope_theta"],
