@@ -41,7 +41,16 @@ T11, T22]]``, from blocks of 1 up to the chunk in ``log2(chunk)`` levels of
 batched products.  It is exact like row-by-row substitution, without its
 ``chunk`` sequential passes; the product form over ``A``'s powers of two
 cancels where keys repeat (all-ones ``A``: ``A^32`` holds 1e17 against an
-inverse of 0s and 1s) and was not taken (PERF.md section 4).
+inverse of 0s and 1s) and was not taken (PERF.md section 4).  The backward
+pass keeps ``T`` and nothing of the levels that built it: ``dT = -T dA T``,
+so a cotangent ``G`` of ``T`` is ``-T^T G T^T`` of ``A``, two products of the
+``T`` the forward pass has (``_unit_lower_inverse``'s ``custom_vjp``).  Under
+``remat`` a layer is computed twice (the block's checkpoint keeps nothing)
+and its chunk systems, convolution and gated norm lie under checkpoints of
+their own inside it; the chunk systems' keeps ``T`` by name (``KEPT``), so
+its third pass inverts nothing and is left the decays ``D`` and the two Gram
+products ``K K^T`` and ``Q K^T``, which cost less computed again than kept
+(PERF.md section 6, PR 36).
 
 **Gated full attention**: ``wq`` gives ``2 head_dim`` a head, query and gate;
 ``q = norm0(query)``, ``k = norm0(x W_k)`` a head; RoPE on the first
@@ -79,6 +88,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from ..utils.profiling import device_span
 from .keye_vl2 import _rope_tables
@@ -92,6 +102,11 @@ HIGHEST = lax.Precision.HIGHEST
 GDN_COUNTERS = ("gdn_chunks", "gdn_chunks_reset", "gdn_gates",
                 "gdn_decay_sum")
 L2_EPS = 1e-6
+#: what the checkpoint around a layer's chunk systems keeps by name for its
+#: backward pass: a chunk's inverse (the module docstring says why no more)
+KEPT = "gdn_inverse"
+#: the name scope of the inverse's levels (a test counts the products in it)
+LEVELS = "gdn_inverse_levels"
 
 
 def _norm0(x, w, eps):
@@ -133,7 +148,8 @@ def _causal_conv(x, taps, docs):
     return y
 
 
-def _unit_lower_inverse(a):
+@jax.named_scope(LEVELS)
+def _by_halves(a):
     """``(I + a)^-1`` of strictly lower-triangular ``a[..., C, C]``, ``C`` a
     power of two, by halves: the diagonal blocks of size ``m`` are inverted
     from those of size ``m / 2`` and the quarter between them."""
@@ -154,7 +170,27 @@ def _unit_lower_inverse(a):
             jnp.concatenate([t11, jnp.zeros_like(t11)], axis=-1),
             jnp.concatenate([low, t22], axis=-1)], axis=-2)
         half *= 2
-    return t.reshape(lead + (c, c))
+    return checkpoint_name(t.reshape(lead + (c, c)), KEPT)
+
+
+@jax.custom_vjp
+def _unit_lower_inverse(a):
+    """``T = (I + a)^-1`` as :func:`_by_halves` builds it, differentiated
+    through ``T`` itself and not through the levels: ``dT = -T da T``, so a
+    cotangent ``G`` of ``T`` is ``-T^T G T^T`` of ``a``."""
+    return _by_halves(a)
+
+
+def _inverse_fwd(a):
+    t = _by_halves(a)
+    return t, t
+
+
+def _inverse_bwd(t, g):
+    return (-_exact("...ji,...jk,...lk->...il", t, g, t),)
+
+
+_unit_lower_inverse.defvjp(_inverse_fwd, _inverse_bwd)
 
 
 def _chunk_prep(q, k, v, beta, g, docs, chunk):
@@ -250,10 +286,10 @@ def _gate_norm(o, z, w, eps):
 
 def _gated_delta_net(p, h, docs, sizes, again=lambda f: f):
     """(the layer's output ``[B, S, H]`` of the residual stream ``h``, its
-    counters).  ``again`` (``jax.checkpoint`` under ``remat``) wraps what is
-    cheap to compute a third time and holds many row-sized arrays: the
-    convolution's shifted copies, the chunks' decays and the levels of their
-    inverse, the gated norm."""
+    counters).  ``again`` (``jax.checkpoint`` under ``remat``, keeping what
+    ``KEPT`` names) wraps what holds many row-sized arrays and is cheap to
+    compute a third time: the convolution's shifted copies, the gated norm,
+    and of the chunk systems all but the inverse."""
     b, s, _ = h.shape
     hk, hv = sizes["linear_key_heads_held"], sizes["linear_value_heads_held"]
     dk, dv = sizes["linear_key_dim"], sizes["linear_value_dim"]
@@ -371,11 +407,21 @@ def _experts_of(p, h, sizes):
     return y, counters
 
 
+#: ``jax.checkpoint`` as the checkpoints inside a Gated DeltaNet layer run it
+#: (the chunk systems' is the one that holds the name)
+_again_keeping = functools.partial(
+    jax.checkpoint,
+    policy=jax.checkpoint_policies.save_only_these_names(KEPT))
+
+
 def _block(p, h, docs, kind, sizes, remat):
     again = jax.checkpoint if remat else (lambda f: f)
     if kind == "linear":
+        # the layer's own checkpoint keeps nothing; those inside it keep
+        # what is named ``KEPT``
         out, counters = again(functools.partial(
-            _gated_delta_net, sizes=sizes, again=again))(p, h, docs)
+            _gated_delta_net, sizes=sizes,
+            again=_again_keeping if remat else again))(p, h, docs)
     else:
         out, counters = _gated_attention(p, h, docs, sizes, again), {}
     h = h + out
@@ -427,6 +473,13 @@ class Qwen3Next(TokenDecoder):
                    "shared_down": (width, hid), "shared_sigmoid": (hid,)}
         self.declare([{**mixer_weights(kind, z), **experts}
                       for kind in layer_kinds(z)])
+
+    @property
+    def remat_keeps(self):
+        """What the chunk systems' checkpoint keeps by name: the journal's
+        ``fwd_bwd`` event carries it (``train/state.py:fwd_bwd_plan``)."""
+        linear = "linear" in layer_kinds(self.sizes)
+        return (KEPT,) if self.remat and linear else ()
 
     def dummy_input(self, input_shape):
         """What ``init`` traces: one row of one whole chunk."""

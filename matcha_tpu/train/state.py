@@ -200,10 +200,15 @@ def fwd_bwd_plan(model, num_workers: int, grad_chunk: Optional[int] = None, *,
     heal rely on; a model that already fills the lanes would pay P times
     the FLOPs for nothing; and a loop over packs cannot run over a worker
     axis that a mesh shards (``worker_shards`` devices).  ``reason`` names
-    the first condition that keeps ``vmap`` over workers.
+    the first condition that keeps ``vmap`` over workers.  ``remat_keeps``,
+    where the model has one: what its inner checkpoints keep by name for the
+    backward pass (``models/qwen3_next.py``).
     """
     slab = grad_chunk or num_workers
     plan = {"packed": False, "workers_per_pack": 1, "packs_per_slab": slab}
+    keeps = getattr(model, "remat_keeps", ())
+    if keeps:
+        plan["remat_keeps"] = list(keeps)
     width = getattr(model, "pack_width", None)
     if width is None:
         return {**plan, "reason": f"{type(model).__name__} has no packed form"}

@@ -199,6 +199,118 @@ def test_unit_lower_inverse_where_keys_repeat():
         rtol=1e-4, atol=1e-4)
 
 
+def _lower(*shape, rng=np.random.default_rng(5)):
+    return np.tril(rng.normal(size=shape), -1)
+
+
+#: strictly lower-triangular ``a[..., C, C]`` a case: random at three sizes,
+#: one key repeated (all ones), and leading dimensions
+INVERSE_CASES = {"C4": _lower(3, 4, 4), "C16": _lower(3, 16, 16),
+                 "C64": 0.3 * _lower(2, 64, 64),
+                 "keys_repeat": np.tril(np.ones((1, 64, 64)), -1),
+                 "leading_dimensions": _lower(2, 3, 2, 16, 16)}
+
+
+@pytest.mark.parametrize("case", list(INVERSE_CASES))
+def test_inverse_is_differentiated_through_itself_as_through_its_levels(case):
+    """``-T^T G T^T`` (``_unit_lower_inverse``'s rule) against autodiff
+    through the construction by halves, below the diagonal, where ``a`` has
+    its entries and ``_chunk_prep``'s ``where`` lets the cotangent through;
+    the values themselves bit for bit."""
+    a = jnp.asarray(INVERSE_CASES[case], jnp.float32)
+    probe = jnp.asarray(np.random.default_rng(6).normal(size=a.shape),
+                        jnp.float32)
+    below = np.tril(np.ones(a.shape[-2:], bool), -1)
+    with jax.default_matmul_precision("highest"):
+        got, want = (
+            jax.jit(jax.grad(lambda a, f=f: jnp.sum(f(a) * probe)))(a)
+            for f in (qwen3_next._unit_lower_inverse, qwen3_next._by_halves))
+        np.testing.assert_array_equal(
+            jax.jit(qwen3_next._unit_lower_inverse)(a),
+            jax.jit(qwen3_next._by_halves)(a))
+    assert not np.any(np.where(below, 0.0, want))  # the levels read no more
+    close(jnp.where(below, got, 0.0), want, tol=2e-6)
+
+
+AGAINS = {"checkpoint": jax.checkpoint, "keeping": qwen3_next._again_keeping}
+
+
+@pytest.mark.parametrize("again", list(AGAINS))
+def test_gradients_under_checkpoints_equal_those_without(again):
+    """The chunk systems under a checkpoint of their own inside the layer's
+    (plain, and keeping ``KEPT`` by name as ``_block`` runs them): the
+    gradient by every input equals the unwrapped rule's and the
+    token-by-token recurrence's, document starts inside chunks."""
+    inputs, docs = delta_rule_inputs()
+    probe = jnp.asarray(np.random.default_rng(9).normal(
+        size=inputs[2].shape), jnp.float32)
+    rule = lambda again: lambda *a: qwen3_next._gated_delta_rule(
+        *a, docs, 8, again)[0]
+    forms = (jax.checkpoint(rule(AGAINS[again])), rule(lambda f: f),
+             lambda *a: recurrent(*a, docs))
+    with jax.default_matmul_precision("highest"):
+        under, plain, token = (
+            jax.jit(jax.grad(lambda *a, f=f: jnp.sum(f(*a) * probe),
+                             argnums=tuple(range(5))))(*inputs)
+            for f in forms)
+    for name, got, same, want in zip("q k v beta g".split(), under, plain,
+                                     token):
+        close(got, same, tol=1e-6, name=name)
+        close(got, want, tol=1e-5, name=name)
+
+
+def level_products(jaxpr):
+    """How many ``dot_general`` equations the program holds under the
+    inverse's name scope (the levels' own and whatever autodiff derives
+    from them), sub-programs included."""
+    def walk(jaxpr):
+        found = 0
+        for eqn in jaxpr.eqns:
+            found += eqn.primitive.name == "dot_general" \
+                and qwen3_next.LEVELS in str(eqn.source_info.name_stack)
+            for value in eqn.params.values():
+                for sub in value if isinstance(value, (list, tuple)) \
+                        else [value]:
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        found += walk(sub)
+        return found
+
+    return walk(jaxpr.jaxpr)
+
+
+@pytest.mark.parametrize("remat, inversions", [(True, 2), (False, 1)])
+def test_a_layers_gradient_inverts_twice_and_transposes_no_level(
+        remat, inversions, monkeypatch):
+    """The program of a linear layer's gradient: under ``remat`` the levels
+    of a chunk's inverse are built in the forward pass and in the layer's
+    recomputation and not a third time (the chunk systems' checkpoint keeps
+    ``T`` by name), and no product is derived from a level (the backward
+    pass is ``T``'s own rule: two products outside the scope).  Without the
+    policy or without the rule the count says so."""
+    sizes = sizes_of()
+    p = layer_weights("linear", sizes)
+    h = jax.random.normal(jax.random.PRNGKey(9), (2, SEQ, 16))
+    docs = rows(2)[1][:, :-1]
+    per_inverse = 2 * int(np.log2(sizes["gdn_chunk"]))
+
+    def program(remat):
+        return jax.make_jaxpr(jax.grad(lambda p, h: jnp.sum(jnp.square(
+            qwen3_next._block(p, h, docs, "linear", sizes, remat)[0])),
+            argnums=(0, 1)))(p, h)
+
+    assert level_products(jax.make_jaxpr(qwen3_next._by_halves)(
+        jnp.zeros((8, 8)))) == per_inverse
+    assert level_products(program(remat)) == inversions * per_inverse
+    if remat:  # what the count reads where an edit drops either half
+        monkeypatch.setattr(qwen3_next, "_again_keeping", jax.checkpoint)
+        assert level_products(program(True)) == 3 * per_inverse
+        monkeypatch.undo()
+        monkeypatch.setattr(qwen3_next, "_unit_lower_inverse",
+                            qwen3_next._by_halves)
+        assert level_products(program(True)) > 3 * per_inverse
+
+
 def test_a_row_of_two_documents_is_the_two_documents_alone():
     """State, convolution and attention alike: logits of a packed row of 13
     + 19 positions equal, position for position, those of each document as a
@@ -419,7 +531,8 @@ def test_counters_equal_a_numpy_count():
 def test_trains_by_name_through_train(tmp_path):
     """``model="qwen3_next"`` on the normal path: the loss falls, nothing
     retraces, the ``gdn_*`` counters ride each period's record beside the
-    expert layer's, and evaluation gives the held-out loss."""
+    expert layer's, the ``fwd_bwd`` event names what the chunk systems'
+    checkpoint keeps, and evaluation gives the held-out loss."""
     from matcha_tpu.train import TrainConfig, train
 
     sizes = sizes_of(hidden=32, expert_width=24, vocab_held=48)
@@ -435,6 +548,8 @@ def test_trains_by_name_through_train(tmp_path):
     losses = [h["loss"] for h in result.history]
     assert np.all(np.isfinite(losses)) and losses[-1] < losses[0]
     assert "retrace" not in [e["kind"] for e in result.recorder.events]
+    (plan,) = [e for e in result.recorder.events if e["kind"] == "fwd_bwd"]
+    assert plan["remat_keeps"] == [qwen3_next.KEPT] and not plan["packed"]
     records = [e for e in result.recorder.events if e["kind"] == "spans"]
     assert len(records) == 3
     rows_an_epoch = 3 * 2 * 2  # steps x workers x rows
