@@ -1,0 +1,11 @@
+"""Device milliseconds a step in a Gated DeltaNet layer's chunk systems: the
+decays, the triangular system and its inverse (``matcha/gdn_chunk_prep``),
+from the traced window's capture joined to the epoch program's own scopes
+(``chipbench/scopes.py``).  None in an untraced run and on a program with no
+device-side reader."""
+
+from chipbench.scopes import scope_ms
+
+
+def read(run):
+    return scope_ms(run, "matcha/gdn_chunk_prep")

@@ -1,0 +1,11 @@
+"""Device milliseconds a step in the output head and the cross-entropy, a
+chunk of positions at a time (``matcha/lm_head_loss``), from the traced
+window's capture joined to the epoch program's own scopes
+(``chipbench/scopes.py``).  None in an untraced run and on a program with no
+device-side reader."""
+
+from chipbench.scopes import scope_ms
+
+
+def read(run):
+    return scope_ms(run, "matcha/lm_head_loss")

@@ -176,13 +176,13 @@ echo "== attribution pytest lane =="
 JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python -m pytest tests/ -q \
     -m attribution -p no:cacheprovider || rc=1
 
-echo "== overlap fixture smoke (pinned fixture overlap) =="
-# the profile renderer on the dbuf trace fixture must reproduce the
-# pinned 95.0% overlap (acceptance floor is >75%)
+echo "== device scopes smoke (the committed v5e capture) =="
+# the profile renderer on the toy program's capture from the chip must name
+# its scopes from the capture's own HLO
 PROFILE_OUT="$(JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python obs_tpu.py \
-    profile tests/fixtures/trace_overlap_1step_dbuf.trace.json.gz)" || rc=1
-grep -q '95.0%' <<<"$PROFILE_OUT" || { \
-    echo "overlap fixture smoke: pinned overlap not reproduced: $PROFILE_OUT"; rc=1; }
+    profile tests/fixtures/v5e_toy.xplane.pb)" || rc=1
+grep -q 'matcha/fwd_bwd' <<<"$PROFILE_OUT" || { \
+    echo "device scopes smoke: no scope named: $PROFILE_OUT"; rc=1; }
 
 echo "== attribution + timeline smoke (committed reference journal) =="
 TRACE_OUT="$(mktemp)"

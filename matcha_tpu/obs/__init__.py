@@ -21,9 +21,12 @@ Plus the *performance* twin (DESIGN.md §15, ISSUE 8):
 * :mod:`costs` — compiled-cost introspection (``cost_analysis`` /
   ``memory_analysis`` of every program the loop builds, journaled as v2
   ``compile`` events) and the automatic roofline / §9 capacity tables.
-* :mod:`xprof` — executed-trace parsing: device-lane phase attribution
-  via the ``comm/*`` / ``matcha/*`` named scopes and the comm/comp
-  overlap fraction (loud when a trace has no device rows).
+* :mod:`xprof` — the device-side reader: a ``jax.profiler`` capture's
+  ``XLA Ops`` rows joined, by the capture's own HLO, to the ``device_span``
+  (``matcha/*`` / ``comm/*``) and the pass each instruction was traced
+  under; the ``device_scopes`` record (device time by program and scope,
+  and the share of ``comm/*`` time under other work; loud when a capture
+  has no device plane).
 
 And the *live* half (DESIGN.md §17, ISSUE 10):
 
@@ -104,7 +107,7 @@ from .journal import (
 )
 from .telemetry import Telemetry, TelemetrySpec, telemetry_flush, telemetry_step
 from .timeline import build_timeline, timeline_for_run, validate_trace
-from .xprof import TraceParseError, overlap_report, profile_report
+from .xprof import TraceParseError, device_scopes, scope_map
 
 __all__ = [
     "ANOMALY_CAUSES",
@@ -132,6 +135,7 @@ __all__ = [
     "count_journal_lines",
     "compose_predicted_rho",
     "critical_path_report",
+    "device_scopes",
     "drift_report",
     "epoch_series",
     "get_fs",
@@ -139,8 +143,6 @@ __all__ = [
     "link_costs_artifact",
     "mad_zscores",
     "make_event",
-    "overlap_report",
-    "profile_report",
     "read_heartbeats",
     "read_journal",
     "read_journal_tail",
@@ -149,6 +151,7 @@ __all__ = [
     "resolve_journal_path",
     "roofline_report",
     "salvage_journal",
+    "scope_map",
     "telemetry_flush",
     "telemetry_step",
     "timeline_for_run",

@@ -43,7 +43,12 @@ __all__ = ["SCHEMA_VERSION", "ACCEPTED_VERSIONS", "EVENT_KINDS",
            "latest_per_epoch", "epoch_series", "append_journal_record"]
 
 #: v2 (ISSUE 8) adds only new kinds — ``compile`` (the cost ledger's
-#: program introspection) and ``profile`` (overlap-truth trace analysis).
+#: program introspection) and ``profile`` (the Chrome-trace overlap reader's
+#: record: retired with that reader in ISSUE 37, nothing writes it, and an
+#: old journal's still validate).  ``device_scopes`` (ISSUE 37) stands
+#: beside ``compile``: device time by program and ``device_span`` from a
+#: profiler capture (``obs.xprof.device_scopes``), journaled by ``train()``
+#: under ``trace_dir`` and by ``obs_tpu.py profile --journal``.
 #: v3 (ISSUE 10) is additive again: ``heartbeat`` (the live health plane's
 #: per-host liveness/progress record, mirrored from the per-host heartbeat
 #: files under ``health/``) and ``anomaly`` (a streaming detector's verdict
@@ -74,7 +79,7 @@ FAULT_KINDS = frozenset({
 #: ``membership`` (ISSUE 9) joins additively: elastic join/leave/rejoin
 #: reconciliations at epoch boundaries, carrying the re-derived α/ρ so
 #: drift replay re-bases exactly where the live monitor did.
-V2_KINDS = frozenset({"compile", "profile", "membership"})
+V2_KINDS = frozenset({"compile", "profile", "device_scopes", "membership"})
 #: Kinds introduced by schema v3 (ISSUE 10) — invalid inside a v1/v2 event
 #: for the same reason.  ``heartbeat`` carries per-host progress + the
 #: per-worker stats the anomaly detectors read; ``anomaly`` carries one
@@ -147,10 +152,17 @@ REQUIRED_FIELDS: Dict[str, frozenset] = {
     # extracted cost/footprint ledger the roofline consumes
     "compile": frozenset({"label", "fingerprint", "compile_seconds",
                           "flops", "hbm_bytes", "peak_bytes"}),
-    # v2: one per parsed profiler trace (obs.xprof) — executed-kernel
-    # phase attribution and the comm/comp overlap fraction
+    # v2, retired (ISSUE 37): what the Chrome-trace overlap reader wrote;
+    # kept so that the journals that hold one validate
     "profile": frozenset({"source", "comm_seconds", "compute_seconds",
                           "overlap_seconds", "overlap_fraction"}),
+    # v2 (ISSUE 37): one per reduced profiler capture (obs.xprof) — the
+    # capture's path, the window, ``programs`` (``{module: {device_s, runs,
+    # ops_s, matched_s, scopes: {scope: {device_s, own_s, ops, by_pass}},
+    # unmatched_top}}``) and the share of ``comm/*`` device time that ran
+    # under other work (None where the window has no ``comm/*`` row)
+    "device_scopes": frozenset({"source", "window_s", "programs",
+                                "overlap_fraction"}),
     # v2 (ISSUE 9): one per elastic-membership reconciliation — the old and
     # new live sets, what triggered the change, and the α/ρ the schedule
     # was re-folded to (``replanned`` False while hysteresis defers the

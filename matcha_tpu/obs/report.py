@@ -28,6 +28,7 @@ def _fmt_bytes(n: Optional[float]) -> str:
 
 
 from .journal import fmt_value as _fmt  # noqa: E402 — shared cell formatter
+from .xprof import main_program  # noqa: E402
 
 
 def summarize(events: List[dict]) -> Dict:
@@ -75,7 +76,8 @@ def summarize(events: List[dict]) -> Dict:
     retrace = [e for e in events if e.get("kind") == "retrace"]
     bench = [e for e in events if e.get("kind") == "bench"]
     compiles = [e for e in events if e.get("kind") == "compile"]
-    profiles = [e for e in events if e.get("kind") == "profile"]
+    profiles = [e for e in events
+                if e.get("kind") in ("profile", "device_scopes")]
     attributions = [e for e in events if e.get("kind") == "attribution"]
     total_bytes = sum(r["wire_bytes"] or 0.0 for r in rows) or None
     return {
@@ -180,8 +182,16 @@ def render_summary(events: List[dict], source: str = "events.jsonl") -> str:
                 f"peak {_fmt_bytes(e.get('peak_bytes'))}")
     for e in digest["profile"]:
         frac = e.get("overlap_fraction")
-        lines.append(f"profile: {os.path.basename(str(e.get('source')))} "
-                     f"overlap {'-' if frac is None else f'{frac:.1%}'}")
+        line = (f"profile: {os.path.basename(str(e.get('source')))} "
+                f"overlap {'-' if frac is None else f'{frac:.1%}'}")
+        if e.get("programs"):  # a device_scopes record
+            name = main_program(e)
+            p = e["programs"][name]
+            line += (f"; {name.split('(')[0]} {_fmt(p['device_s'], 4)} s on "
+                     f"the device, "
+                     f"{100 * p['matched_s'] / max(p['device_s'], 1e-30):.1f}"
+                     f"% under a scope")
+        lines.append(line)
     for e in digest["attribution"]:
         ident = e.get("identifiable") or []
         lines.append(

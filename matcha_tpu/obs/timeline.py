@@ -59,7 +59,7 @@ _MARK_SPANS = {
 _PHASE_TID = 1
 #: journal kinds drawn as instants
 _INSTANTS = {"run_start", "resume", "plan", "drift", "retrace", "anomaly",
-             "bench", "profile", "attribution"}
+             "bench", "profile", "device_scopes", "attribution"}
 
 
 def _ev(name: str, ph: str, ts: float, pid: int, tid: int, src: str,

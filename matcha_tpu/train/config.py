@@ -193,12 +193,13 @@ class TrainConfig:
     # describes); telemetry=False disables it too.
     drift_tolerance: float = 0.25
     drift_patience: int = 2
-    # overlap-truth capture (DESIGN.md §15): when set, exactly one epoch
+    # device time by scope (DESIGN.md §15): when set, exactly one epoch
     # (trace_epoch, clamped to the run) is wrapped in a jax.profiler trace
-    # written under this directory — the executed-kernel record
-    # `obs_tpu.py profile` parses for the comm/comp overlap fraction.
-    # Epoch 1 by default: epoch 0 would trace the compiles, drowning the
-    # steady-state kernels the overlap question is about.
+    # written under this directory, under the span `profile`; after it the
+    # loop reduces the capture (obs.xprof.device_scopes), journals the
+    # `device_scopes` event and writes scopes.json beside the capture;
+    # `obs_tpu.py profile <dir>` prints the same table later.  Epoch 1 by
+    # default: epoch 0 would trace the compiles, not the steady state.
     trace_dir: Optional[str] = None
     trace_epoch: int = 1
     # initial-consensus sync (reference train_mpi.py:97 sync_allreduce).

@@ -21,8 +21,10 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
+import json
 import os
 import time
+import warnings
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -997,15 +999,20 @@ def train(config: TrainConfig, resume_dir: Optional[str] = None,
                 snapshot = jax.tree_util.tree_map(jnp.copy, state)
             else:
                 snapshot = None
-        # overlap-truth capture (DESIGN.md §15): exactly one clamped epoch
+        # device time by scope (DESIGN.md §15): exactly one clamped epoch
         # runs inside a jax.profiler trace window when trace_dir is set;
         # the epoch-boundary block_until_ready below sits INSIDE the
         # window so asynchronously dispatched kernels land in the capture
-        # (the utils.profiling.trace contract)
+        # (the utils.profiling.trace contract).  The profiler's start and
+        # its stop (which writes the capture: seconds) are the span
+        # ``profile``, outside the two clock reads of ``epoch_time``
         tracing = (config.trace_dir is not None
                    and epoch == min(config.trace_epoch, config.epochs - 1))
-        t0 = time.time()
-        with trace(config.trace_dir) if tracing else contextlib.nullcontext():
+        with contextlib.ExitStack() as profiler:
+            if tracing:
+                with spans.span("profile"):
+                    profiler.enter_context(trace(config.trace_dir))
+            t0 = time.time()
             if config.scan_epoch:
                 state, epoch_metrics = _run_epoch_scanned(
                     e_scan, state, loader, stacks, epoch, rng,
@@ -1042,7 +1049,11 @@ def train(config: TrainConfig, resume_dir: Optional[str] = None,
                 # graftcontract: sync — THE one deliberate per-epoch barrier
                 # (wall-clock truth + everything below rides this sync)
                 jax.block_until_ready(state.params)
-        epoch_time = time.time() - t0
+            epoch_time = time.time() - t0
+            if tracing:
+                with spans.span("profile"):
+                    profiler.close()
+                    _journal_device_scopes(recorder, config.trace_dir, epoch)
         period_samples = bpe * config.num_workers * config.batch_size
         # what the model counted over the epoch (``COUNTER_PREFIX``): it
         # rides the period's ``spans`` record and the history, not the means
@@ -1411,6 +1422,30 @@ def _config_snapshot(config: TrainConfig) -> Dict:
         else:
             out[field.name] = str(v)
     return out
+
+
+def _journal_device_scopes(recorder, trace_dir: str, epoch: int) -> None:
+    """What the capture just written under ``trace_dir`` says of the traced
+    epoch: one ``device_scopes`` event (``obs.xprof.device_scopes``: device
+    time by program and ``device_span``), and beside the capture
+    ``scopes.json`` with that record and the map it was joined through
+    (``{program: {instruction: [innermost scope, pass]}}``).  A capture
+    with no device plane (the CPU) journals nothing and warns."""
+    from ..obs.xprof import TraceParseError, device_scopes
+    from ..utils.atomicio import atomic_publish
+
+    maps: Dict = {}
+    try:
+        record = device_scopes(trace_dir, keep_maps=maps)
+    except TraceParseError as e:
+        warnings.warn(f"trace_dir: no device_scopes record ({e})")
+        return
+    atomic_publish(os.path.join(trace_dir, "scopes.json"), json.dumps({
+        "record": record, "maps": {
+            module: {name: [scopes[-1] if scopes else None, which]
+                     for name, (scopes, which, _) in scope_map.items()}
+            for module, scope_map in maps.items()}}), prefix=".scopes.")
+    recorder.log_event("device_scopes", epoch=epoch, **record)
 
 
 def _reconcile_mix_pending(state, overlap: str, communicator, flattener,

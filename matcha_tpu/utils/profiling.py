@@ -51,9 +51,12 @@ def trace(log_dir: str, *, create_perfetto_link: bool = False):
 #: "Profiling" says what code each covers).  ``membership_bootstrap`` and
 #: an on-demand or emergency ``checkpoint`` open inside another span
 #: (``prime``, ``boundary_hook``, ``divergence_check``) and are its
-#: children; every other name is a leaf of the epoch period.
+#: children; every other name is a leaf of the epoch period.  ``profile``
+#: is recorded only by the epoch that ``trace_dir`` captures: the profiler's
+#: start before the epoch and, after it, its stop (which writes the capture)
+#: with the capture's reduction to the ``device_scopes`` event.
 SPAN_NAMES = (
-    "boundary_hook", "prime", "membership_bootstrap", "snapshot",
+    "boundary_hook", "prime", "membership_bootstrap", "snapshot", "profile",
     "load_batches", "stack_batches", "h2d", "ledger_observe", "dispatch",
     "epoch_python", "wait_device",
     "divergence_check", "comm_split_timer", "evaluate", "record_epoch",
@@ -140,10 +143,12 @@ def _annotate(name: str):
 def device_span(name: str):
     """Named scope for *in-graph* phases (``jax.named_scope``).
 
-    Ops traced under the scope carry ``name`` in their HLO metadata, so a
-    ``jax.profiler`` trace attributes the fused step's kernels to the
-    phase that emitted them (``matcha/begin_mix``, ``matcha/apply_mix``,
-    ``matcha/heal``, ...) even after XLA fuses across the phase boundary.
+    Ops traced under the scope carry ``name`` in the ``op_name`` of their
+    HLO metadata (``matcha/fwd_bwd``, ``comm/step``, ``matcha/heal``, ...),
+    and a fusion across a phase boundary keeps each fused instruction's.
+    A ``jax.profiler`` capture's rows do not show it: ``obs.xprof`` joins
+    them to the HLO the capture carries and gives device time by scope
+    (``obs_tpu.py profile``, the journal's ``device_scopes`` event).
     Pure trace-time construct: adds zero runtime work and cannot trip the
     retrace sanitizer (tests/test_obs.py pins both properties).
     """

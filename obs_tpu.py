@@ -51,13 +51,19 @@ Performance observability (DESIGN.md §15):
     multiplication: persistent state bytes and chips needed per
     (communicator, N).
 
-``profile TRACE... [--md PATH] [--journal PATH]``
-    Overlap truth: parse executed ``jax.profiler`` traces (the
-    ``*.trace.json.gz`` a ``--trace-dir`` run or ``utils.profiling.trace``
-    captured), attribute device kernel rows to phases via the ``comm/*`` /
-    ``matcha/*`` named scopes, and report the comm/comp overlap fraction
-    per trace.  Exits 2 with a clear message when a trace has no device
-    rows (a CPU capture) instead of reporting a fake 0%.
+``profile CAPTURE... [--md PATH] [--journal PATH]``
+    Device time by scope: reduce ``jax.profiler`` captures (a
+    ``--trace-dir`` run's directory, any directory above an ``*.xplane.pb``,
+    or the file) with the program's one device-side reader
+    (``matcha_tpu.obs.xprof``): every executed operation joined, through the
+    HLO the capture itself carries, to the ``device_span`` (``matcha/*`` /
+    ``comm/*``) and the pass it was traced under.  Prints, a program, the
+    table (scope, ms a run, share, forward / recomputed / recomputed_inner /
+    backward), the longest operations under no scope, and the share of
+    ``comm/*`` device time that ran under other work (``-`` where the
+    capture has no ``comm/*`` row).  ``--journal`` appends one
+    ``device_scopes`` event a capture.  Exits 2 with a clear message when a
+    capture has no device plane (the CPU's) instead of a table of zeros.
 
 Live health plane (DESIGN.md §17):
 
@@ -318,20 +324,22 @@ def cmd_capacity(args) -> int:
 
 
 def cmd_profile(args) -> int:
-    from matcha_tpu.obs.xprof import profile_report, render_profile_markdown
+    from matcha_tpu.obs.xprof import device_scopes, render_device_scopes
 
-    reports = [profile_report(src) for src in args.traces]
-    md = render_profile_markdown(reports)
-    print(md)
+    records = [device_scopes(src) for src in args.captures]
+    text = "\n\n".join(
+        "\n".join([f"# device time by scope: {r['source']}"]
+                  + render_device_scopes(r)) for r in records)
+    print(text)
     if args.md:
         with open(args.md, "w") as f:
-            f.write(md)
+            f.write("```\n" + text + "\n```\n")
         print(f"# markdown written to {args.md}", file=sys.stderr)
     if args.journal:
         from matcha_tpu.obs import append_journal_record
 
-        for r in reports:
-            append_journal_record(args.journal, "profile", **r)
+        for r in records:
+            append_journal_record(args.journal, "device_scopes", **r)
     return 0
 
 
@@ -584,13 +592,14 @@ def main(argv=None) -> int:
     s.set_defaults(fn=cmd_timeline)
 
     s = sub.add_parser("profile",
-                       help="overlap truth from executed profiler traces")
-    s.add_argument("traces", nargs="+",
-                   help="trace dirs (a --trace-dir capture) or "
-                        "*.trace.json.gz files")
+                       help="device time by scope from profiler captures")
+    s.add_argument("captures", nargs="+",
+                   help="capture dirs (a --trace-dir run's) or "
+                        "*.xplane.pb files")
     s.add_argument("--md", default=None)
     s.add_argument("--journal", default=None,
-                   help="also append one `profile` event per trace here")
+                   help="also append one `device_scopes` event per capture "
+                        "here")
     s.set_defaults(fn=cmd_profile)
 
     args = p.parse_args(argv)

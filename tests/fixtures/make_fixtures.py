@@ -61,87 +61,7 @@ def make_emnist(root: str) -> None:
               rng.integers(0, 47, size=ROWS, dtype=np.uint8))
 
 
-def make_trace_fixtures(root: str) -> None:
-    """Miniature Chrome trace-event captures for the overlap-truth parser
-    (``matcha_tpu.obs.xprof``, ISSUE 8).
-
-    Byte-faithful to what ``jax.profiler`` exports on hardware: process
-    metadata names a ``/device:TPU:0`` lane next to the ``/host:CPU`` one,
-    complete (``ph=X``) kernel rows carry the ``device_span`` named scopes
-    in their ``args.tf_op`` metadata.  Two schedules, same arithmetic:
-
-    * ``trace_overlap_off`` — eager: each step's comm rows run *after* its
-      compute rows on the same stream → overlap fraction 0.
-    * ``trace_overlap_1step`` — pipelined: comm rows ride a second device
-      stream, 300 of every 400 µs under the next compute block → overlap
-      fraction 0.75.
-    * ``trace_overlap_1step_dbuf`` — pipelined + double-buffered perm
-      kernel (ISSUE 19): the flag-window DMAs no longer serialize against
-      the row gathers, so each comm row sits almost entirely under its
-      step's compute block — 380 of every 400 µs → overlap fraction 0.95.
-
-    A host-side row whose name contains ``comm/`` is planted in both:
-    host lanes prove nothing about kernel concurrency and the parser must
-    ignore them.
-    """
-    import json as _json
-
-    def meta(pid, name, tid=None, tname=None):
-        out = [{"ph": "M", "pid": pid, "name": "process_name",
-                "args": {"name": name}}]
-        if tid is not None:
-            out.append({"ph": "M", "pid": pid, "tid": tid,
-                        "name": "thread_name", "args": {"name": tname}})
-        return out
-
-    def x(pid, tid, ts, dur, name, tf_op):
-        return {"ph": "X", "pid": pid, "tid": tid, "ts": float(ts),
-                "dur": float(dur), "name": name, "args": {"tf_op": tf_op}}
-
-    host = meta(1, "/host:CPU", 10, "python")
-    dev = (meta(100, "/device:TPU:0 (pid 100)", 1, "XLA Ops") +
-           [{"ph": "M", "pid": 100, "tid": 2, "name": "thread_name",
-             "args": {"name": "XLA Ops Stream 2"}}])
-    shadow = [x(1, 10, 500, 50, "$comm/step host shadow", "host")]
-
-    off, on, dbuf = [], [], []
-    for i in range(4):
-        t = 1000 + 1200 * i
-        off += [x(100, 1, t, 800, "fusion.12", "matcha/fwd_bwd/dot_general"),
-                x(100, 1, t + 800, 90, "fusion.13", "matcha/sgd/add"),
-                x(100, 1, t + 900, 200, "ppermute.4", "comm/step/ppermute")]
-        t = 1000 + 1000 * i
-        on += [x(100, 1, t, 900, "fusion.12", "matcha/fwd_bwd/dot_general"),
-               x(100, 2, t + 700, 400, "ppermute.4",
-                 "comm/begin_mix/ppermute")]
-        # double-buffered: same 400 µs comm row, but it no longer waits on
-        # its flag-window DMA — only the final 20 µs (the last window's
-        # tail past the compute block) stick out: [t+520, t+920] vs
-        # compute [t, t+900] → 380/400 overlapped
-        dbuf += [x(100, 1, t, 900, "fusion.12", "matcha/fwd_bwd/dot_general"),
-                 x(100, 2, t + 520, 400, "ppermute.4",
-                   "comm/begin_mix/ppermute")]
-    # one unattributed device row per trace: executed kernel work that
-    # carries no scope still counts as compute ("other")
-    off.append(x(100, 1, 6000, 100, "fusion.99", "unattributed"))
-    on.append(x(100, 1, 5000, 100, "fusion.99", "unattributed"))
-    dbuf.append(x(100, 1, 5000, 100, "fusion.99", "unattributed"))
-
-    for name, events in (("trace_overlap_off", host + dev + shadow + off),
-                         ("trace_overlap_1step", host + dev + shadow + on),
-                         ("trace_overlap_1step_dbuf",
-                          host + dev + shadow + dbuf)):
-        path = os.path.join(root, f"{name}.trace.json.gz")
-        with open(path, "wb") as raw:
-            with gzip.GzipFile(fileobj=raw, mode="wb", mtime=0) as f:
-                f.write(_json.dumps(
-                    {"displayTimeUnit": "ns",
-                     "metadata": {"highres-ticks": True},
-                     "traceEvents": events}).encode())
-
-
 if __name__ == "__main__":
     make_cifar10(HERE)
     make_emnist(HERE)
-    make_trace_fixtures(HERE)
     print(f"fixtures written under {HERE}")

@@ -53,9 +53,15 @@ def test_counters_fill_the_cells_own_metrics(cell, line):
     metrics that read the program's counters and spans still report, each
     in the cell that lists it and in no other."""
     bench = catalog.benchmark()
-    mine = {m["name"] for m in bench["per_layer"]
-            if cell in m.get("workloads", ())}
+    listed = [m for m in bench["per_layer"] if cell in m.get("workloads", ())]
+    mine = {m["name"] for m in listed if m["source"] == "program_counter"}
     assert mine == CELLS[cell]
+    # the cell's other own metrics read the device trace's scopes (PR 37,
+    # tests/test_device_scopes.py): nothing to read on the CPU
+    scoped = {m["name"] for m in listed} - mine
+    assert scoped and not scoped & set(line["metrics"])
+    assert all(m["source"] == "device_trace" for m in listed
+               if m["name"] in scoped)
     got = {k: v["value"] for k, v in line["metrics"].items()}
     assert mine <= set(got) and not (set(COUNTER_METRICS) - mine) & set(got)
     if "moe_slot_fill_pct" in mine:

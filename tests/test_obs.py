@@ -319,7 +319,7 @@ def test_v1_events_validate_verbatim_and_v2_kinds_are_versioned():
     `compile`/`profile` event claiming v=1 is a lying envelope."""
     from matcha_tpu.obs.journal import EVENT_KINDS, V2_KINDS
 
-    assert V2_KINDS == {"compile", "profile", "membership"}
+    assert V2_KINDS == {"compile", "profile", "device_scopes", "membership"}
     assert V2_KINDS <= EVENT_KINDS
     v1 = {"v": 1, "kind": "resume", "t": 0.5, "epoch": 3}
     assert validate_event(v1) == []

@@ -1,17 +1,18 @@
-"""Performance observability (ISSUE 8): cost ledger, roofline, overlap truth.
+"""Performance observability (ISSUE 8): cost ledger, roofline, the traced
+epoch.
 
-Three layers, mirroring the subsystem: pure cost extraction
+Two layers, mirroring the subsystem: pure cost extraction
 (``obs.costs.analyze_program`` against hand-checkable programs, the
 north-star roofline pin vs ROOFLINE.md's arithmetic, the §9 capacity
-table), the train-loop integration (every program the loop compiles
+table) and the train-loop integration (every program the loop compiles
 journals a v2 ``compile`` event; a cache-growth ``retrace`` arrives with
-the added program's compile event), and the executed-trace parser (the
-committed miniature fixtures pin 0% eager vs 75% pipelined overlap, and a
-real CPU capture must fail loudly instead of reporting a fake 0%).
+the added program's compile event; ``trace_dir`` captures one epoch under
+the ``profile`` span).  The device-side reader of such a capture
+(``obs.xprof``, ``obs_tpu.py profile``) is tested in
+``tests/test_device_scopes.py``.
 """
 
 import dataclasses
-import io
 import math
 import pathlib
 
@@ -30,19 +31,12 @@ from matcha_tpu.obs.costs import (
     render_roofline_markdown,
     roofline_report,
 )
-from matcha_tpu.obs.xprof import (
-    TraceParseError,
-    overlap_report,
-    profile_report,
-    render_profile_markdown,
-)
 from matcha_tpu.topology import decompose, make_graph
 from matcha_tpu.train import TrainConfig, train
 
 pytestmark = pytest.mark.obs
 
 REPO = pathlib.Path(__file__).resolve().parents[1]
-FIXTURES = REPO / "tests" / "fixtures"
 
 # the obs test recipe (tests/test_obs.py BASE), small
 BASE = TrainConfig(
@@ -253,89 +247,26 @@ def test_retrace_event_is_accompanied_by_its_compile_event(monkeypatch):
 
 
 def test_trace_dir_captures_exactly_one_window(instrumented_run):
-    _, trace_dir = instrumented_run
-    files = [p for p in pathlib.Path(trace_dir).rglob("*") if p.is_file()]
-    assert files and any(str(p).endswith(".trace.json.gz") for p in files)
-
-
-# ------------------------------------------------------------- overlap truth
-
-def test_fixture_traces_pin_the_overlap_arithmetic():
-    """Acceptance: the committed miniature traces report a higher comm/comp
-    overlap fraction for the pipelined schedule than the eager one, with
-    hand-checkable numbers (0% vs 75%)."""
-    off = profile_report(str(FIXTURES / "trace_overlap_off.trace.json.gz"))
-    on = profile_report(str(FIXTURES / "trace_overlap_1step.trace.json.gz"))
-    dbuf = profile_report(
-        str(FIXTURES / "trace_overlap_1step_dbuf.trace.json.gz"))
-    assert off["overlap_fraction"] == pytest.approx(0.0, abs=1e-9)
-    assert on["overlap_fraction"] == pytest.approx(0.75, rel=1e-6)
-    assert on["overlap_fraction"] > off["overlap_fraction"]
-    # the double-buffered perm kernel's capture (ISSUE 19 acceptance):
-    # strictly above the pipelined 75%, at the ≥90% target — the comm
-    # rows no longer serialize on their flag-window DMAs
-    assert dbuf["overlap_fraction"] == pytest.approx(0.95, rel=1e-6)
-    assert dbuf["overlap_fraction"] > on["overlap_fraction"]
-    assert dbuf["overlap_fraction"] >= 0.90
-    # attribution: 4 comm rows each, the unattributed row counts as
-    # compute ("other"), the host-side comm/ shadow row is ignored
-    assert off["rows"]["comm"] == 4 and on["rows"]["comm"] == 4
-    assert dbuf["rows"]["comm"] == 4
-    assert off["rows"]["other"] == 1
-    assert any("/device:" in p for p in off["device_processes"])
-    # each report is a valid v2 `profile` journal event payload
-    for rep in (off, on, dbuf):
-        assert validate_event(make_event("profile", 0.0, **rep)) == []
-
-
-def test_overlap_report_interval_arithmetic_units():
-    meta = [{"ph": "M", "pid": 7, "name": "process_name",
-             "args": {"name": "/device:TPU:0"}}]
-
-    def x(ts, dur, op, tid=1):
-        return {"ph": "X", "pid": 7, "tid": tid, "ts": ts, "dur": dur,
-                "name": "k", "args": {"tf_op": op}}
-
-    # comm [0, 10] vs compute [5, 25]: 5 of 10 comm µs overlap
-    rep = overlap_report(meta + [x(0, 10, "comm/step/pp"),
-                                 x(5, 20, "matcha/fwd_bwd/dot", tid=2)])
-    assert rep["overlap_fraction"] == pytest.approx(0.5)
-    # no comm rows at all: no claim either way, never a fake number
-    rep = overlap_report(meta + [x(0, 10, "matcha/sgd/add")])
-    assert rep["overlap_fraction"] is None
-    # device process without any complete rows: loud
-    with pytest.raises(TraceParseError, match="no complete"):
-        overlap_report(meta)
-
-
-def test_cpu_trace_fails_loudly_not_fake_zero(tmp_path):
-    """A REAL capture on this CPU backend has host lanes only: the parser
-    must raise with a clear message, and the CLI must exit non-zero."""
-    import jax
-    import jax.numpy as jnp
-
-    import obs_tpu
-    from matcha_tpu.utils import trace
-
-    f = jax.jit(lambda x: jnp.sum(x * x))
-    f(jnp.ones(16))
-    with trace(str(tmp_path)):
-        jax.block_until_ready(f(jnp.ones(16)))
-    with pytest.raises(TraceParseError, match="no device rows"):
-        profile_report(str(tmp_path))
-    assert obs_tpu.main(["profile", str(tmp_path)]) == 2
-
-
-def test_profile_errors_on_missing_and_empty_sources(tmp_path):
-    with pytest.raises(TraceParseError, match="no trace at"):
-        profile_report(str(tmp_path / "nowhere"))
-    (tmp_path / "empty").mkdir()
-    with pytest.raises(TraceParseError, match="no \\*\\.trace"):
-        profile_report(str(tmp_path / "empty"))
-    bad = tmp_path / "bad.trace.json"
-    bad.write_text("not json")
-    with pytest.raises(TraceParseError, match="not a readable"):
-        profile_report(str(bad))
+    """One capture, of epoch 1 alone; the profiler's start and its stop are
+    that epoch's two ``profile`` spans, leaves of the period around the
+    epoch's own.  (The CPU's capture has no device plane: the loop warns
+    and journals no ``device_scopes`` event; tests/test_device_scopes.py
+    drives that event through a capture that has one.)"""
+    result, trace_dir = instrumented_run
+    captures = list(pathlib.Path(trace_dir).rglob("*.xplane.pb"))
+    assert len(captures) == 1
+    periods = {e["epoch"]: e for e in result.recorder.events
+               if e["kind"] == "spans"}
+    names = {k: [s["name"] for s in r["spans"]] for k, r in periods.items()}
+    assert "profile" not in names[0]
+    assert names[1].count("profile") == 2
+    first, last = (i for i, n in enumerate(names[1]) if n == "profile")
+    assert names[1][first - 1] == "snapshot"
+    assert names[1][last - 1] == "wait_device"
+    assert all(s["parent"] == periods[1]["period"]
+               for s in periods[1]["spans"] if s["name"] == "profile")
+    assert not [e for e in result.recorder.events
+                if e["kind"] == "device_scopes"]
 
 
 # ----------------------------------------------------------------------- CLI
@@ -395,25 +326,3 @@ def test_cli_summary_shows_cost_ledger(capsys):
     out = capsys.readouterr().out
     assert "compiled programs (cost ledger): 1" in out
     assert "epoch_scan" in out
-
-
-def test_cli_profile_renders_and_journals(tmp_path, capsys):
-    import obs_tpu
-
-    journal = tmp_path / "session.jsonl"
-    md = tmp_path / "profile.md"
-    rc = obs_tpu.main([
-        "profile",
-        str(FIXTURES / "trace_overlap_off.trace.json.gz"),
-        str(FIXTURES / "trace_overlap_1step.trace.json.gz"),
-        str(FIXTURES / "trace_overlap_1step_dbuf.trace.json.gz"),
-        "--md", str(md), "--journal", str(journal)])
-    assert rc == 0
-    out = capsys.readouterr().out
-    assert "75.0%" in out and "0.0%" in out and "95.0%" in out
-    events = read_journal(str(journal))
-    assert [e["kind"] for e in events] == ["profile"] * 3
-    assert all(validate_event(e) == [] for e in events)
-    assert events[1]["overlap_fraction"] == pytest.approx(0.75, rel=1e-6)
-    assert events[2]["overlap_fraction"] == pytest.approx(0.95, rel=1e-6)
-    assert md.read_text().startswith("# Overlap truth")

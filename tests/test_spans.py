@@ -96,10 +96,11 @@ def test_a_run_emits_the_vocabulary_in_loop_order(run):
 
 def test_every_loop_path_is_named_in_the_vocabulary():
     """Names this file's runs do not reach (a flush every tenth epoch, a
-    membership join) are still the tuple's: nothing else is."""
+    membership join, the epoch ``trace_dir`` captures:
+    ``tests/test_perfobs.py``) are still the tuple's: nothing else is."""
     assert set(SPAN_NAMES) == set(BEFORE + AFTER + sum(INSIDE.values(), [])
                                   ) | {"membership_bootstrap",
-                                       "recorder_flush"}
+                                       "recorder_flush", "profile"}
     assert len(set(SPAN_NAMES)) == len(SPAN_NAMES)
 
 

@@ -253,9 +253,11 @@ def _parse(argv=None):
                         "testing drift detection (obs_tpu.py drift)")
     p.add_argument("--trace-dir", default=None, dest="trace_dir",
                    help="capture one epoch (--trace-epoch) as a "
-                        "jax.profiler trace under this dir — the executed-"
-                        "kernel record obs_tpu.py profile parses for the "
-                        "comm/comp overlap fraction (DESIGN.md §15)")
+                        "jax.profiler trace under this dir; on the chip the "
+                        "run journals a device_scopes event (device time by "
+                        "program and device_span) and writes scopes.json "
+                        "there; obs_tpu.py profile DIR prints the table "
+                        "(DESIGN.md §15)")
     p.add_argument("--trace-epoch", type=int, default=1, dest="trace_epoch",
                    help="which epoch to trace (clamped to the run; default "
                         "1 so compiles don't drown the steady-state window)")
