@@ -229,6 +229,10 @@ class KeyeVL2(TokenDecoder):
     """``sizes`` as in ``chipbench/configs/keye-vl2-30b-a3b.ep16-s8k.json``
     (README "Training a language model" lists the keys)."""
 
+    @property
+    def expert_layers(self):
+        return self.sizes["num_layers"]
+
     def setup(self):
         z = self.sizes
         hid, heads, di = z["hidden"], z["indexer_heads"], \

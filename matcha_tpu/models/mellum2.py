@@ -20,11 +20,20 @@ absent ones would add is left out and the partial sum goes on
 held are laid out sorted by expert in ``moe_capacity`` rows
 (``ROWS_PER_EVEN_SLOT`` times what an even router would send here, or
 ``sizes["moe_rows_per_even_slot"]`` times where a configuration says so) and
-three grouped products (``jax.lax.ragged_dot``) run over them: the cost
-follows the number of slots, not the busiest expert, which matters because
-a row's tokens route alike (one expert held took 3.7 times its even share of a step
-at the published widths).  Slots past the rows go through every expert held
-under a 0/1 mask, in a branch that runs only in a step that has such slots.
+three grouped products run over them (gate, up, down; with their two
+gradients each, nine a layer): the cost follows the number of slots, not the
+busiest expert, which matters because a row's tokens route alike (one expert
+held took 3.7 times its even share of a step at the published widths).  On
+the TPU the products are ``ops/grouped.py``'s kernels (``moe_gmm``, its
+transposed-weights form, ``moe_tgmm``: tiles of 512 rows by the whole of
+both widths at every benchmark shape, 32,768 x 2,304 x 896, 32,768 x 2,048 x
+768 and 10,240 x 2,048 x 512; six kernel sites a program), on bfloat16
+operands rounded here, float32 sums; where rows or a width do not tile (rows
+not in 128s, a width not in whole lanes: the test sizes) and anywhere off
+the TPU they are ``jax.lax.ragged_dot``, and the journal's ``backend`` event
+says which and why (:func:`expert_products`).  Slots past the rows go through
+every expert held under a 0/1 mask, in a branch that runs only in a step that
+has such slots.
 Shapes are static either way, and the counters say what it cost:
 ``moe_rows_computed`` rows went through an expert for ``moe_slots_held``
 slots that were real.
@@ -59,6 +68,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from ..ops.grouped import (FORMS, grouped_dot, grouped_dot_transposed,
+                           grouped_outer, product_plan)
 from ..utils.profiling import device_span
 
 __all__ = ["Mellum2", "TokenDecoder", "rope_inv_freq", "moe_capacity",
@@ -194,31 +205,25 @@ def _one_bf16_pass() -> bool:
 
 @jax.custom_vjp
 def _grouped_bf16(lhs, weights, groups):
-    """``lax.ragged_dot`` of float32 operands as one bfloat16 pass with
+    """The grouped product of float32 operands as one bfloat16 pass with
     float32 accumulation, in the backward products too: what a ``dot`` at
-    the default precision is on the MXU.  The grouped kernel takes its
-    operands as they come, so the cotangent has to be rounded by hand: left
-    float32, it makes half of the backward products float32 ones."""
+    the default precision is on the MXU.  Operands and the cotangent are
+    rounded by hand (left float32, the cotangent makes half of the backward
+    products float32 ones); what multiplies them is ``ops/grouped.py``: its
+    kernels where the shapes tile, ``lax.ragged_dot`` where they do not."""
     return _grouped_bf16_fwd(lhs, weights, groups)[0]
 
 
 def _grouped_bf16_fwd(lhs, weights, groups):
     lhs, weights = lhs.astype(jnp.bfloat16), weights.astype(jnp.bfloat16)
-    return lax.ragged_dot(lhs, weights, groups,
-                          preferred_element_type=jnp.float32), (
-                              lhs, weights, groups)
+    return grouped_dot(lhs, weights, groups), (lhs, weights, groups)
 
 
 def _grouped_bf16_bwd(kept, g):
     lhs, weights, groups = kept
     g = g.astype(jnp.bfloat16)
-    by_rows = lax.RaggedDotDimensionNumbers(
-        dot_dimension_numbers=(((0,), (0,)), ((), ())),
-        lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
-    return (lax.ragged_dot(g, jnp.swapaxes(weights, 1, 2), groups,
-                           preferred_element_type=jnp.float32),
-            lax.ragged_dot_general(lhs, g, groups, by_rows,
-                                   preferred_element_type=jnp.float32),
+    return (grouped_dot_transposed(g, weights, groups),
+            grouped_outer(lhs, g, groups),
             np.zeros(groups.shape, jax.dtypes.float0))
 
 
@@ -226,13 +231,44 @@ _grouped_bf16.defvjp(_grouped_bf16_fwd, _grouped_bf16_bwd)
 
 
 def _grouped_product(lhs, weights, groups):
-    """``lax.ragged_dot`` at the precision ``jnp.dot`` has by default.  On
-    the TPU float32 operands cost the grouped kernel several passes (4.8 ms
-    a weight-gradient product of 32,768 rows, 14% of the MXU's peak;
-    PERF.md section 6, PR 27)."""
+    """The grouped product at the precision ``jnp.dot`` has by default.  On
+    the TPU that is :func:`_grouped_bf16` (float32 operands would cost the
+    grouped kernel several passes); anywhere else ``lax.ragged_dot`` as it
+    is."""
     if _one_bf16_pass():
         return _grouped_bf16(lhs, weights, groups)
     return lax.ragged_dot(lhs, weights, groups)
+
+
+def expert_products(sizes, tokens: int, layers: int, remat: bool,
+                    workers: int) -> dict:
+    """What runs the grouped products of a step, for the journal's
+    ``backend`` event: a record a form and shape (``ops/grouped.py:
+    product_plan``: the kernel and its tiles, or ``lax.ragged_dot`` and the
+    reason), with how many products of a step are of it (a layer has gate,
+    up and down, each once forward, again where ``remat`` recomputes the
+    layer, and once a gradient), the kernel sites an epoch program holds
+    (one a form and shape) and the products that run on them."""
+    rows = moe_capacity(tokens, sizes)
+    hid, width = sizes["hidden"], sizes["expert_width"]
+    passes = {"gmm": 2 if remat else 1, "gmm_transposed": 1, "tgmm": 1}
+    products = []
+    for form in FORMS:
+        # (k, n) as the kernel tiles them: gate and up, then down
+        for count, (k, n) in ((2, (hid, width)), (1, (width, hid))):
+            if form == "gmm_transposed":
+                k, n = n, k
+            plan = (product_plan(form, rows, k, n, jnp.bfloat16, jnp.bfloat16)
+                    if _one_bf16_pass() else
+                    {"form": form, "rows": rows, "k": k, "n": n,
+                     "kernel": "lax.ragged_dot", "reason": "float32 products "
+                     "off the TPU's one-bfloat16-pass path"})
+            products.append({**plan, "per_step":
+                             count * passes[form] * layers * workers})
+    on_kernel = [p for p in products if "tiles" in p]
+    return {"products_per_step": sum(p["per_step"] for p in products),
+            "on_kernel": sum(p["per_step"] for p in on_kernel),
+            "kernel_sites": len(on_kernel), "products": products}
 
 
 def _swiglu(x, p, product):
@@ -250,8 +286,8 @@ def _experts(p, x, w_held, took, sizes):
 
     The slots are laid out sorted by expert in ``rows`` rows (expert ``e``'s
     queue starts where the queues before it end), and three grouped
-    products (``lax.ragged_dot``, one group an expert) run over them, so
-    the cost follows the slots and not the busiest expert.  Rows past the
+    products (:func:`_grouped_product`, one group an expert) run over them,
+    so the cost follows the slots and not the busiest expert.  Rows past the
     last slot hold a token at weight 0 and count with the last expert."""
     b, s, hidden = x.shape
     tokens, held = took.shape
@@ -424,6 +460,13 @@ class TokenDecoder(nn.Module):
         """What ``init`` traces: parameters do not depend on the length."""
         return jnp.zeros((1, 8), jnp.int32)
 
+    def expert_products(self, tokens: int, workers: int) -> dict:
+        """:func:`expert_products` of a step of ``workers`` workers over
+        ``tokens`` positions each (a subclass says how many of its layers
+        hold the expert layer: ``expert_layers``)."""
+        return expert_products(self.sizes, tokens, self.expert_layers,
+                               self.remat, workers)
+
     def normed(self, h, counters, summed=MOE_COUNTERS):
         """(the final norm's output ``[B, S, H]``, the layers' counters
         ``summed`` and ``moe_load[layer, expert held]`` stacked)."""
@@ -447,10 +490,14 @@ class Mellum2(TokenDecoder):
     """``sizes`` as in ``chipbench/configs/mellum2-12b-a2.5b.ep8-s4k.json``
     (README "Training a language model" lists the keys)."""
 
+    @property
+    def expert_layers(self):
+        return len(self.sizes["layer_types"])
+
     def setup(self):
         z = self.sizes
         self.declare([{**attention_weights(z), **expert_weights(z)}]
-                     * len(z["layer_types"]))
+                     * self.expert_layers)
 
     def hidden(self, ids, docs):
         """(the final norm's output ``[B, S, H]``, the expert layers'

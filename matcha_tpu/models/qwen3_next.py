@@ -465,6 +465,10 @@ class Qwen3Next(TokenDecoder):
     norm = staticmethod(_norm0)
     norm_init = staticmethod(nn.initializers.zeros)
 
+    @property
+    def expert_layers(self):
+        return len(layer_kinds(self.sizes))
+
     def setup(self):
         z = self.sizes
         hid, width = z["hidden"], z["shared_expert_width"]

@@ -1,4 +1,6 @@
-"""Device-level primitive ops: batched flatten/unflatten, compressors."""
+"""Device-level primitive ops: batched flatten/unflatten, compressors, and
+the expert layer's grouped matrix products (``ops.grouped``, imported by the
+models that use it)."""
 
 from .compress import (
     COMPRESSOR_NAMES,

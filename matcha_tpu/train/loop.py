@@ -762,6 +762,12 @@ def train(config: TrainConfig, resume_dir: Optional[str] = None,
                 communicator, flattener, overlap=config.overlap,
                 staleness=config.staleness, faults=faults is not None,
                 elastic=elastic_ctl is not None))
+        # and, of a model with an expert layer, what runs its grouped
+        # products: the kernel and its tiles a form and shape, or the stock
+        # product and why
+        if hasattr(model, "expert_products"):
+            backend_decision["expert_products"] = model.expert_products(
+                config.batch_size * (input_shape[0] - 1), config.num_workers)
         recorder.log_event("backend", **backend_decision)
     # how the forward/backward runs (packs of workers side by side in the
     # lanes, or vmap over workers) and, where it is the latter, why

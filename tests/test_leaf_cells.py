@@ -23,8 +23,8 @@ import pytest
 from chipbench import catalog, harness
 from matcha_tpu import topology as tp
 from matcha_tpu.communicator import make_decen
-from matcha_tpu.models import select_model
-from matcha_tpu.ops import WorkerFlattener
+from matcha_tpu.models import mellum2, select_model
+from matcha_tpu.ops import WorkerFlattener, grouped
 from matcha_tpu.parallel import (STREAM_MAX_WORKERS, leaf_views,
                                  pallas_gossip, worker_deviation_rows,
                                  worker_disagreement)
@@ -120,6 +120,52 @@ def test_exchange_of_every_configuration_lowers_inside_the_setup_budget(
     assert len(leaves) <= TEXT_BUDGET * len(flat), (len(leaves), len(flat))
     # and the leaves in place are nearly all of the state
     assert plan["small_buffer_elements"] < 0.07 * n * flattener.dim, plan
+
+
+#: gate/up and down x forward, data gradient, weight gradient (PR 38)
+GROUPED_SITE_BUDGET = 6
+
+
+@pytest.mark.parametrize("cell", CELLS[2:])
+def test_expert_layer_of_every_token_configuration_holds_six_kernel_sites(
+        cell, monkeypatch):
+    """The grouped products of the expert layer at published widths and the
+    cell's rows, on the TPU branch: two ``remat`` layers' gradient, lowered
+    for TPU (shapes alone), holds one kernel site a form and shape, the
+    recomputed forward's calls among them, as the journal's record counts;
+    every product of a step runs on one, at tiles that hold the whole
+    widths inside the VMEM budget the chooser was given."""
+    monkeypatch.setattr(mellum2, "_one_bf16_pass", lambda: True)
+    monkeypatch.setattr(grouped, "_interpret", lambda: False)
+    tc, model, _ = _model(cell, rehearsal=False)
+    z = model.sizes
+    rows_a_worker, tokens = tc["batch_size"], tc["batch_size"] * z["seq_len"]
+    record = model.expert_products(tokens, tc["num_workers"])
+    assert record["products_per_step"] == record["on_kernel"] == 96
+    assert record["kernel_sites"] == GROUPED_SITE_BUDGET
+    rows = mellum2.moe_capacity(tokens, z)
+    for product in record["products"]:
+        assert product["rows"] == rows and "reason" not in product
+        assert product["tiles"] == [512, product["k"], product["n"]]
+        assert grouped.vmem_bytes(
+            product["form"], product["tiles"], jnp.bfloat16, jnp.bfloat16,
+            product["k"]) <= grouped.VMEM_BUDGET
+    hid, width, held = z["hidden"], z["expert_width"], len(z["experts_held"])
+    spec = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
+    layer = {"router": spec(hid, z["num_experts"]),
+             "gate": spec(held, hid, width), "up": spec(held, hid, width),
+             "down": spec(held, width, hid)}
+
+    def two_layers(layers, x):
+        for p in layers:
+            x = x + jax.checkpoint(lambda p, x: mellum2._moe(p, x, z)[0])(p, x)
+        return jnp.sum(x)
+
+    text = jax.jit(jax.grad(two_layers, argnums=(0, 1))).trace(
+        [layer, layer], spec(rows_a_worker, z["seq_len"], hid)).lower(
+            lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") == record["kernel_sites"]
+    assert "ragged_dot" not in text
 
 
 def test_a_tree_of_many_shapes_keeps_to_the_budget_of_sites(monkeypatch):

@@ -5,6 +5,8 @@ Mosaic); arithmetic must match a lax.scan over ``gossip_mix_dense``
 step-for-step in f32.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -316,6 +318,33 @@ def _exchange_readings(on: str, sharding) -> dict:
             "temp_bytes": compiled.memory_analysis().temp_size_in_bytes}
 
 
+def _grouped_readings(sharding) -> list:
+    """Compile the expert layer's three grouped products at the Mellum
+    cell's gate/up shape (32,768 rows x 2,304 x 896, eight experts) with the
+    tiles ``choose_tiles`` picks, for the described device."""
+    from matcha_tpu.ops import grouped
+
+    rows, k, n, experts = 32768, 2304, 896, 8
+    spec = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                               sharding=sharding)
+    sizes = jax.ShapeDtypeStruct((experts,), jnp.int32, sharding=sharding)
+    cases = {"gmm": (grouped.moe_gmm, spec(rows, k), spec(experts, k, n)),
+             "gmm_transposed": (
+                 functools.partial(grouped.moe_gmm, transposed=True),
+                 spec(rows, n), spec(experts, k, n)),
+             "tgmm": (grouped.moe_tgmm, spec(rows, k), spec(rows, n))}
+    readings = []
+    for form, (kernel, a, b) in cases.items():
+        compiled = jax.jit(kernel).lower(a, b, sizes).compile()
+        readings.append({
+            "form": form,
+            "kernels": compiled.as_text().count(
+                'custom_call_target="tpu_custom_call"'),
+            "temp_bytes": compiled.memory_analysis().temp_size_in_bytes,
+            "out_bytes": compiled.memory_analysis().output_size_in_bytes})
+    return readings
+
+
 def _compile_all_for_v5e() -> int:
     """Child-process body of the tests below: compile every kernel case,
     and one pack of cell 2 packed and per worker, for one device of a
@@ -353,6 +382,8 @@ def _compile_all_for_v5e() -> int:
         print("STEP", json.dumps(_step_readings(packed, PACK_WORKERS, sharding)))
     for on in ("leaves", "flat"):
         print("EXCHANGE", json.dumps(_exchange_readings(on, sharding)))
+    for reading in _grouped_readings(sharding):
+        print("GROUPED", json.dumps(reading))
     return 0
 
 
@@ -421,6 +452,30 @@ def test_exchange_on_the_leaves_compiles_for_v5e_with_half_the_bytes(v5e_child):
                                                                      flat)
     assert leaves["temp_bytes"] < leaves["state_bytes"] // 8
     assert flat["temp_bytes"] >= flat["state_bytes"]
+
+
+def test_grouped_products_compile_for_v5e_at_whole_width_tiles(v5e_child):
+    """The expert layer's three grouped products at the Mellum cell's
+    gate/up shape, tiles of 512 rows by the whole of both widths under the
+    48 MiB the kernels ask of VMEM (the compiler's own scope is 16 MiB and
+    would refuse them): each compiles to one kernel, and the program holds
+    no copy of an operand beside it (no transposed weights, no transposed
+    activations)."""
+    import json
+
+    readings = {r["form"]: r for r in (
+        json.loads(line.split(" ", 1)[1])
+        for line in v5e_child.splitlines() if line.startswith("GROUPED "))}
+    assert set(readings) == {"gmm", "gmm_transposed", "tgmm"}
+    rows, k, n, experts = 32768, 2304, 896, 8
+    assert readings["gmm"]["out_bytes"] == rows * n * 4
+    assert readings["gmm_transposed"]["out_bytes"] == rows * k * 4
+    assert readings["tgmm"]["out_bytes"] == experts * k * n * 4
+    for form, reading in readings.items():
+        assert reading["kernels"] == 1, reading
+        # the grid's few hundred int32 and nothing operand-sized (the
+        # weights are 33 MB, a bfloat16 operand 59 MB or more)
+        assert reading["temp_bytes"] < 1 << 20, reading
 
 
 def test_kernel_blocks_that_cannot_fit_are_refused_by_name():

@@ -130,6 +130,26 @@ def test_loss_falls_and_nothing_retraces(run):
     assert result.state.batch_stats == {}
 
 
+def test_backend_event_says_what_runs_the_grouped_products(run):
+    """Off the TPU the expert layer keeps ``lax.ragged_dot``, and the run's
+    ``backend`` event says so, a record a form and shape with the reason
+    (PR 38; on the chip it names the kernels and their tiles)."""
+    _, result, _ = run
+    (event,) = [e for e in result.recorder.events if e["kind"] == "backend"]
+    record = event["expert_products"]
+    # 2 layers x (gate, up, down) x (forward, recomputed, two gradients) x
+    # 2 workers
+    assert record["products_per_step"] == 48
+    assert record["on_kernel"] == record["kernel_sites"] == 0
+    assert len(record["products"]) == 6
+    for product in record["products"]:
+        assert product["kernel"] == "lax.ragged_dot"
+        assert "off the TPU" in product["reason"]
+        # an even router's slots twice over: 64 tokens x 2 of 8 x 2 held x 2
+        assert product["rows"] == 64
+    json.dumps(event)
+
+
 def test_spans_cover_the_period_and_dispatch_counts_tokens(run):
     path, result, _ = run
     records = [e for e in result.recorder.events if e["kind"] == "spans"]
