@@ -60,8 +60,8 @@ class Dataset:
     y_test: np.ndarray
     num_classes: int
     name: str = "dataset"
-    #: rows are ``[S + 1]`` token ids with document numbers as ``y``
-    #: (``load_tokens``), not an image and its label
+    #: rows are token ids with what the task needs beside them as ``y``
+    #: (``load_tokens``: the layout is the task's), not an image and its label
     token_rows: bool = False
 
 
@@ -255,13 +255,28 @@ def load_npz(path: str, dataset: str = "cifar10", num_classes: int | None = None
 
 
 def load_tokens(path: str) -> Dataset:
-    """A token data set for next-token training: ``x_*`` int32 ``[n, S + 1]``
-    token ids and ``y_*`` int32 ``[n, S + 1]`` the number of the document
-    each position belongs to (documents packed end to end; the layout
-    ``chipbench/tasks/next_token.py:make`` writes).  Both are kept as they
-    are and fed row for row: the model cuts a row into ``S`` inputs and the
-    ``S`` next ids, and masks attention and the loss by the document
-    numbers.  ``num_classes`` is the largest id + 1."""
+    """A token data set: ``x_*`` and ``y_*``, two int32 arrays of one shape
+    ``[n, width]``, kept as they are and fed row for row.  What a row holds
+    is the task's, and the model that trains on it says how it reads it
+    (``TokenDecoder.row_tokens``, ``row_positions``, ``judged_positions``);
+    the loader and the loop read nothing off a row's width.  The two layouts
+    there are:
+
+    * next-token prediction (``chipbench/tasks/next_token.py:make``;
+      ``mellum2``, ``keye_vl2``, ``qwen3_next``): ``x`` ``[n, S + 1]`` token
+      ids, ``y`` ``[n, S + 1]`` the number of the document each position
+      belongs to (documents packed end to end).  The model cuts a row into
+      ``S`` inputs and the ``S`` next ids, and masks attention and the loss
+      by the document numbers.
+    * block diffusion (``chipbench/tasks/block_diffusion.py:make``;
+      ``sdar``): ``x`` ``[n, 2 S]``, the row's noisy copy (the ``[MASK]`` id
+      where a position is masked) and then its clean copy; ``y`` ``[n, 2
+      S]``, the ``S`` document numbers and then, a position, the noise level
+      ``t`` of its block in 65,536ths.  The model runs all ``2 S`` positions
+      through every layer and predicts the ``S`` tokens' own ids at the
+      masked positions.
+
+    ``num_classes`` is the largest id + 1."""
     with np.load(path) as z:
         split = {k: np.ascontiguousarray(z[k], dtype=np.int32)
                  for k in ("x_train", "y_train", "x_test", "y_test")}
@@ -270,15 +285,17 @@ def load_tokens(path: str) -> Dataset:
         if x.ndim != 2 or x.shape != y.shape or x.shape[1] < 2:
             raise ValueError(
                 f"{path}: x_{half} {x.shape} and y_{half} {y.shape} must be "
-                f"the same [n, S + 1] ids and document numbers")
+                f"the same [n, width] ids and document numbers (with what "
+                f"else the task lays beside them)")
     return Dataset(split["x_train"], split["y_train"], split["x_test"],
                    split["y_test"], int(split["x_train"].max()) + 1,
                    name="tokens", token_rows=True)
 
 
 def judged_positions(docs: np.ndarray) -> int:
-    """Positions of token rows ``docs[n, S + 1]`` that carry a loss: those
-    whose next id lies in the same document."""
+    """Positions of next-token rows ``docs[n, S + 1]`` that carry a loss:
+    those whose next id lies in the same document.  (A block-diffusion row's
+    are its masked positions: ``models/sdar.py:Sdar.judged_positions``.)"""
     return int(np.sum(docs[:, 1:] == docs[:, :-1]))
 
 

@@ -62,7 +62,7 @@ from jax.ad_checkpoint import checkpoint_name
 from ..utils.profiling import device_span
 from .mellum2 import (MOE_COUNTERS, TokenDecoder, _head_loss, _moe,
                       _next_ids, _rms_norm, _rope, _visible,
-                      attention_weights, expert_weights, rope_inv_freq)
+                      attention_weights, expert_weights, rope_tables)
 
 __all__ = ["KeyeVL2"]
 
@@ -72,13 +72,6 @@ KEPT = "dsa_threshold"
 #: what a layer's attention counts (the module docstring says of what)
 DSA_COUNTERS = ("dsa_queries", "dsa_queries_selecting", "dsa_keys_visible",
                 "dsa_keys_kept", "dsa_kl_sum")
-
-
-def _rope_tables(s, head_dim, theta):
-    inv_freq, _ = rope_inv_freq("sliding", {"head_dim": head_dim,
-                                            "rope_theta": theta})
-    angle = jnp.arange(s, dtype=jnp.float32)[:, None] * inv_freq[None, :]
-    return jnp.cos(angle), jnp.sin(angle)
 
 
 def _project(p, h, sizes):
@@ -91,12 +84,12 @@ def _project(p, h, sizes):
         sizes["kv_heads_held"]
     heads, di = sizes["indexer_heads"], sizes["indexer_head_dim"]
     x = _rms_norm(h, p["attn_norm"], sizes["rms_norm_eps"])
-    cos, sin = _rope_tables(s, d, sizes["rope_theta"])
+    cos, sin = rope_tables(s, d, sizes["rope_theta"])
     q = _rope(jnp.dot(x, p["wq"]).reshape(b, s, hq, d), cos, sin)
     k = _rope(jnp.dot(x, p["wk"]).reshape(b, s, hkv, d), cos, sin)
     v = jnp.dot(x, p["wv"]).reshape(b, s, hkv, d)
     xi = lax.stop_gradient(x)
-    cos, sin = _rope_tables(s, di, sizes["rope_theta"])
+    cos, sin = rope_tables(s, di, sizes["rope_theta"])
     exact = functools.partial(jnp.dot, precision=HIGHEST)
     qi = _rope(exact(xi, p["idx_wq"]).reshape(b, s, heads, di), cos, sin)
     ki = _rope(exact(xi, p["idx_wk"]).reshape(b, s, 1, di), cos, sin)[:, :, 0]

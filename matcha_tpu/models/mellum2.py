@@ -68,12 +68,13 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from ..data.datasets import judged_positions
 from ..ops.grouped import (FORMS, grouped_dot, grouped_dot_transposed,
                            grouped_outer, product_plan)
 from ..utils.profiling import device_span
 
-__all__ = ["Mellum2", "TokenDecoder", "rope_inv_freq", "moe_capacity",
-           "attention_weights", "expert_weights"]
+__all__ = ["Mellum2", "TokenDecoder", "rope_inv_freq", "rope_tables",
+           "moe_capacity", "attention_weights", "expert_weights"]
 
 INIT_STD = 0.02
 #: rows of the grouped expert products over the slots an even router would
@@ -134,6 +135,19 @@ def _rope(x, cos, sin):
     a, b = x[..., :half], x[..., half:]
     cos, sin = cos[None, :, None, :], sin[None, :, None, :]
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+
+
+def rope_tables(positions, head_dim, theta):
+    """(cos, sin), each ``[len(positions), head_dim / 2]``, of plain RoPE at
+    the float32 ``positions`` given, or at ``0..s-1`` where a length ``s`` is:
+    a row's own positions, or whatever a model lays out (``models/sdar.py``:
+    both copies of a row carry ``0..S-1``)."""
+    inv_freq, _ = rope_inv_freq("sliding", {"head_dim": head_dim,
+                                            "rope_theta": theta})
+    if isinstance(positions, int):
+        positions = jnp.arange(positions, dtype=jnp.float32)
+    angle = positions[:, None] * inv_freq[None, :]
+    return jnp.cos(angle), jnp.sin(angle)
 
 
 def _visible(q_pos, k_pos, q_docs, k_docs, window):
@@ -331,8 +345,11 @@ def _experts(p, x, w_held, took, sizes):
     return y, computed.astype(jnp.float32)
 
 
-def _moe(p, x, sizes):
-    """(the experts held's part of the layer's output, its counters)."""
+def _moe(p, x, sizes, marked=None):
+    """(the experts held's part of the layer's output, its counters).  With
+    ``marked[B, S]`` (positions a model wants told apart: ``models/sdar.py``
+    marks the masked ones) the counters also hold ``moe_slots_marked``, the
+    slots held that a marked position sent."""
     b, s, hidden = x.shape
     with device_span("matcha/moe_route"):
         w, sel = _route(p, x.reshape(b * s, hidden), sizes)
@@ -343,8 +360,12 @@ def _moe(p, x, sizes):
     with device_span("matcha/moe_experts"):
         y, computed = _experts(p, x, w_held, took, sizes)
     load = jnp.sum(took, axis=0).astype(jnp.float32)
-    return y, {"moe_slots_held": jnp.sum(load), "moe_rows_computed": computed,
-               "moe_load": load}
+    counters = {"moe_slots_held": jnp.sum(load),
+                "moe_rows_computed": computed, "moe_load": load}
+    if marked is not None:
+        counters["moe_slots_marked"] = jnp.sum(
+            took & marked.reshape(b * s, 1)).astype(jnp.float32)
+    return y, counters
 
 
 def _block(p, h, docs, kind, sizes):
@@ -363,10 +384,15 @@ def _next_ids(x_raw, y_raw):
     return ids, docs, jnp.where(y_raw[:, 1:] == docs, x_raw[:, 1:], -1)
 
 
-def _head_loss(h, head, targets, sizes):
+def _head_loss(h, head, targets, sizes, weights=None, normaliser=None):
     """The untied head and its loss over ``h[B, S, H]``, a chunk of positions
     at a time under ``jax.checkpoint``: (the mean over judged positions of
-    float32 softmax cross-entropy, token accuracy over them, how many)."""
+    float32 softmax cross-entropy, token accuracy over them, how many).
+    ``targets`` is -1 where a position is not judged.  With ``weights[B, S]``
+    a judged position's cross-entropy counts that many times, and with a
+    ``normaliser`` the sum is divided by it and not by the number judged
+    (``models/sdar.py``: ``1 / t`` a masked position, over every token of the
+    batch)."""
     b, s, _ = h.shape
     chunk = sizes.get("loss_chunk", 1024)  # a test seam, as ``attn_block``
     if s % chunk:
@@ -374,12 +400,14 @@ def _head_loss(h, head, targets, sizes):
 
     @jax.checkpoint
     def of_chunk(part):
-        h_c, t_c = part  # [B, chunk, H], [B, chunk]
+        h_c, t_c, *w_c = part  # [B, chunk, H], [B, chunk], weights alike
         logits = jnp.dot(h_c, head).astype(jnp.float32)
         judged = t_c >= 0
         picked = jnp.take_along_axis(
             logits, jnp.maximum(t_c, 0)[..., None], axis=-1)[..., 0]
         nll = jax.scipy.special.logsumexp(logits, axis=-1) - picked
+        if w_c:
+            nll = nll * w_c[0]
         hit = jnp.argmax(logits, axis=-1) == t_c
         return (jnp.sum(jnp.where(judged, nll, 0.0)),
                 jnp.sum(judged & hit), jnp.sum(judged))
@@ -387,10 +415,12 @@ def _head_loss(h, head, targets, sizes):
     with device_span("matcha/lm_head_loss"):
         split = lambda a: jnp.moveaxis(
             a.reshape((b, s // chunk, chunk) + a.shape[2:]), 1, 0)
-        nll, hits, judged = lax.map(of_chunk, (split(h), split(targets)))
+        parts = (h, targets) if weights is None else (h, targets, weights)
+        nll, hits, judged = lax.map(of_chunk, tuple(map(split, parts)))
         judged = jnp.sum(judged).astype(jnp.float32)
         positions = jnp.maximum(judged, 1.0)
-        loss = jnp.sum(nll) / positions
+        loss = jnp.sum(nll) / (positions if normaliser is None
+                               else normaliser)
     return loss, jnp.sum(hits) / positions, judged
 
 
@@ -459,6 +489,25 @@ class TokenDecoder(nn.Module):
     def dummy_input(self, input_shape):
         """What ``init`` traces: parameters do not depend on the length."""
         return jnp.zeros((1, 8), jnp.int32)
+
+    # What a raw row of the data set is, is the model's to say: the loop
+    # asks here and reads nothing off a row's width.  These are next-token
+    # training's: a row ``[S + 1]`` of ids is ``S`` inputs and the ``S``
+    # next ids; a model whose task lays a row out otherwise gives its own
+    # (``models/sdar.py``).
+
+    def row_tokens(self, width: int) -> int:
+        """Tokens a raw row of ``width`` numbers gives a step to predict."""
+        return width - 1
+
+    def row_positions(self, width: int) -> int:
+        """Positions of such a row that pass through every layer."""
+        return self.row_tokens(width)
+
+    def judged_positions(self, x_raw, y_raw) -> int:
+        """Positions of the raw rows (numpy, on the host) that carry a loss:
+        what an evaluation batch's mean is a mean over."""
+        return judged_positions(y_raw)
 
     def expert_products(self, tokens: int, workers: int) -> dict:
         """:func:`expert_products` of a step of ``workers`` workers over

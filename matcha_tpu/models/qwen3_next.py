@@ -91,9 +91,8 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from ..utils.profiling import device_span
-from .keye_vl2 import _rope_tables
 from .mellum2 import (TokenDecoder, _head_loss, _moe, _next_ids, _rope,
-                      _swiglu, _visible, expert_weights)
+                      _swiglu, _visible, expert_weights, rope_tables)
 
 __all__ = ["Qwen3Next"]
 
@@ -334,7 +333,7 @@ def _attn_project(p, h, sizes):
         sizes["kv_heads_held"]
     eps = sizes["rms_norm_eps"]
     x = _norm0(h, p["attn_norm"], eps)
-    cos, sin = _rope_tables(s, sizes["rotary_dim"], sizes["rope_theta"])
+    cos, sin = rope_tables(s, sizes["rotary_dim"], sizes["rope_theta"])
     query, gate = jnp.split(jnp.dot(x, p["wq"]).reshape(b, s, hq, 2 * d), 2,
                             axis=-1)
     q = _partial_rope(_norm0(query, p["q_norm"], eps), cos, sin)

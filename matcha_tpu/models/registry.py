@@ -11,9 +11,10 @@ Also registers explicit names the reference cannot express: ``resnet20``
 (BASELINE.json's model), ``resnet32/44/56/110``, ``vgg11/13/19``, and the
 sparse decoders for next-token training whose sizes come as ``sizes={...}``:
 ``mellum2`` (``models/mellum2.py``), ``keye_vl2``, whose attention reads a
-learned choice of keys (``models/keye_vl2.py``), and ``qwen3_next``, three
+learned choice of keys (``models/keye_vl2.py``), ``qwen3_next``, three
 layers of linear attention to one of gated softmax attention
-(``models/qwen3_next.py``).
+(``models/qwen3_next.py``), and ``sdar``, trained by block diffusion over a
+noisy and a clean copy of every row (``models/sdar.py``).
 """
 
 from __future__ import annotations
@@ -27,6 +28,7 @@ from .mellum2 import Mellum2
 from .mlp import MLP
 from .qwen3_next import Qwen3Next
 from .resnet import ResNet, ResNetImageNet
+from .sdar import Sdar
 from .vgg import VGG
 from .wrn import WideResNet
 
@@ -54,7 +56,7 @@ DATASET_SHAPES = {
 
 
 TOKEN_MODELS = {"mellum2": Mellum2, "keye_vl2": KeyeVL2,
-                "qwen3_next": Qwen3Next}
+                "qwen3_next": Qwen3Next, "sdar": Sdar}
 
 
 def dataset_num_classes(dataset: str) -> int:

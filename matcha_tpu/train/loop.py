@@ -38,7 +38,6 @@ from ..obs.telemetry import make_telemetry_spec, telemetry_flush
 from ..utils import SpanRecorder, trace
 from ..data import (
     WorkerBatches,
-    judged_positions,
     load_npz,
     load_tokens,
     normalized_zero,
@@ -767,7 +766,8 @@ def train(config: TrainConfig, resume_dir: Optional[str] = None,
         # product and why
         if hasattr(model, "expert_products"):
             backend_decision["expert_products"] = model.expert_products(
-                config.batch_size * (input_shape[0] - 1), config.num_workers)
+                config.batch_size * model.row_positions(input_shape[0]),
+                config.num_workers)
         recorder.log_event("backend", **backend_decision)
     # how the forward/backward runs (packs of workers side by side in the
     # lanes, or vmap over workers) and, where it is the latter, why
@@ -1024,7 +1024,8 @@ def train(config: TrainConfig, resume_dir: Optional[str] = None,
                     e_scan, state, loader, stacks, epoch, rng,
                     config.scan_chunk,
                     spans, ledger=cost_ledger, label=_step_label, mesh=mesh,
-                    token_rows=dataset.token_rows)
+                    row_tokens=(model.row_tokens if dataset.token_rows
+                                else None))
             else:
                 with spans.span("epoch_python"):
                     sums: Dict[str, float] = {}
@@ -1244,7 +1245,8 @@ def train(config: TrainConfig, resume_dir: Optional[str] = None,
                 test_loss, test_acc = _evaluate_in_batches(
                     evaluate, state, dataset.x_test, dataset.y_test,
                     batch=eval_batch, ledger=cost_ledger,
-                    weigh=judged_positions if dataset.token_rows else len,
+                    weigh=(model.judged_positions if dataset.token_rows
+                           else None),
                 )
                 if faults is not None or member_alive_np is not None:
                     # same quarantine exemption as the train-side metrics: a
@@ -1673,7 +1675,7 @@ def _run_epoch_scanned(scan_step, state, loader: WorkerBatches,
                        stacks: _HostStacks, epoch: int,
                        rng, scan_chunk: Optional[int], spans, ledger=None,
                        label: str = "epoch_scan", mesh=None,
-                       token_rows: bool = False):
+                       row_tokens=None):
     """One epoch through the scanned step, whole-epoch or chunk-pipelined.
 
     ``scan_chunk=None`` stages the full ``[steps, N, B, ...]`` stack (the
@@ -1689,12 +1691,14 @@ def _run_epoch_scanned(scan_step, state, loader: WorkerBatches,
     """
 
     def tokens_of(xs):
-        """A token job's (``token_rows``: ``data.Dataset``) ``dispatch``
-        says how many positions it predicts: ``S`` of every ``[S + 1]`` row
-        of ids."""
-        if not token_rows:
+        """A token job's ``dispatch`` says how many tokens it predicts:
+        ``row_tokens(width)`` of every row, which is the model's to say
+        (``TokenDecoder.row_tokens``: ``S`` of a next-token row ``[S + 1]``,
+        ``S`` of a block-diffusion row ``[2 S]``), and None for image rows."""
+        if row_tokens is None:
             return {}
-        return {"tokens": int(np.prod(xs.shape[:-1])) * (xs.shape[-1] - 1)}
+        return {"tokens": int(np.prod(xs.shape[:-1]))
+                * row_tokens(xs.shape[-1])}
 
     def run_segment(s, first, steps, **segment):
         """Gather steps ``[first, first + steps)`` into a kept stack, put
@@ -1787,7 +1791,7 @@ def _with_counters(epoch_metrics, counts):
 
 
 def _evaluate_in_batches(evaluate, state, x_test, y_test, batch: int = 512,
-                         ledger=None, weigh=len):
+                         ledger=None, weigh=None):
     """Full-test-set eval (reference test() covers the partial tail batch too,
     util.py:422-432) — at most two compiled shapes: `batch` and the tail."""
     losses, accs, weights = [], [], []
@@ -1805,7 +1809,9 @@ def _evaluate_in_batches(evaluate, state, x_test, y_test, batch: int = 512,
         # graftcontract: sync — second half of the same eval readback
         accs.append(np.asarray(a))
         # a batch's mean is over its rows, or over the positions judged
-        weights.append(weigh(y_test[i : i + batch]))
+        # (``weigh(x, y)``: a token model's own count of them)
+        weights.append(len(yl) if weigh is None else weigh(
+            x_test[i : i + batch], y_test[i : i + batch]))
     # graftcontract: sync — host batch-size weights (never device values)
     w = np.asarray(weights, np.float64)[:, None]
     return (

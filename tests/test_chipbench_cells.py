@@ -1,4 +1,4 @@
-"""The three token cells rehearsed tiny on the CPU from ``stage_job`` to
+"""The four token cells rehearsed tiny on the CPU from ``stage_job`` to
 ``check.compare``, as ``chipbench/tests/test_cells_on_cpu.py`` rehearses
 every cell of ``BENCHMARK.json`` outside tier-1 (the two conv cells take a
 minute and half a minute there; these fit here), and the control that has
@@ -20,8 +20,16 @@ CELLS = {
         "dsa_selecting_pct", "dsa_keys_kept_pct", "dsa_indexer_kl"},
     "qwen3-next-80b-a3b.ep64-s8k.w2-matcha": {
         "gdn_chunks_reset_pct", "gdn_decay_mean"},
+    "sdar-30b-a3b.ep16-s4k.w2-matcha": {
+        "bd_masked_pct", "bd_scored_over_visible", "bd_masked_slots_pct"},
 }
 COUNTER_METRICS = sorted(set().union(*CELLS.values()))
+
+import reference_once  # noqa: E402  (beside this file)
+
+# the control plants its fault in the program alone: the reference it is
+# held to is the sound run's, computed once (ROADMAP D11)
+reference_once.install()
 
 _spec = importlib.util.spec_from_file_location(
     "chipbench_tests_cells_on_cpu", Path(__file__).resolve().parents[1]
@@ -68,6 +76,12 @@ def test_counters_fill_the_cells_own_metrics(cell, line):
         assert 0 < got["moe_slot_fill_pct"] <= 100
         assert 1 <= got["moe_load_max_over_mean"] <= 2  # 2 experts held
         assert 90 < got["loss_positions_pct"] <= 100
+    elif "bd_masked_pct" in mine:  # 64 tokens a row in blocks of 32 queries
+        assert 35 < got["bd_masked_pct"] < 70  # 52.5 of many blocks; 256 here
+        # 8,192 pairs scored a row for the 4,352 that the four rules let see
+        # in one document (1.88), and fewer where a row holds two
+        assert 1.88 <= got["bd_scored_over_visible"] < 4
+        assert 0 < got["bd_masked_slots_pct"] < 100
     elif "gdn_decay_mean" in mine:  # 8 chunks a row, a start in a few
         assert 0 < got["gdn_chunks_reset_pct"] < 50
         assert 0 < got["gdn_decay_mean"] < 1

@@ -12,6 +12,12 @@ from pathlib import Path
 THERE = Path(__file__).resolve().parents[1] / "chipbench" / "tests"
 sys.path.insert(0, str(THERE))  # ``planted_faults_gdn``, ``test_cells_on_cpu``
 
+import reference_once  # noqa: E402  (beside this file)
+
+# a fault lies in the program alone: the reference is computed once a
+# question, not once a fault (ROADMAP D11)
+reference_once.install()
+
 _spec = importlib.util.spec_from_file_location(
     "chipbench_tests_test_gdn_cell_faults", THERE / "test_gdn_cell_faults.py")
 _module = importlib.util.module_from_spec(_spec)

@@ -391,10 +391,10 @@ NEW = ["fwd_bwd_ms", "sgd_ms", "gossip_ms", "scope_matched_pct",
        "moe_experts_ms", "moe_route_ms", "lm_head_loss_ms", "attn_window_ms",
        "attn_full_ms", "dsa_index_ms", "dsa_select_ms", "attn_sparse_ms",
        "gdn_chunk_prep_ms", "gdn_scan_ms", "gdn_conv_ms",
-       "attn_full_gated_ms"]
+       "attn_full_gated_ms", "bd_attn_ms"]
 #: the model file a cell's configuration runs (the image cells: none)
 MODEL_OF = {"mellum2": "models/mellum2.py", "keye-vl2": "models/keye_vl2.py",
-            "qwen3-next": "models/qwen3_next.py"}
+            "qwen3-next": "models/qwen3_next.py", "sdar": "models/sdar.py"}
 #: the scopes every model's step has, and the token models' shared layers
 SHARED = ["train/state.py", "models/mellum2.py"]
 

@@ -211,7 +211,8 @@ def test_evaluation_gives_held_out_loss_and_token_accuracy(run):
 @pytest.mark.parametrize("conf_name, cell", [
     ("mellum2-12b-a2.5b.ep8-s4k", "mellum2-12b-a2.5b.ep8-s4k.w2-matcha"),
     ("keye-vl2-30b-a3b.ep16-s8k", "keye-vl2-30b-a3b.ep16-s8k.w2-matcha"),
-    ("qwen3-next-80b-a3b.ep64-s8k", "qwen3-next-80b-a3b.ep64-s8k.w2-matcha")])
+    ("qwen3-next-80b-a3b.ep64-s8k", "qwen3-next-80b-a3b.ep64-s8k.w2-matcha"),
+    ("sdar-30b-a3b.ep16-s4k", "sdar-30b-a3b.ep16-s4k.w2-matcha")])
 def test_job_file_hands_the_program_the_configurations_sizes(conf_name, cell):
     """The harness passes ``TrainConfig`` fields only, so a token cell's job
     file repeats the configuration's sizes: they must not drift apart."""
@@ -246,6 +247,13 @@ def test_job_file_hands_the_program_the_configurations_sizes(conf_name, cell):
             sizes["linear_value_heads_held"], sizes["linear_key_dim"],
             sizes["linear_value_dim"], sizes["conv_kernel"],
             sizes["shared_expert_width"], sizes["rotary_dim"])
+    elif "block_length" in sizes:
+        assert (conf["num_hidden_layers"], conf["rope_theta"],
+                conf["rms_norm_eps"], conf["norm_topk_prob"]) == (
+            sizes["num_layers"], sizes["rope_theta"], sizes["rms_norm_eps"],
+            sizes["norm_topk_prob"])
+        assert sizes["mask_id"] == sizes["vocab_held"] - 1
+        assert sizes["seq_len"] % sizes["block_length"] == 0
     else:
         indexer = conf["sa_config"]
         assert (conf["num_hidden_layers"], conf["rope_theta"],

@@ -4,7 +4,15 @@ trees are what the parent's were: names, order, shapes and dtypes at the
 published sizes, and the initial values of the rehearsal sizes under a fixed
 seed (a parameter's value follows its place among the ``param`` calls, so a
 changed order would change it).  The sums were taken on the parent commit
-(fbdcdca) by this file's own functions."""
+(fbdcdca) by this file's own functions.
+
+PR 39 edited ``models/mellum2.py`` again (RoPE tables by given positions, a
+weight a position in ``_head_loss``, marked positions in ``_moe``, what a raw
+row is on ``TokenDecoder``) for the block-diffusion model: the three standing
+token cells' trees, the linear-attention cell's now among them, are still
+what that PR's parent (9661a8b) gave, and the new cell's tree is pinned
+beside them as that PR left it (51 leaves: a layer's ``q_norm`` and
+``k_norm`` follow its ``wo``)."""
 
 import copy
 import hashlib
@@ -24,6 +32,12 @@ PARENT = {
     "keye-vl2-30b-a3b.ep16-s8k.w2-matcha": (
         55, "547e4b3df1cd29ef9041da6df9152a79c440cb2151fac43295380f41c2172293",
         "b46d163d5e02f0d3151a27760c98592e2dd5a6b4ace169ef126cb90da2f42ec2"),
+    "qwen3-next-80b-a3b.ep64-s8k.w2-matcha": (
+        70, "807c11b33ec2a05c0f2336c3754e75d1cf937fabcb8aa640a27a59716d7155ac",
+        "145b40ea827e3b71d213aa40fdde1b6c156239f6f5550e32f57f02edc49e62a5"),
+    "sdar-30b-a3b.ep16-s4k.w2-matcha": (
+        51, "8c50b8b10b8d505cd36ddaeaed6424fdbd0dae7a93de3e6b5817429d40bd6643",
+        "59a64628cb084eb0b5a4cb69c6dcf72a8c921e939340e51b47a8b519aab6c06e"),
 }
 
 

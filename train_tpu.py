@@ -57,7 +57,7 @@ def _parse(argv=None):
     p.add_argument("--description", default="matcha_tpu run")
     p.add_argument("--model", default="resnet20",
                    help="res|resnet<d>|VGG|vgg<d>|wrn|wrn-<d>-<k>|mlp|mellum2|"
-                        "keye_vl2")
+                        "keye_vl2|qwen3_next|sdar")
     p.add_argument("--model-kwargs", default=None, dest="model_kwargs",
                    help="JSON (or the path of a JSON file) of keyword "
                         "arguments for the model: a model whose sizes are "
