@@ -5,7 +5,8 @@ A planted fault lies in the program alone, so every fault of a cell asks
 the same configuration, hyperparameters, initial state, rows, schedule and
 precision.  The fault collectors (``tests/test_chipbench_*_faults.py``) and
 ``tests/test_chipbench_cells.py`` used to have it answered again for every
-fault, which was most of their minutes (ROADMAP D11).  :func:`install` puts a
+fault, which was most of their minutes (ROADMAP D11).  :func:`install`
+(called by ``chipbench_tests.load``, which they all load through) puts a
 memo in front of it, keyed by a digest of **everything** it reads, so an
 answer is reused only where the question is the same to the byte; a fault
 that did reach the reference's inputs would get its own answer."""

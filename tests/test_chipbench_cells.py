@@ -2,14 +2,15 @@
 ``check.compare``, as ``chipbench/tests/test_cells_on_cpu.py`` rehearses
 every cell of ``BENCHMARK.json`` outside tier-1 (the two conv cells take a
 minute and half a minute there; these fit here), and the control that has
-to fail."""
+to fail.  This file runs the two softmax-attention cells;
+``test_chipbench_cells_more.py`` runs the same cases on the other two, beside
+it (a cell is two rehearsals, a minute of a busy machine)."""
 
-import importlib.util
 import json
-from pathlib import Path
 
 import pytest
 
+import chipbench_tests  # beside this file
 from chipbench import catalog
 
 #: each token cell, and the counters' metrics that are its own
@@ -24,22 +25,15 @@ CELLS = {
         "bd_masked_pct", "bd_scored_over_visible", "bd_masked_slots_pct"},
 }
 COUNTER_METRICS = sorted(set().union(*CELLS.values()))
+HERE, MORE = list(CELLS)[:2], list(CELLS)[2:]
 
-import reference_once  # noqa: E402  (beside this file)
-
-# the control plants its fault in the program alone: the reference it is
-# held to is the sound run's, computed once (ROADMAP D11)
-reference_once.install()
-
-_spec = importlib.util.spec_from_file_location(
-    "chipbench_tests_cells_on_cpu", Path(__file__).resolve().parents[1]
-    / "chipbench" / "tests" / "test_cells_on_cpu.py")
-_cells_on_cpu = importlib.util.module_from_spec(_spec)
-_spec.loader.exec_module(_cells_on_cpu)
-rehearse = _cells_on_cpu.rehearse  # its one rehearsal run, not its tests
+# its one rehearsal run, not its tests.  The control plants its fault in the
+# program alone: the reference it is held to is the sound run's, computed
+# once (``chipbench_tests.load``)
+rehearse = chipbench_tests.load("test_cells_on_cpu.py").rehearse
 
 
-@pytest.fixture(scope="module", params=list(CELLS))
+@pytest.fixture(scope="module", params=HERE)
 def cell(request):
     return request.param
 

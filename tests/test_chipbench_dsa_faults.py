@@ -5,25 +5,6 @@
 ``test_chipbench_token_faults.py`` collects the other token cell's.  A file
 of its own, so that the two run beside each other."""
 
-import importlib.util
-import sys
-from pathlib import Path
+import chipbench_tests  # beside this file
 
-THERE = Path(__file__).resolve().parents[1] / "chipbench" / "tests"
-sys.path.insert(0, str(THERE))  # ``planted_faults_dsa``, ``test_cells_on_cpu``
-
-import reference_once  # noqa: E402  (beside this file)
-
-# a fault lies in the program alone: the reference is computed once a
-# question, not once a fault (ROADMAP D11)
-reference_once.install()
-
-_spec = importlib.util.spec_from_file_location(
-    "chipbench_tests_test_dsa_cell_faults", THERE / "test_dsa_cell_faults.py")
-_module = importlib.util.module_from_spec(_spec)
-sys.modules[_spec.name] = _module
-_spec.loader.exec_module(_module)
-
-# its tests, under their own names
-globals().update({name: thing for name, thing in vars(_module).items()
-                  if name.startswith("test_")})
+globals().update(chipbench_tests.tests_of("test_dsa_cell_faults.py"))

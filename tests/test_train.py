@@ -227,45 +227,47 @@ def test_compress_warmup_validation():
         TrainConfig(communicator="choco", compress_warmup_epochs=-1)
 
 
-def test_train_conv_model_smoke():
+#: ResNet-8, 4 workers on a generator ring, two epochs of separable synthetic
+#: images.  Sized for ~35 s of single-core XLA-CPU compile; the full-size
+#: conv configs run on TPU via benchmarks/run_baselines.py.
+CONV = TrainConfig(
+    name="conv-smoke", model="resnet8", dataset="synthetic_image",
+    dataset_kwargs={"num_train": 64, "num_test": 32, "separation": 40.0},
+    num_workers=4, devices=1, graphid=None, topology="ring", batch_size=4, epochs=2,
+    lr=0.05, warmup=False, matcha=False, fixed_mode="all", seed=0,
+    save=False, eval_every=1, measure_comm_split=False,
+)
+
+
+@pytest.fixture(scope="module")
+def conv_history():
+    """``train(CONV)`` once a file: the smoke's run is the reference the
+    memory knobs are held to."""
+    return train(CONV).history
+
+
+def test_train_conv_model_smoke(conv_history):
     """A conv model through the vmapped train step (not just a forward pass —
-    test_models stops there): ResNet-8, 4 workers on a generator ring, two
-    epochs of separable synthetic images, deterministic loss decrease.
-    Sized for ~35 s of single-core XLA-CPU compile; the full-size conv
-    configs run on TPU via benchmarks/run_baselines.py."""
-    cfg = TrainConfig(
-        name="conv-smoke", model="resnet8", dataset="synthetic_image",
-        dataset_kwargs={"num_train": 64, "num_test": 32, "separation": 40.0},
-        num_workers=4, devices=1, graphid=None, topology="ring", batch_size=4, epochs=2,
-        lr=0.05, warmup=False, matcha=False, fixed_mode="all", seed=0,
-        save=False, eval_every=3, measure_comm_split=False,
-    )
-    hist = train(cfg).history
+    test_models stops there): deterministic loss decrease."""
+    hist = conv_history
     assert len(hist) == 2
     assert np.isfinite(hist[-1]["loss"])
     assert hist[1]["loss"] < hist[0]["loss"]  # measured: 2.369 -> 2.079
     assert np.isfinite(hist[-1]["disagreement"])
 
 
-def test_train_remat_and_grad_chunk_exact():
+def test_train_remat_and_grad_chunk_exact(conv_history):
     """remat (block-level rematerialization) and grad_chunk (worker-slab
     fwd/bwd) are pure memory/FLOPs trades — both must reproduce the default
     step bit-for-bit-ish (state.py make_train_step, models _remat_block).
-    One epoch of the conv smoke config under each knob."""
-    cfg = TrainConfig(
-        name="remat-eq", model="resnet8", dataset="synthetic_image",
-        dataset_kwargs={"num_train": 32, "num_test": 16, "separation": 40.0},
-        num_workers=4, devices=1, graphid=None, topology="ring", batch_size=4, epochs=1,
-        lr=0.05, warmup=False, matcha=False, fixed_mode="all", seed=0,
-        save=False, eval_every=1, measure_comm_split=False,
-    )
-    ref = train(cfg).history[-1]
+    The conv smoke config under each knob."""
+    ref = conv_history[-1]
     # grad_chunk=2 in the combined knob: with 4 workers, grad_chunk=4 would
     # short-circuit to plain vmap and never test remat inside the lax.map
     # slab path (the matcha-resnet50-imagenet-256w production combination)
     for knob in ({"remat": True}, {"grad_chunk": 2},
                  {"remat": True, "grad_chunk": 2}):
-        got = train(dataclasses.replace(cfg, **knob)).history[-1]
+        got = train(dataclasses.replace(CONV, **knob)).history[-1]
         assert got["loss"] == pytest.approx(ref["loss"], rel=1e-5), knob
         assert got["test_acc_mean"] == pytest.approx(
             ref["test_acc_mean"], abs=1e-6), knob
