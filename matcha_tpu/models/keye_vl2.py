@@ -40,7 +40,8 @@ one layer's, which is what lets 8,192 positions run without a fused kernel
 (compile-only, v5e: one worker's forward/backward of the cell takes 4.85 GB
 of temporaries).  A block keeps the selection's thresholds (``[block]``
 numbers, by name), so its recomputation does not search for them again.
-``remat`` recomputes a layer's projections and its expert layer besides.
+``remat`` recomputes a layer's projections and its expert layer besides, the
+expert layer over the dispatch it kept (``mellum2.checkpointed``).
 
 Counters, returned with the loss and summed over layers: ``dsa_queries``
 (layer-queries), ``dsa_queries_selecting`` (those that see more than
@@ -62,7 +63,8 @@ from jax.ad_checkpoint import checkpoint_name
 from ..utils.profiling import device_span
 from .mellum2 import (MOE_COUNTERS, TokenDecoder, _head_loss, _moe,
                       _next_ids, _rms_norm, _rope, _visible,
-                      attention_weights, expert_weights, rope_tables)
+                      attention_weights, checkpointed, expert_weights,
+                      rope_tables)
 
 __all__ = ["KeyeVL2"]
 
@@ -210,7 +212,7 @@ def _experts_of(p, h, sizes):
 
 
 def _block(p, h, docs, sizes, remat):
-    again = jax.checkpoint if remat else (lambda f: f)
+    again = checkpointed(remat)
     projected = again(functools.partial(_project, sizes=sizes))(p, h)
     out, counters = _sparse_attention(*projected, docs, sizes)
     h = h + jnp.dot(out, p["wo"])

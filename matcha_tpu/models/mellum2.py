@@ -34,6 +34,21 @@ the TPU they are ``jax.lax.ragged_dot``, and the journal's ``backend`` event
 says which and why (:func:`expert_products`).  Slots past the rows go through
 every expert held under a 0/1 mask, in a branch that runs only in a step that
 has such slots.
+**A layer's rows are dispatched once.**  The tokens ``[T, H]`` are rounded to
+bfloat16 first and their rows gathered after, at two bytes an element, and
+gate and up read that one operand (:func:`_dispatched_bf16`): rounding
+commutes with a gather, so the kernels read the values they would of float32
+rows rounded after, and nothing passes over ``[rows, H]`` to round it.  The
+rounding sits before the gather behind a rule of its own because the way back
+must not be rounded: the two data gradients are summed and scatter-added into
+``[T, H]`` in float32, where a token in several slots sums them.  The layout
+is one scatter of whole numbers (the slot a row holds) and a gather of the
+weights by it.  The gathered rows (151 MB a layer at 32,768 x 2,304) and the
+layout's small arrays carry names (``MOE_KEPT``), and a model wraps its layers
+in :func:`checkpointed`, which under ``remat`` keeps those and computes the
+rest again: the second pass is gate, up, SwiGLU and down over kept rows (down
+too: its output meets the cotangent in the router's gradient), no layout, no
+gather, no rounding of the rows.
 Shapes are static either way, and the counters say what it cost:
 ``moe_rows_computed`` rows went through an expert for ``moe_slots_held``
 slots that were real.
@@ -59,6 +74,7 @@ model's), so a model's layers may be of kinds with different ones
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any
 
@@ -67,6 +83,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from ..data.datasets import judged_positions
 from ..ops.grouped import (FORMS, grouped_dot, grouped_dot_transposed,
@@ -74,7 +91,8 @@ from ..ops.grouped import (FORMS, grouped_dot, grouped_dot_transposed,
 from ..utils.profiling import device_span
 
 __all__ = ["Mellum2", "TokenDecoder", "rope_inv_freq", "rope_tables",
-           "moe_capacity", "attention_weights", "expert_weights"]
+           "moe_capacity", "attention_weights", "expert_weights",
+           "checkpointed", "MOE_KEPT"]
 
 INIT_STD = 0.02
 #: rows of the grouped expert products over the slots an even router would
@@ -83,6 +101,22 @@ INIT_STD = 0.02
 ROWS_PER_EVEN_SLOT = 2
 #: what the expert layer counts beside ``moe_load``, summed over the layers
 MOE_COUNTERS = ("moe_slots_held", "moe_rows_computed")
+#: what the expert layer names for the checkpoint around it to keep
+#: (:func:`checkpointed`): the rows gathered for the products, as the
+#: products read them, and the layout's small arrays (``fits``, ``slot``,
+#: ``w_rows``, ``groups``), so that a recomputed layer dispatches nothing
+MOE_ROWS, MOE_LAYOUT = MOE_KEPT = ("moe_rows", "moe_layout")
+
+
+def checkpointed(remat: bool):
+    """What a token model wraps a layer, or a part of one, in: under
+    ``remat`` a ``jax.checkpoint`` that keeps, of all it computes, what the
+    expert layer names (``MOE_KEPT``); otherwise nothing."""
+    if not remat:
+        return lambda f: f
+    return functools.partial(
+        jax.checkpoint,
+        policy=jax.checkpoint_policies.save_only_these_names(*MOE_KEPT))
 
 
 def rope_inv_freq(kind: str, sizes):
@@ -244,11 +278,66 @@ def _grouped_bf16_bwd(kept, g):
 _grouped_bf16.defvjp(_grouped_bf16_fwd, _grouped_bf16_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _dispatched_bf16(tokens, flat, token, weights, groups):
+    """The grouped products of the rows ``flat[token]`` with each of
+    ``weights`` (gate and up: one left operand), each as
+    :func:`_grouped_bf16` runs one.  ``flat[T, H]`` is rounded to bfloat16
+    once, its rows are gathered at two bytes an element and named
+    ``MOE_ROWS``, and the products read them as they are: rounding commutes
+    with a gather, so these are the values of gathering float32 rows and
+    rounding them after.  The way back stays float32: the products' data
+    gradients are summed and scatter-added into ``[T, H]`` unrounded (an
+    ``astype`` before the gather would have autodiff round that cotangent to
+    bfloat16 before the sum over a token's slots: another result).
+    ``token[rows]`` is the token a row holds, ``tokens`` where it holds
+    none: such a row reads the last token and adds nothing back."""
+    return _dispatched_bf16_fwd(tokens, flat, token, weights, groups)[0]
+
+
+def _dispatched_bf16_fwd(tokens, flat, token, weights, groups):
+    rows = checkpoint_name(
+        flat.astype(jnp.bfloat16)[jnp.minimum(token, tokens - 1)], MOE_ROWS)
+    weights = tuple(w.astype(jnp.bfloat16) for w in weights)
+    return (tuple(grouped_dot(rows, w, groups) for w in weights),
+            (rows, token, weights, groups))
+
+
+def _dispatched_bf16_bwd(tokens, kept, gs):
+    rows, token, weights, groups = kept
+    gs = [g.astype(jnp.bfloat16) for g in gs]
+    backs = [grouped_dot_transposed(g, w, groups)
+             for g, w in zip(gs, weights)]
+    back = sum(backs[1:], backs[0])
+    return (jnp.zeros((tokens, rows.shape[1]), back.dtype).at[token].add(
+                back, mode="drop"),
+            np.zeros(token.shape, jax.dtypes.float0),
+            tuple(grouped_outer(rows, g, groups) for g in gs),
+            np.zeros(groups.shape, jax.dtypes.float0))
+
+
+_dispatched_bf16.defvjp(_dispatched_bf16_fwd, _dispatched_bf16_bwd)
+
+
+def _gate_and_up(flat, token, p, groups):
+    """(gate's, up's) grouped products of the rows ``flat[token]`` (as
+    :func:`_dispatched_bf16` reads ``token``), at the precision ``jnp.dot``
+    has by default: on the TPU one bfloat16 pass (float32 operands would
+    cost the grouped kernel several); anywhere else ``lax.ragged_dot`` of
+    the float32 rows.  The gathered rows carry the name ``MOE_ROWS`` either
+    way."""
+    tokens = flat.shape[0]
+    weights = (p["gate"], p["up"])
+    if _one_bf16_pass():
+        return _dispatched_bf16(tokens, flat, token, weights, groups)
+    rows = checkpoint_name(flat[jnp.minimum(token, tokens - 1)], MOE_ROWS)
+    return tuple(lax.ragged_dot(rows, w, groups) for w in weights)
+
+
 def _grouped_product(lhs, weights, groups):
-    """The grouped product at the precision ``jnp.dot`` has by default.  On
-    the TPU that is :func:`_grouped_bf16` (float32 operands would cost the
-    grouped kernel several passes); anywhere else ``lax.ragged_dot`` as it
-    is."""
+    """The grouped product of float32 ``lhs`` at the precision ``jnp.dot``
+    has by default: :func:`_grouped_bf16` on the TPU, anywhere else
+    ``lax.ragged_dot`` as it is."""
     if _one_bf16_pass():
         return _grouped_bf16(lhs, weights, groups)
     return lax.ragged_dot(lhs, weights, groups)
@@ -261,7 +350,9 @@ def expert_products(sizes, tokens: int, layers: int, remat: bool,
     product_plan``: the kernel and its tiles, or ``lax.ragged_dot`` and the
     reason), with how many products of a step are of it (a layer has gate,
     up and down, each once forward, again where ``remat`` recomputes the
-    layer, and once a gradient), the kernel sites an epoch program holds
+    layer (down too, though the second pass keeps its dispatch: the router's
+    gradient reads its output; read off the compiled epoch program, PR 42),
+    and once a gradient), the kernel sites an epoch program holds
     (one a form and shape) and the products that run on them."""
     rows = moe_capacity(tokens, sizes)
     hid, width = sizes["hidden"], sizes["expert_width"]
@@ -300,9 +391,12 @@ def _experts(p, x, w_held, took, sizes):
 
     The slots are laid out sorted by expert in ``rows`` rows (expert ``e``'s
     queue starts where the queues before it end), and three grouped
-    products (:func:`_grouped_product`, one group an expert) run over them,
-    so the cost follows the slots and not the busiest expert.  Rows past the
-    last slot hold a token at weight 0 and count with the last expert."""
+    products (:func:`_gate_and_up`, :func:`_grouped_product`; one group an
+    expert) run over them, so the cost follows the slots and not the busiest
+    expert.  Rows past the last slot hold a token at weight 0 and count
+    with the last expert.  The gathered rows and the layout's arrays carry
+    the names ``MOE_KEPT``, which a checkpoint around the layer keeps
+    (:func:`checkpointed`)."""
     b, s, hidden = x.shape
     tokens, held = took.shape
     flat = x.reshape(tokens, hidden)
@@ -310,16 +404,24 @@ def _experts(p, x, w_held, took, sizes):
     count = jnp.sum(took, axis=0)
     start = jnp.cumsum(count) - count  # where each expert's queue starts
     place = start[None, :] + jnp.cumsum(took, axis=0) - 1
-    fits = took & (place < rows)
-    at = jnp.where(fits, place, rows)  # ``rows`` is out of bounds: dropped
-    token = jnp.full((rows,), tokens, jnp.int32).at[at].set(
-        jnp.arange(tokens, dtype=jnp.int32)[:, None], mode="drop")
-    w_rows = jnp.zeros((rows,), w_held.dtype).at[at].set(w_held, mode="drop")
+    layout = lambda a: checkpoint_name(a, MOE_LAYOUT)
+    fits = layout(took & (place < rows))
+    # the slot ``token x held + expert`` each row holds, by one scatter of
+    # whole numbers (``rows`` is out of bounds: dropped); a row that holds
+    # none reads ``tokens x held``: token ``tokens``, weight 0
+    slot = layout(jnp.full((rows,), tokens * held, jnp.int32).at[
+        jnp.where(fits, place, rows)].set(
+            jnp.arange(tokens * held, dtype=jnp.int32).reshape(tokens, held),
+            mode="drop"))
+    token = slot // held
+    w_rows = layout(w_held.reshape(-1).at[slot].get(mode="fill",
+                                                    fill_value=0))
     ends = jnp.minimum(start + count, rows)
     groups = (ends - jnp.minimum(start, rows)).astype(jnp.int32)
-    groups = groups.at[-1].add(rows - jnp.sum(groups))
-    grouped = lambda lhs, weights: _grouped_product(lhs, weights, groups)
-    y_rows = _swiglu(flat[jnp.minimum(token, tokens - 1)], p, grouped)
+    groups = layout(groups.at[-1].add(rows - jnp.sum(groups)))
+    gate_rows, up_rows = _gate_and_up(flat, token, p, groups)
+    y_rows = _grouped_product(jax.nn.silu(gate_rows) * up_rows, p["down"],
+                              groups)
     y = jnp.zeros_like(flat).at[token].add(y_rows * w_rows[:, None],
                                           mode="drop")
 
@@ -509,6 +611,14 @@ class TokenDecoder(nn.Module):
         what an evaluation batch's mean is a mean over."""
         return judged_positions(y_raw)
 
+    @property
+    def remat_keeps(self):
+        """What the layers' checkpoints keep by name for the backward pass
+        under ``remat`` (:func:`checkpointed`; a subclass adds its own):
+        the journal's ``fwd_bwd`` event carries it
+        (``train/state.py:fwd_bwd_plan``)."""
+        return MOE_KEPT if self.remat else ()
+
     def expert_products(self, tokens: int, workers: int) -> dict:
         """:func:`expert_products` of a step of ``workers`` workers over
         ``tokens`` positions each (a subclass says how many of its layers
@@ -557,8 +667,7 @@ class Mellum2(TokenDecoder):
         for p, kind in zip(self.layers, self.sizes["layer_types"]):
             block = lambda p, h, docs, kind=kind: _block(
                 p, h, docs, kind, self.sizes)
-            h, c = (jax.checkpoint(block) if self.remat else block)(
-                p, h, docs)
+            h, c = checkpointed(self.remat)(block)(p, h, docs)
             counters.append(c)
         return self.normed(h, counters)
 

@@ -45,7 +45,8 @@ inverse of 0s and 1s) and was not taken (PERF.md section 4).  The backward
 pass keeps ``T`` and nothing of the levels that built it: ``dT = -T dA T``,
 so a cotangent ``G`` of ``T`` is ``-T^T G T^T`` of ``A``, two products of the
 ``T`` the forward pass has (``_unit_lower_inverse``'s ``custom_vjp``).  Under
-``remat`` a layer is computed twice (the block's checkpoint keeps nothing)
+``remat`` a layer is computed twice (the block's checkpoint keeps nothing of
+the mixer; the expert layer's keeps its dispatch, ``mellum2.MOE_KEPT``)
 and its chunk systems, convolution and gated norm lie under checkpoints of
 their own inside it; the chunk systems' keeps ``T`` by name (``KEPT``), so
 its third pass inverts nothing and is left the decays ``D`` and the two Gram
@@ -92,7 +93,8 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..utils.profiling import device_span
 from .mellum2 import (TokenDecoder, _head_loss, _moe, _next_ids, _rope,
-                      _swiglu, _visible, expert_weights, rope_tables)
+                      _swiglu, _visible, checkpointed, expert_weights,
+                      rope_tables)
 
 __all__ = ["Qwen3Next"]
 
@@ -414,10 +416,10 @@ _again_keeping = functools.partial(
 
 
 def _block(p, h, docs, kind, sizes, remat):
-    again = jax.checkpoint if remat else (lambda f: f)
+    again = checkpointed(remat)
     if kind == "linear":
-        # the layer's own checkpoint keeps nothing; those inside it keep
-        # what is named ``KEPT``
+        # the layer's own checkpoint keeps nothing of it; those inside it
+        # keep what is named ``KEPT``
         out, counters = again(functools.partial(
             _gated_delta_net, sizes=sizes,
             again=_again_keeping if remat else again))(p, h, docs)
@@ -479,10 +481,12 @@ class Qwen3Next(TokenDecoder):
 
     @property
     def remat_keeps(self):
-        """What the chunk systems' checkpoint keeps by name: the journal's
-        ``fwd_bwd`` event carries it (``train/state.py:fwd_bwd_plan``)."""
+        """What the expert layers' checkpoints keep by name, and the chunk
+        systems' beside it: the journal's ``fwd_bwd`` event carries it
+        (``train/state.py:fwd_bwd_plan``)."""
         linear = "linear" in layer_kinds(self.sizes)
-        return (KEPT,) if self.remat and linear else ()
+        return super().remat_keeps + (
+            (KEPT,) if self.remat and linear else ())
 
     def dummy_input(self, input_shape):
         """What ``init`` traces: one row of one whole chunk."""

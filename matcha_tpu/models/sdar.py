@@ -66,7 +66,8 @@ from jax import lax
 
 from ..utils.profiling import device_span
 from .mellum2 import (MOE_COUNTERS, TokenDecoder, _head_loss, _moe, _rms_norm,
-                      _rope, attention_weights, expert_weights, rope_tables)
+                      _rope, attention_weights, checkpointed, expert_weights,
+                      rope_tables)
 
 __all__ = ["Sdar"]
 
@@ -193,7 +194,7 @@ def _experts_of(p, h, masked, sizes):
 
 
 def _block(p, h, docs, masked, sizes, remat):
-    again = jax.checkpoint if remat else (lambda f: f)
+    again = checkpointed(remat)
     projected = again(functools.partial(_project, sizes=sizes))(p, h)
     out, counters = _bd_attention(*projected, docs, sizes)
     h = h + jnp.dot(out, p["wo"])

@@ -549,7 +549,8 @@ def test_trains_by_name_through_train(tmp_path):
     assert np.all(np.isfinite(losses)) and losses[-1] < losses[0]
     assert "retrace" not in [e["kind"] for e in result.recorder.events]
     (plan,) = [e for e in result.recorder.events if e["kind"] == "fwd_bwd"]
-    assert plan["remat_keeps"] == [qwen3_next.KEPT] and not plan["packed"]
+    assert plan["remat_keeps"] == [*mellum2.MOE_KEPT, qwen3_next.KEPT]
+    assert not plan["packed"]
     records = [e for e in result.recorder.events if e["kind"] == "spans"]
     assert len(records) == 3
     rows_an_epoch = 3 * 2 * 2  # steps x workers x rows
