@@ -49,9 +49,16 @@ in :func:`checkpointed`, which under ``remat`` keeps those and computes the
 rest again: the second pass is gate, up, SwiGLU and down over kept rows (down
 too: its output meets the cotangent in the router's gradient), no layout, no
 gather, no rounding of the rows.
+**Rows that hold no slot are in no group.**  The groups are the slots' own
+counts and sum to the rows or less; the kernels' grids visit the row tiles a
+group touches and stop at the last (a quarter to a half of the rows hold a
+slot at the benchmark's sizes), and what a product leaves on a row in no
+group (anything, on the kernels) leaves the layer by an index out of bounds.
 Shapes are static either way, and the counters say what it cost:
-``moe_rows_computed`` rows went through an expert for ``moe_slots_held``
-slots that were real.
+``moe_rows_computed`` rows were laid out, gathered, passed through SwiGLU and
+scatter-added for ``moe_slots_held`` slots that were real, and
+``moe_rows_multiplied`` of them lay in a row tile the products visited (all
+of them where ``lax.ragged_dot`` runs the products).
 
 **The model supplies its loss** (:meth:`Mellum2.batch_loss`, which
 ``train/state.py`` calls in place of cross-entropy over one label a row):
@@ -100,7 +107,8 @@ INIT_STD = 0.02
 #: a step at the published widths (PERF.md section 6, PR 27)
 ROWS_PER_EVEN_SLOT = 2
 #: what the expert layer counts beside ``moe_load``, summed over the layers
-MOE_COUNTERS = ("moe_slots_held", "moe_rows_computed")
+MOE_COUNTERS = ("moe_slots_held", "moe_rows_computed",
+                "moe_rows_multiplied")
 #: what the expert layer names for the checkpoint around it to keep
 #: (:func:`checkpointed`): the rows gathered for the products, as the
 #: products read them, and the layout's small arrays (``fits``, ``slot``,
@@ -291,7 +299,8 @@ def _dispatched_bf16(tokens, flat, token, weights, groups):
     ``astype`` before the gather would have autodiff round that cotangent to
     bfloat16 before the sum over a token's slots: another result).
     ``token[rows]`` is the token a row holds, ``tokens`` where it holds
-    none: such a row reads the last token and adds nothing back."""
+    none: such a row reads the last token, is in no group and adds nothing
+    back (whatever its products left on it is dropped by index)."""
     return _dispatched_bf16_fwd(tokens, flat, token, weights, groups)[0]
 
 
@@ -353,7 +362,10 @@ def expert_products(sizes, tokens: int, layers: int, remat: bool,
     layer (down too, though the second pass keeps its dispatch: the router's
     gradient reads its output; read off the compiled epoch program, PR 42),
     and once a gradient), the kernel sites an epoch program holds
-    (one a form and shape) and the products that run on them."""
+    (one a form and shape) and the products that run on them.
+    ``empty_rows`` says where the rows that hold no slot count: in no group,
+    so a kernel's grid stops at the last tile that holds a slot (what it ran
+    over is the counter ``moe_rows_multiplied``)."""
     rows = moe_capacity(tokens, sizes)
     hid, width = sizes["hidden"], sizes["expert_width"]
     passes = {"gmm": 2 if remat else 1, "gmm_transposed": 1, "tgmm": 1}
@@ -373,7 +385,8 @@ def expert_products(sizes, tokens: int, layers: int, remat: bool,
     on_kernel = [p for p in products if "tiles" in p]
     return {"products_per_step": sum(p["per_step"] for p in products),
             "on_kernel": sum(p["per_step"] for p in on_kernel),
-            "kernel_sites": len(on_kernel), "products": products}
+            "kernel_sites": len(on_kernel), "empty_rows": "in no group",
+            "products": products}
 
 
 def _swiglu(x, p, product):
@@ -387,16 +400,24 @@ def _experts(p, x, w_held, took, sizes):
     """The experts held on the tokens that chose them: ``x[B, S, H]``,
     ``w_held[T, E]`` each token's weight on each expert held (0 where it
     did not choose it), ``took[T, E]`` who chose whom.  Returns (their
-    weighted sum ``[B, S, H]``, rows that went through an expert).
+    weighted sum ``[B, S, H]``, ``{"moe_rows_computed": rows laid out, which
+    every gather, SwiGLU pass and scatter-add runs over, "moe_rows_multiplied":
+    rows of the row tiles the grouped products visit}``, each with every
+    expert held on every token more in a step whose slots spill).
 
     The slots are laid out sorted by expert in ``rows`` rows (expert ``e``'s
     queue starts where the queues before it end), and three grouped
     products (:func:`_gate_and_up`, :func:`_grouped_product`; one group an
     expert) run over them, so the cost follows the slots and not the busiest
-    expert.  Rows past the last slot hold a token at weight 0 and count
-    with the last expert.  The gathered rows and the layout's arrays carry
-    the names ``MOE_KEPT``, which a checkpoint around the layer keeps
-    (:func:`checkpointed`)."""
+    expert.  Rows past the last slot are in no group: the kernels pass over
+    the row tiles that hold only such rows, and what a product leaves on
+    such a row is anything (``ops/grouped.py``, "Rows in no group";
+    ``lax.ragged_dot`` leaves zeros).  None of it is kept: such a row holds
+    token ``tokens`` and slot ``tokens x held``, both out of bounds, so
+    the output's scatter-add and the two cotangents' (into the tokens, into
+    ``w_held``) drop it by index, and everything between is row by row.
+    The gathered rows and the layout's arrays carry the names ``MOE_KEPT``,
+    which a checkpoint around the layer keeps (:func:`checkpointed`)."""
     b, s, hidden = x.shape
     tokens, held = took.shape
     flat = x.reshape(tokens, hidden)
@@ -417,8 +438,7 @@ def _experts(p, x, w_held, took, sizes):
     w_rows = layout(w_held.reshape(-1).at[slot].get(mode="fill",
                                                     fill_value=0))
     ends = jnp.minimum(start + count, rows)
-    groups = (ends - jnp.minimum(start, rows)).astype(jnp.int32)
-    groups = layout(groups.at[-1].add(rows - jnp.sum(groups)))
+    groups = layout((ends - jnp.minimum(start, rows)).astype(jnp.int32))
     gate_rows, up_rows = _gate_and_up(flat, token, p, groups)
     y_rows = _grouped_product(jax.nn.silu(gate_rows) * up_rows, p["down"],
                               groups)
@@ -443,8 +463,16 @@ def _experts(p, x, w_held, took, sizes):
         overflow,
         lambda: lax.map(every_expert, (x, w_rest.reshape(b, s, held))),
         lambda: jnp.zeros_like(x))
-    computed = rows + jnp.where(overflow, held * tokens, 0)
-    return y, computed.astype(jnp.float32)
+    past = jnp.where(overflow, held * tokens, 0)
+    # the slots fill the rows from the first, so the row tiles a group
+    # touches are those up to the last slot's (one ``tm`` for the six
+    # shapes: it follows the rows); ``lax.ragged_dot`` multiplies every row
+    tiles = product_plan("gmm", rows, hidden, p["gate"].shape[2],
+                         jnp.bfloat16, jnp.bfloat16).get("tiles") \
+        if _one_bf16_pass() else None
+    visited = -(-jnp.sum(groups) // tiles[0]) * tiles[0] if tiles else rows
+    return y, {"moe_rows_computed": (rows + past).astype(jnp.float32),
+               "moe_rows_multiplied": (visited + past).astype(jnp.float32)}
 
 
 def _moe(p, x, sizes, marked=None):
@@ -460,10 +488,10 @@ def _moe(p, x, sizes, marked=None):
         w_held = jnp.sum(jnp.where(chosen, w[:, :, None], 0.0), axis=1)
         took = jnp.any(chosen, axis=1)
     with device_span("matcha/moe_experts"):
-        y, computed = _experts(p, x, w_held, took, sizes)
+        y, rows_run = _experts(p, x, w_held, took, sizes)
     load = jnp.sum(took, axis=0).astype(jnp.float32)
-    counters = {"moe_slots_held": jnp.sum(load),
-                "moe_rows_computed": computed, "moe_load": load}
+    counters = {"moe_slots_held": jnp.sum(load), **rows_run,
+                "moe_load": load}
     if marked is not None:
         counters["moe_slots_marked"] = jnp.sum(
             took & marked.reshape(b * s, 1)).astype(jnp.float32)

@@ -28,7 +28,16 @@ so a row tile is read once and an expert's weights once a group.
 step a (row tile, group) pair that share rows, groups in order, so a tile
 that two groups share is visited by both, one after the other, and each
 writes its own rows.  Its length is static (row tiles + groups - 1, the most
-there can be); steps past the pairs there are do nothing.
+there can be); steps past the pairs there are stay on the last pair's tile
+and group, so that they fetch nothing, write nothing back and do nothing.
+
+**Rows in no group.**  The groups may sum to fewer than the rows (the expert
+layer's rows past its last slot): those rows are in no pair, a row tile
+that holds only such rows is never visited, and a product's time follows
+the tiles its groups touch.  What the forward forms' output holds on a row
+in no group is whatever the buffer held (anything: NaN too), so a caller
+lets no such row reach a result it keeps (``models/mellum2.py:_experts``
+drops them by index); the weight gradient reads only rows in a group.
 
 :func:`grouped_dot`, :func:`grouped_dot_transposed` and
 :func:`grouped_outer` are what a model calls: the kernel where
@@ -117,8 +126,11 @@ def _steps(group_sizes, rows: int, tm: int, every_group: bool):
     ``tile_of[steps]``, ``[live]`` how many of the steps are pairs).  A
     group takes the tiles its rows touch; one that holds no row takes none,
     or with ``every_group`` one (the weight gradient writes its zeros
-    there).  ``steps`` is the most there can be; the rest repeat the last
-    group on the last tile, where nothing has to be fetched for them."""
+    there).  The sizes sum to ``rows`` or less: rows past the sum are in no
+    pair.  ``steps`` is the most there can be; the steps past ``live``
+    repeat the last pair's tile and group, so that no operand's or output's
+    block index changes after the last pair: nothing is fetched for them and
+    nothing but the last pair's block is written back."""
     groups = group_sizes.shape[0]
     tiles = rows // tm
     steps = tiles + groups - 1
@@ -131,11 +143,13 @@ def _steps(group_sizes, rows: int, tm: int, every_group: bool):
     step0 = jnp.cumsum(visits) - visits
     group_of = jnp.repeat(jnp.arange(groups, dtype=jnp.int32), visits,
                           total_repeat_length=steps)
-    tile_of = first[group_of] + jnp.arange(steps, dtype=jnp.int32) \
-        - step0[group_of]
-    return (offsets.astype(jnp.int32), group_of,
-            jnp.clip(tile_of, 0, tiles - 1).astype(jnp.int32),
-            jnp.sum(visits).astype(jnp.int32)[None])
+    step = jnp.arange(steps, dtype=jnp.int32)
+    tile_of = first[group_of] + step - step0[group_of]
+    live = jnp.sum(visits).astype(jnp.int32)
+    pair = jnp.minimum(step, jnp.maximum(live - 1, 0))
+    return (offsets.astype(jnp.int32), group_of[pair],
+            jnp.clip(tile_of, 0, tiles - 1).astype(jnp.int32)[pair],
+            live[None])
 
 
 def _rows_of_group(offsets, group_of, tile_of, s, tm):
@@ -245,10 +259,11 @@ def moe_gmm(lhs: jax.Array, weights: jax.Array, group_sizes: jax.Array, *,
             transposed: bool = False, tiles=None, interpret: bool = False):
     """``out[r] = lhs[r] @ weights[group of r]`` in float32, ``lhs[rows,
     K]``, ``weights[E, K, N]``; with ``transposed`` ``weights[E, N, K]``,
-    read where they lie.  ``group_sizes[E]`` int32 sum to ``rows``: the
-    first ``group_sizes[0]`` rows meet expert 0, and so on.  ``tiles``
-    (tm, tk, tn) as :func:`choose_tiles` picks them unless given (a test
-    seam)."""
+    read where they lie.  ``group_sizes[E]`` int32 sum to ``rows`` or less:
+    the first ``group_sizes[0]`` rows meet expert 0, and so on; a row past
+    the sum meets none and its ``out`` is not written (it holds what the
+    buffer held: see the module's "Rows in no group").  ``tiles`` (tm, tk,
+    tn) as :func:`choose_tiles` picks them unless given (a test seam)."""
     rows, k = lhs.shape
     n = weights.shape[1 if transposed else 2]
     if weights.shape[2 if transposed else 1] != k or \
@@ -295,7 +310,8 @@ def moe_tgmm(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array, *,
              tiles=None, interpret: bool = False):
     """``out[e] = lhs[rows of e]^T @ rhs[rows of e]`` in float32,
     ``lhs[rows, K]``, ``rhs[rows, N]``, ``out[E, K, N]``; zeros for a group
-    of no rows.  ``group_sizes`` and ``tiles`` as :func:`moe_gmm`."""
+    of no rows.  ``group_sizes`` and ``tiles`` as :func:`moe_gmm`: rows past
+    the groups' sum are read by no expert's sum."""
     rows, k = lhs.shape
     n = rhs.shape[1]
     if rhs.shape[0] != rows or group_sizes.ndim != 1:

@@ -4,8 +4,14 @@ what a layer's checkpoint keeps by name gives the step that computes it
 again, to the bit; the rows rounded before the gather are the operands the
 products read before; a rematerialised layer gathers and lays out once; the
 cotangent into the tokens is summed in float32; the journal's plan names what
-is kept.  Small sizes on the CPU; seconds are a case's own inside the
-driver's pool of six workers."""
+is kept.  Since PR 43 a row that holds no slot is in no group: whatever a
+product leaves on such a row (NaN, 1e30) reaches nothing the layer keeps; the
+layer equals the layout that counted those rows with the last expert, to the
+bit; ``moe_rows_multiplied`` counts the row tiles the products visit.  Small
+sizes on the CPU; seconds are a case's own inside the driver's pool of six
+workers."""
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -13,6 +19,7 @@ import numpy as np
 import pytest
 
 from matcha_tpu.models import mellum2, select_model
+from matcha_tpu.ops import grouped
 from test_mellum2 import SEQ, rows, sizes_of  # beside this file
 
 
@@ -280,3 +287,217 @@ def test_plan_lists_what_the_checkpoints_keep(name):
     assert keeps[2:] == ([qwen3_next.KEPT] if name == "qwen3_next" else [])
     assert "remat_keeps" not in fwd_bwd_plan(
         select_model(name, "tokens", sizes=sizes), 2)
+
+
+# ------------------------------------------- rows that hold no slot (PR 43)
+
+def _with_products(monkeypatch, change):
+    """``mellum2``'s three grouped products, each behind ``change(product,
+    form)``; returns how often each was traced."""
+    calls = dict.fromkeys(("grouped_dot", "grouped_dot_transposed",
+                           "grouped_outer"), 0)
+    for name in calls:
+        product = getattr(grouped, name)
+
+        def counted(*args, name=name, product=product):
+            calls[name] += 1
+            return change(product, name)(*args)
+
+        monkeypatch.setattr(mellum2, name, counted)
+    return calls
+
+
+#: what the poisoned products leave on a row in no group: the jitted
+#: function's own argument, put here while it is traced, so that one
+#: compiled program serves 0, NaN and 1e30
+LEFT = {"value": 0.0}
+
+
+def _poisoned(product, name):
+    """The forward forms with every output row past the groups' sum set to
+    ``LEFT["value"]``: what a kernel may leave there is anything (0 is what
+    ``lax.ragged_dot`` leaves); the weight gradient as it is."""
+    if name == "grouped_outer":
+        return product
+
+    def poisoned(lhs, weights, groups):
+        out = product(lhs, weights, groups)
+        in_a_group = jnp.arange(out.shape[0]) < jnp.sum(groups)
+        return jnp.where(in_a_group[:, None], out, LEFT["value"])
+    return poisoned
+
+
+def _counted_as_until_pr_43(product, name):
+    """``groups`` as ``_experts`` laid them out until PR 43: the rows past
+    the last slot count with the last expert."""
+    def padded(lhs, other, groups):
+        rows = lhs.shape[0]
+        groups = groups.at[-1].add(rows - jnp.sum(groups))
+        return product(lhs, other, groups)
+    return padded
+
+
+BATCH = 6  # 192 tokens: 384 rows, three tiles of 128
+
+
+def _layer_sizes(width):
+    return sizes_of(hidden=128, expert_width=width, experts_held=[0, 1, 2, 3])
+
+
+def _given_routing(routing, width=128):
+    """An expert layer on the TPU's branch with its routing given (as in
+    ``test_kept_rows_equal_the_form_until_pr_42_on_the_bf16_path``):
+    (weights, ``x``, ``w_held``, ``took``, a probe).  ``router``: what the
+    router chooses (about half the rows hold a slot); ``spill``: every token
+    takes all four experts held, twice the rows there are, so the overflow
+    branch runs; ``none``: no token chooses an expert held."""
+    z = _layer_sizes(width)
+    tokens = BATCH * SEQ
+    rng = jax.random.split(jax.random.PRNGKey(43), 6)
+    p = {k: 0.3 * jax.random.normal(key, shape) for key, (k, shape) in zip(
+        rng, {"router": (128, 8), "gate": (4, 128, width),
+              "up": (4, 128, width), "down": (4, width, 128)}.items())}
+    x, probe = (jax.random.normal(key, (BATCH, SEQ, 128)) for key in rng[4:])
+    w, sel = mellum2._route(p, x.reshape(tokens, 128), z)
+    chosen = sel[:, :, None] == jnp.arange(4)[None, None, :]
+    w_held = jnp.sum(jnp.where(chosen, w[:, :, None], 0.0), axis=1)
+    took = jnp.any(chosen, axis=1)
+    if routing == "spill":
+        took, w_held = jnp.ones_like(took), jnp.full_like(w_held, 0.25)
+    elif routing == "none":
+        took, w_held = jnp.zeros_like(took), jnp.zeros_like(w_held)
+    return p, x, w_held, took, probe
+
+
+@functools.lru_cache(maxsize=None)
+def _layer_and_gradients(width, products):
+    """``f(weights, x, w_held, took, probe, left=0.0)`` -> ((the layer's
+    output under ``remat``, the rows' counters), the probed output's
+    gradients by every weight, by ``x`` and by ``w_held``), jitted once a
+    width and a set of ``products`` (the name of what the test has
+    ``mellum2`` call, in force when the first call traces it): the routing
+    is an argument, and so is what ``_poisoned`` products leave."""
+    z = _layer_sizes(width)
+
+    def probed(p, x, w_held, took, probe, left=0.0):
+        LEFT["value"] = left
+        try:
+            y, counters = mellum2.checkpointed(True)(
+                lambda *a: mellum2._experts(*a, took, z))(p, x, w_held)
+        finally:
+            LEFT["value"] = 0.0
+        return jnp.sum(y * probe), (y, counters)
+
+    grad = jax.jit(jax.value_and_grad(probed, argnums=(0, 1, 2),
+                                      has_aux=True))
+
+    def layer_and_gradients(*layer):
+        (_, out), grads = grad(*layer)
+        return out, grads
+    return layer_and_gradients
+
+
+def _assert_finite(tree):
+    for path, leaf in jax.tree_util.tree_leaves_with_path(tree):
+        assert np.all(np.isfinite(leaf)), str(path)
+
+
+@pytest.mark.parametrize("value", [np.nan, 1e30], ids=["nan", "1e30"])
+def test_what_a_kernel_leaves_on_a_row_in_no_group_reaches_nothing_kept(
+        value, monkeypatch):
+    """The bfloat16 path at widths that tile (the kernels, under the
+    interpreter, which itself leaves NaN on a tile no step visits): with
+    every output row past the groups' sum of both forward forms set to NaN,
+    or to 1e30, the layer's output and its gradients by every weight, by
+    ``x`` and by ``w_held`` are finite and, to the bit, those with zeros
+    there.  (6 s a case.)"""
+    monkeypatch.setattr(mellum2, "_one_bf16_pass", lambda: True)
+    layer = _given_routing("router")
+    assert mellum2.expert_products(_layer_sizes(128), BATCH * SEQ, 1, True,
+                                   1)["kernel_sites"] == 6
+    calls = _with_products(monkeypatch, _poisoned)
+    clean, poisoned = (_layer_and_gradients(128, "poisoned")(*layer, left)
+                       for left in (0.0, value))
+    # gate, up, down and the recomputed three; their data gradients (traced
+    # by the first of the two cases)
+    assert (calls["grouped_dot"], calls["grouped_dot_transposed"]) in (
+        (6, 3), (0, 0))
+    (_, counters), _ = clean
+    assert counters["moe_rows_multiplied"] < counters["moe_rows_computed"]
+    _assert_finite(clean)
+    assert_same_bits(poisoned, clean)
+
+
+@pytest.mark.parametrize("name", TOKEN_MODELS)
+def test_a_token_models_layer_keeps_nothing_of_a_row_in_no_group(
+        name, monkeypatch):
+    """Each token model's expert layer as its block wraps it under
+    ``remat``, on the bfloat16 path at the test's sizes (``lax.ragged_dot``:
+    they do not tile): NaN on every output row past the groups' sum changes
+    no bit of the output or of the gradients by the layer's weights (the
+    router's, which ``w_held``'s reaches, among them) and its input.
+    (4-8 s a case.)"""
+    monkeypatch.setattr(mellum2, "_one_bf16_pass", lambda: True)
+    layer, p, h = expert_layer_of(name)
+    probe = jax.random.normal(jax.random.PRNGKey(10), h.shape)
+
+    def probed(p, h, left):
+        LEFT["value"] = left
+        try:
+            # (a function of its own: a checkpoint's trace is cached by the
+            # function it wraps, and with it the products it called)
+            y = mellum2.checkpointed(True)(lambda p, h: layer(p, h))(p, h)
+        finally:
+            LEFT["value"] = 0.0
+        return jnp.sum(y * probe)
+
+    calls = _with_products(monkeypatch, _poisoned)
+    grad = jax.jit(jax.value_and_grad(probed, argnums=(0, 1)))
+    clean, poisoned = grad(p, h, 0.0), grad(p, h, np.nan)
+    assert calls["grouped_dot"] == 6 and calls["grouped_outer"] == 3
+    _assert_finite(clean)
+    assert float(jnp.max(jnp.abs(clean[1][0]["router"]))) > 0
+    assert_same_bits(poisoned, clean)
+
+
+@pytest.mark.parametrize("routing", ["router", "spill", "none"])
+@pytest.mark.parametrize("path", ["kernels", "ragged_dot"])
+def test_rows_in_no_group_equal_rows_counted_with_the_last_expert(
+        path, routing, monkeypatch):
+    """Routing given: the layer's output and its gradients by every weight,
+    by ``x`` and by ``w_held`` are, to the bit, those of the layout until
+    PR 43 (``_counted_as_until_pr_43``: the rows past the last slot with the
+    last expert, read at weight 0), on the kernels and where the widths do
+    not tile; also where the slots spill (every row holds one, and the
+    overflow branch runs) and where no token chooses an expert held.  And
+    the counters: the rows laid out, and of them those in a row tile a
+    group touches (all of them where ``lax.ragged_dot`` multiplies).
+    (3-8 s a case.)"""
+    monkeypatch.setattr(mellum2, "_one_bf16_pass", lambda: True)
+    width = 128 if path == "kernels" else 24
+    layer = _given_routing(routing, width)
+    z, took = _layer_sizes(width), layer[3]
+    tokens, held = took.shape
+    rows = mellum2.moe_capacity(tokens, z)
+    assert rows == 2 * tokens == 3 * (tm := 128)
+    plan = mellum2.expert_products(z, tokens, 1, True, 1)
+    assert plan["kernel_sites"] == (6 if path == "kernels" else 0)
+    assert plan["empty_rows"] == "in no group"
+    now = _layer_and_gradients(width, "as they are")(*layer)
+    (y, counters), _ = now
+    slots = int(jnp.sum(took))
+    past = held * tokens if slots > rows else 0
+    assert (slots > rows) == (routing == "spill")
+    assert counters["moe_rows_computed"] == rows + past
+    in_tiles = -(-min(slots, rows) // tm) * tm
+    assert counters["moe_rows_multiplied"] == past + (
+        in_tiles if path == "kernels" else rows)
+    if routing == "router":
+        assert 0 < in_tiles < rows
+    elif routing == "spill":  # every row holds a slot
+        assert in_tiles == rows
+    else:
+        assert in_tiles == 0 and not np.any(np.asarray(y))
+    _assert_finite(now)
+    _with_products(monkeypatch, _counted_as_until_pr_43)
+    assert_same_bits(now, _layer_and_gradients(width, "until PR 43")(*layer))

@@ -1,10 +1,11 @@
 """The expert layer's grouped products (``matcha_tpu/ops/grouped.py``, PR 38)
 under the Pallas interpreter against ``lax.ragged_dot`` /
 ``lax.ragged_dot_general``: each form over uneven groups, an empty group, a
-group boundary inside a row tile, the padded last group and tiles that split
-the contraction or the width; the tile chooser; the fallback and its reason;
-``mellum2._grouped_bf16``'s gradients through the kernels against the stock
-products it ran until PR 38."""
+group boundary inside a row tile, the padded last group, groups that sum to
+fewer than the rows (rows in no group, PR 43) and tiles that split the
+contraction or the width; the grid's steps; the tile chooser; the fallback
+and its reason; ``mellum2._grouped_bf16``'s gradients through the kernels
+against the stock products it ran until PR 38."""
 
 import jax
 import jax.numpy as jnp
@@ -17,8 +18,9 @@ from matcha_tpu.ops import grouped
 
 ROWS, K, N, EXPERTS = 512, 256, 384, 4
 BF = jnp.bfloat16
-#: group sizes over 512 rows in tiles of 128 (they sum to the rows: the
-#: expert layer counts the rows past its last slot with the last expert)
+#: group sizes over 512 rows in tiles of 128: the first six sum to the rows
+#: (until PR 43 the expert layer counted the rows past its last slot with the
+#: last expert), the rest to fewer: rows past the sum are in no group
 GROUPS = {
     "uneven": [100, 37, 300, 75],
     "on_tile_boundaries": [128, 128, 0, 256],
@@ -26,6 +28,12 @@ GROUPS = {
     "boundaries_inside_one_tile": [130, 3, 2, 377],
     "padded_last_group": [40, 30, 20, 422],
     "one_group_holds_every_row": [0, 0, 512, 0],
+    "short_last_group_ends_inside_a_tile": [100, 37, 120, 30],
+    "short_empty_groups_first_and_between": [0, 90, 0, 60],
+    "short_empty_groups_last": [200, 61, 0, 0],
+    "short_ends_on_a_tile_boundary": [128, 100, 28, 0],
+    "short_one_row": [0, 0, 1, 0],
+    "every_group_empty": [0, 0, 0, 0],
 }
 #: (tm, tk, tn): whole widths; the contraction split; the width split; the
 #: rows in one tile (every group shares it)
@@ -69,51 +77,81 @@ def _args(form, operands):
             "tgmm": (operands["lhs"], operands["rows_n"])}[form]
 
 
+def _assert_is_the_stock_product(form, case, tiles, operands):
+    """The kernel of ``form`` over ``GROUPS[case]`` against the stock
+    product: on every row in a group for the forward forms (what a row in no
+    group holds is anything: the interpreter leaves NaN, the stock product
+    zeros), on every expert for the weight gradient.  Where the sizes sum to
+    fewer than the rows the kernel also gives, to the bit, what it gives of
+    the layout until PR 43 (the rest of the rows with the last group) over
+    operands whose rows in no group are zero: the same tiles multiplied in
+    the same order, and sums that differ by exact zeros."""
+    sizes = jnp.asarray(GROUPS[case], jnp.int32)
+    a, b = _args(form, operands)
+    held = sum(GROUPS[case])
+    got = _kernel(form, a, b, sizes, tiles)
+    kept = slice(None) if form == "tgmm" else slice(held)
+    np.testing.assert_allclose(got[kept], _stock(form, a, b, sizes)[kept],
+                               **TOL)
+    if held < ROWS:
+        in_a_group = (jnp.arange(ROWS) < held)[:, None]
+        a = jnp.where(in_a_group, a, 0)
+        if form == "tgmm":
+            b = jnp.where(in_a_group, b, 0)
+        padded = _kernel(form, a, b, sizes.at[-1].add(ROWS - held), tiles)
+        np.testing.assert_array_equal(got[kept], padded[kept])
+    return got
+
+
 @pytest.mark.parametrize("tiles", TILES, ids=lambda t: "x".join(map(str, t)))
 @pytest.mark.parametrize("case", list(GROUPS))
 def test_forward_product_is_the_ragged_dot(case, tiles, operands):
-    sizes = jnp.asarray(GROUPS[case], jnp.int32)
-    a, b = _args("gmm", operands)
-    got = _kernel("gmm", a, b, sizes, tiles)
+    got = _assert_is_the_stock_product("gmm", case, tiles, operands)
     assert got.shape == (ROWS, N) and got.dtype == jnp.float32
-    np.testing.assert_allclose(got, _stock("gmm", a, b, sizes), **TOL)
 
 
 @pytest.mark.parametrize("tiles", TILES, ids=lambda t: "x".join(map(str, t)))
 @pytest.mark.parametrize("case", list(GROUPS))
 def test_transposed_weights_product_is_the_ragged_dot_of_the_swapped(
         case, tiles, operands):
-    sizes = jnp.asarray(GROUPS[case], jnp.int32)
-    a, b = _args("gmm_transposed", operands)
-    got = _kernel("gmm_transposed", a, b, sizes, tiles)
+    got = _assert_is_the_stock_product("gmm_transposed", case, tiles,
+                                       operands)
     assert got.shape == (ROWS, K) and got.dtype == jnp.float32
-    np.testing.assert_allclose(got, _stock("gmm_transposed", a, b, sizes),
-                               **TOL)
 
 
 @pytest.mark.parametrize("tiles", TILES, ids=lambda t: "x".join(map(str, t)))
 @pytest.mark.parametrize("case", list(GROUPS))
 def test_weight_gradient_product_is_the_ragged_dot_general(case, tiles,
                                                            operands):
-    sizes = jnp.asarray(GROUPS[case], jnp.int32)
-    a, b = _args("tgmm", operands)
-    got = _kernel("tgmm", a, b, sizes, tiles)
+    got = _assert_is_the_stock_product("tgmm", case, tiles, operands)
     assert got.shape == (EXPERTS, K, N) and got.dtype == jnp.float32
-    np.testing.assert_allclose(got, _stock("tgmm", a, b, sizes), **TOL)
     for e, size in enumerate(GROUPS[case]):
         if size == 0:  # an expert that took no slot: exactly zero
             assert not np.any(np.asarray(got[e]))
 
 
+@pytest.mark.parametrize("poisoned", ["another_group", "no_group"])
 @pytest.mark.parametrize("form", grouped.FORMS)
-def test_rows_of_other_groups_never_reach_a_product(form, operands):
+def test_rows_of_other_groups_never_reach_a_product(form, poisoned, operands):
     """A tile that groups share is masked, not weighted: a NaN in another
-    group's rows stays out of this group's sums."""
-    sizes = jnp.asarray([130, 126, 250, 6], jnp.int32)
+    group's rows stays out of this group's sums, and so does one in the rows
+    of no group (the last group ends inside a tile and shares it with
+    them; the tile after holds only such rows)."""
+    sizes = jnp.asarray({"another_group": [130, 126, 250, 6],
+                         "no_group": [130, 100, 26, 2]}[poisoned], jnp.int32)
     a, b = _args(form, operands)
-    poisoned = a.at[130:256].set(jnp.nan)  # all of group 1, and only it
-    got = np.asarray(_kernel(form, poisoned, b, sizes, (128, 128, 128)))
     clean = np.asarray(_kernel(form, a, b, sizes, (128, 128, 128)))
+    if poisoned == "no_group":
+        held = 258  # two rows into the third tile of four
+        got = np.asarray(_kernel(form, a.at[held:].set(jnp.nan), b, sizes,
+                                 (128, 128, 128)))
+        others = slice(None) if form == "tgmm" else slice(held)
+        assert not np.any(np.isnan(got[others]))
+        np.testing.assert_array_equal(got[others], clean[others])
+        return
+    # all of group 1, and only it
+    got = np.asarray(_kernel(form, a.at[130:256].set(jnp.nan), b, sizes,
+                             (128, 128, 128)))
     if form == "tgmm":
         assert np.all(np.isnan(got[1]))
         others = [0, 2, 3]
@@ -121,6 +159,91 @@ def test_rows_of_other_groups_never_reach_a_product(form, operands):
         assert np.all(np.isnan(got[130:256]))
         others = np.r_[0:130, 256:ROWS]
     np.testing.assert_array_equal(got[others], clean[others])
+
+
+# ------------------------------------------------------------ the grid's steps
+
+def _steps_until_pr_43(group_sizes, rows, tm, every_group):
+    """``grouped._steps`` as it stood until PR 43: the steps past the pairs
+    repeat the last group and walk on over the tiles to the last."""
+    groups = group_sizes.shape[0]
+    tiles = rows // tm
+    steps = tiles + groups - 1
+    ends = jnp.cumsum(group_sizes)
+    starts = ends - group_sizes
+    offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends])
+    first = starts // tm
+    visits = jnp.where(group_sizes > 0, (ends + tm - 1) // tm - first,
+                       1 if every_group else 0)
+    step0 = jnp.cumsum(visits) - visits
+    group_of = jnp.repeat(jnp.arange(groups, dtype=jnp.int32), visits,
+                          total_repeat_length=steps)
+    tile_of = first[group_of] + jnp.arange(steps, dtype=jnp.int32) \
+        - step0[group_of]
+    return (offsets.astype(jnp.int32), group_of,
+            jnp.clip(tile_of, 0, tiles - 1).astype(jnp.int32),
+            jnp.sum(visits).astype(jnp.int32)[None])
+
+
+#: (sizes, rows, tm, every_group) -> (live, the pairs (group, tile) in order)
+STEPS = {
+    "rows_in_no_group": (
+        [700, 0, 900, 300, 0, 0, 500, 0], 8192, 512, False,
+        [(0, 0), (0, 1), (2, 1), (2, 2), (2, 3), (3, 3), (6, 3), (6, 4)]),
+    # a group of no rows visits the tile its start lies on, once
+    "rows_in_no_group_every_group": (
+        [700, 0, 900, 300, 0, 0, 500, 0], 8192, 512, True,
+        [(0, 0), (0, 1), (1, 1), (2, 1), (2, 2), (2, 3), (3, 3), (4, 3),
+         (5, 3), (6, 3), (6, 4), (7, 4)]),
+    "last_group_ends_inside_a_tile": (
+        [100, 37, 120, 30], 512, 128, False,
+        [(0, 0), (1, 0), (1, 1), (2, 1), (2, 2), (3, 2)]),
+    "one_row": ([0, 0, 1, 0], 512, 128, False, [(2, 0)]),
+    "every_group_empty": ([0, 0, 0, 0], 512, 128, False, []),
+    "every_group_empty_every_group": (
+        [0, 0, 0, 0], 512, 128, True, [(0, 0), (1, 0), (2, 0), (3, 0)]),
+    "the_rows_filled": (
+        [100, 37, 300, 75], 512, 128, False,
+        [(0, 0), (1, 0), (1, 1), (2, 1), (2, 2), (2, 3), (3, 3)]),
+}
+
+
+@pytest.mark.parametrize("case", list(STEPS))
+def test_steps_past_the_last_pair_stay_on_its_tile_and_group(case):
+    """The grid's length is static; a step past ``live`` has the block
+    indices of the last pair (lhs and output by tile, weights by group), so
+    nothing is fetched for it and nothing written back after it."""
+    sizes, rows, tm, every_group, pairs = STEPS[case]
+    offsets, group_of, tile_of, live = grouped._steps(
+        jnp.asarray(sizes, jnp.int32), rows, tm, every_group)
+    steps = rows // tm + len(sizes) - 1
+    assert group_of.shape == tile_of.shape == (steps,) and live.shape == (1,)
+    assert offsets.tolist() == np.cumsum([0] + sizes).tolist()
+    assert int(live[0]) == len(pairs)
+    got = list(zip(group_of.tolist(), tile_of.tolist()))
+    assert got[:len(pairs)] == pairs
+    assert set(got[len(pairs):]) <= {pairs[-1] if pairs else got[0]}
+    assert 0 <= min(tile_of.tolist()) and max(tile_of.tolist()) < rows // tm
+
+
+@pytest.mark.parametrize("every_group", [False, True],
+                         ids=["gmm", "every_group"])
+@pytest.mark.parametrize("case", [c for c in GROUPS
+                                  if sum(GROUPS[c]) == ROWS])
+def test_sizes_that_fill_the_rows_step_as_they_did(case, every_group):
+    """Sizes that sum to the rows: the pairs are the parent's, and so is
+    every step past them where the last group holds a row (the expert layer
+    until PR 43: the rows past the last slot were its).  Where the last group
+    is empty and takes no step the parent's dead steps named it, and with it
+    another block of weights to fetch; they stay on the last pair's now."""
+    sizes = jnp.asarray(GROUPS[case], jnp.int32)
+    got = grouped._steps(sizes, ROWS, 128, every_group)
+    want = _steps_until_pr_43(sizes, ROWS, 128, every_group)
+    live = int(want[3][0])
+    upto = None if GROUPS[case][-1] or every_group else live
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a[:upto], b[:upto])
+    assert int(got[3][0]) == live
 
 
 # ------------------------------------------------------------ the tile chooser

@@ -349,6 +349,7 @@ def test_trains_by_name_through_train(tmp_path):
         c = r["counters"]
         assert set(c) == {
             "loss_positions", "moe_slots_held", "moe_rows_computed",
+            "moe_rows_multiplied",
             "moe_load", "dsa_queries", "dsa_queries_selecting",
             "dsa_keys_visible", "dsa_keys_kept", "dsa_kl_sum"}
         assert c["dsa_queries"] == queries

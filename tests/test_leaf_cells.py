@@ -37,6 +37,17 @@ CELLS = [w["name"] for w in catalog.benchmark()["workloads"]]
 IMAGE = (32, 32, 3)
 
 
+def _sizes(cell):
+    """The ``sizes`` a cell's job gives its model, or ``{}``."""
+    kwargs = catalog.load_cell(cell)[1]["train_config"].get("model_kwargs")
+    return (kwargs or {}).get("sizes", {})
+
+
+#: the cells whose model has ``sizes`` and an expert layer, whatever their
+#: place in the list
+TOKEN_CELLS = [c for c in CELLS if "experts_held" in _sizes(c)]
+
+
 @functools.lru_cache(maxsize=None)
 def _schedule(n):
     topology = "chain" if n < 4 else "ring"
@@ -126,7 +137,7 @@ def test_exchange_of_every_configuration_lowers_inside_the_setup_budget(
 GROUPED_SITE_BUDGET = 6
 
 
-@pytest.mark.parametrize("cell", CELLS[2:])
+@pytest.mark.parametrize("cell", TOKEN_CELLS)
 def test_expert_layer_of_every_token_configuration_holds_six_kernel_sites(
         cell, monkeypatch):
     """The grouped products of the expert layer at published widths and the
@@ -220,8 +231,11 @@ def _rehearsal_tree(cell, n, seed=1):
         rng.normal(size=(n,) + a.shape), a.dtype), shapes)
 
 
-TREES = {"wrn": CELLS[0], "mellum": CELLS[2], "keye": CELLS[3],
+TREES = {"wrn": "wrn28-10-c100.w16-matcha",
+         "mellum": "mellum2-12b-a2.5b.ep8-s4k.w2-matcha",
+         "keye": "keye-vl2-30b-a3b.ep16-s8k.w2-matcha",
          "repeated_shapes": None}
+assert set(filter(None, TREES.values())) <= set(CELLS)
 #: every tree at its cell's own N and one more, the repeated shapes at all
 #: four (a token tree's ten shapes at N = 32 compile for half a minute
 #: under the interpreter)

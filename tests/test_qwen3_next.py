@@ -558,6 +558,7 @@ def test_trains_by_name_through_train(tmp_path):
         c = r["counters"]
         assert set(c) == {
             "loss_positions", "moe_slots_held", "moe_rows_computed",
+            "moe_rows_multiplied",
             "moe_load", "gdn_chunks", "gdn_chunks_reset", "gdn_gates",
             "gdn_decay_sum"}
         assert c["gdn_chunks"] == rows_an_epoch * SEQ // 8

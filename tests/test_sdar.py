@@ -491,6 +491,7 @@ def test_trains_by_name_through_train(tmp_path):
         c = r["counters"]
         assert set(c) == {
             "loss_positions", "moe_slots_held", "moe_rows_computed",
+            "moe_rows_multiplied",
             "moe_load", "bd_tokens", "bd_positions_masked",
             "bd_pairs_visible", "bd_pairs_scored", "bd_slots_held_masked"}
         assert c["bd_tokens"] == 12 * SEQ

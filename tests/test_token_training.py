@@ -141,6 +141,7 @@ def test_backend_event_says_what_runs_the_grouped_products(run):
     # 2 workers
     assert record["products_per_step"] == 48
     assert record["on_kernel"] == record["kernel_sites"] == 0
+    assert record["empty_rows"] == "in no group"
     assert len(record["products"]) == 6
     for product in record["products"]:
         assert product["kernel"] == "lax.ragged_dot"
@@ -185,13 +186,16 @@ def test_counters_ride_the_period_and_the_history(run):
         c = r["counters"]
         assert c == h["counters"]
         assert set(c) == {"loss_positions", "moe_slots_held",
-                          "moe_rows_computed", "moe_load"}
+                          "moe_rows_computed", "moe_rows_multiplied",
+                          "moe_load"}
         # every row is fed once an epoch, so the epoch judges what the
         # data set has to judge
         assert c["loss_positions"] == judged_positions(docs)
         load = np.asarray(c["moe_load"])
         assert load.shape == (2, 2) and load.sum() == c["moe_slots_held"]
         assert 0 < c["moe_slots_held"] <= c["moe_rows_computed"]
+        # off the TPU ``lax.ragged_dot`` multiplies every row laid out
+        assert c["moe_rows_multiplied"] == c["moe_rows_computed"]
         assert c["moe_slots_held"] <= 2 * 2 * STEPS * WORKERS * BATCH * SEQ
 
 
