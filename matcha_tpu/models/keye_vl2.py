@@ -22,7 +22,9 @@ input, ``t`` a query position and ``s`` a key position that ``t`` may see
   exact, without a sort: the ``index_topk``-th largest score of a query is
   found bit by bit on the scores' order-preserving integer form (32 counting
   passes), and a key is kept if it lies above it, or on it and early enough.
-  The selection masks; it saves no product (every score is computed).
+  The selection masks the main scores, which are computed for every key up
+  to the block's end; the indexer's own products follow the key tiles that
+  hold a visible pair (below).
 * **the attention**: grouped-query, softmax of ``q . k / sqrt(head_dim)``
   over ``S_t`` only, times ``v``, through ``wo``.
 * **the indexer's loss**: ``p_t`` is the main attention's probabilities
@@ -43,11 +45,32 @@ numbers, by name), so its recomputation does not search for them again.
 ``remat`` recomputes a layer's projections and its expert layer besides, the
 expert layer over the dispatch it kept (``mellum2.checkpointed``).
 
+**Key tiles.**  A block's index scores are computed ``KEY_TILE`` (512) keys at
+a time (:func:`_index_by_tiles`).  Rows are packed documents and a query sees
+its own document only, so many a tile holds no visible (query, key) pair of
+the block: ``sees``, which ``_visible`` returned, reduced over the block's
+queries and the tile's keys, says which (exactly, whatever the order of the
+document numbers).  Such a tile is ``-inf`` as it stands: the tiles run
+through one loop body as often as tiles are live, in the forward pass, the
+block's recomputation and the backward alike, and the ``[block, heads,
+tile]`` products of a hidden tile exist in none of them.  The loop's length
+is data, one number because one row runs at a time; the backward keeps
+nothing but the block's arguments and multiplies a live tile again (what
+autodiff would keep under a ``scan`` of ``cond``s, a hidden tile's zeros
+among it, cost more than the product: PERF.md section 6, PR 44).  A visible
+pair's score is the all-tiles block's: bit for bit on the CPU; on the chip the
+compiler schedules a tile's product otherwise than a whole block's in some
+blocks, and a score may differ in its last bit there (7e-7 at most, PERF.md
+section 6), inside the stated float32 at ``highest``.  The indexer's
+gradients differ besides by the order of their sums over tiles.
+
 Counters, returned with the loss and summed over layers: ``dsa_queries``
 (layer-queries), ``dsa_queries_selecting`` (those that see more than
 ``index_topk`` keys), ``dsa_keys_visible``, ``dsa_keys_kept``, ``dsa_kl_sum``
-(``L_I`` is ``dsa_kl_sum / dsa_queries``), and the expert layer's and the
-loss's as in ``mellum2``.
+(``L_I`` is ``dsa_kl_sum / dsa_queries``), ``dsa_key_tiles`` (key tiles the
+query blocks range over, a layer-row), ``dsa_key_tiles_scored`` (those that
+hold a visible pair and were multiplied; the two are equal where a row is one
+document), and the expert layer's and the loss's as in ``mellum2``.
 """
 
 from __future__ import annotations
@@ -73,7 +96,11 @@ HIGHEST = lax.Precision.HIGHEST
 KEPT = "dsa_threshold"
 #: what a layer's attention counts (the module docstring says of what)
 DSA_COUNTERS = ("dsa_queries", "dsa_queries_selecting", "dsa_keys_visible",
-                "dsa_keys_kept", "dsa_kl_sum")
+                "dsa_keys_kept", "dsa_kl_sum", "dsa_key_tiles",
+                "dsa_key_tiles_scored")
+#: keys a tile of a query block's index scores (chosen on the chip among
+#: 1,024, 512, 256 and 128 at the cell's shape: PERF.md section 5)
+KEY_TILE = 512
 
 
 def _project(p, h, sizes):
@@ -103,6 +130,69 @@ def _index_scores(qi, ki, w):
     """``I[B, q, s] = sum_j w[B, q, j] relu(qi[B, q, j] . ki[B, s])``."""
     dots = jnp.einsum("bqjd,bsd->bqjs", qi, ki, precision=HIGHEST)
     return jnp.sum(w[..., None] * jax.nn.relu(dots), axis=2)
+
+
+def _tile(a, t, tile, axis):
+    return lax.dynamic_slice_in_dim(a, t * tile, tile, axis)
+
+
+def _tile_scores(qi, keys, w, seen):
+    """One tile's :func:`_index_scores`, ``-inf`` where ``seen`` is false."""
+    return jnp.where(seen, _index_scores(qi, keys, w), -jnp.inf)
+
+
+def _live_tiles(sees, tile):
+    """(the key tiles of ``tile`` keys, those in which ``sees[B, q, s]``
+    holds a visible pair first; how many those are)."""
+    b, block, stop = sees.shape
+    live = jnp.any(sees.reshape(b, block, stop // tile, tile), axis=(0, 1, 3))
+    return jnp.argsort(~live, stable=True), jnp.sum(live)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _index_by_tiles(qi, ki, w, sees, tile):
+    """``I[B, q, s]`` of :func:`_index_scores` with ``-inf`` where ``sees``
+    is false, a tile of ``tile`` keys at a time through one body that runs
+    as many times as tiles hold a visible pair: a tile that holds none is
+    ``-inf`` as it stands and is multiplied in no pass.  The trip count is
+    the chip's to read where one row runs at a time (``_sparse_attention``).
+    Nothing is kept for the backward pass but the arguments: it runs over the
+    same tiles and multiplies each again."""
+    order, count = _live_tiles(sees, tile)
+
+    def one(i, index):
+        t = order[i]
+        scores = _tile_scores(qi, _tile(ki, t, tile, 1), w,
+                              _tile(sees, t, tile, 2))
+        return lax.dynamic_update_slice_in_dim(index, scores, t * tile, 2)
+
+    return lax.fori_loop(0, count, one,
+                         jnp.full(sees.shape, -jnp.inf, w.dtype))
+
+
+def _index_by_tiles_fwd(qi, ki, w, sees, tile):
+    return _index_by_tiles(qi, ki, w, sees, tile), (qi, ki, w, sees)
+
+
+def _index_by_tiles_bwd(tile, kept, g):
+    qi, ki, w, sees = kept
+    order, count = _live_tiles(sees, tile)
+
+    def one(i, sums):
+        d_qi, d_ki, d_w = sums
+        t = order[i]
+        _, back = jax.vjp(
+            functools.partial(_tile_scores, seen=_tile(sees, t, tile, 2)),
+            qi, _tile(ki, t, tile, 1), w)
+        of_qi, of_keys, of_w = back(_tile(g, t, tile, 2))
+        return (d_qi + of_qi, lax.dynamic_update_slice_in_dim(
+            d_ki, of_keys, t * tile, 1), d_w + of_w)
+
+    return (*lax.fori_loop(0, count, one,
+                           jax.tree.map(jnp.zeros_like, (qi, ki, w))), None)
+
+
+_index_by_tiles.defvjp(_index_by_tiles_fwd, _index_by_tiles_bwd)
 
 
 def _ordered(scores):
@@ -143,13 +233,17 @@ def _query_block(q, qi, w, q_docs, k, v, ki, k_docs, *, start, sizes):
     """Query positions ``[start, start + Q)`` against the keys ``[0, start +
     Q)``: (the heads' outputs ``[B, Q, heads x d]``, the sum of the queries'
     KL, how many keys the queries see, how many of the queries see more than
-    ``index_topk``, how many keys are kept)."""
+    ``index_topk``, how many keys are kept, how many key tiles the block
+    ranges over, how many of them were scored)."""
     b, block = q.shape[:2]
     stop, topk = start + block, sizes["index_topk"]
+    tile = sizes.get("key_tile", KEY_TILE)  # a test seam: tiles at S = 32
+    if block % tile:
+        tile = block
     sees = _visible(jnp.arange(start, stop), jnp.arange(stop), q_docs, k_docs,
                     None)
     with device_span("matcha/dsa_index"):
-        index = jnp.where(sees, _index_scores(qi, ki, w), -jnp.inf)
+        index = _index_by_tiles(qi, ki, w, sees, tile)
     with device_span("matcha/dsa_select"):
         # up to ``topk`` keys in reach: every visible one is kept
         keep = _select(index, sees, topk) if stop > topk else sees
@@ -168,13 +262,14 @@ def _query_block(q, qi, w, q_docs, k, v, ki, k_docs, *, start, sizes):
                                target * (jnp.log(target) - guess), 0.0))
     visible = jnp.sum(sees, axis=-1)
     return (out.reshape(b, block, -1), kl, jnp.sum(visible),
-            jnp.sum(visible > topk), jnp.sum(jnp.minimum(visible, topk)))
+            jnp.sum(visible > topk), jnp.sum(jnp.minimum(visible, topk)),
+            stop // tile, _live_tiles(sees, tile)[1])
 
 
 def _row_attention(q, k, v, qi, ki, w, docs, sizes):
     """Rows ``[B, S, ...]`` a checkpointed block of query positions at a
     time: (the heads' outputs ``[B, S, heads x d]``, the sum of the queries'
-    KL, and the three counts of :func:`_query_block`)."""
+    KL, and the five counts of :func:`_query_block`)."""
     s = docs.shape[1]
     block = sizes.get("attn_block", 1024)  # a test seam: blocks at S = 32
     if s % block:
@@ -190,7 +285,7 @@ def _row_attention(q, k, v, qi, ki, w, docs, sizes):
         outs.append(out)
         sums.append(counted)
     return (jnp.concatenate(outs, axis=1),
-            *(sum(c[n] for c in sums) for n in range(4)))
+            *(sum(c) for c in zip(*sums)))
 
 
 def _sparse_attention(q, k, v, qi, ki, w, docs, sizes):
@@ -200,11 +295,12 @@ def _sparse_attention(q, k, v, qi, ki, w, docs, sizes):
     out, *sums = lax.map(
         lambda row: _row_attention(*(a[None] for a in row), sizes),
         (q, k, v, qi, ki, w, docs))
-    kl, visible, selecting, kept = (
+    kl, visible, selecting, kept, tiles, scored = (
         jnp.sum(c).astype(jnp.float32) for c in sums)
     return out.reshape(b, s, -1), {
         "dsa_queries": jnp.float32(b * s), "dsa_queries_selecting": selecting,
-        "dsa_keys_visible": visible, "dsa_keys_kept": kept, "dsa_kl_sum": kl}
+        "dsa_keys_visible": visible, "dsa_keys_kept": kept, "dsa_kl_sum": kl,
+        "dsa_key_tiles": tiles, "dsa_key_tiles_scored": scored}
 
 
 def _experts_of(p, h, sizes):

@@ -45,7 +45,8 @@ LONGEST_FIRST = (
     "test_chipbench_gdn_faults", "test_chipbench_dsa_faults",
     "test_leaf_cells", "test_train", "test_packed_fwd_bwd", "test_pallas",
     "test_expert_dispatch",
-    "test_qwen3_next", "test_keye_vl2", "test_chipbench_bd_faults",
+    "test_qwen3_next", "test_keye_vl2", "test_keye_vl2_tiles",
+    "test_chipbench_bd_faults",
     "test_leaf_exchange", "test_chipbench_token_faults",
     "test_chipbench_cells_more", "test_leaf_step", "test_stream_exchange",
     "test_mellum2", "test_chipbench_cells", "test_models",

@@ -6,7 +6,9 @@ every key kept, dense grouped-query attention and ``mellum2``'s ``full`` path
 on the same weights; the shares of 16 chips added back up to the uncut layer;
 each loss term's gradient zero where the other's parameters are; the
 counters against a NumPy count of the mask.  Small sizes, seeded weights,
-float32 products at ``highest``."""
+float32 products at ``highest``.  (The index scores by key tiles are held to
+the form that scores every tile in ``test_keye_vl2_tiles.py``, beside this
+file, which takes its rows and weights from here.)"""
 
 import functools
 
@@ -40,15 +42,23 @@ def sizes_of(topk=8, **more):
 
 def rows(documents, n=3, seed=0):
     """(ids, document numbers) ``[n, SEQ + 1]``: one document a row, or
-    documents packed so that every row holds boundaries."""
+    documents packed so that every row holds boundaries (``many``: a
+    boundary every six positions or so, so that whole key tiles hide from
+    whole query blocks; ``unsorted``: those, their numbers shuffled, two of
+    them alike, so that numbers fall as well as rise and one comes back)."""
     rng = np.random.default_rng(seed)
     ids = rng.integers(0, 24, (n, SEQ + 1), dtype=np.int32)
     if documents == "one":
         docs = np.repeat(np.arange(n, dtype=np.int32)[:, None], SEQ + 1, 1)
     else:
-        cuts = np.sort(rng.choice(np.arange(1, n * (SEQ + 1)), n, False))
+        boundaries = n if documents == "packed" else n * (SEQ + 1) // 6
+        cuts = np.sort(rng.choice(np.arange(1, n * (SEQ + 1)), boundaries,
+                                  False))
         docs = np.searchsorted(cuts, np.arange(n * (SEQ + 1)), "right") \
             .astype(np.int32).reshape(n, SEQ + 1)
+        if documents == "unsorted":
+            numbers = rng.permutation(boundaries + 1).astype(np.int32)
+            docs = np.minimum(numbers, boundaries - 1)[docs]
     return jnp.asarray(ids), jnp.asarray(docs)
 
 
@@ -141,6 +151,8 @@ def test_blocked_equals_unblocked(documents):
     with jax.default_matmul_precision("highest"):
         blocked = compiled(8, 16)[0](params, ids, docs)
         whole = compiled(8, SEQ)[0](params, ids, docs)
+    for counters in (blocked[3], whole[3]):  # they count the blocking itself
+        counters.pop("dsa_key_tiles"), counters.pop("dsa_key_tiles_scored")
     for got, want in zip(jax.tree_util.tree_leaves(blocked),
                          jax.tree_util.tree_leaves(whole)):
         close(got, want, tol=1e-5)
@@ -351,8 +363,13 @@ def test_trains_by_name_through_train(tmp_path):
             "loss_positions", "moe_slots_held", "moe_rows_computed",
             "moe_rows_multiplied",
             "moe_load", "dsa_queries", "dsa_queries_selecting",
-            "dsa_keys_visible", "dsa_keys_kept", "dsa_kl_sum"}
+            "dsa_keys_visible", "dsa_keys_kept", "dsa_kl_sum",
+            "dsa_key_tiles", "dsa_key_tiles_scored"}
         assert c["dsa_queries"] == queries
+        # blocks of 16 at S = 32, a tile a block: 1 + 2 tiles a layer-row
+        assert c["dsa_key_tiles"] == queries // SEQ * 3
+        assert queries // SEQ * 2 <= c["dsa_key_tiles_scored"] \
+            <= c["dsa_key_tiles"]
         assert 0 < c["dsa_queries_selecting"] < queries
         assert 0 < c["dsa_keys_kept"] < c["dsa_keys_visible"]
         assert c["dsa_kl_sum"] > 0
