@@ -56,7 +56,7 @@ def main() -> int:
         def enc(x, key, comp=comp):
             vals, idx = comp(x, args.ratio, key)
             # a readback that depends on the whole encode (dispatch is
-            # asynchronous — see bench.py): sum of values + first index column
+            # asynchronous): sum of values + first index column
             return (jnp.sum(vals.astype(jnp.float32))
                     + jnp.sum(idx[:, :1].astype(jnp.float32)))
 

@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Per-step gossip rate: masked backend vs the cond-skipping backend.
 
-The masked backends (`gather`/`dense`/`fused`) execute every matching every
+The masked backends (`gather`/`dense`) execute every matching every
 step and mask inactive ones to zero — the budget changes arithmetic, not
 time.  The `skip` backend wraps each matching in ``lax.cond`` so inactive
 matchings cost nothing at runtime.  This microbench measures that directly:
@@ -39,8 +39,8 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-# ResNet-20/CIFAR-10 flat parameter count (bench.py computes it from the
-# model; hardcoded here so the microbench never touches the model zoo)
+# ResNet-20/CIFAR-10 flat parameter count (hardcoded here so the
+# microbench never touches the model zoo)
 RESNET20_DIM = 273_258
 
 
@@ -48,8 +48,8 @@ def time_chain(comm, x, flags, steps):
     import jax
     import jax.numpy as jnp
 
-    # the readback serializes the whole chain (see bench.py: dispatch is
-    # asynchronous, and a clock that stops early inflates rates 100x+)
+    # the readback serializes the whole chain (dispatch is asynchronous,
+    # and a clock that stops early inflates rates 100x+)
     run = jax.jit(lambda x: jnp.sum(comm.run(x, flags)[0][:, :8]))
     float(run(x))
     best = float("inf")

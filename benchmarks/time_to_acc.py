@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Wall-clock to target test accuracy: D-PSGD vs MATCHA vs CHOCO.
 
-BASELINE.json's metric has two clauses: gossip-steps/sec (bench.py) and
+BASELINE.json's metric has two clauses: gossip-steps/sec and
 **wall-clock to target test-acc** — the quantity the MATCHA paper actually
 optimizes (arXiv:1905.09435: same accuracy, less communication, therefore
 less wall-clock per epoch on comm-bound clusters).  This harness measures the
@@ -140,7 +140,7 @@ def main():
         # Context the ratios need: MATCHA's wall-clock economy presumes
         # communication dominates the iteration (the reference's MPI world,
         # where gossip is pickled host-memory sendrecv).  On this backend the
-        # gossip chain is a fused on-chip program and comm_share is ~1-2%, so
+        # gossip chain is an on-chip program and comm_share is ~1-2%, so
         # wall-clock-to-target tracks *epochs*-to-target and a lower budget
         # only trades convergence speed for savings on an already-negligible
         # cost.  The budget knob matters again when the worker axis spans
@@ -149,7 +149,7 @@ def main():
         # that makes this explicit rather than claiming a speedup.
         summary["dpsgd_comm_share"] = d["comm_share"]
         summary["note"] = (
-            "comm_share ~0.01-0.02 on one TPU chip: the fused gossip backend "
+            "comm_share ~0.01-0.02 on one TPU chip: the gossip backend "
             "makes communication nearly free, so time-to-target follows "
             "epochs-to-target; MATCHA's budget economy targets comm-bound "
             "(multi-host/MPI) regimes, which this backend has designed away "

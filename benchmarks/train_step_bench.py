@@ -4,9 +4,9 @@
 BASELINE.json's north star is worded as a *training run*: 256 virtual
 workers, ResNet-20/CIFAR-10, MATCHA budget 0.5, one gossip step per SGD step
 (/root/reference/train_mpi.py:113-145 — the loop this framework compiles
-into a single program).  bench.py isolates the gossip chain; this harness
+into a single program).  This harness
 measures the quantity the wording implies — `make_train_step` steps/sec with
-the gossip mix fused into the compiled step — plus the **marginal cost of
+the gossip mix inside the compiled step — plus the **marginal cost of
 gossip** obtained by differencing against an identical run with
 `communicator="none"`, and the roofline argument that connects the two:
 
@@ -100,8 +100,7 @@ def measure(args) -> dict:
             return state, m
 
         chain_j = jax.jit(chain)
-        # time to a scalar readback (dispatch is asynchronous — see
-        # bench.py)
+        # time to a scalar readback (dispatch is asynchronous)
         out_state, m = chain_j(state)
         float(m["loss"])
         log(f"{comm_name}: chain compiled + warm; timing {args.reps} reps...")
@@ -132,7 +131,7 @@ def measure(args) -> dict:
     record = {
         "metric": f"train-steps/sec @ {n} workers x batch {b}, "
                   f"{args.model}@{hw}px, "
-                  f"MATCHA budget 0.5 (gossip fused into the step)",
+                  f"MATCHA budget 0.5 (gossip inside the step)",
         "value": round(rate_full, 3),
         "unit": "train_steps_per_sec",
         "train_steps_per_sec_no_comm": round(rate_none, 3),
@@ -147,7 +146,7 @@ def measure(args) -> dict:
                                 f"{args.model}; fwd/bwd share omitted"}),
             "flops_gossip_per_step": flops_gossip,
             "note": "gossip-steps/sec in a training run == train-steps/sec; "
-                    "the isolated gossip kernel rate (bench.py value) bounds "
+                    "the isolated gossip kernel rate bounds "
                     "the comm term, and the FLOP share bounds what any "
                     "budget<1 can save on-chip",
         },

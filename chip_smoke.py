@@ -15,12 +15,13 @@ Phases, each of which fails the run if it fails:
    taken, finite falling loss, finite replica disagreement, no ``retrace``
    event, and that ``auto`` used every device: ``dense`` on one chip,
    ``shard_map`` with one parameter shard per chip on several.
-2. **kernels** — the Pallas gossip kernels, compiled for the device
-   through ``make_decen`` (never the interpreter on an accelerator), on a
-   20-step flag stream at 16 x 273,258 and 256 x 273,258, f32 state with
-   f32 and bf16 wire: the ``fused`` chain against the per-step ``dense``
-   scan on the device (the streamed exchange at 16 rows, the MXU product
-   at 256).
+2. **kernels** — the exchange ``train()`` runs, ``make_decen(backend=
+   "dense")`` compiled for the device (never the interpreter on an
+   accelerator), on a 20-step flag stream through ``Communicator.run`` at
+   16 x 273,258 (the streamed Pallas pass) and 256 x 273,258 (the MXU
+   product), f32 state with f32 and bf16 wire, against the per-matching
+   ``gather`` chain as the oracle (run ``ORACLE_COLS`` columns at a time:
+   whole, it keeps 15.5 GB of temporaries at 256 rows).
 3. **fold** (more than one device) — one gossip step of the worker-folded
    ``shard_map`` plan: ``collective-permute`` in its compiled HLO, and the
    result equal to the single-chip ``dense`` step.
@@ -44,6 +45,12 @@ import time
 FLAT_DIM = 273_258  # ResNet-20 / CIFAR-10 flat parameter count
 EPOCHS, STEPS = 2, 8  # 2,048 samples / (16 workers x batch 32) = 4 a epoch
 CHAIN_STEPS = 20
+# Columns of the state the gather oracle mixes in one call.  The exchange
+# mixes rows, so columns are independent; XLA keeps every matching's
+# gathered copy of the state, and the 20-step chain at 256 x 273,258 (27
+# matchings) compiled for a described v5e with 15.45 GB of temporaries
+# (PR 45), 1.9 GB at this width.
+ORACLE_COLS = 32_768
 
 # Tolerances, relative to the oracle's largest magnitude.  f32 wire: both
 # sides are the same f32 arithmetic up to the order of a sum (MXU passes at
@@ -129,7 +136,7 @@ def train_phase(tiny: bool, workdir: str) -> dict:
 
 def _chain_schedule(n: int):
     """A ``CHAIN_STEPS``-step flag stream for ``n`` workers.  16: the train
-    phase's own MATCHA schedule.  256: the bench's geometric graph with
+    phase's own MATCHA schedule.  256: a geometric graph with
     every matching drawn at p = 0.5 — MATCHA's solved probabilities cost a
     ~200 s CVX solve there, and the kernels see only flags and alpha."""
     from matcha_tpu import topology as tp
@@ -143,11 +150,19 @@ def _chain_schedule(n: int):
                           mode="bernoulli", seed=0)
 
 
-def kernel_phase(dim: int) -> list:
+#: ``(workers, wire)`` of the kernel phase: one worker count on each side of
+#: ``parallel.STREAM_MAX_WORKERS`` (the streamed pass, the MXU product)
+KERNEL_CASES = [(16, "f32"), (16, "bf16"), (256, "f32"), (256, "bf16")]
+
+
+def kernel_phase(dim: int, cases=KERNEL_CASES) -> list:
+    import warnings
+
     import jax
     import jax.numpy as jnp
 
     from matcha_tpu.communicator import make_decen
+    from matcha_tpu.parallel import dense_exchange_form
 
     def timed(fn, x):
         """(result, first-call seconds, second-call seconds), both calls
@@ -162,33 +177,38 @@ def kernel_phase(dim: int) -> list:
         return out, secs[0], secs[1]
 
     rows = []
-    for n in (16, 256):
-        sched = _chain_schedule(n)
+    scheds = {n: _chain_schedule(n) for n in sorted({n for n, _ in cases})}
+    for n, wire in cases:
+        sched = scheds[n]
         flags = jnp.asarray(sched.flags[:CHAIN_STEPS], jnp.float32)
         x = jax.random.normal(jax.random.PRNGKey(n), (n, dim), jnp.float32)
-        for wire in ("f32", "bf16"):
-            kernel, oracle = "fused", "dense"
-            comms = [make_decen(sched, backend=b, wire_dtype=wire)
-                     for b in (kernel, oracle)]
-            assert comms[0].multi_step is not None, kernel
-            got, compile_s, run_s = timed(
-                jax.jit(lambda v, c=comms[0]: c.run(v, flags)[0]), x)
-            want, _, oracle_s = timed(
-                jax.jit(lambda v, c=comms[1]: c.run(v, flags)[0]), x)
-            err = float(jnp.max(jnp.abs(got - want))
-                        / jnp.max(jnp.abs(want)))
-            moved = float(jnp.max(jnp.abs(want - x)))
-            tol = TOL_F32 if wire == "f32" else TOL_BF16
-            row = {"kernel": kernel, "oracle": oracle, "n": n,
-                   "dim": dim, "wire": wire, "rel_err": err, "tol": tol,
-                   "first_call_seconds": round(compile_s, 2),
-                   "chain_seconds": round(run_s, 4),
-                   "oracle_chain_seconds": round(oracle_s, 4)}
-            rows.append(row)
-            print(f"# kernel {json.dumps(row)}", flush=True)
-            assert bool(jnp.isfinite(got).all()), row
-            assert moved > 0.0, f"flag stream mixed nothing: {row}"
-            assert err <= tol, row
+        with warnings.catch_warnings():
+            # make_decen warns that gather is slow at 256 rows: as the
+            # oracle it runs twice
+            warnings.simplefilter("ignore", UserWarning)
+            dense, gather = (make_decen(sched, backend=b, wire_dtype=wire)
+                             for b in ("dense", "gather"))
+        got, compile_s, run_s = timed(
+            jax.jit(lambda v: dense.run(v, flags)[0]), x)
+        oracle_run = jax.jit(lambda v: gather.run(v, flags)[0])
+        want, _, oracle_s = timed(
+            lambda v: jnp.concatenate(
+                [oracle_run(v[:, at:at + ORACLE_COLS])
+                 for at in range(0, dim, ORACLE_COLS)], axis=1), x)
+        err = float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
+        moved = float(jnp.max(jnp.abs(want - x)))
+        tol = TOL_F32 if wire == "f32" else TOL_BF16
+        row = {"kernel": dense_exchange_form(n)["form"],
+               "oracle": "gather", "n": n,
+               "dim": dim, "wire": wire, "rel_err": err, "tol": tol,
+               "first_call_seconds": round(compile_s, 2),
+               "chain_seconds": round(run_s, 4),
+               "oracle_chain_seconds": round(oracle_s, 4)}
+        rows.append(row)
+        print(f"# kernel {json.dumps(row)}", flush=True)
+        assert bool(jnp.isfinite(got).all()), row
+        assert moved > 0.0, f"flag stream mixed nothing: {row}"
+        assert err <= tol, row
     return rows
 
 

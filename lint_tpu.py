@@ -76,8 +76,8 @@ from matcha_tpu.analysis import (
 
 # the shipped lint surface: the package and every executable entry point.
 # tests/ is deliberately excluded — fixtures *construct* violations.
-DEFAULT_PATHS = ["matcha_tpu", "train_tpu.py", "plan_tpu.py", "bench.py",
-                 "obs_tpu.py", "serve_tpu.py"]
+DEFAULT_PATHS = ["matcha_tpu", "train_tpu.py", "plan_tpu.py", "obs_tpu.py",
+                 "serve_tpu.py"]
 DEFAULT_BASELINE = "graftlint_baseline.json"
 DEFAULT_PLAN_PATHS = ["benchmarks"]
 

@@ -27,31 +27,19 @@ def select_communicator(
     backend: str = "auto",
     compressor: str = "top_k",
     seed: int = 0,
-    block_d: int | None = None,
-    w_window: int = 1,
     wire_dtype=None,
 ) -> Communicator:
     """Registry keyed by the reference's algorithm names (README.md:17-53):
     ``decen`` (D-PSGD/MATCHA), ``choco`` (CHOCO-SGD), ``centralized``
     (AllReduce baseline), ``none``.  ``compressor`` selects CHOCO's message
     compressor from the ops registry (``matcha_tpu.ops.COMPRESSOR_NAMES``);
-    ``seed`` seeds the stochastic compressors' PRNG carry.  ``block_d`` and
-    ``w_window`` tune the fused Pallas kernel (decen
-    only; see :func:`make_decen`).  ``wire_dtype`` (``"f32"``/``"bf16"``) narrows the
-    exchanged tensors at the gossip boundary for every communicator except
-    ``none`` (which exchanges nothing)."""
+    ``seed`` seeds the stochastic compressors' PRNG carry.  ``wire_dtype``
+    (``"f32"``/``"bf16"``) narrows the exchanged tensors at the gossip
+    boundary for every communicator except ``none`` (which exchanges
+    nothing)."""
     if name == "decen":
         return make_decen(schedule, mesh=mesh, backend=backend,
-                          block_d=block_d, w_window=w_window,
                           wire_dtype=wire_dtype)
-    if block_d is not None or w_window != 1:
-        import warnings
-
-        warnings.warn(
-            f"block_d/w_window tune the decen fused kernel and have no "
-            f"effect on communicator '{name}' — the flags are being ignored",
-            stacklevel=2,
-        )
     if name == "choco":
         if backend == "skip":
             raise ValueError(
@@ -59,7 +47,7 @@ def select_communicator(
                 "sparse); use communicator='decen' with backend='skip', or "
                 "a masked choco backend")
         # map the gossip backend vocabulary onto choco's two forms: the
-        # dense/fused/gather spellings are all the single-array batched path
+        # dense/gather spellings are both the single-array batched path
         choco_backend = backend if backend in ("auto", "shard_map") else "batched"
         return make_choco(schedule, ratio=ratio, consensus_lr=consensus_lr,
                           mesh=mesh, backend=choco_backend,

@@ -65,12 +65,11 @@ class Communicator:
     realized mixing matrix stays doubly stochastic over survivors.  Omitting
     it (or passing ``None``) compiles the exact unmasked program.
 
-    ``multi_step``, when present, runs a whole flag stream in one fused
-    launch (e.g. the Pallas VMEM-resident gossip kernel) — arithmetically
-    equivalent to scanning ``step``, used by ``run`` for consensus-only
-    phases and the micro-benchmark.  It knows no survivors (the W-stack
-    kernel's mixing matrices are precomputed maskless), so ``run`` scans
-    ``step`` for every masked chain.
+    ``multi_step``, when present, runs a whole flag stream in one launch
+    (CHOCO's ``shard_map`` form scans the stream inside one ``shard_map``
+    call) — arithmetically equivalent to scanning ``step``, used by ``run``
+    for consensus-only phases and the comm-split timer.  It takes no
+    survivor mask, so ``run`` scans ``step`` for every masked chain.
 
     ``leaves_step``, when present, is ``step`` over the parameter leaves
     where they lie (``parallel.pallas_gossip.tree_mix``): the same ``W_t``
@@ -325,14 +324,13 @@ class Communicator:
     def run(self, flat: jax.Array, flags: jax.Array, carry: Any = None,
             alive: Any = None):
         """Scan the communicator over a whole flag stream (consensus-only runs,
-        tests, and the gossip micro-benchmark).
+        tests, and the comm-split timer).
 
         ``alive``: optional survivor mask — ``f32[N]`` (held constant for
         the chain) or ``f32[T, N]`` (per-step, scanned alongside the flags).
-        Masked chains take the per-step scan — ``multi_step`` fusions like
-        the Pallas W-stack kernel precompute mixing matrices that do not
-        know about survivors, so bypassing them is a correctness
-        requirement, not a missing optimization."""
+        Masked chains take the per-step scan — ``multi_step`` takes no
+        mask, so bypassing it is a correctness requirement, not a missing
+        optimization."""
         import jax.numpy as jnp
         from jax import lax
 
@@ -340,8 +338,8 @@ class Communicator:
             carry = self.init(flat)
 
         flags = jnp.asarray(flags, jnp.float32)
-        if flags.shape[0] == 0:  # empty stream: identity (a zero-size Pallas
-            return flat, carry   # grid would not even initialize its output)
+        if flags.shape[0] == 0:  # empty stream: identity
+            return flat, carry
 
         if alive is None:
             if self.multi_step is not None:

@@ -13,9 +13,7 @@ skip-iteration early return (communicator.py:140-141) without a branch.
 
 Every backend accepts the resilience layer's optional survivor mask
 (``step(..., alive)``): dead workers' exchanges collapse to self-loops with
-the weight renormalized onto the survivor (see ``parallel.gossip``).  The
-fused Pallas ``multi_step`` is flag-stream-only; ``Communicator.run``
-routes masked chains through the per-step scan instead.
+the weight renormalized onto the survivor (see ``parallel.gossip``).
 """
 
 from __future__ import annotations
@@ -41,7 +39,7 @@ from .base import Communicator
 __all__ = ["GOSSIP_BACKENDS", "make_decen", "resolve_gossip_backend"]
 
 #: every name ``TrainConfig.gossip_backend`` / :func:`make_decen` takes
-GOSSIP_BACKENDS = ("auto", "dense", "fused", "gather", "skip", "shard_map")
+GOSSIP_BACKENDS = ("auto", "dense", "gather", "skip", "shard_map")
 
 
 def resolve_gossip_backend(schedule, mesh=None,
@@ -54,12 +52,12 @@ def resolve_gossip_backend(schedule, mesh=None,
     decentralization: ICI carries only gossip edges) and ``dense`` on one
     chip.  One resolver on purpose: :func:`make_decen` and the train loop
     both call it, so the journaled decision is definitionally the backend
-    that compiled.  Where that backend's per-step mix is the dense exchange
-    (``dense``, ``fused``), the record's ``exchange`` names the form it
-    compiles to at this worker count (``parallel.gossip.
-    dense_exchange_form``: ``streamed`` or ``mxu``, with the N and the
-    crossover it was chosen from) — the one choice the one-chip exchange
-    has, made from the static N inside ``gossip_mix_dense``.
+    that compiled.  Where that backend is ``dense``, the record's
+    ``exchange`` names the form it compiles to at this worker count
+    (``parallel.gossip.dense_exchange_form``: ``streamed`` or ``mxu``, with
+    the N and the crossover it was chosen from) — the one choice the
+    one-chip exchange has, made from the static N inside
+    ``gossip_mix_dense``.
     """
     if requested != "auto":
         record = {"requested": requested, "chosen": requested,
@@ -72,7 +70,7 @@ def resolve_gossip_backend(schedule, mesh=None,
         record = {"requested": "auto", "chosen": "dense",
                   "reason": "one chip: the dense exchange, in the form its "
                             "worker count asks for"}
-    if record["chosen"] in ("dense", "fused"):
+    if record["chosen"] == "dense":
         record["exchange"] = dense_exchange_form(
             schedule.num_workers, _single_chip(mesh))
     return record
@@ -87,9 +85,6 @@ def make_decen(
     mesh=None,
     backend: str = "auto",
     compute_dtype=jnp.float32,
-    chunk: int = 1,
-    block_d: int | None = None,
-    w_window: int = 1,
     wire_dtype=None,
 ) -> Communicator:
     """Build the gossip communicator for a schedule.
@@ -101,13 +96,6 @@ def make_decen(
                           ``STREAM_MAX_WORKERS`` rows one streamed
                           vector-unit pass over the state, in place; above
                           that, or under a mesh, one MXU matmul.
-      * ``"fused"``     — dense per-step, plus the Pallas multi-step kernel
-                          (VMEM-resident state, streamed W_t stack) for whole
-                          flag streams — the bench configuration.  The
-                          kernel compiles for the device on every platform
-                          but ``cpu``, where it runs under the Pallas
-                          interpreter (the tier-1 mesh) — an accelerator
-                          never interprets.
       * ``"gather"``    — per-matching static gathers (any N under jit).
       * ``"skip"``      — per-matching ``lax.cond``: inactive matchings are
                           not executed, so the MATCHA budget buys back real
@@ -127,30 +115,10 @@ def make_decen(
                           loop journals the decision record (``backend``
                           event).
 
-    ``chunk`` (fused backend only): collapse runs of ``chunk`` consecutive
-    mixing matrices into their product before the Pallas kernel — exactly the
-    same ``x_T`` by associativity at ~``chunk``× fewer apply-FLOPs (see
-    ``compose_mixing_stack``).  Intermediate per-step iterates are then not
-    materialized, so keep the default 1 for training loops that interleave
-    gossip with SGD; raise it for consensus-only chains and the bench.
-
-    ``block_d`` (fused backend only): the Pallas kernel's resident D-block
-    size; None keeps the kernel's default.  Per-step W-stream
-    traffic is ``ceil(D/block_d)·N²``, so bigger blocks cut HBM traffic
-    linearly until the [N, block_d] in+out blocks stop fitting the 16 MiB
-    scoped VMEM — a request that cannot fit raises
-    :class:`~matcha_tpu.parallel.GossipKernelResourceError` here, at build
-    time (f32 state at N=256: 2048 fits, 4096 does not).
-
-    ``w_window`` (fused backend only): consecutive ``W_t`` per D-block grid
-    visit.  Unlike ``chunk`` this keeps the exact per-step arithmetic (every
-    step's matmul executes in order) — it only amortizes grid overhead and
-    enlarges W DMAs, so it is valid for the training-regime measurement.
-
     ``wire_dtype`` (``"f32"``/``"bf16"``/None): dtype of the *exchanged*
     tensors at the gossip boundary — bf16 halves the bytes every backend
     moves per step (ppermute blocks on ICI for shard_map, the HBM state
-    stream for gather/skip, the MXU operand pass for dense/fused) while
+    stream for gather/skip, the MXU operand pass for dense) while
     master parameters and the delta accumulation stay f32.  For the MXU
     backends this rides the existing ``compute_dtype``/``mxu_precision``
     seam: bf16 wire ⇒ one native bf16 MXU pass with f32 accumulation
@@ -159,47 +127,30 @@ def make_decen(
     same values (``W_t`` and the state rounded to the wire dtype, float32
     products and sums) but moves no fewer bytes: it rounds the float32 state
     as it reads it, on one chip, where nothing crosses a wire.  An explicit
-    ``compute_dtype`` below f32 wins over the wire knob (the bench passes
-    bf16 state directly).
+    ``compute_dtype`` below f32 wins over the wire knob.
     """
     # the one validator of schedule-built tables (GL101's runtime half):
     # every row gather below reads what it returns
     perms, _ = involution_tables(schedule.perms)
     alpha = float(schedule.alpha)
     wire = resolve_wire_dtype(wire_dtype)
-    state_itemsize = jnp.dtype(compute_dtype).itemsize
     if wire is not None and jnp.dtype(compute_dtype).itemsize >= 4:
-        # the dense/fused matmul *is* the exchange: its operand pass in the
+        # the dense matmul *is* the exchange: its operand pass in the
         # wire dtype (f32 accumulate) is exactly the bf16-wire semantics
         compute_dtype = wire
 
     if backend == "auto":
         backend = resolve_gossip_backend(schedule, mesh)["chosen"]
 
-    if backend != "fused" and (block_d is not None or w_window != 1):
-        import warnings
-
-        warnings.warn(
-            f"block_d and w_window tune the fused backend's Pallas kernel; "
-            f"backend '{backend}' ignores them. "
-            f"Note the fused kernel runs multi-step *chains* "
-            f"(Communicator.run / the comm-split timer) — the per-step "
-            f"training mix is the dense exchange either way.",
-            stacklevel=2,
-        )
-
-    multi_step = None
     if backend == "gather":
         if perms.shape[1] >= 64:
             import warnings
 
             warnings.warn(
                 f"gossip_backend='gather' walks the full state once per "
-                f"matching and measures ~60x slower than 'dense'/'fused' at "
-                f"N={perms.shape[1]} (README Performance table: 18 vs 4764+ "
-                f"steps/s at N=256). Use backend='dense' (single chip) or "
-                f"'fused'; 'gather' remains for small-N debugging and "
-                f"oracle tests.",
+                f"matching and measures ~60x slower than 'dense' at "
+                f"N={perms.shape[1]}. Use backend='dense' (single chip); "
+                f"'gather' remains for small-N debugging and oracle tests.",
                 stacklevel=2,
             )
         mix: Callable = lambda x, w, alive=None: gossip_mix(
@@ -213,38 +164,6 @@ def make_decen(
     elif backend == "dense":
         mix = dense_gossip_fn(schedule.laplacians(), compute_dtype=compute_dtype,
                               single_chip=_single_chip(mesh))
-    elif backend == "fused":
-        from ..parallel import (
-            build_mixing_stack,
-            compose_mixing_stack,
-            fused_gossip_run,
-        )
-        from ..parallel.pallas_gossip import check_fused_fits, pallas_interpret
-
-        mix = dense_gossip_fn(schedule.laplacians(), compute_dtype=compute_dtype,
-                              single_chip=_single_chip(mesh))
-        laplacians = schedule.laplacians()
-        interpret = pallas_interpret()
-
-        kernel_kwargs = {} if block_d is None else {"block_d": block_d}
-        if w_window > 1:
-            kernel_kwargs["w_window"] = w_window
-        # fail here, by name, rather than in Mosaic's allocator at the
-        # first chain: the state rides in the caller's compute_dtype when
-        # that is narrower than f32 (the bench), else f32 (training)
-        check_fused_fits(perms.shape[1], state_itemsize=state_itemsize,
-                         stack_itemsize=jnp.dtype(compute_dtype).itemsize,
-                         **kernel_kwargs)
-
-        def multi_step(flat, carry, flags):
-            stack = build_mixing_stack(
-                laplacians, alpha, flags, dtype=compute_dtype
-            )
-            if chunk > 1:
-                stack = compose_mixing_stack(stack, chunk)
-            return fused_gossip_run(flat, stack, interpret=interpret,
-                                    **kernel_kwargs), carry
-
     elif backend == "shard_map":
         if mesh is None:
             raise ValueError("shard_map backend needs a mesh")
@@ -276,8 +195,7 @@ def make_decen(
     wire_tag = "" if wire is None else f",wire={jnp.dtype(wire).name}"
     return Communicator(
         name=f"decen[{backend}{wire_tag}]", init=init, step=step,
-        multi_step=multi_step, leaves_step=leaves_step,
-        leaves_refusal=leaves_refusal,
+        leaves_step=leaves_step, leaves_refusal=leaves_refusal,
     )
 
 
@@ -285,7 +203,7 @@ def _leaves_refusal(backend: str, n: int, mesh) -> str | None:
     """Why a decen communicator's per-step exchange has no leaf form, or
     ``None`` where it has: the leaf form is the streamed pass, so it exists
     exactly where ``dense_exchange_form`` says ``streamed``."""
-    if backend not in ("dense", "fused"):
+    if backend != "dense":
         return f"gossip backend '{backend}' is not the dense exchange"
     form = dense_exchange_form(n, _single_chip(mesh))
     if form["form"] == "streamed":

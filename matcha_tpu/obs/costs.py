@@ -2,8 +2,7 @@
 
 Until ISSUE 8 every performance ceiling in this repo was hand-derived:
 ``benchmarks/ROOFLINE.md`` multiplies 2·N²·D by hand, DESIGN.md §9 does the
-HBM capacity arithmetic in a prose table, and ``bench.py`` carries its own
-FLOP/byte *model* of the kernels it times.  This module extracts those
+HBM capacity arithmetic in a prose table.  This module extracts those
 numbers from the **compiled program itself** instead:
 
 * :func:`analyze_program` lowers + compiles any jitted callable against
@@ -46,7 +45,7 @@ import numpy as np
 __all__ = ["ChipSpec", "CHIP_PEAKS", "CPU_PROVISIONAL", "UnknownChipError",
            "chip_peaks", "resolve_chip", "abstract_args", "program_fingerprint",
            "analyze_program", "CostLedger", "Roofline", "gossip_step_costs",
-           "gossip_chain_costs", "elision_epoch_costs", "flat_param_dim",
+           "elision_epoch_costs", "flat_param_dim",
            "roofline_report",
            "capacity_report", "render_roofline_markdown",
            "render_capacity_markdown"]
@@ -68,7 +67,7 @@ class ChipSpec:
 
 
 #: device_kind substring → pinned peaks.  This is the ONE chip table in the
-#: repo: ``bench.py`` imports :func:`chip_peaks` from here.
+#: repo.
 CHIP_PEAKS: Dict[str, ChipSpec] = {
     "v6": ChipSpec(918.0, 1640.0, 32.0),
     "v5p": ChipSpec(459.0, 2765.0, 95.0),
@@ -303,8 +302,7 @@ class CostLedger:
 def flat_param_dim(model_name: str, dataset: str = "synthetic",
                    num_classes: int = 10) -> int:
     """Flat parameter dimension D of a registry model, via ``eval_shape``
-    (shapes only — nothing compiles or runs; the same trick bench.py uses
-    to size the north-star state)."""
+    (shapes only — nothing compiles or runs)."""
     import jax
     import jax.numpy as jnp
 
@@ -346,75 +344,9 @@ def gossip_step_costs(n: int, dim: int, decomposed: Sequence[Sequence[tuple]],
     return analyze_program(fn, x, w, label=f"gossip_step_dense_{wire_dtype}")
 
 
-def gossip_chain_costs(n: int, dim: int, decomposed,
-                       wire_dtype: str = "bf16",
-                       t_steps: int = 200, block_d: int = 2048) -> Dict:
-    """Extracted per-step costs of a T-step *chain* program — the fused
-    W-stack kernel, amortized over its ``t_steps`` (the regime the kernel
-    exists for: the state is read and written once per chain, and only the
-    streamed ``[T, N, N]`` W stack scales with T).
-
-    Compiled abstractly (``.lower().compile()``; interpret mode on the CPU
-    only — the same program text tier-1 tests execute): ``hbm_bytes`` is the
-    program-boundary argument+output traffic, so the chain's bytes carry
-    the ``[T, N, N]`` stack straight from XLA's own statement of what must
-    cross HBM.  Per-step fields divide by ``t_steps``.
-
-    ``stream_hbm_bytes_per_step`` subtracts the exactly-known one-time
-    state read+write (``2·N·D·state_bytes``) before amortizing: it is the
-    *streamed operand* — per step, ``N²·w`` of W stack.  Note the boundary
-    counts each operand ONCE per program; the physical per-D-block
-    re-stream (``ceil(D/bd)×``) is realized traffic and shows up in
-    ``bytes_accessed``, exactly the boundary-vs-realized split the module
-    docstring defines.  ``model_*`` fields carry the hand model the
-    extraction is checked against (``2·N²·D`` MXU FLOPs/step).
-    """
-    import jax
-    import jax.numpy as jnp
-
-    from ..parallel import fused_gossip_run
-    from ..parallel.gossip import resolve_wire_dtype
-    from ..parallel.pallas_gossip import pallas_interpret
-
-    wire = resolve_wire_dtype(None if wire_dtype == "f32" else wire_dtype)
-    wire_bytes = 4 if wire is None else jnp.dtype(wire).itemsize
-    state_dtype = jnp.float32 if wire is None else wire
-    interpret = pallas_interpret()
-    x = jax.ShapeDtypeStruct((n, dim), state_dtype)
-    stack = jax.ShapeDtypeStruct((t_steps, n, n), state_dtype)
-    # re-jit a closure over the static kwargs: analyze_program needs a
-    # bare .lower(*arrays) surface, and jit-of-jit lowers to the same
-    # program (the inner call inlines)
-    fn = jax.jit(lambda xx, ss: fused_gossip_run(
-        xx, ss, block_d=block_d, interpret=interpret))
-    costs = analyze_program(
-        fn, x, stack, label=f"gossip_chain_fused_{wire_dtype}")
-    # boundary stream: the W stack crosses HBM once per program —
-    # N²·w per step (pad rows for T % w_window ride along upstream)
-    model_stream = float(n * n * wire_bytes)
-    state_bytes = 2.0 * n * dim * jnp.dtype(state_dtype).itemsize
-    per_step = {
-        "backend": "fused", "t_steps": int(t_steps),
-        "block_d": int(block_d), "matchings": len(decomposed),
-        "flops_per_step": costs["flops"] / t_steps,
-        "hbm_bytes_per_step": costs["hbm_bytes"] / t_steps,
-        "stream_hbm_bytes_per_step":
-            max(costs["hbm_bytes"] - state_bytes, 0.0) / t_steps,
-        "bytes_accessed_per_step": costs["bytes_accessed"] / t_steps,
-        # hand model, per step: streamed operand + the amortized one-time
-        # state read/write (2·N·D·w/T) — what the extracted boundary
-        # number should match
-        "model_hbm_bytes": model_stream + state_bytes / t_steps,
-        "model_stream_hbm_bytes": model_stream,
-        "model_flops": 2.0 * n * n * dim,
-    }
-    return {**costs, **per_step}
-
-
 def elision_epoch_costs(n: int, dim: int, decomposed,
                         backend: str = "dense", wire_dtype: str = "bf16",
-                        t_steps: int = 200, local_every: int = 1,
-                        block_d: int = 2048) -> Dict:
+                        t_steps: int = 200, local_every: int = 1) -> Dict:
     """Per-epoch gossip-attributed HBM boundary bytes under local-step
     elision (DESIGN.md §24) — the ledger's statement of what universal
     elision removes.
@@ -423,43 +355,30 @@ def elision_epoch_costs(n: int, dim: int, decomposed,
     only on steps with ``t % L == 0`` — ``ceil(T/L)`` of ``T`` — and the
     thinned steps' gossip programs never run, so their boundary traffic
     vanishes rather than being multiplied by an identity.  This function
-    prices exactly that executed set:
-
-    - ``dense``: the per-step ``W_t @ x`` program's boundary ``hbm_bytes``
-      (:func:`gossip_step_costs` — state in+out and the flag row, each a
-      real program boundary every executed step) × executed steps.
-    - ``fused``: one chain program over the executed steps
-      (:func:`gossip_chain_costs` at ``t_steps = ceil(T/L)``), minus the
-      one-time state read+write both an L=1 and an L=4 epoch pay once —
-      i.e. the *streamed operand* bytes, the term elision actually thins
-      (W-stack rows).
+    prices exactly that executed set: the per-step ``W_t @ x`` program's
+    boundary ``hbm_bytes`` (:func:`gossip_step_costs` — state in+out and
+    the flag row, each a real program boundary every executed step) ×
+    executed steps.
 
     Returns the underlying program costs plus ``exec_steps``,
     ``gossip_hbm_bytes_per_epoch``, and ``gossip_hbm_bytes_per_step``
     (per *scheduled* step, ÷T — the number steps/s improvements track).
     The ≥2× L=1→L=4 reduction acceptance pin lives in
-    ``tests/test_overlap.py``; ``bench.py --suite elision_grid`` records
-    the same quantity next to measured steps/s.
+    ``tests/test_overlap.py``.
     """
     local_every = max(int(local_every), 1)
     t_steps = int(t_steps)
     if t_steps < 1:
         raise ValueError(f"t_steps must be >= 1, got {t_steps}")
     exec_steps = -(-t_steps // local_every)  # ceil: t=0 always mixes
-    if backend in ("dense", "skip"):
-        # skip shares dense's per-executed-step program — its thinning
-        # already happened at the flag level, so the executed set is the
-        # same program either way
-        costs = gossip_step_costs(n, dim, decomposed, wire_dtype=wire_dtype)
-        per_epoch = costs["hbm_bytes"] * exec_steps
-    elif backend == "fused":
-        costs = gossip_chain_costs(
-            n, dim, decomposed, wire_dtype=wire_dtype,
-            t_steps=exec_steps, block_d=block_d)
-        per_epoch = costs["stream_hbm_bytes_per_step"] * exec_steps
-    else:
+    if backend not in ("dense", "skip"):
         raise ValueError(
-            f"unknown elision backend {backend!r} (dense|skip|fused)")
+            f"unknown elision backend {backend!r} (dense|skip)")
+    # skip shares dense's per-executed-step program — its thinning already
+    # happened at the flag level, so the executed set is the same program
+    # either way
+    costs = gossip_step_costs(n, dim, decomposed, wire_dtype=wire_dtype)
+    per_epoch = costs["hbm_bytes"] * exec_steps
     return {
         **costs,
         "backend": backend,
@@ -500,89 +419,38 @@ class Roofline:
 
 def roofline_report(n: int, dim: int, decomposed, wire_dtype: str = "bf16",
                     chip: Optional[str] = None,
-                    measured_steps_per_sec: Optional[float] = None,
-                    backend: str = "dense") -> Dict:
-    """The automatic roofline: extracted per-step costs + the pinned chip
-    peaks → ceilings, hand-model deltas, and (when a measured rate is
-    supplied) the measured-vs-ceiling ratio.
-
-    ``backend`` selects whose program is priced: ``"dense"`` compiles the
-    per-step matmul (the historical report), ``"fused"`` compiles the
-    multi-step chain kernel and amortizes per step (its boundary bytes
-    carry the ``[T, N, N]`` W stack).  Every ratio derived from a measured
-    rate records ``measured_vs_ceiling_backend`` — the ratio must name its
-    denominator (a fused rate quoted against the dense ceiling, or vice
-    versa, is the mis-citation this field exists to prevent).
+                    measured_steps_per_sec: Optional[float] = None) -> Dict:
+    """The automatic roofline: the dense per-step program's extracted costs
+    + the pinned chip peaks → ceilings, hand-model deltas, and (when a
+    measured rate is supplied) the measured-vs-ceiling ratio.
     """
-    if backend == "fused":
-        costs = gossip_chain_costs(n, dim, decomposed,
-                                   wire_dtype=wire_dtype)
-        # XLA's cost_analysis does not multiply a scanned grid's body by
-        # its trip count (the chain kernel lowers to a grid scan), so the
-        # extracted chain FLOPs undercount by ~T× — the hand model is the
-        # floor of work the formulation must issue, so the ceiling uses
-        # whichever is larger; the raw extraction is kept alongside.
-        # Boundary bytes are shape-derived and exact either way.
-        flops = max(costs["flops_per_step"], costs["model_flops"])
-        hbm = costs["hbm_bytes_per_step"]
-        model_flops = costs["model_flops"]
-        model_hbm = costs["model_hbm_bytes"]
-        extra = {"bytes_accessed_per_step": costs["bytes_accessed_per_step"],
-                 "stream_hbm_bytes_per_step":
-                     costs["stream_hbm_bytes_per_step"],
-                 "model_stream_hbm_bytes": costs["model_stream_hbm_bytes"],
-                 "extracted_flops_per_step": costs["flops_per_step"],
-                 "t_steps": costs["t_steps"], "block_d": costs["block_d"],
-                 "matchings": costs["matchings"]}
-    elif backend == "dense":
-        costs = gossip_step_costs(n, dim, decomposed, wire_dtype=wire_dtype)
-        flops = costs["flops"]
-        hbm = costs["hbm_bytes"]
-        # the hand model this machine-checks (ROOFLINE.md: 2·N²·D FLOPs,
-        # 2·N·D·wire_bytes boundary traffic; the N² W-matrix term is the
-        # extracted number's honest surplus over the hand model)
-        bytes_el = 2 if wire_dtype == "bf16" else 4
-        model_flops = 2.0 * n * n * dim
-        model_hbm = 2.0 * n * dim * bytes_el
-        extra = {"bytes_accessed_per_step": costs["bytes_accessed"]}
-    else:
-        raise ValueError(f"unknown roofline backend {backend!r} "
-                         f"(dense|fused)")
+    costs = gossip_step_costs(n, dim, decomposed, wire_dtype=wire_dtype)
+    flops = costs["flops"]
+    hbm = costs["hbm_bytes"]
+    # the hand model this machine-checks (ROOFLINE.md: 2·N²·D FLOPs,
+    # 2·N·D·wire_bytes boundary traffic; the N² W-matrix term is the
+    # extracted number's honest surplus over the hand model)
+    bytes_el = 2 if wire_dtype == "bf16" else 4
+    model_flops = 2.0 * n * n * dim
+    model_hbm = 2.0 * n * dim * bytes_el
     name, spec = resolve_chip(chip)
     report = {
         "n": int(n), "dim": int(dim), "wire_dtype": wire_dtype,
-        "backend": backend,
         "flops_per_step": flops,
         "hbm_bytes_per_step": hbm,
         "peak_bytes": costs["peak_bytes"],
         "compile_seconds": costs["compile_seconds"],
         "fingerprint": costs["fingerprint"],
-        **extra,
+        "bytes_accessed_per_step": costs["bytes_accessed"],
+        "model_flops": model_flops, "model_hbm_bytes": model_hbm,
+        "flops_vs_model": flops / model_flops,
+        "hbm_vs_model": hbm / model_hbm,
     }
-    report.update(
-        model_flops=model_flops, model_hbm_bytes=model_hbm,
-        # the model-check ratio always uses the RAW extraction — for the
-        # chain backend flops_per_step is the max(extracted, model)
-        # ceiling floor, and a ratio of that against the model would read
-        # 1.0 exactly when the extraction undercounts, silently disabling
-        # the low-side check this field exists for
-        flops_vs_model=extra.get("extracted_flops_per_step", flops)
-        / model_flops,
-        hbm_vs_model=hbm / model_hbm,
-    )
     report.update(Roofline(name, spec).ceilings(flops, hbm))
     if measured_steps_per_sec is not None:
         report["measured_steps_per_sec"] = float(measured_steps_per_sec)
         report["measured_vs_ceiling"] = (
             float(measured_steps_per_sec) / report["ceiling_steps_per_sec"])
-        # name the denominator: which backend's ceiling this ratio was
-        # computed against (it must be impossible to quote it against the
-        # wrong kernel)
-        report["measured_vs_ceiling_backend"] = backend
-        # the Pallas-promotion gate ratio: the fused kernel removes the
-        # dense HBM wall (ROOFLINE.md), so its honest ceiling is the
-        # compute bound — a measured rate above the dense ceiling_steps is
-        # itself the evidence the formulation beat the memory wall
         report["measured_vs_compute_bound"] = (
             float(measured_steps_per_sec)
             / report["compute_bound_steps_per_sec"])
@@ -652,40 +520,22 @@ def _gb(x: float) -> str:
     return f"{x:.0f} B"
 
 
-#: Per-backend labels for the markdown hand-model column.
-_MODEL_LABELS = {
-    "dense": ("2·N²·D", "2·N·D·w"),
-    "fused": ("2·N²·D", "N²·w + 2·N·D·w/T"),
-}
-_BACKEND_TITLES = {
-    "dense": "dense per-step gossip",
-    "fused": "fused W-stack chain (per step)",
-}
-
-
 def render_roofline_markdown(report: Dict, source: str = "") -> str:
     prov = (" (**CPU-provisional peaks** — relative arithmetic only)"
             if report.get("provisional") else "")
-    backend = report.get("backend", "dense")
-    flops_label, hbm_label = _MODEL_LABELS.get(backend,
-                                               _MODEL_LABELS["dense"])
-    raw_flops = report.get("extracted_flops_per_step",
-                           report["flops_per_step"])
-    clamped = raw_flops < report["flops_per_step"]
     lines = [
-        f"# Automatic roofline — "
-        f"{_BACKEND_TITLES.get(backend, backend)} @ N={report['n']}, "
+        f"# Automatic roofline — dense per-step gossip @ N={report['n']}, "
         f"D={report['dim']}, {report['wire_dtype']} wire", "",
         f"Extracted from the compiled program via `cost_analysis()` / "
         f"`memory_analysis()` (program `{report['fingerprint']}`); chip "
         f"peaks pinned for **{report['chip']}**{prov}.", "",
         "| quantity | extracted | hand model | ratio |",
         "|---|---:|---:|---:|",
-        f"| FLOPs/step | {raw_flops:.4g} "
-        f"| {report['model_flops']:.4g} ({flops_label}) "
+        f"| FLOPs/step | {report['flops_per_step']:.4g} "
+        f"| {report['model_flops']:.4g} (2·N²·D) "
         f"| {report['flops_vs_model']:.4f} |",
         f"| HBM bytes/step (boundary) | {report['hbm_bytes_per_step']:.4g} "
-        f"| {report['model_hbm_bytes']:.4g} ({hbm_label}) "
+        f"| {report['model_hbm_bytes']:.4g} (2·N·D·w) "
         f"| {report['hbm_vs_model']:.4f} |",
         "",
         f"| ceiling | steps/s |",
@@ -697,22 +547,10 @@ def render_roofline_markdown(report: Dict, source: str = "") -> str:
         f"| **binding: {report['bound']}** "
         f"| **{report['ceiling_steps_per_sec']:.1f}** |",
     ]
-    if clamped:
-        lines += ["", f"FLOPs note: XLA's cost analysis does not multiply "
-                      f"the chain's grid-scan body by its trip count, so "
-                      f"the raw extraction above undercounts; the ceilings "
-                      f"use the hand-model floor "
-                      f"({report['flops_per_step']:.4g} FLOPs/step)."]
     if "measured_steps_per_sec" in report:
-        origin = report.get("measured_backend")
-        via = (f" (rate measured on the **{origin}** backend)"
-               if origin and origin != backend else "")
         lines += ["", f"Measured: **{report['measured_steps_per_sec']:.1f} "
-                      f"steps/s**{via} = "
-                      f"{report['measured_vs_ceiling']:.1%} of "
-                      f"the **{report.get('measured_vs_ceiling_backend', backend)}** "
-                      f"ceiling (the ratio's denominator — quote it against "
-                      f"no other backend's)."]
+                      f"steps/s** = {report['measured_vs_ceiling']:.1%} of "
+                      f"the ceiling."]
     if source:
         lines += ["", f"Source: `{source}`"]
     lines.append("")

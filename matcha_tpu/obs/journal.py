@@ -15,10 +15,10 @@ Format: one JSON object per line, append-only.  Every event carries
   error: the committed reference journal pins the vocabulary so the
   format cannot drift silently),
 * ``t``     — seconds since the writing process's start (standalone
-  appenders like ``bench.py --journal`` use absolute unix time).  ``t``
-  is monotone only within one process's appended segment — a resumed
-  run restarts the clock, so a resumed journal's ``t`` *drops* at the
-  resume point.  Readers must order by **line position**, never by
+  appenders like ``obs_tpu.py roofline --journal`` use absolute unix
+  time).  ``t`` is monotone only within one process's appended segment —
+  a resumed run restarts the clock, so a resumed journal's ``t`` *drops*
+  at the resume point.  Readers must order by **line position**, never by
   ``t`` (everything in this package does),
 
 plus kind-specific payload fields (``REQUIRED_FIELDS``).  A resumed run
@@ -513,8 +513,8 @@ def epoch_series(events: Iterable[dict], kind: str, field: str,
 
 
 def append_journal_record(path: str, kind: str, **fields) -> dict:
-    """One-shot appender for standalone emitters (``bench.py --journal``,
-    session stamps): no Recorder, no run clock — ``t`` is absolute unix
+    """One-shot appender for standalone emitters (``obs_tpu.py roofline
+    --journal``, session stamps): no Recorder, no run clock — ``t`` is absolute unix
     time (``bestio.wall_clock``: identical to ``time.time()`` outside the
     chaos harness's skew injection), monotone within the file like any
     run journal.  IO rides the ``obs.bestio`` fs seam.  Returns the event

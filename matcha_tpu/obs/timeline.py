@@ -151,7 +151,7 @@ def build_timeline(events: Sequence[dict],
                      for h in hosts]
 
     # --- journal events: the run-relative clock is the trace clock -------
-    # standalone appenders (bench.py --journal, attribute --journal,
+    # standalone appenders (roofline --journal, attribute --journal,
     # session stamps) write *absolute* unix t into the same file; anchor
     # anything wall-clock-sized at the run horizon instead of 50 years out
     _ABS = 1e8  # > 3 run-years: unambiguously a wall clock

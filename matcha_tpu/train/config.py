@@ -79,7 +79,6 @@ class TrainConfig:
     compress_warmup_epochs: int = 0
     # gossip backend (communicator.decen.GOSSIP_BACKENDS): dense (W_t x
     # once a step: one streamed pass at small N, an MXU matmul above),
-    # fused (dense per step + the Pallas W-stack kernel for chains),
     # gather, skip, shard_map, or auto (shard_map on a real mesh, dense on
     # one chip; the decision is journaled as a `backend` event)
     gossip_backend: str = "auto"

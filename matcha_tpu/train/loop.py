@@ -200,8 +200,8 @@ def train(config: TrainConfig, resume_dir: Optional[str] = None,
         # and the comm-split timer honest (a zero row counts zero wire
         # bytes), and the step itself now *elides* thinned steps — the
         # gossip call compiles inside a lax.cond keyed on the step cursor
-        # (make_train_step's local_steps), so dense/fused stop
-        # executing the identity mix instead of multiplying by it.
+        # (make_train_step's local_steps), so dense stops executing the
+        # identity mix instead of multiplying by it.
         # The schedule fingerprint stays the as-built stream: thinning is
         # config-derived, so a resume re-derives it identically.
         keep = (np.arange(len(run_flags)) % config.local_steps

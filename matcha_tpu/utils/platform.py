@@ -1,8 +1,8 @@
 """JAX platform pinning and the one compile-cache seam.
 
-Every entry point that compiles (``train_tpu.py``, ``bench.py``,
-``chip_smoke.py``, ``python -m matcha_tpu.serve.trainer``, the benchmark
-harnesses) passes through :func:`pin_platform` before its first backend
+Every entry point that compiles (``train_tpu.py``, ``chip_smoke.py``,
+``python -m matcha_tpu.serve.trainer``, the benchmark harnesses) passes
+through :func:`pin_platform` before its first backend
 use, so they all share one persistent XLA compile cache:
 
 * ``JAX_COMPILATION_CACHE_DIR`` set — JAX reads it itself; nothing here

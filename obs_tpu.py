@@ -33,17 +33,15 @@ Commands
 
 Performance observability (DESIGN.md §15):
 
-``roofline [--backend dense|fused] [--workers N] [--dim D |
---model M] [--chip C] [--measured R | --source SRC] [--md PATH]``
-    The automatic roofline: compile the selected gossip program at the
-    requested shape — the dense per-step matmul or the fused W-stack
-    chain — extract FLOPs/HBM-bytes from the compiled
-    cost analysis, and emit compute-bound / HBM-bound steps/s ceilings
-    against the pinned chip peaks (CPU gets explicit provisional
-    placeholders) — machine-checking benchmarks/ROOFLINE.md.
-    ``--measured`` (or a bench record via ``--source``) adds the
-    measured-vs-ceiling ratio; the report names which backend's ceiling
-    the ratio divides by.  Exit 1 when a ceiling is non-finite.
+``roofline [--workers N] [--dim D | --model M] [--chip C]
+[--measured R | --source SRC] [--md PATH]``
+    The automatic roofline: compile the dense per-step gossip program at
+    the requested shape, extract FLOPs/HBM-bytes from the compiled cost
+    analysis, and emit compute-bound / HBM-bound steps/s ceilings against
+    the pinned chip peaks (CPU gets explicit provisional placeholders) —
+    machine-checking benchmarks/ROOFLINE.md.  ``--measured`` (or a bench
+    record via ``--source``) adds the measured-vs-ceiling ratio.  Exit 1
+    when a ceiling is non-finite.
 
 ``capacity [--dim D | --model M] [--workers N,N] [--chip C] [--md PATH]``
     Re-derive the DESIGN.md §9 HBM capacity table from the compiled
@@ -200,15 +198,13 @@ def _resolve_dim(args) -> int:
 
 
 def _resolve_measured(args):
-    """``(steps_per_sec, backend)`` — explicit ``--measured`` (backend =
-    the ``--measured-backend`` flag), or the first rate row a ``--source``
-    (bench journal / BENCH_r*.json / run dir) yields, with the record's
-    own ``backend`` field carried along so the ratio is attributed to the
-    kernel that was actually measured, never assumed."""
+    """Steps/s for the vs-ceiling ratio — explicit ``--measured``, or the
+    first rate row a ``--source`` (bench journal / BENCH_r*.json / run
+    dir) yields; ``None`` where neither gives one."""
     if args.measured is not None:
-        return float(args.measured), getattr(args, "measured_backend", None)
+        return float(args.measured)
     if not args.source:
-        return None, None
+        return None
     from matcha_tpu.obs.report import compare_sources
 
     rows, problems = compare_sources([args.source])
@@ -216,7 +212,7 @@ def _resolve_measured(args):
         print(f"# {p}", file=sys.stderr)
     for row in rows:
         if row.get("value") and row.get("unit") == "gossip_steps_per_sec":
-            return float(row["value"]), row.get("backend")
+            return float(row["value"])
     # name what WAS there and what would have worked — "no record" alone
     # sends the operator diffing JSON shapes by hand
     found = sorted({str(r.get("unit")) for r in rows}) or ["nothing"]
@@ -226,18 +222,6 @@ def _resolve_measured(args):
           f"unit=gossip_steps_per_sec, a BENCH_r*.json driver capture "
           f"(record/parsed/tail wrappers ok), or a raw bench record",
           file=sys.stderr)
-    return None, None
-
-
-def _normalize_measured_backend(label):
-    """Map a bench record's ``backend`` field onto the roofline backend
-    vocabulary; unknown labels return None (unattributable)."""
-    if label is None:
-        return None
-    label = str(label)
-    for key in ("fused", "dense"):
-        if key in label:
-            return key
     return None
 
 
@@ -255,32 +239,10 @@ def cmd_roofline(args) -> int:
         n = args.workers
         decomposed = decompose(make_graph(args.topology, n, seed=1), n, seed=1)
     dim = _resolve_dim(args)
-    measured, measured_from = _resolve_measured(args)
-    # attribute the measured rate to the kernel that produced it: the
-    # explicit --measured-backend flag wins, else the source record's own
-    # `backend` field — a rate must never be quoted against another
-    # backend's ceiling (the denominator mis-citation
-    # measured_vs_ceiling_backend exists to prevent)
-    m_backend = args.measured_backend or _normalize_measured_backend(
-        measured_from)
-
     report = roofline_report(n, dim, decomposed,
                              wire_dtype=args.wire_dtype,
                              chip=args.chip,
-                             measured_steps_per_sec=measured,
-                             backend=args.backend)
-    if measured is not None and m_backend is not None:
-        # origin of the rate, recorded next to the denominator: a
-        # fused rate against the dense report is the intended
-        # formulation pairing (same 2·N²·D compute bound), but
-        # the record must say so rather than imply a same-backend
-        # measurement
-        report["measured_backend"] = m_backend
-        if m_backend != args.backend:
-            print(f"# note: measured rate comes from the "
-                  f"{m_backend!r} backend; this report's ceilings "
-                  f"price {args.backend!r} (the record carries both "
-                  f"labels)", file=sys.stderr)
+                             measured_steps_per_sec=_resolve_measured(args))
     md = render_roofline_markdown(report, source=args.source or "")
     ok = all(math.isfinite(report[k]) and report[k] > 0 for k in
              ("flops_per_step", "hbm_bytes_per_step",
@@ -509,22 +471,8 @@ def main(argv=None) -> int:
                    help="zoo topology id instead of the generator")
     s.add_argument("--wire-dtype", default="bf16", choices=["f32", "bf16"],
                    dest="wire_dtype")
-    s.add_argument("--backend", default="dense",
-                   choices=["dense", "fused"],
-                   help="whose program to price: the dense per-step matmul "
-                        "(historical default) or the fused W-stack chain "
-                        "(exit 1 when a ceiling is non-finite)")
     s.add_argument("--measured", type=float, default=None,
                    help="measured steps/s for the vs-ceiling ratio")
-    s.add_argument("--measured-backend", default=None,
-                   choices=["dense", "fused"],
-                   dest="measured_backend",
-                   help="which backend produced the measured rate "
-                        "(default: the --source record's own `backend` "
-                        "field).  The report always emits the ratio but "
-                        "records BOTH labels (measured_backend + "
-                        "measured_vs_ceiling_backend) and notes "
-                        "cross-backend pairings")
     s.add_argument("--source", default=None,
                    help="bench journal / BENCH_r*.json / run dir to read "
                         "the measured rate from instead of --measured")

@@ -36,8 +36,7 @@ from matcha_tpu.analysis.engine import load_source
 pytestmark = pytest.mark.analysis
 
 REPO = pathlib.Path(__file__).resolve().parents[1]
-LINT_TARGETS = ["matcha_tpu", "train_tpu.py", "plan_tpu.py", "bench.py",
-                "serve_tpu.py"]
+LINT_TARGETS = ["matcha_tpu", "train_tpu.py", "plan_tpu.py", "serve_tpu.py"]
 
 
 def _lint(tmp_path, code, rules=None, filename="snippet.py"):
@@ -238,7 +237,7 @@ def test_gl004_silent_on_seam_threaded_dtype_and_out_of_scope(tmp_path):
     """, filename=_EXCHANGE_FILE)
     assert vs == []
     # the identical hard cast OUTSIDE the exchange layer is not GL004's
-    # business (bench.py deliberately runs bf16 state end-to-end)
+    # business
     vs = _lint(tmp_path, """
         import jax.numpy as jnp
 

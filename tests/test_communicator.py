@@ -1,11 +1,14 @@
 """Communicator golden tests against independent numpy simulations of the
 reference per-rank semantics (communicator.py:79-268)."""
 
+import json
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import chip_smoke
 from matcha_tpu import topology as tp
 from matcha_tpu.communicator import (
     make_centralized,
@@ -64,6 +67,66 @@ def test_decen_skip_iterations_are_identity():
     x0 = jnp.asarray(random_state(8, 7))
     got, _ = comm.run(x0, sched.flags)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(x0))
+
+
+@pytest.mark.parametrize("compute", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("form", ["streamed", "mxu"])
+def test_dense_chain_matches_gather_oracle(form, compute):
+    """The chain the comm-split timer runs (``Communicator.run`` over
+    ``dense``, a scan of its step) against the per-matching ``gather``
+    chain, in both forms of the dense exchange.  A ``compute_dtype`` below
+    float32 is the bf16 wire by another name: the same program, and within
+    the wire's budget of a step (2^-8 of the largest value) of the oracle
+    that rounds what it exchanges."""
+    from gossip_cases import MXU_SCHED  # beside this file
+
+    from matcha_tpu.parallel import dense_exchange_form
+
+    sched = MXU_SCHED if form == "mxu" else matcha_schedule(
+        tp.select_graph(0), 8, iterations=12, budget=0.6, seed=0)
+    n = sched.num_workers
+    assert dense_exchange_form(n)["form"] == form
+    wire = None if compute == jnp.float32 else "bf16"
+    x = jnp.asarray(random_state(n, 40, seed=n))
+    flags = jnp.asarray(sched.flags, jnp.float32)
+    dense = make_decen(sched, backend="dense", compute_dtype=compute)
+    assert dense.multi_step is None  # the chain is the scan of the step
+    got, _ = jax.jit(dense.run)(x, flags)
+    want, _ = jax.jit(make_decen(sched, backend="gather",
+                                 wire_dtype=wire).run)(x, flags)
+    assert got.dtype == x.dtype
+    assert float(jnp.max(jnp.abs(want - x))) > 0.1  # the stream mixed
+    if wire is None:
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-6)
+        return
+    budget = len(flags) * 2.0 ** -8 * float(jnp.max(jnp.abs(want)))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0,
+                               atol=budget)
+    by_wire, _ = jax.jit(make_decen(sched, backend="dense",
+                                    wire_dtype=wire).run)(x, flags)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(by_wire))
+
+
+@pytest.mark.parametrize("n,wire", chip_smoke.KERNEL_CASES)
+def test_chip_smoke_kernel_phase_rehearsed_on_the_cpu(n, wire, monkeypatch,
+                                                      capsys):
+    """``chip_smoke.py``'s kernel phase itself, a case at a time at a tiny
+    width: the exchange ``train()`` runs against the ``gather`` oracle, one
+    worker count on each side of the crossover, the oracle over three
+    slabs of columns with a ragged last one."""
+    from matcha_tpu.parallel import STREAM_MAX_WORKERS
+
+    sizes = {size for size, _ in chip_smoke.KERNEL_CASES}
+    assert min(sizes) <= STREAM_MAX_WORKERS < max(sizes)
+    monkeypatch.setattr(chip_smoke, "ORACLE_COLS", 48)
+    [row] = chip_smoke.kernel_phase(100, [(n, wire)])
+    assert (row["kernel"], row["oracle"]) == (
+        "streamed" if n <= STREAM_MAX_WORKERS else "mxu", "gather")
+    assert (row["n"], row["dim"], row["wire"]) == (n, 100, wire)
+    assert row["rel_err"] <= row["tol"]
+    assert f"# kernel {json.dumps(row)}" in capsys.readouterr().out
 
 
 def test_decen_shard_map_backend_parity():
@@ -246,7 +309,7 @@ def test_choco_stochastic_shard_map_contracts():
 
     # multi_step (one shard_map scan) ≡ per-step driving: the key schedule is
     # bit-identical (same split-per-step recurrence), the state agrees up to
-    # f32 reassociation between the fused and per-step compiled programs.
+    # f32 reassociation between the one-scan and per-step compiled programs.
     # The per-step driver is jitted ONCE and reused — driving comm.step
     # eagerly re-traced the shard_map program on every call and was the
     # single most expensive line in tier-1 (~140 s for 8 steps vs ~2 s
@@ -291,7 +354,7 @@ def test_registry():
         mesh = worker_mesh(8)
         assert "shard_map" in select_communicator("choco", sched, mesh=mesh).name
         assert "shard_map" not in select_communicator(
-            "choco", sched, mesh=mesh, backend="fused").name
+            "choco", sched, mesh=mesh, backend="dense").name
     assert select_communicator("centralized").name == "centralized"
     assert select_communicator("none").name == "none"
     with pytest.raises(KeyError):
@@ -340,34 +403,21 @@ def test_gather_backend_warns_at_large_n():
         make_decen(sched, backend="dense")
 
 
-def test_fused_knobs_warn_on_other_backends():
-    """block_d/w_window only shape the fused Pallas kernel; silently
-    accepting them on dense/gather (or non-decen communicators) misattributes
-    tuning results — both seams must warn."""
-    import warnings
-
-    from matcha_tpu import topology as tp
-    from matcha_tpu.schedule import fixed_schedule
-
-    sched = fixed_schedule(tp.select_graph(5), 8, iterations=2)
-    with pytest.warns(UserWarning, match="fused"):
-        make_decen(sched, backend="dense", w_window=4)
-    with pytest.warns(UserWarning, match="no effect"):
-        select_communicator("choco", sched, block_d=4096)
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        make_decen(sched, backend="fused", w_window=4, block_d=512)
-
-
-def _refused_config():
+def _refused_config(backend):
     from matcha_tpu.train import TrainConfig
 
-    TrainConfig(gossip_backend="perm")
+    return lambda: TrainConfig(gossip_backend=backend)
 
 
-def _refused_make_decen():
-    make_decen(fixed_schedule(tp.select_graph(5), 8, iterations=2),
-               backend="perm")
+def _refused_make_decen(**kwargs):
+    return lambda: make_decen(
+        fixed_schedule(tp.select_graph(5), 8, iterations=2), **kwargs)
+
+
+def _refused_select_communicator(**kwargs):
+    return lambda: select_communicator(
+        "decen", fixed_schedule(tp.select_graph(5), 8, iterations=2),
+        **kwargs)
 
 
 def _refused_cli(*argv):
@@ -376,25 +426,57 @@ def _refused_cli(*argv):
     return lambda: train_tpu.parse_args(list(argv))
 
 
+def _refused_obs_cli(*argv):
+    import obs_tpu
+
+    return lambda: obs_tpu.main(list(argv))
+
+
+def _refused_elision_costs():
+    from matcha_tpu.obs.costs import elision_epoch_costs
+
+    elision_epoch_costs(8, 64, tp.select_graph(5), backend="fused")
+
+
+#: the five names an unknown backend is told there are
+_FIVE = r"\['auto', 'dense', 'gather', 'skip', 'shard_map'\]"
+
+
 @pytest.mark.parametrize("call,error,names", [
-    (_refused_config, ValueError, "perm.*dense.*fused.*gather"),
-    (_refused_make_decen, KeyError, "perm.*dense.*fused.*gather"),
-    (_refused_cli("--backend", "perm"), ValueError,
-     "perm.*dense.*fused.*gather"),
+    (_refused_config("perm"), ValueError, "perm.*" + _FIVE),
+    (_refused_make_decen(backend="perm"), KeyError, "perm.*" + _FIVE),
+    (_refused_cli("--backend", "perm"), ValueError, "perm.*" + _FIVE),
     # argparse refuses an option it does not know with exit status 2
     (_refused_cli("--gossip-measured-ratio", "0.9"), SystemExit, "2"),
     (_refused_cli("--gossip-measured-vs-ceiling", "0.9"), SystemExit, "2"),
     (_refused_cli("--gossip-measured-source", "x.json"), SystemExit, "2"),
     (_refused_cli("--block-d", "4096"), SystemExit, "2"),
     (_refused_cli("--w-window", "4"), SystemExit, "2"),
+    (_refused_config("fused"), ValueError, "fused.*" + _FIVE),
+    (_refused_make_decen(backend="fused"), KeyError, "fused.*" + _FIVE),
+    (_refused_cli("--backend", "fused"), ValueError, "fused.*" + _FIVE),
+    (_refused_make_decen(chunk=2), TypeError, "chunk"),
+    (_refused_make_decen(block_d=4096), TypeError, "block_d"),
+    (_refused_make_decen(w_window=4), TypeError, "w_window"),
+    (_refused_select_communicator(block_d=4096), TypeError, "block_d"),
+    (_refused_obs_cli("roofline", "--backend", "fused"), SystemExit, "2"),
+    (_refused_obs_cli("roofline", "--measured-backend", "dense"),
+     SystemExit, "2"),
+    (_refused_elision_costs, ValueError, r"fused.*\(dense\|skip\)"),
 ], ids=["TrainConfig", "make_decen", "cli-backend", "cli-measured-ratio",
         "cli-measured-vs-ceiling", "cli-measured-source", "cli-block-d",
-        "cli-w-window"])
-def test_what_pr29_removed_is_refused_by_name(call, error, names):
+        "cli-w-window", "TrainConfig-fused", "make_decen-fused",
+        "cli-backend-fused", "make_decen-chunk", "make_decen-block_d",
+        "make_decen-w_window", "select_communicator-block_d",
+        "obs-roofline-backend", "obs-roofline-measured-backend",
+        "elision-costs-fused"])
+def test_what_pr29_and_pr45_removed_is_refused_by_name(call, error, names):
     """The permutation-form backend, the measurement its gate asked the
-    user for and the two kernel-tuning flags are gone (PR 29): each is
-    refused where an unknown name is refused, and an unknown backend is
-    told which there are."""
+    user for and the two kernel-tuning flags are gone (PR 29), and so are
+    the ``fused`` backend, its three arguments, its cost model and the
+    roofline's two options that chose a backend (PR 45): each is refused
+    where an unknown name is refused, and an unknown backend is told which
+    five there are."""
     with pytest.raises(error, match=names):
         call()
 

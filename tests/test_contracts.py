@@ -34,8 +34,8 @@ from matcha_tpu.analysis.engine import load_source
 pytestmark = pytest.mark.contracts
 
 REPO = pathlib.Path(__file__).resolve().parents[1]
-LINT_TARGETS = ["matcha_tpu", "train_tpu.py", "plan_tpu.py", "bench.py",
-                "obs_tpu.py", "serve_tpu.py"]
+LINT_TARGETS = ["matcha_tpu", "train_tpu.py", "plan_tpu.py", "obs_tpu.py",
+                "serve_tpu.py"]
 
 
 def _src(tmp_path, code, filename="snippet.py"):

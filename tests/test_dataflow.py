@@ -493,7 +493,7 @@ def test_gl103_silent_on_the_shipped_exchange_shape_and_out_of_scope(tmp_path):
     """, filename=_WIRE_FILE)
     assert vs == []
     # identical double-cast outside parallel/+communicator/ is not GL103's
-    # business (bench.py runs bf16 state end-to-end deliberately)
+    # business
     vs = _lint(tmp_path, """
         def elsewhere(x, wire_dtype):
             wire = resolve_wire_dtype(wire_dtype)

@@ -40,8 +40,8 @@ from matcha_tpu.utils.atomicio import atomic_publish
 pytestmark = pytest.mark.durability
 
 REPO = pathlib.Path(__file__).resolve().parents[1]
-LINT_TARGETS = ["matcha_tpu", "train_tpu.py", "plan_tpu.py", "bench.py",
-                "obs_tpu.py", "serve_tpu.py"]
+LINT_TARGETS = ["matcha_tpu", "train_tpu.py", "plan_tpu.py", "obs_tpu.py",
+                "serve_tpu.py"]
 
 
 @pytest.fixture(autouse=True)

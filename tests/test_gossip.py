@@ -209,7 +209,7 @@ def test_gossip_contracts_disagreement():
 
 
 def test_dense_backend_feature_sharded_parity():
-    """The README/DESIGN scaling claim for the dense/fused path: with the
+    """The README/DESIGN scaling claim for the dense path: with the
     worker state sharded along the *feature* axis, the N×N mixing matmul is
     chip-local (each chip mixes its own D-slice; zero collectives needed for
     gossip itself).  Run the dense backend under jit with x sharded over 8

@@ -1078,23 +1078,25 @@ def test_cli_compare_reads_multichip_records(tmp_path, capsys):
     assert "rc=7" in out and "skipped" in out
 
 
-def test_bench_journal_sink_appends_valid_event(tmp_path):
-    """bench.py --journal mirrors the final record as a `bench` event the
-    compare renderer reads (no subprocess: the sink function is the
-    contract)."""
-    import argparse
-
-    import bench
+def test_bench_journal_sink_appends_valid_event(tmp_path, capsys):
+    """``obs_tpu.py roofline --journal`` mirrors its report as a `bench`
+    event the journal's schema validates (``append_journal_record``, the
+    one-shot appender of standalone emitters); without the option it
+    writes nothing."""
+    import obs_tpu
 
     path = tmp_path / "j.jsonl"
-    args = argparse.Namespace(journal=str(path))
-    bench._journal_record(args, {"value": 5000.1, "unit": "x"})
-    # no-op, must not create anything
-    bench._journal_record(argparse.Namespace(journal=None), {"value": 1})
+    argv = ["roofline", "--workers", "4", "--topology", "ring",
+            "--dim", "512", "--measured", "5000.1"]
+    assert obs_tpu.main(argv) == 0
+    assert not path.exists()
+    assert obs_tpu.main(argv + ["--journal", str(path)]) == 0
+    capsys.readouterr()
     [event] = read_journal(str(path))
     assert validate_event(event) == []
-    assert event["record"]["value"] == 5000.1
-    assert event["status"] == "measured"
+    assert event["kind"] == "bench"
+    assert event["record"]["unit"] == "roofline_report"
+    assert event["record"]["roofline"]["measured_steps_per_sec"] == 5000.1
 
 
 # ------------------------------------------------------------- checkpointing

@@ -24,8 +24,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from gossip_cases import alive_of, make_comm, sched_of  # beside this file
 from matcha_tpu import topology as tp
-from matcha_tpu.communicator import make_centralized, make_choco, make_decen
+from matcha_tpu.communicator import make_choco, make_decen
 from matcha_tpu.parallel import shard_workers, worker_mesh
 from matcha_tpu.schedule import matcha_schedule
 from matcha_tpu.schedule.solvers import (
@@ -36,38 +37,36 @@ from matcha_tpu.schedule.solvers import (
 SIZE = tp.graph_size(0)
 SCHED = matcha_schedule(tp.select_graph(0), SIZE, iterations=10, budget=0.5,
                         seed=3)
-# one dead worker: drain equivalence and mean preservation must hold under
-# an arbitrary survivor mask (the masked W stays doubly stochastic over
-# survivors, so the delayed-apply argument is unchanged)
-ALIVE = np.array([1, 1, 0, 1, 1, 1, 1, 1], np.float32)[:SIZE]
+BACKENDS = ["gather", "dense", "skip", "dense-mxu", "choco", "centralized"]
 
-BACKENDS = ["gather", "dense", "skip", "fused", "choco", "centralized"]
+
+def _sched(backend):
+    return sched_of(backend, SCHED)
 
 
 def _make(backend, wire=None):
-    if backend == "choco":
-        return make_choco(SCHED, ratio=0.5, consensus_lr=0.3, wire_dtype=wire)
-    if backend == "centralized":
-        return make_centralized(wire_dtype=wire)
-    return make_decen(SCHED, backend=backend, wire_dtype=wire)
+    return make_comm(backend, SCHED, wire)
 
 
-def _x0(d=21, seed=0):
+def _x0(d=21, seed=0, n=SIZE):
     return jnp.asarray(
-        np.random.default_rng(seed).normal(size=(SIZE, d)).astype(np.float32))
+        np.random.default_rng(seed).normal(size=(n, d)).astype(np.float32))
 
 
 @pytest.mark.parametrize("masked", [False, True], ids=["full", "alive-mask"])
 @pytest.mark.parametrize("backend", BACKENDS)
 def test_delayed_mix_drains_to_eager(backend, masked):
     """Pipelined chain + one drain step == eager chain, every backend,
-    with and without a dead worker."""
+    with and without a dead worker: drain equivalence must hold under an
+    arbitrary survivor mask (the masked W stays doubly stochastic over
+    survivors, so the delayed-apply argument is unchanged)."""
     comm = _make(backend)
-    alive = ALIVE if masked else None
-    x0 = _x0()
-    eager, ce = jax.jit(lambda x: comm.run(x, SCHED.flags, alive=alive))(x0)
+    sched = _sched(backend)
+    alive = alive_of(sched) if masked else None
+    x0 = _x0(n=sched.num_workers)
+    eager, ce = jax.jit(lambda x: comm.run(x, sched.flags, alive=alive))(x0)
     over, co = jax.jit(
-        lambda x: comm.run_overlapped(x, SCHED.flags, alive=alive))(x0)
+        lambda x: comm.run_overlapped(x, sched.flags, alive=alive))(x0)
     np.testing.assert_allclose(np.asarray(eager), np.asarray(over),
                                rtol=1e-5, atol=1e-6)
     # carries thread identically (issue-time advance): CHOCO's {x̂, s}
@@ -124,8 +123,9 @@ def test_bf16_wire_one_step_parity(backend):
     acceptance bound of ISSUE 4."""
     f32c = _make(backend)
     b16c = _make(backend, wire="bf16")
-    x0 = _x0(d=33, seed=2)
-    flags0 = jnp.asarray(SCHED.flags[0], jnp.float32)
+    sched = _sched(backend)
+    x0 = _x0(d=33, seed=2, n=sched.num_workers)
+    flags0 = jnp.asarray(sched.flags[0], jnp.float32)
     a, _ = f32c.step(x0, f32c.init(x0), flags0)
     b, _ = b16c.step(x0, b16c.init(x0), flags0)
     scale = float(jnp.max(jnp.abs(a)))
@@ -379,9 +379,10 @@ def test_run_elided_matches_compacted_chain(backend, masked):
     """run_elided(flags, L) == run(flags[::L]) on every backend: an elided
     step executes nothing — no arithmetic, no wire, no carry advance."""
     comm = _make(backend)
-    alive = ALIVE if masked else None
-    x0 = _x0(d=19, seed=7)
-    flags = jnp.asarray(SCHED.flags, jnp.float32)
+    sched = _sched(backend)
+    alive = alive_of(sched) if masked else None
+    x0 = _x0(d=19, seed=7, n=sched.num_workers)
+    flags = jnp.asarray(sched.flags, jnp.float32)
     el, ce = jax.jit(lambda x: comm.run_elided(
         x, flags, ELISION_L, alive=alive))(x0)
     ref, cr = jax.jit(lambda x: comm.run(
@@ -396,16 +397,22 @@ def test_run_elided_matches_compacted_chain(backend, masked):
 
 @pytest.mark.parametrize("wire", [None, "bf16"], ids=["f32", "bf16"])
 @pytest.mark.parametrize("masked", [False, True], ids=["full", "alive-mask"])
-@pytest.mark.parametrize("backend", ["gather", "dense", "skip", "fused"])
+@pytest.mark.parametrize("backend", ["gather", "dense", "skip", "dense-mxu"])
 def test_run_elided_matches_thinned_stream(backend, masked, wire):
     """run_elided(full flags, L) == run(thinned flags): eliding a step is
     exactly what multiplying by the identity a zero row builds used to be —
     the drain-equivalence contract of the restructured epoch, on every
     flag-thinning backend × alive mask × wire dtype."""
     comm = _make(backend, wire)
-    alive = ALIVE if masked else None
-    x0 = _x0(d=23, seed=8)
-    flags = np.asarray(SCHED.flags, np.float32).copy()
+    sched = _sched(backend)
+    alive = alive_of(sched) if masked else None
+    x0 = _x0(d=23, seed=8, n=sched.num_workers)
+    flags = np.asarray(sched.flags, np.float32).copy()
+    # end on an executed step: under the bf16 wire the dense exchange rounds
+    # the state it reads, so a zero row after the last executed step leaves
+    # the state rounded where elision leaves it as it was (an earlier zero
+    # row is absorbed by the next step's own rounding)
+    flags = flags[:len(flags) - (len(flags) - 1) % ELISION_L]
     thinned = flags.copy()
     thinned[np.arange(len(thinned)) % ELISION_L != 0] = 0.0
     el, _ = jax.jit(lambda x: comm.run_elided(
@@ -441,29 +448,26 @@ def test_run_elided_offset_and_traced_every():
 
 
 def test_elision_ledger_2x_reduction():
-    """Acceptance pin (ISSUE 19): for dense and the fused chain at L=4,
-    the compiled-cost ledger's per-epoch gossip-attributed boundary bytes
-    drop ≥2× vs L=1 — the thinned steps' programs are *gone*, not
-    multiplied by I.  The ratio is exactly T/ceil(T/L) (every executed
-    step pays the same per-step program, or the same W-stack row)."""
+    """Acceptance pin (ISSUE 19): for dense at L=4, the compiled-cost
+    ledger's per-epoch gossip-attributed boundary bytes drop ≥2× vs L=1 —
+    the thinned steps' programs are *gone*, not multiplied by I.  The
+    ratio is exactly T/ceil(T/L) (every executed step pays the same
+    per-step program)."""
     from matcha_tpu.obs.costs import elision_epoch_costs
 
     t_steps = 40
-    for backend in ("dense", "fused"):
-        c1 = elision_epoch_costs(SIZE, 1024, SCHED.decomposed,
-                                 backend=backend, t_steps=t_steps,
-                                 local_every=1)
-        c4 = elision_epoch_costs(SIZE, 1024, SCHED.decomposed,
-                                 backend=backend, t_steps=t_steps,
-                                 local_every=4)
-        assert c1["exec_steps"] == t_steps
-        assert c4["exec_steps"] == -(-t_steps // 4)
-        ratio = c1["gossip_hbm_bytes_per_epoch"] \
-            / c4["gossip_hbm_bytes_per_epoch"]
-        assert ratio >= 2.0, (backend, ratio)
-        # L=1 prices the exact unthinned chain: per-epoch == per-step × T
-        assert c1["gossip_hbm_bytes_per_epoch"] == pytest.approx(
-            c1["gossip_hbm_bytes_per_step"] * t_steps)
+    c1 = elision_epoch_costs(SIZE, 1024, SCHED.decomposed, backend="dense",
+                             t_steps=t_steps, local_every=1)
+    c4 = elision_epoch_costs(SIZE, 1024, SCHED.decomposed, backend="dense",
+                             t_steps=t_steps, local_every=4)
+    assert c1["exec_steps"] == t_steps
+    assert c4["exec_steps"] == -(-t_steps // 4)
+    ratio = c1["gossip_hbm_bytes_per_epoch"] \
+        / c4["gossip_hbm_bytes_per_epoch"]
+    assert ratio >= 2.0, ratio
+    # L=1 prices the exact unthinned chain: per-epoch == per-step × T
+    assert c1["gossip_hbm_bytes_per_epoch"] == pytest.approx(
+        c1["gossip_hbm_bytes_per_step"] * t_steps)
 
 
 @pytest.mark.parametrize("backend", ["dense", "skip"])

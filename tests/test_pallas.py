@@ -1,8 +1,9 @@
-"""Pallas fused gossip kernel vs the per-step dense backend.
+"""The Pallas gossip kernels (the streamed exchange, flat and on the
+leaves) and the grouped products as the TPU's compiler sees them.
 
-On CPU the kernel runs under the Pallas interpreter (same program, no
-Mosaic); arithmetic must match a lax.scan over ``gossip_mix_dense``
-step-for-step in f32.
+On CPU the kernels run under the Pallas interpreter (same program, no
+Mosaic); the cases below lower them for TPU and, in a child process,
+compile them for a described v5e.
 """
 
 import functools
@@ -16,9 +17,6 @@ from matcha_tpu import topology as tp
 from matcha_tpu.communicator import make_decen
 from matcha_tpu.parallel import (
     STREAM_MAX_WORKERS,
-    GossipKernelResourceError,
-    build_mixing_stack,
-    fused_gossip_run,
     leaf_mix,
     stream_mix,
     tree_mix,
@@ -34,108 +32,14 @@ def _schedule(n=8, iterations=12, budget=0.6):
     return matcha_schedule(dec, n, iterations=iterations, budget=budget, seed=0)
 
 
-def test_fused_matches_dense_scan():
-    sched = _schedule()
-    n = sched.perms.shape[1]
-    rng = np.random.default_rng(0)
-    x = jnp.asarray(rng.normal(size=(n, 40)), jnp.float32)
-    flags = jnp.asarray(sched.flags, jnp.float32)
-
-    dense = make_decen(sched, backend="dense")
-    fused = make_decen(sched, backend="fused")
-    assert fused.multi_step is not None
-
-    xd, _ = dense.run(x, flags)
-    xf, _ = fused.run(x, flags)
-    np.testing.assert_allclose(np.asarray(xd), np.asarray(xf), rtol=1e-5, atol=1e-6)
-
-
-def test_fused_matches_dense_scan_mixed_dtype():
-    # f32 state with bf16 wire dtype: fused must round the state into bf16 at
-    # each step's input exactly like gossip_mix_dense
-    sched = _schedule()
-    n = sched.perms.shape[1]
-    x = jnp.asarray(np.random.default_rng(2).normal(size=(n, 33)), jnp.float32)
-    flags = jnp.asarray(sched.flags, jnp.float32)
-    dense = make_decen(sched, backend="dense", compute_dtype=jnp.bfloat16)
-    fused = make_decen(sched, backend="fused", compute_dtype=jnp.bfloat16)
-    xd, _ = dense.run(x, flags)
-    xf, _ = fused.run(x, flags)
-    assert xf.dtype == x.dtype
-    np.testing.assert_allclose(np.asarray(xd), np.asarray(xf), rtol=0, atol=0)
-
-
-def test_mixing_stack_rows_sum_to_one():
-    sched = _schedule()
-    stack = np.asarray(
-        build_mixing_stack(sched.laplacians(), sched.alpha, sched.flags, jnp.float32)
-    )
-    # every W_t is symmetric doubly-stochastic-by-construction: rows sum to 1
-    np.testing.assert_allclose(stack.sum(axis=-1), 1.0, atol=1e-5)
-    np.testing.assert_allclose(stack, np.swapaxes(stack, -1, -2), atol=1e-6)
-
-
-def test_fused_block_boundary():
-    # D not divisible by block_d exercises the padded edge block
-    sched = _schedule(iterations=5)
-    n = sched.perms.shape[1]
-    x = jnp.asarray(np.random.default_rng(1).normal(size=(n, 37)), jnp.float32)
-    stack = build_mixing_stack(sched.laplacians(), sched.alpha, sched.flags, jnp.float32)
-    out = fused_gossip_run(x, stack, block_d=16, interpret=True)
-    ref = x
-    for t in range(stack.shape[0]):
-        ref = jnp.dot(stack[t], ref)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5, atol=1e-6)
-
-
 def test_empty_flag_stream_is_identity():
     sched = _schedule(iterations=3)
     n = sched.perms.shape[1]
     x = jnp.asarray(np.random.default_rng(3).normal(size=(n, 10)), jnp.float32)
     empty = np.zeros((0, sched.flags.shape[1]), np.float32)
-    for backend in ("dense", "fused", "gather"):
+    for backend in ("dense", "gather"):
         out, _ = make_decen(sched, backend=backend).run(x, empty)
         np.testing.assert_array_equal(np.asarray(out), np.asarray(x))
-
-
-def test_compose_mixing_stack_chunked_parity():
-    """Chunked composition (compose_mixing_stack) must reproduce the per-step
-    chain exactly up to float reordering — including a chunk that does not
-    divide T (identity padding) and chunk >= T (single product)."""
-    from matcha_tpu.parallel import compose_mixing_stack
-
-    sched = _schedule(iterations=24)
-    n = sched.perms.shape[1]
-    x0 = jnp.asarray(np.random.default_rng(7).normal(size=(n, 33)), jnp.float32)
-    a, _ = make_decen(sched, backend="dense").run(x0, sched.flags)
-    stack = build_mixing_stack(sched.laplacians(), sched.alpha, sched.flags, jnp.float32)
-    for chunk in (1, 4, 7, 24, 50):
-        composed = compose_mixing_stack(stack, chunk)
-        if chunk > 1:  # granularity rounds up to a power of two
-            chunk2 = 1 << int(np.ceil(np.log2(chunk)))
-            assert composed.shape[0] == -(-24 // chunk2)
-        else:
-            assert composed.shape[0] == 24
-        b, _ = make_decen(sched, backend="fused", chunk=chunk).run(x0, sched.flags)
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6)
-
-
-def test_w_window_bitwise_matches_window1():
-    """The W-window kernel executes the same per-step arithmetic (cast, dot,
-    cast, in stream order) — results must be BITWISE identical to w_window=1
-    for any window, including windows that do not divide T (front identity
-    padding) and windows >= T, in both pure-f32 and mixed bf16-wire modes."""
-    sched = _schedule(iterations=13)  # prime: nothing divides it
-    n = sched.perms.shape[1]
-    x = jnp.asarray(np.random.default_rng(11).normal(size=(n, 37)), jnp.float32)
-    flags = jnp.asarray(sched.flags, jnp.float32)
-    for dtype in (jnp.float32, jnp.bfloat16):
-        base, _ = make_decen(sched, backend="fused",
-                             compute_dtype=dtype).run(x, flags)
-        for w in (2, 4, 5, 13, 64):
-            out, _ = make_decen(sched, backend="fused", compute_dtype=dtype,
-                                w_window=w).run(x, flags)
-            np.testing.assert_array_equal(np.asarray(base), np.asarray(out))
 
 
 # ------------------------------------------------- the TPU compiler's view
@@ -144,8 +48,6 @@ def test_w_window_bitwise_matches_window1():
 # lower — and, where libtpu offers a compile-only v5e topology, compile —
 # the real kernels at the train shapes, from the CPU host.
 
-RESNET20_DIM = 273_258  # not a multiple of 128: the last D-block is ragged
-CHAIN = 20
 #: elements of the 2.34 GB float32 state of PR 28's gossip-only chains
 #: (16 x 36,547,072; PERF.md section 6)
 CHAIN_STATE_ELEMENTS = 16 * 36_547_072
@@ -155,25 +57,19 @@ def _kernel_program(kernel, n, wire, dim):
     """``(fn, abstract args)`` of one kernel program at ``[n, dim]``, f32
     state, compiled (``interpret=False``): one in-place exchange of the
     flat state (``stream``) or of one leaf ``[n, r, c]`` with its sums
-    (``leaf``; ``dim`` is ``(r, c)``), or a ``CHAIN``-step chain
-    (``fused``)."""
+    (``leaf``; ``dim`` is ``(r, c)``)."""
     f32 = jnp.float32
     if kernel == "leaf":
         return (lambda x, w: leaf_mix(x, w, wire_dtype=wire)), \
             (jax.ShapeDtypeStruct((n,) + dim, f32),
              jax.ShapeDtypeStruct((n, n), f32))
-    x = jax.ShapeDtypeStruct((n, dim), f32)
-    if kernel == "stream":
-        return (lambda x, w: stream_mix(x, w, wire_dtype=wire)), \
-            (x, jax.ShapeDtypeStruct((n, n), f32))
-    stack = jax.ShapeDtypeStruct(
-        (CHAIN, n, n), f32 if wire == "f32" else jnp.bfloat16)
-    return (lambda x, stack: fused_gossip_run(x, stack)), (x, stack)
+    return (lambda x, w: stream_mix(x, w, wire_dtype=wire)), \
+        (jax.ShapeDtypeStruct((n, dim), f32),
+         jax.ShapeDtypeStruct((n, n), f32))
 
 
-KERNEL_CASES = [("fused", n, w, RESNET20_DIM)
-                for n in (16, 256) for w in ("f32", "bf16")] \
-    + [  # the cells' own shapes (cell 1's D is no multiple of 128), an odd N
+KERNEL_CASES = \
+    [  # the cells' own shapes (cell 1's D is no multiple of 128), an odd N
        ("stream", 16, "f32", 36_546_980), ("stream", 16, "bf16", 36_546_980),
        ("stream", 2, "f32", 267_211_008), ("stream", 3, "f32", 100_000)] \
     + [  # both sides of every chunk width up to the crossover: 32 is the
@@ -200,10 +96,9 @@ KERNEL_CASES = [("fused", n, w, RESNET20_DIM)
 
 @pytest.mark.parametrize("kernel,n,wire,dim", KERNEL_CASES)
 def test_pallas_kernels_cross_lower_for_tpu(kernel, n, wire, dim):
-    """The Pallas TPU lowering accepts the fused chain at 16 x 273,258 and
-    256 x 273,258 and the streamed exchange at the cells' shapes and at
-    every N up to its crossover, f32 and bf16 wire — no chip needed, and
-    the next construct it refuses fails here."""
+    """The Pallas TPU lowering accepts the streamed exchange at the cells'
+    shapes and at every N up to its crossover, f32 and bf16 wire — no chip
+    needed, and the next construct it refuses fails here."""
     fn, args = _kernel_program(kernel, n, wire, dim)
     lowered = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
     assert "tpu_custom_call" in lowered.as_text()
@@ -370,13 +265,11 @@ def _compile_all_for_v5e() -> int:
         fn, args = _kernel_program(*case)
         args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
                 for a in args]
-        donate = (0,) if case[0] in ("stream", "leaf") else ()
-        compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
-        if donate:
-            # in place: no product, no second state-sized buffer
-            assert "convolution(" not in compiled.as_text(), case
-            state = 4 * int(np.prod(args[0].shape))
-            assert compiled.memory_analysis().temp_size_in_bytes < state // 8
+        compiled = jax.jit(fn, donate_argnums=(0,)).lower(*args).compile()
+        # in place: no product, no second state-sized buffer
+        assert "convolution(" not in compiled.as_text(), case
+        state = 4 * int(np.prod(args[0].shape))
+        assert compiled.memory_analysis().temp_size_in_bytes < state // 8
         print("COMPILED", *case)
     for packed in (True, False):
         print("STEP", json.dumps(_step_readings(packed, PACK_WORKERS, sharding)))
@@ -476,23 +369,6 @@ def test_grouped_products_compile_for_v5e_at_whole_width_tiles(v5e_child):
         # the grid's few hundred int32 and nothing operand-sized (the
         # weights are 33 MB, a bfloat16 operand 59 MB or more)
         assert reading["temp_bytes"] < 1 << 20, reading
-
-
-def test_kernel_blocks_that_cannot_fit_are_refused_by_name():
-    """A D-block whose resident buffers overrun the 16 MiB scoped VMEM is
-    a GossipKernelResourceError naming the shape — from the kernel at
-    trace time and from make_decen at build time — not a Mosaic dump."""
-    x = jax.ShapeDtypeStruct((256, RESNET20_DIM), jnp.float32)
-    stack = jax.ShapeDtypeStruct((CHAIN, 256, 256), jnp.float32)
-    with pytest.raises(GossipKernelResourceError, match="block_d=8192"):
-        jax.eval_shape(lambda x, s: fused_gossip_run(x, s, block_d=8192),
-                       x, stack)
-    n = 256
-    sched = fixed_schedule(tp.decompose(tp.ring_graph(n), n, seed=0), n,
-                           iterations=2)
-    with pytest.raises(GossipKernelResourceError, match="fused"):
-        make_decen(sched, backend="fused", block_d=8192)
-    make_decen(sched, backend="fused")  # the default block fits
 
 
 if __name__ == "__main__":

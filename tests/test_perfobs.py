@@ -114,9 +114,8 @@ def test_roofline_reproduces_rooflinemd_ceilings_at_north_star():
     assert rep["compute_bound_steps_per_sec"] == pytest.approx(5500, rel=0.05)
     assert rep["hbm_bound_steps_per_sec"] == pytest.approx(2900, rel=0.05)
     assert rep["bound"] == "hbm" and not rep["provisional"]
-    # the committed fused rate (5005.7, r4 live window) sits at ~91% of the
-    # compute ceiling — the Pallas-promotion gate ratio — and ABOVE the
-    # dense HBM ceiling, which is exactly the fused kernel's point
+    # both ratios of a supplied rate: 5005.7 steps/s is ~91% of the compute
+    # ceiling and above the per-step program's HBM ceiling
     assert 0.85 < rep["measured_vs_compute_bound"] < 1.0
     assert rep["measured_vs_ceiling"] > 1.0
     md = render_roofline_markdown(rep)

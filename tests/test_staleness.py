@@ -40,8 +40,7 @@ import numpy as np
 import pytest
 
 from matcha_tpu import topology as tp
-from matcha_tpu.communicator import make_centralized, make_choco, make_decen
-from matcha_tpu.parallel import STREAM_MAX_WORKERS
+from gossip_cases import alive_of, make_comm, sched_of  # beside this file
 from matcha_tpu.schedule import matcha_schedule
 from matcha_tpu.schedule.solvers import (
     solve_activation_probabilities,
@@ -56,36 +55,19 @@ SIZE = tp.graph_size(0)
 SCHED = matcha_schedule(tp.select_graph(0), SIZE, iterations=12, budget=0.5,
                         seed=3)
 
-# `dense` at SIZE = 8 rows is the streamed form of the one-chip exchange;
-# `dense-mxu` is the same backend on a ring wide enough that
-# gossip_mix_dense takes the MXU product (the form cell 2 trains on)
-MXU_SIZE = STREAM_MAX_WORKERS + 8
-MXU_SCHED = matcha_schedule(
-    tp.decompose(tp.ring_graph(MXU_SIZE), MXU_SIZE, seed=0), MXU_SIZE,
-    iterations=12, budget=0.5, seed=3)
-
-BACKENDS = ["gather", "dense", "skip", "fused", "dense-mxu", "choco",
-            "centralized"]
+BACKENDS = ["gather", "dense", "skip", "dense-mxu", "choco", "centralized"]
 
 
 def _sched(backend):
-    return MXU_SCHED if backend == "dense-mxu" else SCHED
+    return sched_of(backend, SCHED)
 
 
 def _make(backend, wire=None):
-    if backend == "choco":
-        return make_choco(SCHED, ratio=0.5, consensus_lr=0.3, wire_dtype=wire)
-    if backend == "centralized":
-        return make_centralized(wire_dtype=wire)
-    return make_decen(_sched(backend), backend=backend.split("-")[0],
-                      wire_dtype=wire)
+    return make_comm(backend, SCHED, wire)
 
 
 def _alive(backend):
-    """Worker 2 dead, at the backend's own worker count."""
-    alive = np.ones(_sched(backend).num_workers, np.float32)
-    alive[2] = 0.0
-    return alive
+    return alive_of(_sched(backend))
 
 
 def _x0(d=21, seed=0, n=SIZE):
@@ -125,8 +107,7 @@ def test_ring_k1_bitwise_matches_overlapped(backend, wire, masked):
 @pytest.mark.parametrize("masked", [False, True], ids=["full", "alive-mask"])
 @pytest.mark.parametrize("k", [2, 3])
 @pytest.mark.parametrize("backend",
-                         ["gather", "dense", "skip", "fused", "dense-mxu",
-                          "choco"])
+                         ["gather", "dense", "skip", "dense-mxu", "choco"])
 def test_kdeep_drain_telescopes_when_thinned(backend, k, masked):
     """local_steps ≥ K: every delta is consumed before the next is issued,
     so the drained K-deep pipeline == the eager chain on the thinned

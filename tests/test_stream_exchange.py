@@ -253,14 +253,14 @@ class _Mesh:
         self.size = size
 
 
-@pytest.mark.parametrize("requested", ["auto", "fused"])
+@pytest.mark.parametrize("requested", ["auto", "dense"])
 @pytest.mark.parametrize("devices", [None, 1, 4],
                          ids=["no-mesh", "one-device", "four-devices"])
 def test_resolve_gossip_backend_table(devices, requested):
     """Explicit: as asked.  ``auto``: ``shard_map`` on several devices,
     ``dense`` on one chip.  The record is the journal's pinned triple and,
-    where the per-step mix is the dense exchange, the form it compiles to;
-    nothing else."""
+    where the backend is ``dense``, asked for by name or not, the form it
+    compiles to; nothing else."""
     sched = _schedule(16)
     single = devices in (None, 1)
     record = resolve_gossip_backend(
@@ -301,7 +301,7 @@ def test_job_files_resolve_to_the_form_the_issue_names(cell):
 
 
 @pytest.mark.parametrize("backend,form", [("auto", "streamed"),
-                                          ("fused", "streamed"),
+                                          ("dense", "streamed"),
                                           ("gather", None)])
 def test_backend_event_names_the_form_that_compiled(tmp_path, backend, form):
     config = TrainConfig(

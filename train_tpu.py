@@ -109,11 +109,11 @@ def _parse(argv=None):
     p.add_argument("--centralized", action="store_true", help="AllReduce baseline")
     p.add_argument("--randomSeed", type=int, default=9001, dest="seed")
     p.add_argument("--backend", default="auto",
-                   help="gossip backend: fused|dense|gather|skip|"
+                   help="gossip backend: dense|gather|skip|"
                         "shard_map|auto (skip = per-matching lax.cond; "
                         "inactive matchings cost nothing, so budget < 1 "
                         "buys real time; gather is a small-N debugging "
-                        "path — ~60x slower than dense/fused at N>=64 and "
+                        "path — ~60x slower than dense at N>=64 and "
                         "warns there; auto = shard_map on several devices, "
                         "dense on one chip, journaled as a `backend` event)")
     p.add_argument("--overlap", default="off", choices=["off", "1step"],
